@@ -10,7 +10,9 @@ from __future__ import annotations
 import sys
 
 
-def main(argv: list[str] | None = None) -> None:
+def main(argv: list[str] | None = None):
+    """Run one workload; returns ``run_workload``'s ``(state, history)``
+    (None for ``--help`` and ``--spawn``, whose ranks own their states)."""
     argv = sys.argv[1:] if argv is None else list(argv)
     from distributed_deep_learning_tpu.workloads import WORKLOADS
 
@@ -27,11 +29,18 @@ def main(argv: list[str] | None = None) -> None:
         # shared, pods launch ranks via the scheduler instead)
         rest = [a for a in rest if a != "--spawn"]
         from distributed_deep_learning_tpu.runtime.launch import launch_local
-        from distributed_deep_learning_tpu.utils.config import parse_args
+        from distributed_deep_learning_tpu.utils.config import (Device,
+                                                                parse_args)
 
-        n = parse_args(rest, workload=name).world_size
+        config = parse_args(rest, workload=name)
+        n = config.world_size
         if n < 2:
             raise SystemExit("--spawn needs -r N with N >= 2")
+        if config.device not in (None, Device.CPU):
+            raise SystemExit(
+                f"--spawn runs its ranks on the CPU (processes cannot "
+                f"share a chip); it cannot honour -d "
+                f"{config.device.value} — drop -d or pass -d cpu")
         for res in launch_local(n, [name, *rest]):
             sys.stdout.write(res.stdout)
         return
@@ -39,7 +48,7 @@ def main(argv: list[str] | None = None) -> None:
     from distributed_deep_learning_tpu.workloads import get_spec, run_workload
 
     spec = get_spec(name)
-    run_workload(spec, parse_args(rest, workload=name))
+    return run_workload(spec, parse_args(rest, workload=name))
 
 
 if __name__ == "__main__":

@@ -2,9 +2,9 @@
 
 The eager image path (PIL decode + native resize, :mod:`.imagefolder` /
 :mod:`.pcb`) delivers ~35 img/s/chip on the CI box while the TPU train
-step consumes ~2,400 (``BENCH_r05.json``) — at ImageNet scale the HOST is
-the binding constraint.  Decode work is also *identical every epoch*: the
-same file decodes to the same pixels.  So it is done ONCE, offline: a
+step consumes ~2,400 (the builders' one 2026-07-31 sample) — at ImageNet
+scale the HOST is the binding constraint.  Decode work is also *identical
+every epoch*: the same file decodes to the same pixels.  So it is done ONCE, offline: a
 packing pass walks any dataset exposing the ``ArrayDataset`` contract
 (``__len__``/``batch``) — images, tabular windows, token rows — through
 its own (threaded) decode machinery and writes one flat binary artifact;

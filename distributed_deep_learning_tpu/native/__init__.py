@@ -1,15 +1,19 @@
 """ctypes bindings for the native host-data library, with NumPy fallbacks.
 
-Build-on-first-import: compiles ``ddl_native.cpp`` with g++ into this
-directory the first time it's needed (a few hundred ms, cached thereafter).
-Every binding has a NumPy fallback with identical semantics, selected when
-compilation is impossible or ``DDL_DISABLE_NATIVE=1`` — the test suite runs
-both paths against each other.
+Build-on-first-use: compiles ``ddl_native.cpp`` with g++ into this
+directory the first time it's needed (a few hundred ms).  The binary is
+named by a hash of the source, so a library built from other source — or
+copied in from elsewhere with its mtime reset — is never loaded: the build
+runs whenever the file for THIS source is absent.  Every binding has a
+NumPy fallback with identical semantics, selected when compilation is
+impossible or ``DDL_DISABLE_NATIVE=1`` — the test suite runs both paths
+against each other, and :func:`status` says which one a process took.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -18,11 +22,11 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "ddl_native.cpp")
-_LIB = os.path.join(_DIR, "libddl_native.so")
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _tried = False
+_status = "not-loaded"
 
 _i64 = ctypes.c_int64
 _i32 = ctypes.c_int32
@@ -30,19 +34,37 @@ _f32p = np.ctypeslib.ndpointer(np.float32, flags="C_CONTIGUOUS")
 _i64p = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")
 
 
-def _build() -> bool:
+def _lib_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_DIR, f"libddl_native_{digest}.so")
+
+
+def _build(lib_path: str) -> str | None:
+    """Compile the source to ``lib_path``; returns None on success, else
+    a one-line reason.  Built under a per-process temporary name and
+    renamed, so concurrent first uses never load a half-written file."""
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
-           _SRC, "-o", _LIB]
+           _SRC, "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        return True
-    except (subprocess.SubprocessError, FileNotFoundError):
-        return False
+        os.replace(tmp, lib_path)
+        return None
+    except FileNotFoundError:
+        return "g++ not found"
+    except subprocess.CalledProcessError as exc:
+        return f"g++ exited {exc.returncode}"
+    except (subprocess.SubprocessError, OSError) as exc:
+        return type(exc).__name__
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
 
 
 def get_lib() -> ctypes.CDLL | None:
     """The loaded library, building it if necessary; None ⇒ use fallbacks."""
-    global _lib, _tried
+    global _lib, _tried, _status
     if _lib is not None or _tried:
         return _lib
     with _lock:
@@ -50,14 +72,18 @@ def get_lib() -> ctypes.CDLL | None:
             return _lib
         _tried = True
         if os.environ.get("DDL_DISABLE_NATIVE") == "1":
+            _status = "numpy-fallback (DDL_DISABLE_NATIVE=1)"
             return None
-        if not os.path.exists(_LIB) or (
-                os.path.getmtime(_LIB) < os.path.getmtime(_SRC)):
-            if not _build():
+        lib_path = _lib_path()
+        if not os.path.exists(lib_path):
+            reason = _build(lib_path)
+            if reason is not None:
+                _status = f"numpy-fallback ({reason})"
                 return None
         try:
-            lib = ctypes.CDLL(_LIB)
-        except OSError:
+            lib = ctypes.CDLL(lib_path)
+        except OSError as exc:
+            _status = f"numpy-fallback (load failed: {exc})"
             return None
         lib.ddl_gather_rows.argtypes = [_f32p, _i64, _i64p, _i64, _f32p]
         lib.ddl_gather_rows.restype = None
@@ -76,11 +102,20 @@ def get_lib() -> ctypes.CDLL | None:
             _f32p]
         lib.ddl_crop_resize_bilinear.restype = None
         _lib = lib
+        _status = "built"
         return _lib
 
 
 def available() -> bool:
     return get_lib() is not None
+
+
+def status() -> str:
+    """``"built"`` or ``"numpy-fallback (<reason>)"`` — which path this
+    process's host-data ops take (loads or builds the library if no call
+    has yet)."""
+    get_lib()
+    return _status
 
 
 # ---------------------------------------------------------------------------

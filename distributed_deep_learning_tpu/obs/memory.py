@@ -261,7 +261,6 @@ def buffer_attribution(memory: dict[str, int], *, state: Any = None,
         "breakdown": breakdown,
         "total_bytes": sum(v for v in breakdown.values()
                            if isinstance(v, int)),
-        "missing_fields": list(memory.get("memory_fields_missing", ())),
         "top_leaves": top_leaves(state, top_n) if state is not None else [],
         "donation": donation_audit(memory, donated_bytes),
     }

@@ -113,7 +113,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, kv_ref, o_ref, lse_ref, *,
         if causal:
             s = _causal_mask(s, q_off, i * block_k, bq, block_k, window)
         if kv_ref is not None:
-            valid = kv_ref[0, :, pl.ds(i * block_k, block_k)]  # (1, bk) f32
+            valid = kv_ref[0, i]                     # (1, bk) f32
             s = jnp.where(valid > 0, s, NEG_INF)
         blk_max = jnp.max(s, axis=-1, keepdims=True)
         new_m = jnp.maximum(m, blk_max)
@@ -156,6 +156,17 @@ def _fit_block(length: int, requested: int) -> int:
                if length % b == 0)
 
 
+def _mask_blocks(kvalid, block_k):
+    """Padding mask ``(B, 1, Tk)`` → ``(B, Tk // block_k, 1, block_k)``: one
+    key block per leading index, which a kernel picks with an untiled
+    index.  Slicing the mask along its lane dimension instead must start
+    at a multiple of 128, which the chip's compiler cannot prove for
+    blocks of 64 or 96 keys (any T below 128 or without a 128-multiple
+    divisor) and refuses — invisible in interpret mode."""
+    B, _, Tk = kvalid.shape
+    return kvalid.reshape(B, Tk // block_k, 1, block_k)
+
+
 def _flash_fwd(q, k, v, kvalid, sm_scale, causal, block_q, block_k,
                interpret, window=None):
     BH, Tq, D = q.shape
@@ -183,12 +194,13 @@ def _flash_fwd(q, k, v, kvalid, sm_scale, causal, block_q, block_k,
     ]
     args = [q, k, v]
     if kvalid is not None:
-        # (B, 1, Tk): the trailing size-1 sublane dim keeps the block
-        # Mosaic-legal (a (1, Tk) block over 2D (B, Tk) is not)
+        # every key block of this batch row; the size-1 sublane dim keeps
+        # the block Mosaic-legal (a (1, bk) block over a 2D mask is not)
         in_specs.append(pl.BlockSpec(
-            (1, 1, Tk), lambda b, qi: (b // valid_group, 0, 0),
+            (1, Tk // block_k, 1, block_k),
+            lambda b, qi: (b // valid_group, 0, 0, 0),
             memory_space=pltpu.VMEM))
-        args.append(kvalid)
+        args.append(_mask_blocks(kvalid, block_k))
     out, lse = pl.pallas_call(
         kernel,
         grid=(BH, Tq // block_q),
@@ -227,7 +239,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, kv_ref,
         if causal:
             s = _causal_mask(s, q_off, i * block_k, bq, block_k, window)
         if kv_ref is not None:
-            valid = kv_ref[0, :, pl.ds(i * block_k, block_k)]  # (1, bk)
+            valid = kv_ref[0, i]                     # (1, bk)
             s = jnp.where(valid > 0, s, NEG_INF)
         p = jnp.exp(s - lse)                         # (bq, bk) f32
         dp = _dot(do, v, ((1,), (1,)))               # (bq, bk) f32
@@ -252,7 +264,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, kv_ref,
     v = v_ref[0]
     bk, d = k.shape
     k_off = pl.program_id(1) * bk
-    valid = kv_ref[0, :, pl.ds(k_off, bk)] if kv_ref is not None else None
+    valid = kv_ref[0, 0] if kv_ref is not None else None     # (1, bk)
 
     def body(i, carry):
         dk, dv = carry
@@ -315,8 +327,14 @@ def _flash_bwd(q, k, v, kvalid, out, lse, g, sm_scale, causal, block_q,
                           memory_space=pltpu.VMEM)
     lsefull = pl.BlockSpec((1, Tq, 1), lambda b, i: (b, 0, 0),
                            memory_space=pltpu.VMEM)
-    kvfull = pl.BlockSpec((1, 1, Tk), lambda b, i: (b // valid_group, 0, 0),
+    kvfull = pl.BlockSpec((1, Tk // block_k, 1, block_k),
+                          lambda b, i: (b // valid_group, 0, 0, 0),
                           memory_space=pltpu.VMEM)
+    kvblk = pl.BlockSpec((1, 1, 1, block_k),
+                         lambda b, i: (b // valid_group, i, 0, 0),
+                         memory_space=pltpu.VMEM)
+    if kvalid is not None:
+        kvalid = _mask_blocks(kvalid, block_k)
 
     # ---- dQ: grid over query blocks -------------------------------------
     dq_kernel = functools.partial(
@@ -349,7 +367,7 @@ def _flash_bwd(q, k, v, kvalid, out, lse, g, sm_scale, causal, block_q,
     dkv_specs = [qfull, kblk_shared, kblk_shared, qfull, lsefull, lsefull]
     dkv_args = [q, k, v, g, lse, delta]
     if kvalid is not None:
-        dkv_specs.append(kvfull)
+        dkv_specs.append(kvblk)
         dkv_args.append(kvalid)
     dk, dv = pl.pallas_call(
         dkv_kernel,
@@ -533,8 +551,43 @@ def make_attention_fn(causal: bool = False, **kw):
         call_kw = dict(kw)
         if window is not None:  # call-time window wins over the maker's
             call_kw["window"] = window
-        return flash_attention(q, k, v, causal=causal or forced_causal,
-                               key_valid=key_valid, **call_kw).astype(dtype)
+
+        def kernel(q, k, v, key_valid=None):
+            return flash_attention(q, k, v, causal=causal or forced_causal,
+                                   key_valid=key_valid, **call_kw)
+
+        return _per_shard(kernel, q, k, v, key_valid).astype(dtype)
 
     attn.supports_gqa = True
     return attn
+
+
+def _per_shard(kernel, q, k, v, key_valid):
+    """Run ``kernel(q, k, v[, key_valid])`` once per shard of the step's mesh.
+
+    XLA cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned"), so a step whose batch or heads are sharded
+    must hand each device its own slice through ``shard_map``: attention is
+    independent per (batch row, head), so batch rides the data-parallel
+    axes and heads the ``model`` axis, with no collective.  The mesh is the
+    one the step builder traced under (``jax.sharding.use_abstract_mesh`` in
+    :func:`..train.step.make_step_fns`); axes already manual (inside an
+    enclosing ``shard_map``) or of size 1 need nothing, and with none left
+    the kernel is called directly — one device, or no mesh at all.
+    """
+    from jax.sharding import AxisType, PartitionSpec as P
+
+    from distributed_deep_learning_tpu.data.loader import BATCH_AXES
+
+    mesh = jax.sharding.get_abstract_mesh()
+    split = {name for name, kind in zip(mesh.axis_names, mesh.axis_types)
+             if kind == AxisType.Auto and mesh.shape[name] > 1}
+    batch = tuple(a for a in BATCH_AXES if a in split) or None
+    heads = "model" if "model" in split else None
+    args = (q, k, v) if key_valid is None else (q, k, v, key_valid)
+    if batch is None and heads is None:
+        return kernel(*args)
+    qkv = P(batch, None, heads, None)
+    specs = (qkv, qkv, qkv) + (() if key_valid is None else (P(batch, None),))
+    return jax.shard_map(kernel, in_specs=specs, out_specs=qkv,
+                         check_vma=False)(*args)

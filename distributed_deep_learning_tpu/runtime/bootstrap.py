@@ -31,14 +31,6 @@ def initialize_runtime(config: Config | None = None) -> DistributedEnv:
     runs (no-op).  Must run before the first device access on multi-host.
     """
     global _INITIALIZED
-    if os.environ.get("DDL_FORCE_CPU") == "1":
-        # spawned local ranks (runtime/launch.py) must not race for the
-        # accelerator; a site plugin may ignore JAX_PLATFORMS, so pin via
-        # jax.config (safe pre-backend-init, matching tests/conftest.py)
-        try:
-            jax.config.update("jax_platforms", "cpu")
-        except Exception:
-            pass
     dist = config.distributed if config is not None else DistributedEnv.from_environ()
     # Only latch once jax.distributed has actually been initialised — an
     # early single-process call must not turn a later multi-host call into
@@ -57,6 +49,57 @@ def initialize_runtime(config: Config | None = None) -> DistributedEnv:
     jax.distributed.initialize(**kwargs)
     _INITIALIZED = True
     return _effective_env(dist)
+
+
+#: the checkout root (the directory holding the package)
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def enable_compile_cache() -> str | None:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR`` places it from outside: when set, JAX
+    reads it itself and no code here names a directory.  Otherwise the
+    cache lives at ``<checkout>/.jax_cache`` — a fixed path, because the
+    path is part of the cache key and a directory that moves never hits —
+    unless the default backend is the CPU, which gets none (returns None):
+    its compiles are cheap, and XLA:CPU logs two screens of machine-feature
+    errors for every executable it loads back.  Call before the first
+    compile; safe to call more than once.
+    """
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        if jax.default_backend() == "cpu":
+            return None
+        cache_dir = os.path.join(_CHECKOUT, ".jax_cache")
+        os.makedirs(cache_dir, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    return cache_dir
+
+
+def describe_devices(devices=None) -> dict:
+    """``{"platform", "device_kind", "device_count"}`` of `devices` (JAX's
+    default backend when None) — the three keys every run log and every
+    script's JSON line carries, so no number is read without its device."""
+    devices = jax.devices() if devices is None else devices
+    return {"platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "device_count": len(devices)}
+
+
+def require_devices(n: int) -> list:
+    """JAX's default-backend devices, or a ``SystemExit`` naming the CPU
+    rehearsal recipe when there are fewer than `n` — a script that needs
+    a mesh says so instead of choosing a platform for its user."""
+    devices = jax.devices()
+    if len(devices) < n:
+        raise SystemExit(
+            f"needs {n} devices; the default backend "
+            f"({devices[0].platform}, {devices[0].device_kind}) has "
+            f"{len(devices)}. Rehearse on the CPU with: JAX_PLATFORMS=cpu "
+            f"XLA_FLAGS=--xla_force_host_platform_device_count={n}")
+    return devices
 
 
 def _effective_env(dist: DistributedEnv) -> DistributedEnv:

@@ -55,10 +55,7 @@ def launch_local(n_processes: int, argv: Sequence[str], *,
             "MASTER_PORT": str(port),
         })
         if force_cpu:
-            # env var alone is not enough when a site plugin pins the
-            # platform; bootstrap honours DDL_FORCE_CPU via jax.config
             env["JAX_PLATFORMS"] = "cpu"
-            env["DDL_FORCE_CPU"] = "1"
             # pin the child's own device count (a pytest parent's forced
             # 8-device flag must not leak into every rank)
             flags = re.sub(r"--xla_force_host_platform_device_count=\d+",

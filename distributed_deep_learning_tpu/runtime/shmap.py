@@ -1,29 +1,7 @@
-"""One ``shard_map`` symbol across the JAX versions this repo meets.
-
-JAX >= 0.7 exports ``jax.shard_map`` with the replication check spelled
-``check_vma``; older releases export it from ``jax.experimental`` and
-call the same knob ``check_rep``.  Every shard_map user in this package
-imports from here so the version split lives in exactly one place.
-"""
+"""``shard_map`` for every user in this package (``jax.shard_map``)."""
 
 from __future__ import annotations
 
-import inspect
-from functools import wraps
-
 import jax
 
-try:  # JAX >= 0.7 exports shard_map at top level
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map  # type: ignore
-
-if "check_vma" in inspect.signature(_shard_map).parameters:
-    shard_map = _shard_map
-else:  # pre-0.7 spelling of the same knob
-
-    @wraps(_shard_map)
-    def shard_map(*args, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map(*args, **kwargs)
+shard_map = jax.shard_map

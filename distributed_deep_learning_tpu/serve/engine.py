@@ -36,6 +36,7 @@ from typing import Callable, Iterable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from distributed_deep_learning_tpu.models.transformer import (
     CausalLM, cached_apply, make_decode_model, sample_tokens,
@@ -754,9 +755,7 @@ class PagedEngine:
             donate = jax.default_backend() != "cpu"
         dk = {"donate_argnums": (1,)} if donate else {}
         ck = {"donate_argnums": (0,)} if donate else {}
-        self.pools = paged.build_pools(self.lm, num_blocks + 1, bs,
-                                       self.padded_len,
-                                       kv_dtype=self.kv_dtype)
+        self.pools = self._new_pools(self.lm)
         self._chunk_prog = CountingJit(self._chunk_impl, **dk)
         self._decode = CountingJit(self._decode_impl, **dk)
         self._copy = CountingJit(self._copy_impl, **ck)
@@ -803,10 +802,7 @@ class PagedEngine:
             # the draft pool INHERITS kv_dtype: speculation gathers and
             # scatters through the same shims, so a mixed-precision pair
             # would silently double the draft's footprint
-            self.draft_pools = paged.build_pools(self.draft_lm,
-                                                 num_blocks + 1, bs,
-                                                 self.padded_len,
-                                                 kv_dtype=self.kv_dtype)
+            self.draft_pools = self._new_pools(self.draft_lm)
             self._draft = CountingJit(self._draft_impl, **dk)
             self._verify = CountingJit(self._verify_impl, **dk)
             self._draft_chunk = CountingJit(self._draft_chunk_impl, **dk)
@@ -821,6 +817,20 @@ class PagedEngine:
         self._spec_enabled = draft_layers is not None
         self._base_chunks_per_tick = self.chunks_per_tick
         self._canary: Optional[_CanaryState] = None
+
+    def _new_pools(self, lm):
+        """Zeroed block pools for `lm`, placed where the weights live.
+        Weights that come out of a training run are committed to its mesh,
+        and so is whatever a jit computes from them: pools left as plain
+        arrays would change type on their first trip through a program
+        and make it trace a second time."""
+        pools = paged.build_pools(lm, self.num_blocks + 1, self.block_size,
+                                  self.padded_len, kv_dtype=self.kv_dtype)
+        sharding = getattr(jax.tree.leaves(self.params)[0], "sharding", None)
+        if isinstance(sharding, NamedSharding):
+            pools = jax.device_put(
+                pools, NamedSharding(sharding.mesh, PartitionSpec()))
+        return pools
 
     # --- quantization shims (identity at full precision) ------------------
     def _wp(self, params):
@@ -1053,13 +1063,9 @@ class PagedEngine:
         self.manager = paged.BlockManager(self.num_blocks, self.block_size,
                                           self.max_slots,
                                           self.blocks_per_slot)
-        self.pools = paged.build_pools(self.lm, self.num_blocks + 1,
-                                       self.block_size, self.padded_len,
-                                       kv_dtype=self.kv_dtype)
+        self.pools = self._new_pools(self.lm)
         if self.draft_layers is not None:
-            self.draft_pools = paged.build_pools(
-                self.draft_lm, self.num_blocks + 1, self.block_size,
-                self.padded_len, kv_dtype=self.kv_dtype)
+            self.draft_pools = self._new_pools(self.draft_lm)
         self.restarts += 1
 
     def swap_params(self, new_params) -> None:

@@ -27,7 +27,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from distributed_deep_learning_tpu.data.loader import BATCH_AXES
 from distributed_deep_learning_tpu.train.objectives import prediction_metrics
 from distributed_deep_learning_tpu.train.state import TrainState
-from distributed_deep_learning_tpu.train.step import _state_sharding
+from distributed_deep_learning_tpu.train.step import (_state_sharding,
+                                                      under_mesh)
 
 
 def make_accum_step_fns(mesh: Mesh, loss_fn: Callable, *,
@@ -54,6 +55,7 @@ def make_accum_step_fns(mesh: Mesh, loss_fn: Callable, *,
         return (x.reshape(accum_steps, m, *x.shape[1:]),
                 y.reshape(accum_steps, m, *y.shape[1:]))
 
+    @under_mesh(mesh)
     def train_step(state: TrainState, x, y):
         xs, ys = _micro(x, y)
         micro_idx = jnp.arange(accum_steps)
@@ -86,6 +88,7 @@ def make_accum_step_fns(mesh: Mesh, loss_fn: Callable, *,
         new_state = state.apply_gradients(mean_grads, model_state=final_ms)
         return new_state, summed
 
+    @under_mesh(mesh)
     def eval_step(state: TrainState, x, y):
         pred, _, _ = state.apply_fn(state.params, state.model_state, x,
                                     train=False)

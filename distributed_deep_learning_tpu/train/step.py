@@ -14,6 +14,7 @@ consequence of sharding: replicated-out params + sharded-in batch ⇒ psum.
 
 from __future__ import annotations
 
+import functools
 from typing import Callable
 
 import jax
@@ -35,6 +36,19 @@ def _state_sharding(mesh: Mesh, state_spec):
     if isinstance(state_spec, P):
         return NamedSharding(mesh, state_spec)
     return jax.tree.map(lambda s: NamedSharding(mesh, s), state_spec)
+
+
+def under_mesh(mesh: Mesh):
+    """Decorator for a step body: trace it with `mesh` as the ambient one,
+    so code deep in the model can see how its inputs are split — the flash
+    kernel must run per shard (``ops.attention_pallas._per_shard``)."""
+    def decorate(step):
+        @functools.wraps(step)
+        def traced(*args):
+            with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+                return step(*args)
+        return traced
+    return decorate
 
 
 def _remat_policy(name: str):
@@ -87,6 +101,7 @@ def make_step_fns(mesh: Mesh, loss_fn: LossFn, *,
 
     _metrics = prediction_metrics
 
+    @under_mesh(mesh)
     def train_step(state: TrainState, x, y):
         rngs = state.step_rngs()
 
@@ -115,6 +130,7 @@ def make_step_fns(mesh: Mesh, loss_fn: LossFn, *,
             return guarded_update(state, grads, new_ms, metrics, sentinel)
         return state.apply_gradients(grads, model_state=new_ms), metrics
 
+    @under_mesh(mesh)
     def eval_step(state: TrainState, x, y):
         pred, _, _ = state.apply_fn(state.params, state.model_state, x,
                                     train=False)

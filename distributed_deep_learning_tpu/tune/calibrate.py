@@ -205,13 +205,10 @@ def run_calibration(spec, config: Config, *, devices=None, dataset=None,
         entry["temp_size_in_bytes"] = temp
         entry["argument_size_in_bytes"] = int(
             memory.get("argument_size_in_bytes", 0))
-        entry["memory_fields_missing"] = list(
-            memory.get("memory_fields_missing", ()))
         entry["steps_per_sec"] = float(result.steps_per_sec)
         analytic = estimate_memory(plan, geom, config.batch_size)
         entry["analytic_act_bytes"] = analytic.activations_bytes
-        if temp > 0 and not entry["memory_fields_missing"] \
-                and plan.zero == "none":
+        if temp > 0 and plan.zero == "none":
             frac = fit_act_fraction(temp, geom, config.batch_size, plan)
             entry["fitted_act_fraction"] = round(frac, 6)
             act_fraction[corner] = frac

@@ -435,14 +435,7 @@ class Checkpointer:
     def _reload_manager(self) -> None:
         """Make the orbax manager re-scan the directory after an external
         change (quarantine rename)."""
-        try:
-            self._mgr.reload()
-        except AttributeError:  # older orbax: rebuild the manager
-            keep = self._mgr._options.max_to_keep  # pragma: no cover
-            self._mgr.close()
-            self._mgr = ocp.CheckpointManager(
-                self._dir, options=ocp.CheckpointManagerOptions(
-                    max_to_keep=keep, create=True))
+        self._mgr.reload()
 
     def wait_until_finished(self) -> None:
         self._mgr.wait_until_finished()

@@ -121,7 +121,8 @@ class Config:
     size            -s    hidden width / bn_size
     epochs          -e    training epochs
     batch_size      -b    global batch size
-    device          -d    cpu | gpu (we add tpu; gpu aliases tpu)
+    device          -d    cpu | gpu (we add tpu; gpu aliases tpu);
+                          unset = JAX's default backend
     num_workers     -w    host-side data-loader worker threads
     mode            -m    sequential | model | pipeline | data
     microbatch      -p    pipeline microbatch SIZE (not count) —
@@ -135,7 +136,7 @@ class Config:
     size: int = 38
     epochs: int = 10    # reference default (CNN/main.py:51)
     batch_size: int = 32  # reference default (CNN/main.py:52)
-    device: Device = Device.TPU
+    device: Device | None = None    # None: JAX's default backend
     num_workers: int = 0
     mode: Mode = Mode.SEQUENTIAL
     microbatch: int | None = 2  # reference -p default; used only in pipeline mode
@@ -337,7 +338,10 @@ def build_parser(workload: str = "") -> argparse.ArgumentParser:
     p.add_argument("-b", "--batch", type=int, default=32,
                    help="global batch size")
     p.add_argument("-d", "--device", choices=[d.value for d in Device],
-                   default="tpu")
+                   default=None,
+                   help="cpu, or tpu (gpu aliases tpu): an explicit tpu "
+                        "is an error when JAX's default backend is not a "
+                        "TPU (default: whatever JAX's default backend is)")
     p.add_argument("-w", "--nworkers", type=int, default=0,
                    help="host-side data loading workers")
     p.add_argument("-m", "--mode", choices=[m.value for m in Mode],
@@ -1144,7 +1148,7 @@ def parse_args(argv: Sequence[str] | None = None, workload: str = "",
         size=args.size,
         epochs=args.epochs,
         batch_size=args.batch,
-        device=Device(args.device),
+        device=Device(args.device) if args.device else None,
         num_workers=args.nworkers,
         mode=Mode(args.mode),
         microbatch=args.pipeline,

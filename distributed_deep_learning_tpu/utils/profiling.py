@@ -93,23 +93,11 @@ _MEMORY_FIELDS = (
 )
 
 
-#: consumers index these two unconditionally (the tune/ calibration
-#: harness, the donation audit) — backends that omit them get 0 plus a
-#: ``memory_fields_missing`` marker instead of pushing KeyErrors
-#: downstream
-_REQUIRED_MEMORY_FIELDS = ("temp_size_in_bytes", "alias_size_in_bytes")
-
-
 def normalize_memory_analysis(stats: Any) -> dict[str, int]:
     """``Compiled.memory_analysis()`` output → dict of its stable integer
     fields, ``{}`` when the backend reports nothing at all.
-
-    Backends that report *some* fields but omit ``temp_size_in_bytes`` /
-    ``alias_size_in_bytes`` (older PJRT plugins) get those filled with 0
-    and listed under ``memory_fields_missing``, so consumers can both
-    index safely and tell "measured zero" from "not reported".
-    ``generated_code_size_in_bytes`` rides along whenever the backend
-    provides it (program size is part of the device footprint)."""
+    ``generated_code_size_in_bytes`` rides along (program size is part of
+    the device footprint)."""
     if stats is None:
         return {}
     out: dict[str, int] = {}
@@ -117,13 +105,6 @@ def normalize_memory_analysis(stats: Any) -> dict[str, int]:
         value = getattr(stats, field, None)
         if isinstance(value, int):
             out[field] = value
-    if not out:                 # nothing reported: keep the {} contract
-        return {}
-    missing = [f for f in _REQUIRED_MEMORY_FIELDS if f not in out]
-    if missing:
-        for field in missing:
-            out[field] = 0
-        out["memory_fields_missing"] = missing  # type: ignore[assignment]
     return out
 
 

@@ -38,8 +38,9 @@ from distributed_deep_learning_tpu.data.loader import make_loaders
 from distributed_deep_learning_tpu.data.splits import train_val_test_split
 from distributed_deep_learning_tpu.parallel.partition import validate_assignment
 from distributed_deep_learning_tpu.parallel.staging import StagedModel
-from distributed_deep_learning_tpu.runtime.bootstrap import (initialize_runtime,
-                                                             is_coordinator)
+from distributed_deep_learning_tpu.runtime.bootstrap import (
+    describe_devices, enable_compile_cache, initialize_runtime,
+    is_coordinator)
 from distributed_deep_learning_tpu.runtime.mesh import build_mesh
 from distributed_deep_learning_tpu.train.loop import EpochResult, fit
 from distributed_deep_learning_tpu.train.objectives import prediction_metrics
@@ -167,13 +168,21 @@ def example_from_dataset(config: Config, dataset) -> jnp.ndarray:
 
 
 def _devices(config: Config) -> list[jax.Device]:
-    """Honour ``-d cpu`` even when an accelerator is present."""
+    """The devices ``-d`` names: ``cpu`` even when an accelerator is
+    present, JAX's default backend when the flag is unset — and an
+    explicit ``tpu`` (``gpu`` aliases it) only where that default backend
+    IS a TPU, so a run that asked for the chip never trains on the host
+    and exits 0."""
     if config.device is Device.CPU:
-        try:
-            return jax.devices("cpu")
-        except RuntimeError:
-            pass
-    return jax.devices()
+        return jax.devices("cpu")
+    devices = jax.devices()
+    if config.device is not None and devices[0].platform != "tpu":
+        raise ValueError(
+            f"-d {config.device.value} asked for a TPU but JAX's default "
+            f"backend is {devices[0].platform!r} "
+            f"({devices[0].device_kind}); drop -d to run on the default "
+            "backend, or pass -d cpu")
+    return devices
 
 
 # ---------------------------------------------------------------------------
@@ -819,9 +828,14 @@ def run_workload(spec: WorkloadSpec, config: Config
                  ) -> tuple[Any, list[EpochResult]]:
     """Train `spec` under `config`; returns (final state, phase history)."""
     initialize_runtime(config)
+    enable_compile_cache()
     devices = _devices(config)
     logger = PhaseLogger(verbose=is_coordinator(),
                          jsonl_path=config.metrics_file)
+    dev = describe_devices(devices)
+    logger.info(f"devices: platform={dev['platform']} "
+                f"device_kind={dev['device_kind']!r} "
+                f"count={dev['device_count']}")
     telemetry = _maybe_telemetry(config)
     if (config.generate_tokens or config.serve) and spec.post_train is None:
         # rejected, not silently dropped (same principle as staged-mode

@@ -488,16 +488,23 @@ _GENERATE_PROMPT_LEN = 8
 
 
 def _gpt_pre_check(config: Config, dataset) -> None:
-    """Reject an impossible ``--generate N`` BEFORE training: generate()
-    checks prompt + N <= max_len itself, but only after the expensive part
-    has finished (ADVICE r3).  Staged/pipelined modes are exempt —
-    :func:`_gpt_generate` skips generation there with a notice, so the
-    length can never be exercised and a pre-train error would reject runs
-    that previously completed."""
+    """Reject, BEFORE training, what the post-train hooks could only
+    refuse after the expensive part has finished (ADVICE r3): an explicit
+    ``--serve`` under a staged/pipelined mode (the engines need the
+    whole-model parameter tree) and an impossible ``--generate N``
+    (generate() checks prompt + N <= max_len itself, but too late).
+    ``--generate`` alone stays exempt under staged/pipelined modes —
+    :func:`_gpt_generate` skips there with a notice, so the length can
+    never be exercised."""
     from distributed_deep_learning_tpu.utils.config import Mode
 
-    if not config.generate_tokens or config.mode in (Mode.MODEL,
-                                                     Mode.PIPELINE):
+    staged = config.mode in (Mode.MODEL, Mode.PIPELINE)
+    if config.serve and staged:
+        raise ValueError(
+            f"--serve needs the whole-model parameter tree; -m "
+            f"{config.mode.value} trains per-stage parameters (use -m "
+            "data or sequential)")
+    if not config.generate_tokens or staged:
         return
     max_len = max(dataset.features.shape[1], 8)  # mirrors _gpt_model
     prompt = min(_GENERATE_PROMPT_LEN, dataset.features.shape[1])
@@ -582,9 +589,10 @@ def _gpt_serve(config: Config, state, logger, dataset) -> None:
     if isinstance(params, dict) and "params" in params:
         params = params["params"]
     if not isinstance(params, dict) or "embed" not in params:
-        logger.info("serve skipped: --serve needs the whole-model "
-                    "parameter tree (-m data or sequential)")
-        return
+        # _gpt_pre_check rejects the staged modes before training; an
+        # explicit --serve that cannot serve is an error, never a notice
+        raise ValueError("--serve needs the whole-model parameter tree "
+                         "(-m data or sequential)")
     model = _gpt_model(config, dataset)
     seq = dataset.features.shape[1]
     # prompt + budget must fit the slot capacity (the model's max_len,
@@ -642,12 +650,8 @@ def _gpt_serve_paged(config: Config, model, params, logger, dataset,
                     "speculation disabled")
         draft = None
     block = min(config.kv_block_size, model.max_len)
-    try:
-        cap = paged_max_len(model.max_len, block, draft is not None,
-                            config.spec_k)
-    except ValueError as exc:
-        logger.info(f"serve: paged engine skipped ({exc})")
-        return
+    cap = paged_max_len(model.max_len, block, draft is not None,
+                        config.spec_k)
     p_hi = max(2, min(p_hi, cap - 1))
     new_hi = max(1, min(new_hi, cap - p_hi))
     trace = make_trace(max(2 * config.max_slots, 8),
@@ -692,8 +696,9 @@ def _gpt_serve_paged(config: Config, model, params, logger, dataset,
         if s is None:
             return
     pg, sp, slo = s["paged"], s["spec"], s["slo"]
-    line = (f"serve(paged): {s['requests']} requests, "
-            f"{s['generated_tokens']} tokens at "
+    line = (f"serve(paged): {s['requests']} requests "
+            f"({len(out['results'])} completed, {len(out['errors'])} "
+            f"errors), {s['generated_tokens']} tokens at "
             f"{s['tokens_per_sec']:.1f} tok/s, prefix hit "
             f"{pg['prefix_hit_rate']:.3f}, cow {pg['cow_copies']}, "
             f"compiles chunk={s['chunk_compiles']} "

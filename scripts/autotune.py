@@ -27,7 +27,6 @@ import sys
 def _script_env() -> None:
     sys.path.insert(
         0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
 def main(argv=None) -> int:
@@ -75,8 +74,11 @@ def main(argv=None) -> int:
         zero_options=("none", "fsdp"), compress_options=("none",),
         grad_accum_options=(1,))
 
+    from distributed_deep_learning_tpu.runtime.bootstrap import (
+        describe_devices, enable_compile_cache)
     from distributed_deep_learning_tpu.workloads.base import _devices
 
+    enable_compile_cache()
     devices = _devices(config)
     n = len(devices)
 
@@ -96,6 +98,7 @@ def main(argv=None) -> int:
             "workload": args.workload, "dry_run": True, "n_devices": n,
             "n_candidates": len(plans), "n_feasible": len(feasible),
             "n_pruned_analytic": len(rejected), "budget_bytes": budget,
+            "device": describe_devices(devices),
         }))
         return 0
 
@@ -124,6 +127,7 @@ def main(argv=None) -> int:
                        search=result.record())
     record = result.record()
     record["artifact"] = out
+    record["device"] = describe_devices(devices)
     if args.calibration:
         record["calibration"] = {"path": args.calibration,
                                  "loaded": calibration is not None}

@@ -90,64 +90,50 @@ def main() -> int:
                         "protocol, rebalance fault gauntlet")
     args = p.parse_args()
 
+    # shrink kills 2 of 8 workers; fleet's migrate_drop scenario parks
+    # spilled KV on a second local device; rebalance's pool-elasticity
+    # scenario needs a reassignable third disagg worker.  The forced
+    # count only shapes the CPU backend (it must land before jax
+    # imports); on any other default backend require_devices says what
+    # is missing.
+    need = {"shrink": 8, "fleet": 2, "rebalance": 4}.get(args.scenario, 1)
+    if need > 1:
+        flags = os.environ.get("XLA_FLAGS", "")
+        if "xla_force_host_platform_device_count" not in flags:
+            os.environ["XLA_FLAGS"] = (
+                flags + f" --xla_force_host_platform_device_count={need}"
+            ).strip()
+    from distributed_deep_learning_tpu.runtime.bootstrap import (
+        describe_devices, require_devices)
+
+    require_devices(need)
+
     if args.scenario == "shrink":
         from distributed_deep_learning_tpu.reshard.drill import \
-            run_shrink_drill
-
-        record = run_shrink_drill(seed=args.seed)
-        print(json.dumps(record))
-        return 0 if record["drill_passed"] else 1
-
-    if args.scenario == "fleet":
-        # the migrate_drop scenario needs a second local device to park
-        # spilled KV on; force a small multi-device CPU host if the
-        # caller hasn't picked a topology (must land before jax imports)
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                flags + " --xla_force_host_platform_device_count=2"
-            ).strip()
+            run_shrink_drill as drill
+    elif args.scenario == "fleet":
         from distributed_deep_learning_tpu.utils.chaos import \
-            run_fleet_resilience_drill
-
-        record = run_fleet_resilience_drill(seed=args.seed)
-        print(json.dumps(record))
-        return 0 if record["drill_passed"] else 1
-
-    if args.scenario == "rebalance":
-        # the pool-elasticity scenario needs >= 3 local devices for a
-        # reassignable disagg worker; force a small multi-device CPU
-        # host if the caller hasn't picked a topology (must land before
-        # jax imports)
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                flags + " --xla_force_host_platform_device_count=4"
-            ).strip()
+            run_fleet_resilience_drill as drill
+    elif args.scenario == "rebalance":
         from distributed_deep_learning_tpu.utils.chaos import \
-            run_rebalance_drill
-
-        record = run_rebalance_drill(seed=args.seed)
-        print(json.dumps(record))
-        return 0 if record["drill_passed"] else 1
-
-    if args.scenario == "serve":
+            run_rebalance_drill as drill
+    elif args.scenario == "serve":
         from distributed_deep_learning_tpu.utils.chaos import \
-            run_serve_resilience_drill
+            run_serve_resilience_drill as drill
+    else:
+        from distributed_deep_learning_tpu.utils.chaos import \
+            run_resilience_drill as drill
 
-        record = run_serve_resilience_drill(seed=args.seed)
-        print(json.dumps(record))
-        return 0 if record["drill_passed"] else 1
-
-    from distributed_deep_learning_tpu.utils.chaos import run_resilience_drill
-
-    record = run_resilience_drill(seed=args.seed)
-    ok = record["containment_bit_identical"] and \
-        record["corrupt_restore_fell_back"] and \
-        record["recovered_bit_identical"]
-    record["drill_passed"] = bool(ok)
-    print(json.dumps({"metric": "resilience drill", **record}))
-    return 0 if ok else 1
+    record = drill(seed=args.seed)
+    if args.scenario == "resilience":
+        record = {"metric": "resilience drill", **record,
+                  "drill_passed": bool(
+                      record["containment_bit_identical"] and
+                      record["corrupt_restore_fell_back"] and
+                      record["recovered_bit_identical"])}
+    record["device"] = describe_devices()
+    print(json.dumps(record))
+    return 0 if record["drill_passed"] else 1
 
 
 if __name__ == "__main__":

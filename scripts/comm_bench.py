@@ -36,7 +36,7 @@ import time
 def _script_env() -> None:
     sys.path.insert(
         0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # only shapes the CPU backend, should that be the one JAX picks
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
@@ -87,14 +87,11 @@ def run(rows: int = 512, cols: int = 2048, inner: int = 256,
     from distributed_deep_learning_tpu.train.step import (make_step_fns,
                                                           place_state)
 
-    devices = jax.devices()
+    from distributed_deep_learning_tpu.runtime.bootstrap import (
+        require_devices)
+
+    devices = require_devices(2)
     S = len(devices)
-    if S < 2:
-        raise RuntimeError(
-            "comm_bench needs >= 2 devices to shard anything; run the "
-            "standalone script (it forces an 8-way host CPU mesh) or set "
-            "XLA_FLAGS=--xla_force_host_platform_device_count=8 before "
-            "jax initialises")
     mesh1d = build_mesh({"data": S})
     axis = "data"
     rng = np.random.default_rng(7)
@@ -281,8 +278,13 @@ def main(argv=None) -> int:
     p.add_argument("--parity-steps", type=int, default=3,
                    help="train steps for the loss-parity gate")
     args = p.parse_args(argv)
+    from distributed_deep_learning_tpu.runtime.bootstrap import (
+        describe_devices, enable_compile_cache)
+
+    enable_compile_cache()
     rec = run(rows=args.rows, cols=args.cols, inner=args.inner,
               steps=args.steps, parity_steps=args.parity_steps)
+    rec["device"] = describe_devices()
     print(json.dumps(rec, indent=1))
     return 0
 

@@ -51,7 +51,7 @@ def main(argv=None) -> int:
     args = p.parse_args(argv)
 
     need = args.devices or (args.prefill_workers + args.decode_workers)
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # only shapes the CPU backend, should that be the one JAX picks
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
@@ -60,9 +60,13 @@ def main(argv=None) -> int:
     sys.path.insert(
         0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+    from distributed_deep_learning_tpu.runtime.bootstrap import (
+        describe_devices, enable_compile_cache, require_devices)
     from distributed_deep_learning_tpu.serve.bench import (
         disagg_serving_bench)
 
+    require_devices(max(need, 2))
+    enable_compile_cache()
     rec = disagg_serving_bench(
         seed=args.seed,
         load_kw=(dict(n_requests=args.requests)
@@ -75,6 +79,7 @@ def main(argv=None) -> int:
         prefill_chunk=args.prefill_chunk,
         kv_dtype=args.kv_dtype,
         decode_passes=args.decode_passes)
+    rec["device"] = describe_devices()
     print(json.dumps(rec))
     u, d = rec["unified"], rec["disagg"]
     print(f"disagg {d['tokens_per_sec']:.0f} tok/s vs unified "

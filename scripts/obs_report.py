@@ -39,7 +39,6 @@ import sys
 def _script_env() -> None:
     sys.path.insert(
         0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
 def _fmt_frac(f: float) -> str:

@@ -27,12 +27,11 @@ import time
 
 
 def _script_env() -> None:
-    """Repo import path + CPU jax (packing is host work; never grab a
-    TPU).  main()-only, so importing this module (the tests reuse
-    build_source) has no side effects on the importer's jax state."""
+    """Repo import path (packing is host work: no device is touched).
+    main()-only, so importing this module (the tests reuse build_source)
+    has no side effects on the importer."""
     sys.path.insert(
         0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
 def build_source(args):

@@ -17,15 +17,16 @@ Two modes:
   the same trace.  The record carries ``prefix_hit_rate``,
   ``slo_attainment``, ``spec_acceptance`` and the prefill-FLOPs saving.
 
-    JAX_PLATFORMS=cpu python scripts/serve_bench.py              # v1 A/B
+    python scripts/serve_bench.py                                # v1 A/B
     python scripts/serve_bench.py --paged                        # paged
     python scripts/serve_bench.py --paged --draft 1 --spec-k 4 \
         --kv-block-size 16 --prefill-chunk 32 --slo-ttft-ms 500  # full
     python scripts/serve_bench.py --paged \
         --kv-dtype int8 --weight-dtype int8            # quantized path
 
-Defaults are CPU-CI sized; see PERFORMANCE.md §Serving for recorded
-numbers and the knob trade-offs.
+Runs on JAX's default backend (``JAX_PLATFORMS=cpu`` rehearses on the
+host); the JSON line's ``device`` names the platform, device kind and
+count the numbers came from.  Defaults are CPU-CI sized.
 """
 
 from __future__ import annotations
@@ -39,7 +40,6 @@ import sys
 def _script_env() -> None:
     sys.path.insert(
         0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
 def _latency_line(tag: str, lat: dict) -> None:
@@ -135,6 +135,8 @@ def main(argv=None) -> int:
                         "implies --obs)")
     args = p.parse_args(argv)
 
+    from distributed_deep_learning_tpu.runtime.bootstrap import (
+        describe_devices, enable_compile_cache)
     # parse-time quantization legality: fail HERE with the flag name,
     # not minutes later inside an engine constructor
     from distributed_deep_learning_tpu.serve.quant import SERVE_DTYPES
@@ -151,6 +153,7 @@ def main(argv=None) -> int:
                 "slot table supports bf16 only (the spec-decode draft "
                 "pool inherits --kv-dtype automatically)")
 
+    enable_compile_cache()
     telemetry = None
     if args.obs or args.obs_trace:
         from distributed_deep_learning_tpu.obs import RunTelemetry
@@ -238,6 +241,7 @@ def main(argv=None) -> int:
                   f"(load in Perfetto / chrome://tracing); "
                   f"events -> {args.obs_file}", file=sys.stderr)
 
+    record["device"] = describe_devices()
     out = json.dumps(record)
     print(out)
     if args.out:

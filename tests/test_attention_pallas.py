@@ -495,3 +495,45 @@ def test_adapter_call_time_window_wins_over_maker():
     expected = dot_product_attention(q, k, v, causal=True, window=4)
     np.testing.assert_allclose(np.asarray(got), np.asarray(expected),
                                rtol=2e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("padded", [False, True], ids=["dense", "key_valid"])
+def test_adapter_runs_kernel_per_shard_under_a_step_mesh(padded):
+    """The chip's compiler refuses to partition a Mosaic kernel, so under a
+    step traced with a mesh whose batch / head axes are split the adapter
+    must hand the kernel one shard at a time (shard_map: batch over
+    data x fsdp, heads over model) — same values and gradients as dense,
+    GQA included.  Without an ambient mesh the call stays direct."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from distributed_deep_learning_tpu.models.transformer import (
+        dot_product_attention)
+    from distributed_deep_learning_tpu.runtime.mesh import build_mesh
+
+    mesh = build_mesh({"data": 2, "fsdp": 2, "model": 2})
+    q, _, _ = _qkv(B=4, T=32, H=4, D=16, seed=50)
+    _, k, v = _qkv(B=4, T=32, H=2, D=16, seed=51)       # 2 KV heads: GQA
+    valid = jnp.arange(32)[None, :] < jnp.array([20, 32, 7, 32])[:, None] \
+        if padded else None
+    fn = make_attention_fn(block_q=16, block_k=16)
+
+    def loss(attend, q, k, v):
+        return jnp.sum(attend(q, k, v, causal=True, key_valid=valid) ** 2)
+
+    def step(q, k, v):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return jax.value_and_grad(lambda *a: loss(fn, *a),
+                                      argnums=(0, 1, 2))(q, k, v)
+
+    sh = NamedSharding(mesh, P(("data", "fsdp"), None, "model", None))
+    got = jax.jit(step, in_shardings=(sh, sh, sh))(q, k, v)
+    dense = lambda q, k, v, **kw: dot_product_attention(  # noqa: E731
+        q, jnp.repeat(k, 2, axis=2), jnp.repeat(v, 2, axis=2), **kw)
+    want = jax.value_and_grad(lambda *a: loss(dense, *a),
+                              argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=2e-4, atol=2e-4)
+    assert "shard_map" in str(jax.make_jaxpr(step)(q, k, v))
+    assert "shard_map" not in str(jax.make_jaxpr(
+        lambda *a: loss(fn, *a))(q, k, v))

@@ -1,3 +1,5 @@
+import pytest
+
 from distributed_deep_learning_tpu.utils.config import (
     Config, DistributedEnv, Mode, parse_args, parse_mesh_arg,
 )
@@ -110,3 +112,72 @@ def test_config_immutable_replace():
     cfg = Config()
     cfg2 = cfg.replace(epochs=9)
     assert cfg.epochs != 9 and cfg2.epochs == 9
+
+
+# --- nothing hides the device (-d, --spawn, the devices line) ---------------
+
+def test_device_flag_unset_means_default_backend():
+    from distributed_deep_learning_tpu.utils.config import Device
+
+    assert parse_args([], env={}).device is None
+    assert Config().device is None
+    assert parse_args(["-d", "tpu"], env={}).device is Device.TPU
+
+
+@pytest.mark.parametrize("flag", ["tpu", "gpu"])
+def test_explicit_tpu_without_a_tpu_is_an_error(flag):
+    """`-d tpu` on a box whose default backend is the CPU used to train on
+    the CPU, print the same log grammar and exit 0."""
+    from distributed_deep_learning_tpu.workloads import base
+
+    with pytest.raises(ValueError, match="default backend is 'cpu'"):
+        base._devices(parse_args(["-d", flag], env={}))
+    assert base._devices(parse_args([], env={}))[0].platform == "cpu"
+    assert base._devices(parse_args(["-d", "cpu"], env={}))[0].platform \
+        == "cpu"
+
+
+def test_spawn_refuses_an_explicit_tpu():
+    """--spawn forces its ranks onto the CPU whatever -d says: with -d tpu
+    it refuses rather than pretending (nothing is launched)."""
+    from distributed_deep_learning_tpu.__main__ import main
+
+    with pytest.raises(SystemExit, match="--spawn runs its ranks on the CPU"):
+        main(["mlp", "-r", "2", "-m", "data", "-d", "tpu", "--spawn"])
+
+
+def test_run_logs_platform_kind_and_count(capsys):
+    import jax
+
+    from distributed_deep_learning_tpu.__main__ import main
+
+    _, history = main(["mlp", "-e", "1", "-b", "64"])
+    assert history
+    assert (f"\"devices: platform=cpu device_kind='cpu' "
+            f"count={len(jax.devices())}\"") in capsys.readouterr().out
+
+
+def test_serve_under_a_staged_mode_is_rejected_before_training():
+    """An explicit --serve that cannot serve raises — it used to train to
+    the end and then log "serve skipped"."""
+    from distributed_deep_learning_tpu.workloads import northstar
+
+    cfg = parse_args(["-m", "pipeline", "--serve"], workload="gpt", env={})
+    with pytest.raises(ValueError, match="--serve needs the whole-model"):
+        northstar._gpt_pre_check(cfg, dataset=None)
+
+
+def test_require_devices_names_the_rehearsal_recipe():
+    import jax
+
+    from distributed_deep_learning_tpu.runtime.bootstrap import (
+        describe_devices, require_devices)
+
+    n = len(jax.devices())
+    assert len(require_devices(n)) == n
+    with pytest.raises(SystemExit, match=(
+            f"JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_"
+            f"device_count={n + 1}")):
+        require_devices(n + 1)
+    assert describe_devices() == {"platform": "cpu", "device_kind": "cpu",
+                                  "device_count": n}

@@ -324,6 +324,7 @@ def test_gpt_generate_too_long_rejected_before_training():
     class Cfg:
         generate_tokens = 56
         mode = Mode.DATA
+        serve = False
     _gpt_pre_check(Cfg(), DS())  # 8 + 56 == 64: fits
 
     Cfg.generate_tokens = 57

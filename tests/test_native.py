@@ -164,3 +164,36 @@ def test_csv_leading_blank_line_column_count(tmp_path, lib_available):
     path.write_text("h1,h2\n\n1,2\n3,4\n")
     got = native.read_csv(str(path), skip_header=True)
     np.testing.assert_array_equal(got, [[1, 2], [3, 4]])
+
+
+def test_library_is_named_by_its_source_and_a_stray_one_is_ignored(
+        tmp_path, monkeypatch, lib_available):
+    """The binary git does not track is loaded only if it was built from
+    THIS source: its name carries the source's hash, so a stale library
+    left in the tree (whatever its mtime) is never picked up, and an
+    absent one is rebuilt."""
+    import hashlib
+    import shutil
+
+    src = tmp_path / "ddl_native.cpp"
+    shutil.copy(native._SRC, src)
+    stray = tmp_path / "libddl_native.so"
+    stray.write_bytes(b"not a library")
+    monkeypatch.setattr(native, "_DIR", str(tmp_path))
+    monkeypatch.setattr(native, "_SRC", str(src))
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_tried", False)
+    monkeypatch.setattr(native, "_status", "not-loaded")
+    digest = hashlib.sha256(src.read_bytes()).hexdigest()[:16]
+    assert native.status() == "built"
+    assert (tmp_path / f"libddl_native_{digest}.so").exists()
+    assert stray.read_bytes() == b"not a library"
+
+
+def test_status_reports_the_fallback_and_why(monkeypatch):
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_tried", False)
+    monkeypatch.setattr(native, "_status", "not-loaded")
+    monkeypatch.setenv("DDL_DISABLE_NATIVE", "1")
+    assert native.status() == "numpy-fallback (DDL_DISABLE_NATIVE=1)"
+    assert not native.available()

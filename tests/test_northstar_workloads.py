@@ -395,6 +395,7 @@ def test_generate_pre_check_exempts_staged_modes():
     class Cfg:
         generate_tokens = 100  # impossible for max_len 64
         mode = Mode.PIPELINE
+        serve = False
     _gpt_pre_check(Cfg(), DS())   # no raise: generation will be skipped
 
     Cfg.mode = Mode.MODEL
@@ -402,4 +403,10 @@ def test_generate_pre_check_exempts_staged_modes():
 
     Cfg.mode = Mode.DATA
     with pytest.raises(ValueError, match="--generate"):
+        _gpt_pre_check(Cfg(), DS())
+
+    # an explicit --serve has no such exemption: the staged modes cannot
+    # serve, and say so before training instead of "serve skipped" after
+    Cfg.serve, Cfg.mode = True, Mode.MODEL
+    with pytest.raises(ValueError, match="--serve"):
         _gpt_pre_check(Cfg(), DS())

@@ -46,16 +46,11 @@ def test_memory_analysis_reports_buffer_bytes():
 def test_normalize_memory_analysis_handles_missing():
     assert normalize_memory_analysis(None) == {}
 
-    class Partial:                       # older jaxlibs expose fewer fields
+    class Partial:
         temp_size_in_bytes = 7
 
-    # missing required fields are zero-filled and flagged, so memory
-    # consumers (obs/memory, tune/calibrate) never KeyError mid-run
-    assert normalize_memory_analysis(Partial()) == {
-        "temp_size_in_bytes": 7,
-        "alias_size_in_bytes": 0,
-        "memory_fields_missing": ["alias_size_in_bytes"],
-    }
+    # only what the backend reported comes back: no field is invented
+    assert normalize_memory_analysis(Partial()) == {"temp_size_in_bytes": 7}
 
 
 def test_normalize_cost_analysis_unwraps_list():
