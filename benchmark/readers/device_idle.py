@@ -1,0 +1,9 @@
+"""Share of the traced window in which no op ran on the device, averaged
+over the chips."""
+
+
+def read(ctx):
+    t = ctx["trace"]
+    if not t:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
