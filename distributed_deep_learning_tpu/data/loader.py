@@ -11,6 +11,13 @@ sharded inputs and never inserts host transfers inside the step.
 Multi-host: each process materialises only its addressable shard of the
 global batch (`jax.make_array_from_process_local_data`), so the loader
 scales to pods without any code change.
+
+The input pipeline reports itself: :meth:`DeviceLoader.iter_batches`
+clocks ``batch_form`` (the dataset's gather / decode) and ``h2d_enqueue``
+(the sharded ``device_put``) of every batch, always on, and publishes
+the sums and a ring of per-batch host seconds as
+``obs.last_run("loader")``; while a profiler session or a Tracer listens
+each batch is a ``ddl:batch`` span with the two as children.
 """
 
 from __future__ import annotations
@@ -22,10 +29,15 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributed_deep_learning_tpu.data.datasets import ArrayDataset
+from distributed_deep_learning_tpu.obs import runlog
+from distributed_deep_learning_tpu.obs.trace import PhaseClock
 
 # Batch dimension is sharded over both data-parallel-ish axes; ZeRO/fsdp
 # meshes reuse the same loader unchanged.
 BATCH_AXES = ("data", "fsdp")
+
+#: the host phases of one batch, see the module docstring
+BATCH_PHASES = ("batch_form", "h2d_enqueue")
 
 
 class DeviceLoader:
@@ -59,6 +71,11 @@ class DeviceLoader:
         for (sl,) in imap.values():
             rows[sl] = True
         self._local_rows = np.flatnonzero(rows)
+        #: this loader's record over all its epochs (obs.last_run("loader")
+        #: while it is the loader last iterated)
+        self.record = runlog.RunRecord(
+            "loader", PhaseClock(BATCH_PHASES),
+            global_batch_size=self.global_batch_size)
 
     def __len__(self) -> int:
         n = len(self.indices)
@@ -110,9 +127,24 @@ class DeviceLoader:
         so its host→device transfer drains while the caller's step k
         dispatch runs — one batch of transfer latency is always hidden,
         even without :class:`PrefetchLoader`."""
+        pc = runlog.publish(self.record).phases
+        form, put = pc.phase("batch_form"), pc.phase("h2d_enqueue")
+        host = self.iter_host_batches(skip)
         prev = None
-        for x, y in self.iter_host_batches(skip):
-            cur = (self._to_device(x), self._to_device(y))
+        while True:
+            # the tick closes before the yield: a span never stays open
+            # across the consumer's step
+            with pc.tick(pc.n_ticks, "batch", trace_id="train",
+                         track="loader") as tk:
+                with form:
+                    xy = next(host, None)
+                if xy is not None:
+                    with put:
+                        cur = (self._to_device(xy[0]),
+                               self._to_device(xy[1]))
+                    tk.kind = "batch"
+            if xy is None:
+                break
             if prev is not None:
                 yield prev
             prev = cur

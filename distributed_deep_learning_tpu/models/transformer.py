@@ -283,8 +283,10 @@ class Embed(nn.Module):
         module dtype, so under bf16 the vocab-wide matmul would accumulate
         in bf16 — here the cast to f32 happens *before* the contraction.
         """
-        table = jnp.asarray(emb.embedding, jnp.float32)
-        return jnp.einsum("...d,vd->...v", x.astype(jnp.float32), table)
+        with jax.named_scope("head"):
+            table = jnp.asarray(emb.embedding, jnp.float32)
+            return jnp.einsum("...d,vd->...v", x.astype(jnp.float32),
+                              table)
 
 
 class TransformerSeq2Seq(nn.Module):
@@ -414,8 +416,10 @@ class CausalLM(nn.Module):
             ignore_id)
 
     def logits_from(self, params, hidden):
-        table = jnp.asarray(self._table(params), jnp.float32)
-        return jnp.einsum("...d,vd->...v", hidden.astype(jnp.float32), table)
+        with jax.named_scope("head"):
+            table = jnp.asarray(self._table(params), jnp.float32)
+            return jnp.einsum("...d,vd->...v", hidden.astype(jnp.float32),
+                              table)
 
 
 class BertEncoder(nn.Module):

@@ -15,6 +15,10 @@ Layout:
 * :mod:`obs.mfu`      — model-FLOP accounting + chip peak table.
 * :mod:`obs.export`   — JSONL event stream + Prometheus exposition.
 * :mod:`obs.bench`    — instrumentation-overhead harness (bench.py).
+* :mod:`obs.trace`    — spans: the ``span()`` front door (profiler +
+  Tracer), the Tracer ring and its Chrome export, ``PhaseClock``.
+* :mod:`obs.runlog`   — ``last_run(kind)``: the record a run publishes
+  as it starts (it outlives a run that raises), and the compile log.
 """
 
 from __future__ import annotations
@@ -27,11 +31,13 @@ from .memory import MemoryTracker
 from .metrics import MetricsRegistry
 from .mfu import chip_peak_flops, measure_step_flops, mfu_record
 from .recorder import FlightRecorder
+from .runlog import compile_log, last_run
 from .timeline import Timeline
-from .trace import Tracer
+from .trace import Tracer, span
 
 __all__ = ["RunTelemetry", "MetricsRegistry", "Timeline", "EventWriter",
-           "Tracer", "FlightRecorder", "MemoryTracker", "chip_peak_flops"]
+           "Tracer", "FlightRecorder", "MemoryTracker", "chip_peak_flops",
+           "span", "last_run", "compile_log"]
 
 
 class RunTelemetry:

@@ -1,0 +1,148 @@
+"""What the last run of each kind left behind, and the compile log:
+the two process-wide records a post-mortem can read with no help from
+the caller.
+
+A serving run that ends by an exception (a crashing supervisor hook, the
+benchmark's window closing) returns nothing, so everything it counted
+would be lost with it.  :class:`RunRecord` is therefore *published* at
+the top of the run (:func:`publish`), replacing the previous run's of the
+same kind, and filled in place as the run goes: ``last_run("serve")`` is
+the paged engine's last ``run()``, ``last_run("loader")`` the last
+:class:`~..data.loader.DeviceLoader` that was iterated.
+
+The compile log answers "which program was traced, lowered, compiled or
+fetched from the cache, when, and for how long" by program name, from
+``jax.monitoring``'s compile-path events.  :func:`install_compile_log`
+(called by :func:`~..runtime.bootstrap.enable_compile_cache`, which every
+entry point calls before it builds a program) registers the listeners
+once a process and marks the log each time, so a reader can take the
+entries of the current start alone.
+"""
+
+from __future__ import annotations
+
+import time
+from collections import deque
+from typing import Iterable, Optional
+
+from .metrics import MetricsRegistry
+from .trace import PhaseClock
+
+__all__ = ["RunRecord", "publish", "last_run", "CompileLog", "compile_log",
+           "install_compile_log"]
+
+
+class RunRecord:
+    """One run's in-memory record: its phase clock (sums and the tick
+    ring), its metrics registry where it has one, and a few facts about
+    the run (`meta`)."""
+
+    __slots__ = ("kind", "phases", "registry", "meta")
+
+    def __init__(self, kind: str, phases: PhaseClock,
+                 registry: Optional[MetricsRegistry] = None,
+                 **meta) -> None:
+        self.kind, self.phases, self.registry = kind, phases, registry
+        self.meta = meta
+
+
+_LAST: dict[str, RunRecord] = {}
+
+
+def publish(record: RunRecord) -> RunRecord:
+    """Make `record` what :func:`last_run` returns for its kind."""
+    _LAST[record.kind] = record
+    return record
+
+
+def last_run(kind: str) -> Optional[RunRecord]:
+    return _LAST.get(kind)
+
+
+# ------------------------------------------------------------ compile log
+
+#: jax.monitoring's compile-path events, under the short names the log uses
+_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+MARK = "mark"
+
+
+class CompileLog:
+    """Bounded log of ``(event, fun_name, start, seconds)``: event is
+    ``trace`` / ``lower`` / ``compile`` (the backend compile, a cache
+    fetch included) / ``retrieve`` (the fetch alone; JAX gives it no
+    name) / ``mark``; `start` is ``time.time()``.  ``trace`` carries the
+    Python function's name, ``lower`` and ``compile`` the module's
+    (``jit(<name>)``; the profiler writes it ``jit_<name>``).  Only a
+    program's own trace is kept, not those of the functions it calls."""
+
+    def __init__(self, capacity: int = 8192) -> None:
+        self.entries: deque = deque(maxlen=capacity)
+
+    def mark(self, label: str) -> None:
+        self.entries.append((MARK, label, time.time(), 0.0))
+
+    def _span(self, event, start, end, fun_name=None, **_):
+        short = _SPANS.get(event)
+        if short is None:
+            return
+        if short == "trace":
+            # JAX reports the trace of every jitted function a program
+            # calls (thousands in a 48-layer model), each before the
+            # program's own: keep the outermost, which contains them
+            entries = self.entries
+            while entries and entries[-1][0] == "trace" \
+                    and entries[-1][2] >= start:
+                entries.pop()
+        self.entries.append((short, fun_name, start, end - start))
+
+    def _duration(self, event, seconds, **_):
+        if event == _RETRIEVAL:
+            self.entries.append(("retrieve", None, time.time() - seconds,
+                                 seconds))
+
+    def since_mark(self) -> list:
+        """The entries after the newest mark (all of them without one)."""
+        out = []
+        for e in reversed(self.entries):
+            if e[0] == MARK:
+                break
+            out.append(e)
+        return out[::-1]
+
+    def seconds(self, names: Iterable[str],
+                events: Iterable[str] = ("trace", "lower"),
+                entries: Optional[list] = None) -> dict:
+        """Summed seconds by event of the programs called `names` (a name
+        matches the function's and the module's form of it), in `entries`
+        (default: since the newest mark)."""
+        want = set(names) | {f"jit({n})" for n in names}
+        out = {e: 0.0 for e in events}
+        for event, fun, _, secs in (self.since_mark() if entries is None
+                                    else entries):
+            if event in out and fun in want:
+                out[event] += secs
+        return out
+
+
+compile_log = CompileLog()
+_installed = False
+
+
+def install_compile_log(label: str = "start") -> CompileLog:
+    """Register the listeners (once a process; JAX keeps them for good)
+    and mark the log."""
+    global _installed
+    if not _installed:
+        from jax import monitoring
+
+        monitoring.register_event_time_span_listener(compile_log._span)
+        monitoring.register_event_duration_secs_listener(
+            compile_log._duration)
+        _installed = True
+    compile_log.mark(label)
+    return compile_log

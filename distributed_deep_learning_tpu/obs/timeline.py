@@ -7,7 +7,6 @@ trainer can actually attribute:
 kind           where it comes from
 =============  ====================================================
 ``data_wait``  host blocked in ``next(loader)`` (input stall)
-``h2d``        explicit host→device transfer outside the loader
 ``dispatch``   host time handing the jitted step to the runtime
 ``compile``    first dispatch of a given step fn (trace + XLA build)
 ``device_sync``host blocked fetching device results (the one
@@ -20,7 +19,8 @@ kind           where it comes from
 :meth:`Timeline.goodput` maps those onto the categories large-scale TPU
 fleet reports use: **productive** (dispatch + device_sync — the time the
 device is doing model math, given the loop's async-dispatch design),
-**input_stall** (data_wait + h2d), **checkpoint**, **recovery**
+**input_stall** (data_wait; what the loader itself spends forming and
+placing a batch is ``obs.last_run("loader")``), **checkpoint**, **recovery**
 (recovery + reshard), **compile**, and **other** (unattributed wall).
 Fractions are of elapsed wall-clock and sum to ≤ 1.0 by construction.
 
@@ -40,7 +40,6 @@ CATEGORY = {
     "dispatch": "productive",
     "device_sync": "productive",
     "data_wait": "input_stall",
-    "h2d": "input_stall",
     "checkpoint": "checkpoint",
     "recovery": "recovery",
     "reshard": "recovery",

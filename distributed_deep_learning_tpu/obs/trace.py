@@ -26,19 +26,31 @@ increment — no string formatting, no dict merging unless the caller
 passes attrs.  The span ring is bounded (``capacity``); old spans fall
 off rather than growing a multi-hour run without bound, and ``dropped``
 reports how many did.
+
+The front door for a region of host code is :func:`span`: one call site
+feeds JAX's profiler (a ``ddl:<name>`` ``TraceAnnotation`` on the host
+plane of the same xplane as the device ops, so on the profiler's clock)
+while a profiler session is open, and the run's installed :class:`Tracer`
+(:func:`use_tracer`) while there is one; with neither it hands back one
+shared null context.  :class:`PhaseClock` adds the always-on half: summed
+seconds and a count per phase and a bounded ring of per-tick records,
+which the engine and the loader publish through :mod:`..obs.runlog`.
 """
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
+import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Any, Iterable, Optional
 
 __all__ = ["Span", "Tracer", "chrome_trace_events", "write_chrome_trace",
-           "read_chrome_trace", "request_trace_id"]
+           "read_chrome_trace", "request_trace_id", "span", "use_tracer",
+           "installed_tracer", "PhaseClock", "SPAN_PREFIX"]
 
 
 def request_trace_id(uid: int) -> str:
@@ -93,7 +105,9 @@ class Tracer:
         self.capacity = capacity
         self.emitted = 0                 # total ever completed
         self.on_span = on_span
-        self._next_id = 1
+        # next(count) is one bytecode-atomic call: the loader's prefetch
+        # thread and the main loop may both record into one Tracer
+        self._ids = itertools.count(1)
         self._open: dict[int, Span] = {}
 
     @property
@@ -107,8 +121,7 @@ class Tracer:
             **attrs: Any) -> int:
         """Record a completed span; returns its span id (usable as a
         later span's ``parent``)."""
-        sid = self._next_id
-        self._next_id = sid + 1
+        sid = next(self._ids)
         sp = Span(name, t0, t1, trace_id, sid, parent, track,
                   attrs or None)
         self.spans.append(sp)
@@ -123,8 +136,7 @@ class Tracer:
               **attrs: Any) -> int:
         """Open a span whose end is not yet known (a request's root span
         opens at arrival and closes at retire)."""
-        sid = self._next_id
-        self._next_id = sid + 1
+        sid = next(self._ids)
         self._open[sid] = Span(name, t0 if t0 is not None else self.clock(),
                                -1.0, trace_id, sid, parent, track,
                                attrs or None)
@@ -230,3 +242,214 @@ def read_chrome_trace(path: str) -> list[dict]:
         doc = json.load(f)
     events = doc["traceEvents"] if isinstance(doc, dict) else doc
     return [e for e in events if e.get("ph") == "X"]
+
+
+# ------------------------------------------------------------ front door
+
+#: every span this package writes into the profiler's trace starts with
+#: it.  Never ``bench:``: the benchmark labels each program run by the
+#: latest ``bench:`` annotation begun before it.
+SPAN_PREFIX = "ddl:"
+
+_NULL = nullcontext()
+_TRACER: Optional[Tracer] = None      # the run's store, see use_tracer
+_OPEN = threading.local()             # per thread: open (id, trace, track)
+_ANNOTATION = None                    # jax.profiler.TraceAnnotation, lazily
+
+
+def _annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use so that
+    importing :mod:`..obs` stays free of JAX."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
+
+
+def installed_tracer() -> Optional[Tracer]:
+    return _TRACER
+
+
+@contextmanager
+def use_tracer(tracer: Optional[Tracer]):
+    """Make `tracer` the store :func:`span` records into for the enclosed
+    run.  ``None`` leaves whatever an outer caller installed."""
+    global _TRACER
+    if tracer is None:
+        yield
+        return
+    prev, _TRACER = _TRACER, tracer
+    try:
+        yield
+    finally:
+        _TRACER = prev
+
+
+def _active() -> bool:
+    return _TRACER is not None or (_ANNOTATION or _annotation()).is_enabled()
+
+
+def span(name: str, *, trace_id: Optional[str] = None,
+         track: Optional[str] = None, parent: Optional[int] = None,
+         **attrs: Any):
+    """Context manager around one named region of host code.
+
+    While a profiler session is open the region is a
+    ``TraceAnnotation("ddl:" + name, **attrs)``: it lands on the host
+    plane of the xplane that holds the device ops, on the profiler's
+    clock.  While a :class:`Tracer` is installed (:func:`use_tracer`) the
+    same call records into its ring; `parent`, `trace_id` and `track`
+    default to the enclosing span's (of this thread), so nesting in the
+    code is nesting in the export.  With neither, the one shared null
+    context comes back and nothing is recorded.
+    """
+    ann = _ANNOTATION or _annotation()
+    profiling = ann.is_enabled()
+    if _TRACER is None and not profiling:
+        return _NULL
+    return _Span(name, attrs, ann if profiling else None, _TRACER,
+                 trace_id, track, parent)
+
+
+class _Span:
+    __slots__ = ("_name", "_attrs", "_ann", "_tracer", "_trace_id",
+                 "_track", "_parent", "_sid")
+
+    def __init__(self, name, attrs, ann, tracer, trace_id, track, parent):
+        self._name, self._attrs = name, attrs
+        self._ann, self._tracer = ann, tracer
+        self._trace_id, self._track, self._parent = trace_id, track, parent
+
+    def __enter__(self):
+        if self._ann is not None:
+            self._ann = self._ann(SPAN_PREFIX + self._name, **self._attrs)
+            self._ann.__enter__()
+        if self._tracer is not None:
+            stack = _OPEN.__dict__.setdefault("stack", [])
+            _, up_trace, up_track = stack[-1] if stack else (None, "run",
+                                                             "main")
+            trace_id = self._trace_id or up_trace
+            track = self._track or up_track
+            parent = self._parent
+            if parent is None and stack:
+                parent = stack[-1][0]
+            self._sid = self._tracer.begin(self._name, trace_id,
+                                           parent=parent, track=track,
+                                           **self._attrs)
+            stack.append((self._sid, trace_id, track))
+        return self
+
+    def __exit__(self, *exc):
+        if self._tracer is not None:
+            _OPEN.stack.pop()
+            self._tracer.end(self._sid)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        return False
+
+
+# --------------------------------------------------- always-on phase sums
+
+class PhaseClock:
+    """What one run's loop spent in each of its named phases, always on.
+
+    The loop wraps each iteration in :meth:`tick` and each region in
+    :meth:`phase`; both cost two ``perf_counter`` reads and a few list
+    writes, and both also go through :func:`span` while something listens
+    (checked once a tick).  Kept per run: summed seconds and a count per
+    phase, and a bounded ring of per-tick records ``(index, kind, meta,
+    wall seconds, seconds per phase in `names` order)``.  A tick left by
+    an exception is recorded with kind ``"aborted"``.
+    """
+
+    def __init__(self, names, spanless=(), ring: int = 4096,
+                 clock=time.perf_counter) -> None:
+        """`spanless` phases are clocked only: their span is opened by
+        the callee (a program object naming its own dispatch)."""
+        self.names = tuple(names)
+        self.clock = clock
+        self.seconds = [0.0] * len(self.names)
+        self.counts = [0] * len(self.names)
+        self.ticks: deque = deque(maxlen=ring)
+        self.n_ticks = 0
+        self._row = [0.0] * len(self.names)
+        self._listening = False
+        self._phases = {n: _Phase(self, i, n, n not in spanless)
+                        for i, n in enumerate(self.names)}
+        self._tick = _Tick(self)
+
+    def phase(self, name: str) -> "_Phase":
+        return self._phases[name]
+
+    def tick(self, index: int, span_name: str = "tick", **attrs) -> "_Tick":
+        """The context manager of one iteration (one object, reused);
+        set its ``kind`` and ``meta`` before it closes."""
+        tk = self._tick
+        tk.index, tk.kind, tk.meta = index, "idle", ()
+        tk._span_name, tk._attrs = span_name, attrs
+        return tk
+
+    def summary(self) -> dict:
+        """``{phase: {"seconds", "count"}}`` of the phases that ran."""
+        return {n: {"seconds": s, "count": c}
+                for n, s, c in zip(self.names, self.seconds, self.counts)
+                if c}
+
+
+class _Phase:
+    __slots__ = ("_pc", "_i", "name", "_spanned", "_t0", "_span")
+
+    def __init__(self, pc: PhaseClock, i: int, name: str,
+                 spanned: bool) -> None:
+        self._pc, self._i, self.name, self._spanned = pc, i, name, spanned
+        self._span = None
+
+    def __enter__(self):
+        if self._spanned and self._pc._listening:
+            self._span = span(self.name)
+            self._span.__enter__()
+        self._t0 = self._pc.clock()
+        return self
+
+    def __exit__(self, *exc):
+        pc, i = self._pc, self._i
+        dt = pc.clock() - self._t0
+        pc.seconds[i] += dt
+        pc.counts[i] += 1
+        pc._row[i] += dt
+        if self._span is not None:
+            self._span.__exit__(*exc)
+            self._span = None
+        return False
+
+
+class _Tick:
+    __slots__ = ("_pc", "index", "kind", "meta", "_span_name", "_attrs",
+                 "_span", "_t0")
+
+    def __init__(self, pc: PhaseClock) -> None:
+        self._pc = pc
+        self._span = None
+
+    def __enter__(self):
+        pc = self._pc
+        pc._listening = _active()
+        if pc._listening:
+            self._span = span(self._span_name, **self._attrs)
+            self._span.__enter__()
+        self._t0 = pc.clock()
+        return self
+
+    def __exit__(self, et, ev, tb):
+        pc = self._pc
+        wall = pc.clock() - self._t0
+        pc.ticks.append((self.index, "aborted" if et is not None
+                         else self.kind, self.meta, wall, tuple(pc._row)))
+        pc.n_ticks += 1
+        pc._row = [0.0] * len(pc.names)
+        if self._span is not None:
+            self._span.__exit__(et, ev, tb)
+            self._span = None
+        return False

@@ -19,6 +19,7 @@ import os
 
 import jax
 
+from distributed_deep_learning_tpu.obs.runlog import install_compile_log
 from distributed_deep_learning_tpu.utils.config import Config, DistributedEnv
 
 _INITIALIZED = False
@@ -67,7 +68,13 @@ def enable_compile_cache() -> str | None:
     its compiles are cheap, and XLA:CPU logs two screens of machine-feature
     errors for every executable it loads back.  Call before the first
     compile; safe to call more than once.
+
+    Every entry point comes through here before it builds a program, so
+    this is also where the compile log starts listening
+    (:func:`..obs.runlog.install_compile_log`: which program was traced,
+    lowered, compiled or fetched, and for how long, by name).
     """
+    install_compile_log("enable_compile_cache")
     cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not cache_dir:
         if jax.default_backend() == "cpu":

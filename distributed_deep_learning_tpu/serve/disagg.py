@@ -216,7 +216,8 @@ class DisaggEngine:
         # one batched chunk program per prefill worker (compile-once
         # per worker: its pools/params are device-committed, so the
         # trace binds to that worker's device)
-        self._bchunk = [CountingJit(self._make_batch_chunk(w.eng))
+        self._bchunk = [CountingJit(self._make_batch_chunk(w.eng),
+                                    "disagg_batch_chunk")
                         for w in self.prefill]
         self.kv_cache_bytes = sum(w.eng.kv_cache_bytes
                                   for w in self.prefill + self.decode)
@@ -365,7 +366,8 @@ class DisaggEngine:
                               num_blocks=self._num_blocks, **kw)
             w = _Worker(len(self.prefill), eng, victim.device)
             self.prefill.append(w)
-            self._bchunk.append(CountingJit(self._make_batch_chunk(eng)))
+            self._bchunk.append(CountingJit(self._make_batch_chunk(eng),
+                                            "disagg_batch_chunk"))
         else:
             if len(self.prefill) < 2 or self.prefill[-1].streams:
                 return False

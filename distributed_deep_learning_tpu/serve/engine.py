@@ -42,6 +42,8 @@ from distributed_deep_learning_tpu.models.transformer import (
     CausalLM, cached_apply, make_decode_model, sample_tokens,
     validate_sampling)
 from distributed_deep_learning_tpu.obs import memory as obs_memory
+from distributed_deep_learning_tpu.obs import runlog
+from distributed_deep_learning_tpu.obs import trace as obs_trace
 from distributed_deep_learning_tpu.obs.metrics import MetricsRegistry
 from distributed_deep_learning_tpu.obs.window import LiveSignals
 from distributed_deep_learning_tpu.serve import cache as slot_cache
@@ -65,19 +67,33 @@ class CountingJit:
     count IS the compile count the tests assert on.  (A cache-evicted
     retrace would also count: the counter is conservative, never
     flattering.)
+
+    ``name`` is the program's: the compiled module is ``jit_<name>`` in a
+    profiler trace and the compile log, so an engine's programs are told
+    apart there.  ``span`` names the :func:`..obs.trace.span` every call
+    runs under: opened HERE, not by the caller, so it nests inside
+    whatever wraps the program object from outside (the benchmark's
+    dispatch annotation), on the same clock.
     """
 
-    def __init__(self, fn, **jit_kwargs):
+    def __init__(self, fn, name: Optional[str] = None,
+                 span: Optional[str] = None, **jit_kwargs):
         self.traces = 0
+        self.name = name or fn.__name__.strip("_<>")
+        self._span = span
 
         def counted(*args):
             self.traces += 1   # runs at trace time only
             return fn(*args)
 
+        counted.__name__ = counted.__qualname__ = self.name
         self._jit = jax.jit(counted, **jit_kwargs)
 
     def __call__(self, *args):
-        return self._jit(*args)
+        if self._span is None:
+            return self._jit(*args)
+        with obs_trace.span(self._span, program=self.name):
+            return self._jit(*args)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -247,8 +263,9 @@ class ServeEngine:
         # own shapes (what the analytic layers x 2 x slots x len x kv-heads
         # x head-dim computation must reproduce bit-exactly)
         self.kv_cache_bytes = obs_memory.pytree_bytes(self.slots)
-        self._prefill = CountingJit(self._prefill_impl, **dk)
-        self._decode = CountingJit(self._decode_impl, **dk)
+        self._prefill = CountingJit(self._prefill_impl, "serve_prefill",
+                                    **dk)
+        self._decode = CountingJit(self._decode_impl, "serve_decode", **dk)
         self.restarts = 0
         self.weight_swaps = 0
 
@@ -643,6 +660,19 @@ class _SpillRecord:
     digest: Optional[bytes] = None   # end-to-end integrity (device path)
 
 
+#: The phases of one :meth:`PagedEngine.run` tick, as they run.  Each is
+#: a ``ddl:<phase>`` span under the tick's ``ddl:tick`` while something
+#: listens, and an always-on sum in the run's published record
+#: (``obs.last_run("serve")``).  The two ``*_dispatch`` spans are opened by
+#: the program object itself (:class:`CountingJit`); the engine only
+#: clocks them.  ``chunk_commit`` runs between dispatch and wait (the host
+#: books the chunk while the device computes it) and again after the hook.
+TICK_PHASES = ("admit", "chunk_prepare", "chunk_dispatch", "chunk_commit",
+               "chunk_wait", "decode_prepare", "decode_dispatch",
+               "decode_wait", "decode_commit", "hook", "tick_end")
+DISPATCH_PHASES = ("chunk_dispatch", "decode_dispatch")
+
+
 class PagedEngine:
     """Paged continuous batching: prefix reuse, chunked prefill,
     speculative decoding — identical greedy outputs, fewer FLOPs.
@@ -756,9 +786,11 @@ class PagedEngine:
         dk = {"donate_argnums": (1,)} if donate else {}
         ck = {"donate_argnums": (0,)} if donate else {}
         self.pools = self._new_pools(self.lm)
-        self._chunk_prog = CountingJit(self._chunk_impl, **dk)
-        self._decode = CountingJit(self._decode_impl, **dk)
-        self._copy = CountingJit(self._copy_impl, **ck)
+        self._chunk_prog = CountingJit(self._chunk_impl, "paged_chunk",
+                                       "chunk_dispatch", **dk)
+        self._decode = CountingJit(self._decode_impl, "paged_decode",
+                                   "decode_dispatch", **dk)
+        self._copy = CountingJit(self._copy_impl, "paged_copy", **ck)
         if spill_dir is not None and not preempt:
             raise ValueError("spill_dir requires preempt=True (it is the "
                              "preemption spill audit directory)")
@@ -794,8 +826,9 @@ class PagedEngine:
         # spill gathers a whole slot WITHOUT donating the pools (they
         # must survive the read); unspill donates them like every other
         # pool-updating program
-        self._spill = CountingJit(self._spill_impl)
-        self._unspill = CountingJit(self._unspill_impl, **ck)
+        self._spill = CountingJit(self._spill_impl, "paged_spill")
+        self._unspill = CountingJit(self._unspill_impl, "paged_unspill",
+                                    **ck)
         if draft_layers is not None:
             self.draft_lm, self.draft_params = spec_mod.truncated_draft(
                 self.lm, params, draft_layers)
@@ -803,10 +836,15 @@ class PagedEngine:
             # scatters through the same shims, so a mixed-precision pair
             # would silently double the draft's footprint
             self.draft_pools = self._new_pools(self.draft_lm)
-            self._draft = CountingJit(self._draft_impl, **dk)
-            self._verify = CountingJit(self._verify_impl, **dk)
-            self._draft_chunk = CountingJit(self._draft_chunk_impl, **dk)
-            self._draft_copy = CountingJit(self._draft_copy_impl, **ck)
+            self._draft = CountingJit(self._draft_impl, "paged_draft",
+                                      "decode_dispatch", **dk)
+            self._verify = CountingJit(self._verify_impl, "paged_verify",
+                                       "decode_dispatch", **dk)
+            self._draft_chunk = CountingJit(
+                self._draft_chunk_impl, "paged_draft_chunk",
+                "chunk_dispatch", **dk)
+            self._draft_copy = CountingJit(self._draft_copy_impl,
+                                           "paged_draft_copy", **ck)
         # exact KV footprint: every allocated pool pytree (draft included
         # when speculating) — the paged analogue of ServeEngine's slots
         self.kv_cache_bytes = obs_memory.pytree_bytes(self.pools)
@@ -881,12 +919,15 @@ class PagedEngine:
         (meaningful on the final chunk only — the caller ignores it
         otherwise; the extra 1-row head projection is noise)."""
         params = self._wp(params)
-        cache = self._gather(pools, table, pos)
+        with jax.named_scope("kv_gather"):
+            cache = self._gather(pools, table, pos)
         hidden, new = cached_apply(self.lm, params, cache, tokens[None])
-        span = paged.extract_span(new, pos, self.chunk)
-        pools = paged.scatter_span(pools, self._qspan(span), wb, wo)
-        h_last = jax.lax.dynamic_slice_in_dim(hidden[0], logit_idx, 1)
-        tok, lp, ok = self._sample(params, h_last, key)
+        with jax.named_scope("kv_write"):
+            span = paged.extract_span(new, pos, self.chunk)
+            pools = paged.scatter_span(pools, self._qspan(span), wb, wo)
+        with jax.named_scope("sample"):
+            h_last = jax.lax.dynamic_slice_in_dim(hidden[0], logit_idx, 1)
+            tok, lp, ok = self._sample(params, h_last, key)
         return pools, tok[0], lp[0], ok[0]
 
     def _draft_chunk_impl(self, dparams, dpools, tokens, table, pos,
@@ -909,16 +950,20 @@ class PagedEngine:
         params = self._wp(params)
 
         def one(table, pos, tok):
-            cache = self._gather(pools, table, pos)
+            with jax.named_scope("kv_gather"):
+                cache = self._gather(pools, table, pos)
             hidden, new = cached_apply(self.lm, params, cache,
                                        tok[None, None])
-            return hidden[0, 0], paged.extract_span(new, pos, 1)
+            with jax.named_scope("kv_write"):
+                return hidden[0, 0], paged.extract_span(new, pos, 1)
 
         h, spans = jax.vmap(one)(tables, positions, toks)
-        kv = jax.tree_util.tree_map_with_path(
-            lambda p, x: x if paged.is_counter(p) else x[:, 0], spans)
-        pools = paged.scatter_span(pools, self._qspan(kv), wb, wo)
-        toks, lp, ok = self._sample(params, h, key)
+        with jax.named_scope("kv_write"):
+            kv = jax.tree_util.tree_map_with_path(
+                lambda p, x: x if paged.is_counter(p) else x[:, 0], spans)
+            pools = paged.scatter_span(pools, self._qspan(kv), wb, wo)
+        with jax.named_scope("sample"):
+            toks, lp, ok = self._sample(params, h, key)
         return pools, toks, lp, ok
 
     def _draft_impl(self, dparams, dpools, tables, positions, toks,
@@ -1004,18 +1049,21 @@ class PagedEngine:
         if self.draft_layers is not None:
             self.draft_pools = self._draft_copy(self.draft_pools, s, d)
 
-    def _make_writable(self, idx: int, lo_pos: int, hi_pos: int) -> int:
+    def _make_writable(self, idx: int, lo_pos: int, hi_pos: int,
+                       whose: Optional[dict] = None) -> int:
         """Run the manager's COW check over every logical block touched
         by positions ``[lo_pos, hi_pos]`` BEFORE computing scatter
-        targets (the check may swap table entries).  Returns the number
-        of blocks actually copied (0 on the common no-COW path), so the
-        caller can attribute a COW span without timing the no-op case."""
+        targets (the check may swap table entries).  Each block actually
+        copied (none on the common path) is a ``cow`` span; `whose`
+        gives it the request's trace id, track and parent.  Returns the
+        number of copies."""
         copies = 0
         for lg in range(lo_pos // self.block_size,
                         hi_pos // self.block_size + 1):
             pair = self.manager.writable(idx, lg)
             if pair is not None:
-                self._cow(*pair)
+                with obs_trace.span("cow", slot=idx, **(whose or {})):
+                    self._cow(*pair)
                 copies += 1
         return copies
 
@@ -1138,31 +1186,38 @@ class PagedEngine:
             self.swap_params(can.params)
         return can.summary()
 
-    def _canary_decode(self, mgr, pos, toks, wb, wo, dec):
+    def _canary_decode(self, mgr, pos, toks, wb, wo, dec, pc):
         """One decode tick under an active canary: two calls of the one
         compiled program.  Call A (stable params) trashes canary slots'
         KV writes; call B (candidate params) trashes everyone else's —
         each weight set's cache stays self-consistent.  Tokens merge
-        per slot; canary slots contribute agreement/drift samples."""
+        per slot; canary slots contribute agreement/drift samples.
+        `pc` is the run's phase clock."""
         can = self._canary
-        wb_old, wb_new = wb.copy(), wb.copy()
-        for i in range(self.max_slots):
-            if i in can.slots:
-                wb_old[i] = paged.TRASH
-            else:
-                wb_new[i] = paged.TRASH
-        tables_dev = jnp.asarray(mgr.tables)
-        pos_dev, toks_dev = jnp.asarray(pos), jnp.asarray(toks)
-        wo_dev = jnp.asarray(wo)
-        key = self._next_key()
-        self.pools, out_o, lp_o, ok_o = self._decode(
-            self.params, self.pools, tables_dev, pos_dev, toks_dev,
-            jnp.asarray(wb_old), wo_dev, key)
-        self.pools, out_n, lp_n, ok_n = self._decode(
-            can.params, self.pools, tables_dev, pos_dev, toks_dev,
-            jnp.asarray(wb_new), wo_dev, key)
-        out_o, lp_o, ok_o = (np.asarray(x) for x in (out_o, lp_o, ok_o))
-        out_n, lp_n, ok_n = (np.asarray(x) for x in (out_n, lp_n, ok_n))
+        with pc.phase("decode_prepare"):
+            wb_old, wb_new = wb.copy(), wb.copy()
+            for i in range(self.max_slots):
+                if i in can.slots:
+                    wb_old[i] = paged.TRASH
+                else:
+                    wb_new[i] = paged.TRASH
+            tables_dev = jnp.asarray(mgr.tables)
+            pos_dev, toks_dev = jnp.asarray(pos), jnp.asarray(toks)
+            wo_dev = jnp.asarray(wo)
+            wb_old, wb_new = jnp.asarray(wb_old), jnp.asarray(wb_new)
+            key = self._next_key()
+        with pc.phase("decode_dispatch"):
+            self.pools, out_o, lp_o, ok_o = self._decode(
+                self.params, self.pools, tables_dev, pos_dev, toks_dev,
+                wb_old, wo_dev, key)
+            self.pools, out_n, lp_n, ok_n = self._decode(
+                can.params, self.pools, tables_dev, pos_dev, toks_dev,
+                wb_new, wo_dev, key)
+        with pc.phase("decode_wait"):
+            out_o, lp_o, ok_o = (np.asarray(x)
+                                 for x in (out_o, lp_o, ok_o))
+            out_n, lp_n, ok_n = (np.asarray(x)
+                                 for x in (out_n, lp_n, ok_n))
         now = time.perf_counter()
         out, lp, ok = out_o.copy(), lp_o.copy(), ok_o.copy()
         for i in can.slots:
@@ -1185,37 +1240,57 @@ class PagedEngine:
 
         ``stats`` carries the v1 throughput/latency accounting plus
         ``paged`` (block pool + prefix hit rate), ``spec`` (acceptance),
-        and ``slo`` (attainment from per-request SLOs) sub-records.
+        ``slo`` (attainment from per-request SLOs) and ``phases`` (summed
+        seconds and count of each of :data:`TICK_PHASES`) sub-records.
+
+        The run says what it is doing as it goes: every tick is a
+        ``tick`` span with its phases as children, through
+        :func:`..obs.trace.span` (the profiler's trace while a session
+        is open, ``telemetry.tracer`` or an installed one while there is
+        one), and the phase sums, the per-tick ring and the registry are
+        published as ``obs.last_run("serve")`` BEFORE the first tick, so
+        they outlive a run that a hook ends by raising.
         """
+        with obs_trace.use_tracer(getattr(telemetry, "tracer", None)):
+            return self._run(requests, telemetry, keep_timeline, on_tick,
+                             admission)
+
+    def _run(self, requests, telemetry, keep_timeline, on_tick,
+             admission) -> dict:
         sched = PagedScheduler(self.max_slots)
         mgr = self.manager
         bs = self.block_size
         n_req = 0
         errors: dict[int, str] = {}
         accepted: list[Request] = []
-        for req in requests:
-            try:
-                self._validate(req)
-            except ValueError as e:
-                errors[req.uid] = str(e)
-                continue
-            sched.submit(req)
-            accepted.append(req)
-            n_req += 1
+        with obs_trace.span("submit", trace_id="engine", track="engine"):
+            for req in requests:
+                try:
+                    self._validate(req)
+                except ValueError as e:
+                    errors[req.uid] = str(e)
+                    continue
+                sched.submit(req)
+                accepted.append(req)
+                n_req += 1
 
         reg = telemetry.registry if telemetry is not None \
             else MetricsRegistry()
         h_ttft = reg.histogram("serve_ttft_seconds")
         h_itl = reg.histogram("serve_intertoken_seconds")
         h_e2e = reg.histogram("serve_e2e_seconds")
-        h_tick = reg.histogram("serve_decode_tick_seconds")
-        h_chunks = reg.histogram("serve_chunks_per_tick")
-        h_accept = reg.histogram("serve_spec_acceptance")
         g_queue = reg.gauge("serve_queue_depth")
         g_occ = reg.gauge("serve_slot_occupancy")
         g_blocks = reg.gauge("serve_kv_blocks_in_use")
         g_hit = reg.gauge("serve_prefix_hit_rate")
         reg.gauge("serve_kv_cache_bytes").set(self.kv_cache_bytes)
+        pc = obs_trace.PhaseClock(TICK_PHASES, spanless=DISPATCH_PHASES)
+        runlog.publish(runlog.RunRecord(
+            "serve", pc, reg, engine="paged", max_slots=self.max_slots,
+            prefill_chunk=self.chunk))
+        (p_admit, p_chunk_prepare, p_chunk_dispatch, p_chunk_commit,
+         p_chunk_wait, p_decode_prepare, p_decode_dispatch, p_decode_wait,
+         p_decode_commit, p_hook, p_tick_end) = map(pc.phase, TICK_PHASES)
 
         # per-slot host state: the token stream (prompt + emitted), how
         # many positions hold committed KV, remaining chunk plans, and
@@ -1234,8 +1309,11 @@ class PagedEngine:
         decode_ticks = occupancy_sum = 0
         t_prefill = t_decode = 0.0
 
-        tracer = getattr(telemetry, "tracer", None) \
-            if telemetry is not None else None
+        # per-request spans (queued, admit, prefix_match, prefill_chunk,
+        # decode, retire) are causal records, not regions of host code:
+        # they go to the Tracer alone, from clock reads the tick takes
+        # anyway; regions go through obs_trace.span / the phase clock
+        tracer = obs_trace.installed_tracer()
         recorder = getattr(telemetry, "recorder", None) \
             if telemetry is not None else None
         live = LiveSignals()
@@ -1309,66 +1387,71 @@ class PagedEngine:
             return False
 
         def make_writable(idx, lo, hi):
-            """COW check with span attribution: the no-copy common case
-            costs one extra clock read only when tracing is on."""
-            if tracer is None:
-                self._make_writable(idx, lo, hi)
-                return
-            t0 = time.perf_counter()
-            n = self._make_writable(idx, lo, hi)
-            if n:
+            """COW check; with a Tracer a copy's ``cow`` span joins the
+            request's own chain."""
+            whose = None
+            if tracer is not None:
                 req = sched.slots[idx].request
-                tracer.add("cow", t0, time.perf_counter(), req.trace_id,
-                           parent=root_span.get(req.uid),
-                           track=f"req{req.uid}", copies=n)
+                whose = {"trace_id": req.trace_id,
+                         "track": f"req{req.uid}",
+                         "parent": root_span.get(req.uid)}
+            self._make_writable(idx, lo, hi, whose)
 
         def run_chunk(idx, ev):
             nonlocal chunk_calls, t_prefill
-            req = sched.slots[idx].request
-            plan = plans[idx].pop(0)
-            L = len(req.prompt)
-            toks = chunk_tokens(stream[idx], plan, self.chunk,
-                                self.pad_fill)
-            rid = root_span.get(req.uid)
-            make_writable(idx, committed[idx], plan.commit_to - 1)
-            wb, wo, _ = write_targets(plan.feed_start, self.chunk,
-                                      committed[idx], L,
-                                      mgr.tables[idx], bs)
-            table_dev = jnp.asarray(mgr.tables[idx])
-            toks_dev = jnp.asarray(toks, jnp.int32)
-            wb_dev, wo_dev = jnp.asarray(wb), jnp.asarray(wo)
-            pos = np.int32(plan.feed_start)
+            with p_chunk_prepare:
+                req = sched.slots[idx].request
+                plan = plans[idx].pop(0)
+                L = len(req.prompt)
+                toks = chunk_tokens(stream[idx], plan, self.chunk,
+                                    self.pad_fill)
+                make_writable(idx, committed[idx], plan.commit_to - 1)
+                wb, wo, _ = write_targets(plan.feed_start, self.chunk,
+                                          committed[idx], L,
+                                          mgr.tables[idx], bs)
+                table_dev = jnp.asarray(mgr.tables[idx])
+                toks_dev = jnp.asarray(toks, jnp.int32)
+                wb_dev, wo_dev = jnp.asarray(wb), jnp.asarray(wo)
+                pos = np.int32(plan.feed_start)
             t0 = time.perf_counter()
-            self.pools, tok, c_lp, c_ok = self._chunk_prog(
-                self.params, self.pools, toks_dev, table_dev, pos,
-                np.int32(max(plan.logit_index, 0)), wb_dev, wo_dev,
-                self._next_key())
-            if self.draft_layers is not None:
-                self.draft_pools = self._draft_chunk(
-                    self.draft_params, self.draft_pools, toks_dev,
-                    table_dev, pos, wb_dev, wo_dev)
-            committed[idx] = plan.commit_to
-            mgr.register_committed(idx, stream[idx], committed[idx])
-            chunk_calls += 1
-            if ev is not None:
-                ev["chunks"].append(req.uid)
-            sched.note_chunk(idx)
-            if plan.is_last:
-                first = int(tok)       # host fetch = device barrier
-                now = time.perf_counter()
-                t_prefill += now - t0
-                pendtok[idx] = first
-                first_wall[req.uid] = now
-                ttft_s[req.uid] = now - sched.arrival_wall.get(req.uid,
-                                                               now)
-                h_ttft.observe(ttft_s[req.uid])
-                live.observe_ttft(ttft_s[req.uid], now)
-                if tracer is not None:
-                    tracer.add("prefill_chunk", t0, now, req.trace_id,
-                               parent=rid, track=f"req{req.uid}",
-                               feed_start=plan.feed_start,
-                               commit_to=plan.commit_to, is_last=True)
-                if on_tick is not None:
+            with p_chunk_dispatch:
+                self.pools, tok, c_lp, c_ok = self._chunk_prog(
+                    self.params, self.pools, toks_dev, table_dev, pos,
+                    np.int32(max(plan.logit_index, 0)), wb_dev, wo_dev,
+                    self._next_key())
+                if self.draft_layers is not None:
+                    self.draft_pools = self._draft_chunk(
+                        self.draft_params, self.draft_pools, toks_dev,
+                        table_dev, pos, wb_dev, wo_dev)
+            with p_chunk_commit:    # while the device runs the chunk
+                committed[idx] = plan.commit_to
+                mgr.register_committed(idx, stream[idx], committed[idx])
+                chunk_calls += 1
+                if ev is not None:
+                    ev["chunks"].append(req.uid)
+                sched.note_chunk(idx)
+            with p_chunk_wait:
+                if plan.is_last:
+                    first = int(tok)       # host fetch = device barrier
+                else:
+                    jax.block_until_ready(self.pools)
+            now = time.perf_counter()
+            t_prefill += now - t0
+            if tracer is not None:
+                tracer.add("prefill_chunk", t0, now, req.trace_id,
+                           parent=root_span.get(req.uid),
+                           track=f"req{req.uid}",
+                           feed_start=plan.feed_start,
+                           commit_to=plan.commit_to, is_last=plan.is_last)
+            if not plan.is_last:
+                return
+            pendtok[idx] = first
+            first_wall[req.uid] = now
+            ttft_s[req.uid] = now - sched.arrival_wall.get(req.uid, now)
+            h_ttft.observe(ttft_s[req.uid])
+            live.observe_ttft(ttft_s[req.uid], now)
+            if on_tick is not None:
+                with p_hook:
                     on_tick(TickReport(
                         tick=tick, kind="prefill", elapsed_s=now - t0,
                         emitted=[(req.uid, first)],
@@ -1376,17 +1459,9 @@ class PagedEngine:
                         logprob={req.uid: float(c_lp)},
                         slots=[idx], engine=self,
                         queue_depth=sched.queue_depth(tick)))
+            with p_chunk_commit:
                 stream[idx].append(first)
                 emit(idx, first, now)
-            else:
-                jax.block_until_ready(self.pools)
-                t1 = time.perf_counter()
-                t_prefill += t1 - t0
-                if tracer is not None:
-                    tracer.add("prefill_chunk", t0, t1, req.trace_id,
-                               parent=rid, track=f"req{req.uid}",
-                               feed_start=plan.feed_start,
-                               commit_to=plan.commit_to, is_last=False)
 
         # --- priority preemption (opt-in): spilled-slot parking lot ----
         spilled: list[_SpillRecord] = []
@@ -1522,15 +1597,10 @@ class PagedEngine:
                                 committed=rec.committed)
             return True
 
-        t_start = time.perf_counter()
-        tick = 0
-        while sched.pending or sched.occupancy or spilled:
-            sched.mark_arrivals(tick, time.perf_counter())
-            g_queue.set(sched.queue_depth(tick))
-            ev = ({"tick": tick, "placed": [], "chunks": [],
-                   "decoded": [], "shed": [], "preempted": [],
-                   "resumed": []} if keep_timeline else None)
-
+        def admit_all(tick, ev):
+            """One tick's admission: place, resume, shed and preempt until
+            the head of the queue has to wait."""
+            nonlocal shared_tokens, prompt_tokens
             # admission: FIFO while a slot AND its whole block budget
             # are available (no partial admission, no pool deadlock);
             # an AdmissionController may shed the head first — placed
@@ -1617,184 +1687,214 @@ class PagedEngine:
                     recorder.record("admit", uid=req.uid, slot=idx,
                                     shared_len=shared)
 
-            if not sched.occupancy:
-                nxt = sched.next_arrival()
-                if nxt is None:
-                    if spilled:
-                        continue       # parked work only: resume next pass
-                    break
-                tick = max(tick, nxt)  # idle engine: jump to arrival
-                continue
-            occupancy_sum += sched.occupancy
-            g_occ.set(sched.occupancy)
+        t_start = time.perf_counter()
+        tick = 0
+        while sched.pending or sched.occupancy or spilled:
+            depth = sched.queue_depth(tick)     # walks the queue: once
+            with pc.tick(tick, trace_id="engine", track="engine",
+                         tick=tick, decoding=sched.occupancy
+                         - len(sched.prefilling),
+                         prefilling=len(sched.prefilling),
+                         queue=depth) as tk:
+                ev = ({"tick": tick, "placed": [], "chunks": [],
+                       "decoded": [], "shed": [], "preempted": [],
+                       "resumed": []} if keep_timeline else None)
+                with p_admit:
+                    sched.mark_arrivals(tick, time.perf_counter())
+                    g_queue.set(depth)
+                    admit_all(tick, ev)
 
-            # chunked prefill under the per-tick budget, round-robin
-            budget = self.chunks_per_tick
-            ran = 0
-            while budget > 0 and sched.prefilling:
-                for idx in sched.chunk_order():
-                    if budget == 0:
+                if not sched.occupancy:
+                    nxt = sched.next_arrival()
+                    if nxt is None:
+                        if spilled:
+                            continue   # parked work only: resume next pass
                         break
-                    if idx not in sched.prefilling:
-                        continue       # finished earlier this pass
-                    run_chunk(idx, ev)
-                    budget -= 1
-                    ran += 1
-            h_chunks.observe(ran)
+                    tick = max(tick, nxt)  # idle engine: jump to arrival
+                    continue
+                occupancy_sum += sched.occupancy
+                g_occ.set(sched.occupancy)
 
-            # decode every tick: live streams advance regardless of how
-            # much prefill work is queued — the stall bound
-            dec = sched.decoding_slots()
-            if dec:
-                use_spec = (self.draft_layers is not None
-                            and self._spec_enabled)
-                if not use_spec:
-                    toks = np.zeros(self.max_slots, np.int32)
-                    pos = np.zeros(self.max_slots, np.int32)
-                    wb = np.full(self.max_slots, paged.TRASH, np.int32)
-                    wo = np.zeros(self.max_slots, np.int32)
-                    for i in dec:
-                        c = committed[i]
-                        make_writable(i, c, c)
-                        toks[i] = pendtok[i]
-                        pos[i] = c
-                        wb[i] = mgr.tables[i, c // bs]
-                        wo[i] = c % bs
-                    t0 = time.perf_counter()
+                # chunked prefill under the per-tick budget, round-robin
+                budget = self.chunks_per_tick
+                ran = 0
+                while budget > 0 and sched.prefilling:
+                    for idx in sched.chunk_order():
+                        if budget == 0:
+                            break
+                        if idx not in sched.prefilling:
+                            continue       # finished earlier this pass
+                        run_chunk(idx, ev)
+                        budget -= 1
+                        ran += 1
+
+                # decode every tick: live streams advance regardless of
+                # how much prefill work is queued — the stall bound
+                dec = sched.decoding_slots()
+                tk.kind = "decode" if dec else "prefill"
+                tk.meta = (len(dec), ran)
+                if dec and not (self.draft_layers is not None
+                                and self._spec_enabled):
+                    with p_decode_prepare:
+                        toks = np.zeros(self.max_slots, np.int32)
+                        pos = np.zeros(self.max_slots, np.int32)
+                        wb = np.full(self.max_slots, paged.TRASH, np.int32)
+                        wo = np.zeros(self.max_slots, np.int32)
+                        for i in dec:
+                            c = committed[i]
+                            make_writable(i, c, c)
+                            toks[i] = pendtok[i]
+                            pos[i] = c
+                            wb[i] = mgr.tables[i, c // bs]
+                            wo[i] = c % bs
+                        t0 = time.perf_counter()
+                        if self._canary is None:
+                            dev = (jnp.asarray(mgr.tables),
+                                   jnp.asarray(pos), jnp.asarray(toks),
+                                   jnp.asarray(wb), jnp.asarray(wo),
+                                   self._next_key())
                     if self._canary is not None:
                         out, lp_h, ok_h = self._canary_decode(
-                            mgr, pos, toks, wb, wo, dec)
+                            mgr, pos, toks, wb, wo, dec, pc)
                     else:
-                        self.pools, out, lp_h, ok_h = self._decode(
-                            self.params, self.pools,
-                            jnp.asarray(mgr.tables), jnp.asarray(pos),
-                            jnp.asarray(toks), jnp.asarray(wb),
-                            jnp.asarray(wo), self._next_key())
-                        out = np.asarray(out)   # host fetch = barrier
-                        lp_h, ok_h = np.asarray(lp_h), np.asarray(ok_h)
+                        with p_decode_dispatch:
+                            self.pools, out, lp_h, ok_h = self._decode(
+                                self.params, self.pools, *dev)
+                        with p_decode_wait:
+                            out = np.asarray(out)   # host fetch = barrier
+                            lp_h, ok_h = np.asarray(lp_h), np.asarray(ok_h)
                     now = time.perf_counter()
                     t_decode += now - t0
-                    h_tick.observe(now - t0)
                     decode_ticks += 1
-                    if tracer is not None:
-                        tracer.add("decode_tick", t0, now, "engine",
-                                   track="engine", slots=len(dec))
                     if on_tick is not None:
-                        on_tick(TickReport(
-                            tick=tick, kind="decode", elapsed_s=now - t0,
-                            emitted=[(sched.slots[i].request.uid,
-                                      int(out[i])) for i in dec],
-                            finite={sched.slots[i].request.uid:
-                                    bool(ok_h[i]) for i in dec},
-                            logprob={sched.slots[i].request.uid:
-                                     float(lp_h[i]) for i in dec},
-                            slots=list(dec), engine=self,
-                            queue_depth=sched.queue_depth(tick)))
-                    for i in dec:
-                        tok = int(out[i])
-                        committed[i] += 1
-                        stream[i].append(tok)
-                        mgr.register_committed(i, stream[i], committed[i])
-                        pendtok[i] = tok
-                        r = sched.slots[i].request
-                        if ev is not None:
-                            ev["decoded"].append(r.uid)
-                        if tracer is not None:
-                            tracer.add("decode", t0, now, r.trace_id,
-                                       parent=root_span.get(r.uid),
-                                       track=f"req{r.uid}")
-                        emit(i, tok, now)
-                else:
+                        with p_hook:
+                            on_tick(TickReport(
+                                tick=tick, kind="decode",
+                                elapsed_s=now - t0,
+                                emitted=[(sched.slots[i].request.uid,
+                                          int(out[i])) for i in dec],
+                                finite={sched.slots[i].request.uid:
+                                        bool(ok_h[i]) for i in dec},
+                                logprob={sched.slots[i].request.uid:
+                                         float(lp_h[i]) for i in dec},
+                                slots=list(dec), engine=self,
+                                queue_depth=sched.queue_depth(tick)))
+                    with p_decode_commit:
+                        for i in dec:
+                            tok = int(out[i])
+                            committed[i] += 1
+                            stream[i].append(tok)
+                            mgr.register_committed(i, stream[i],
+                                                   committed[i])
+                            pendtok[i] = tok
+                            r = sched.slots[i].request
+                            if ev is not None:
+                                ev["decoded"].append(r.uid)
+                            if tracer is not None:
+                                tracer.add("decode", t0, now, r.trace_id,
+                                           parent=root_span.get(r.uid),
+                                           track=f"req{r.uid}")
+                            emit(i, tok, now)
+                elif dec:
                     k = self.spec_k
                     T = k + 1
-                    toks = np.zeros(self.max_slots, np.int32)
-                    pos = np.zeros(self.max_slots, np.int32)
-                    wb = np.full((self.max_slots, T), paged.TRASH,
-                                 np.int32)
-                    wo = np.zeros((self.max_slots, T), np.int32)
-                    for i in dec:
-                        c = committed[i]
-                        make_writable(i, c, c + k)
-                        toks[i] = pendtok[i]
-                        pos[i] = c
-                        pp = np.arange(c, c + T)
-                        wb[i] = mgr.tables[i][pp // bs]
-                        wo[i] = pp % bs
-                    tables_dev = jnp.asarray(mgr.tables)
-                    pos_dev = jnp.asarray(pos)
-                    wb_dev, wo_dev = jnp.asarray(wb), jnp.asarray(wo)
+                    with p_decode_prepare:
+                        toks = np.zeros(self.max_slots, np.int32)
+                        pos = np.zeros(self.max_slots, np.int32)
+                        wb = np.full((self.max_slots, T), paged.TRASH,
+                                     np.int32)
+                        wo = np.zeros((self.max_slots, T), np.int32)
+                        for i in dec:
+                            c = committed[i]
+                            make_writable(i, c, c + k)
+                            toks[i] = pendtok[i]
+                            pos[i] = c
+                            pp = np.arange(c, c + T)
+                            wb[i] = mgr.tables[i][pp // bs]
+                            wo[i] = pp % bs
+                        tables_dev = jnp.asarray(mgr.tables)
+                        pos_dev = jnp.asarray(pos)
+                        wb_dev, wo_dev = jnp.asarray(wb), jnp.asarray(wo)
                     t0 = time.perf_counter()
-                    self.draft_pools, props = self._draft(
-                        self.draft_params, self.draft_pools, tables_dev,
-                        pos_dev, jnp.asarray(toks), wb_dev, wo_dev)
-                    props = np.asarray(props)
+                    with p_decode_dispatch:
+                        self.draft_pools, props = self._draft(
+                            self.draft_params, self.draft_pools,
+                            tables_dev, pos_dev, jnp.asarray(toks),
+                            wb_dev, wo_dev)
+                    with p_decode_wait:
+                        props = np.asarray(props)
                     verify_toks = np.concatenate(
                         [toks[:, None], props], axis=1).astype(np.int32)
-                    self.pools, g, v_lp, v_ok = self._verify(
-                        self.params, self.pools, tables_dev, pos_dev,
-                        jnp.asarray(verify_toks), wb_dev, wo_dev)
-                    g = np.asarray(g)       # host fetch = device barrier
-                    v_lp, v_ok = np.asarray(v_lp), np.asarray(v_ok)
+                    with p_decode_dispatch:
+                        self.pools, g, v_lp, v_ok = self._verify(
+                            self.params, self.pools, tables_dev, pos_dev,
+                            jnp.asarray(verify_toks), wb_dev, wo_dev)
+                    with p_decode_wait:
+                        g = np.asarray(g)   # host fetch = device barrier
+                        v_lp, v_ok = np.asarray(v_lp), np.asarray(v_ok)
                     now = time.perf_counter()
                     t_decode += now - t0
-                    h_tick.observe(now - t0)
                     decode_ticks += 1
                     spec_rounds += len(dec)
-                    if tracer is not None:
-                        tracer.add("decode_tick", t0, now, "engine",
-                                   track="engine", slots=len(dec),
-                                   speculative=True)
                     # acceptance decided BEFORE any state mutates, so
                     # the tick report (and a hook that rejects it) sees
                     # exactly what would be committed
                     acc = {i: spec_mod.greedy_accept(props[i], g[i])
                            for i in dec}
                     if on_tick is not None:
-                        on_tick(TickReport(
-                            tick=tick, kind="decode", elapsed_s=now - t0,
-                            emitted=[(sched.slots[i].request.uid, int(t))
-                                     for i in dec for t in acc[i][1]],
-                            finite={sched.slots[i].request.uid:
-                                    bool(v_ok[i]) for i in dec},
-                            logprob={sched.slots[i].request.uid:
-                                     float(v_lp[i, 0]) for i in dec},
-                            slots=list(dec), engine=self,
-                            queue_depth=sched.queue_depth(tick)))
-                    for i in dec:
-                        a, emitted = acc[i]
-                        proposed_total += k
-                        accepted_total += a
-                        h_accept.observe(a / k if k else 0.0)
-                        committed[i] += a + 1
-                        r = sched.slots[i].request
-                        if ev is not None:
-                            ev["decoded"].append(r.uid)
-                        if tracer is not None:
-                            tracer.add("decode", t0, now, r.trace_id,
-                                       parent=root_span.get(r.uid),
-                                       track=f"req{r.uid}", accepted=a)
-                        retired = False
-                        for tok in emitted:
-                            stream[i].append(tok)
-                            if emit(i, tok, now):
-                                retired = True
-                                break
-                        if not retired:
-                            pendtok[i] = emitted[-1]
-                            mgr.register_committed(i, stream[i],
-                                                   committed[i])
-            noww = time.perf_counter()
-            live.sample(sched.queue_depth(tick), sched.occupancy, noww)
-            if admission is not None:
-                admission.observe(live, sched.queue_depth(tick), noww)
-                admission.apply(self)
-            if telemetry is not None and noww - last_window_emit >= 1.0:
-                last_window_emit = noww
-                telemetry.writer.emit("obs_window", scope="serve",
-                                      **live.signals(noww))
-            if ev is not None:
-                timeline.append(ev)
-            tick += 1
+                        with p_hook:
+                            on_tick(TickReport(
+                                tick=tick, kind="decode",
+                                elapsed_s=now - t0,
+                                emitted=[(sched.slots[i].request.uid,
+                                          int(t))
+                                         for i in dec for t in acc[i][1]],
+                                finite={sched.slots[i].request.uid:
+                                        bool(v_ok[i]) for i in dec},
+                                logprob={sched.slots[i].request.uid:
+                                         float(v_lp[i, 0]) for i in dec},
+                                slots=list(dec), engine=self,
+                                queue_depth=sched.queue_depth(tick)))
+                    with p_decode_commit:
+                        for i in dec:
+                            a, emitted = acc[i]
+                            proposed_total += k
+                            accepted_total += a
+                            committed[i] += a + 1
+                            r = sched.slots[i].request
+                            if ev is not None:
+                                ev["decoded"].append(r.uid)
+                            if tracer is not None:
+                                tracer.add("decode", t0, now, r.trace_id,
+                                           parent=root_span.get(r.uid),
+                                           track=f"req{r.uid}",
+                                           accepted=a)
+                            retired = False
+                            for tok in emitted:
+                                stream[i].append(tok)
+                                if emit(i, tok, now):
+                                    retired = True
+                                    break
+                            if not retired:
+                                pendtok[i] = emitted[-1]
+                                mgr.register_committed(i, stream[i],
+                                                       committed[i])
+                with p_tick_end:
+                    noww = time.perf_counter()
+                    live.sample(sched.queue_depth(tick), sched.occupancy,
+                                noww)
+                    if admission is not None:
+                        admission.observe(live, sched.queue_depth(tick),
+                                          noww)
+                        admission.apply(self)
+                    if telemetry is not None \
+                            and noww - last_window_emit >= 1.0:
+                        last_window_emit = noww
+                        telemetry.writer.emit("obs_window", scope="serve",
+                                              **live.signals(noww))
+                    if ev is not None:
+                        timeline.append(ev)
+                tick += 1
 
         total = time.perf_counter() - t_start
         tokens = int(sum(len(v) for v in sched.finished.values()))
@@ -1875,6 +1975,7 @@ class PagedEngine:
             "slo": slo_report(accepted, ttft_s, e2e_s),
             "latency": latency,
             "window": live.signals(),
+            "phases": pc.summary(),
         }
         if recorder is not None:
             mgr.on_event = None

@@ -162,8 +162,8 @@ class BlockMigrator:
         self.chunk_bytes = int(chunk_bytes)
         self.stats = MigrationStats()
         self.tracer = tracer
-        self._gather = CountingJit(self._gather_impl)
-        self._scatter = CountingJit(self._scatter_impl)
+        self._gather = CountingJit(self._gather_impl, "migrate_gather")
+        self._scatter = CountingJit(self._scatter_impl, "migrate_scatter")
         if registry is not None:
             self._c_bytes = registry.counter("serve_migration_bytes",
                                              wire=wire)

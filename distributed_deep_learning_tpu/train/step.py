@@ -116,19 +116,24 @@ def make_step_fns(mesh: Mesh, loss_fn: LossFn, *,
             else:
                 pred, new_ms, aux = fwd(params, state.model_state, x,
                                         train=True, rngs=rngs)
-            loss = loss_fn(pred, y)
-            # gradient objective includes the model's aux losses (MoE load
-            # balance etc.); logged metrics report the task loss
-            return loss + aux, (_metrics(pred, y, loss), new_ms)
+            # scopes name, in a profiler trace, the work no Flax module
+            # names: the loss here, the optimizer update below
+            with jax.named_scope("loss"):
+                loss = loss_fn(pred, y)
+                # gradient objective includes the model's aux losses (MoE
+                # load balance etc.); logged metrics report the task loss
+                return loss + aux, (_metrics(pred, y, loss), new_ms)
 
         grad_fn = jax.value_and_grad(compute, has_aux=True)
         (_, (metrics, new_ms)), grads = grad_fn(state.params)
-        if sentinel is not None:
-            from distributed_deep_learning_tpu.train.sentinel import (
-                guarded_update)
+        with jax.named_scope("optimizer"):
+            if sentinel is not None:
+                from distributed_deep_learning_tpu.train.sentinel import (
+                    guarded_update)
 
-            return guarded_update(state, grads, new_ms, metrics, sentinel)
-        return state.apply_gradients(grads, model_state=new_ms), metrics
+                return guarded_update(state, grads, new_ms, metrics,
+                                      sentinel)
+            return state.apply_gradients(grads, model_state=new_ms), metrics
 
     @under_mesh(mesh)
     def eval_step(state: TrainState, x, y):

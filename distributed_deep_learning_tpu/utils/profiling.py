@@ -7,7 +7,8 @@ here:
 
 * :func:`trace` — ``jax.profiler`` device traces (TensorBoard/XProf
   format): per-op device timelines, HBM usage, ICI collectives.
-* :func:`annotate` — named host-side regions that show up in the trace.
+  Named host-side regions in that trace come from one door,
+  :func:`..obs.trace.span`.
 * :func:`hlo_text` / :func:`compiled_text` — the compiler's view of a
   jitted function before/after XLA optimisation (the ``dynamo.explain``
   analogue; there are no "graph breaks" to hunt — if it traced, it's one
@@ -44,12 +45,6 @@ def trace(log_dir: str | None) -> Iterator[None]:
         yield
     finally:
         jax.profiler.stop_trace()
-
-
-def annotate(name: str):
-    """Named region context manager; nests and appears on the trace
-    timeline (host track)."""
-    return jax.profiler.TraceAnnotation(name)
 
 
 def _lowered(fn: Callable, *args, **kwargs):

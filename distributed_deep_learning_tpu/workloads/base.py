@@ -36,6 +36,7 @@ from jax.sharding import PartitionSpec as P
 from distributed_deep_learning_tpu.data.datasets import ArrayDataset
 from distributed_deep_learning_tpu.data.loader import make_loaders
 from distributed_deep_learning_tpu.data.splits import train_val_test_split
+from distributed_deep_learning_tpu.obs import trace as obs_trace
 from distributed_deep_learning_tpu.parallel.partition import validate_assignment
 from distributed_deep_learning_tpu.parallel.staging import StagedModel
 from distributed_deep_learning_tpu.runtime.bootstrap import (
@@ -872,19 +873,27 @@ def run_workload(spec: WorkloadSpec, config: Config
                              f"gpt option; workload {spec.name!r} models "
                              "define their own head layout")
     try:
-        dataset = _build_dataset(spec, config)
-        if spec.pre_train_check is not None:
-            spec.pre_train_check(config, dataset)
-        if config.autotune or config.plan_file:
-            # plan fields never affect dataset construction, so the built
-            # dataset is reused by the search's measured trials
-            config = _resolve_plan(spec, config, devices, logger, dataset)
-        state, history = _run_workload(spec, config, devices, logger,
-                                       dataset, telemetry=telemetry)
-        if (config.generate_tokens or config.serve) and \
-                spec.post_train is not None:
-            spec.post_train(config, state, logger, dataset)
-        return state, history
+        # --obs-trace: the run's Tracer is the store of every
+        # obs.trace.span() below (the loader's batches, the engine's tick
+        # tree), whoever calls them and with or without a telemetry handle
+        with obs_trace.use_tracer(getattr(telemetry, "tracer", None)):
+            dataset = _build_dataset(spec, config)
+            if spec.pre_train_check is not None:
+                spec.pre_train_check(config, dataset)
+            if config.autotune or config.plan_file:
+                # plan fields never affect dataset construction, so the
+                # built dataset is reused by the search's measured trials
+                config = _resolve_plan(spec, config, devices, logger,
+                                       dataset)
+            state, history = _run_workload(spec, config, devices, logger,
+                                           dataset, telemetry=telemetry)
+            if (config.generate_tokens or config.serve) and \
+                    spec.post_train is not None:
+                # --profile-dir covers serving too (a second session
+                # beside the training one, under the same directory)
+                with profiling.trace(config.profile_dir):
+                    spec.post_train(config, state, logger, dataset)
+            return state, history
     finally:
         if telemetry is not None:
             summary = telemetry.close()

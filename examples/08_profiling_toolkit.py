@@ -25,6 +25,7 @@ import numpy as np
 import optax
 
 from distributed_deep_learning_tpu.models.mlp import MLP
+from distributed_deep_learning_tpu.obs import trace as obs_trace
 from distributed_deep_learning_tpu.runtime.mesh import build_mesh
 from distributed_deep_learning_tpu.train.objectives import cross_entropy_loss
 from distributed_deep_learning_tpu.train.state import create_train_state
@@ -70,7 +71,7 @@ def main():
     # 4. device trace for TensorBoard/XProf
     trace_dir = tempfile.mkdtemp()
     with profiling.trace(trace_dir):
-        with profiling.annotate("profiled-step"):
+        with obs_trace.span("profiled-step"):   # "ddl:profiled-step"
             state, m = train_step(state, x, y)
             float(m["loss"])
     import os

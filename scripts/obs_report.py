@@ -12,6 +12,7 @@ users saw.
     python scripts/obs_report.py obs_events.jsonl --trace    # span trace
     python scripts/obs_report.py obs_events.jsonl --window   # live windows
     python scripts/obs_report.py obs_events.jsonl --memory   # memory view
+    python scripts/obs_report.py --xplane DIR                # profiler trace
 
 ``--prom`` dumps the final metrics snapshot in Prometheus text
 exposition format (for a textfile collector or diffing against a scrape
@@ -27,6 +28,21 @@ is taken from the stream's ``obs_trace`` event; pass
 ``--window`` prints the rolling-window live signals (``obs_window``
 events): windowed TTFT/ITL percentiles, queue depth, slot occupancy
 and request/token rates over the run.
+
+``--xplane DIR`` reads the profiler trace a ``--profile-dir DIR`` run
+wrote (no event stream needed) and answers the two questions the device
+trace alone cannot: device seconds by named scope of each program (the
+``jax.named_scope`` / Flax module path of every op), and device idle
+time by the host phase it fell in (the engine's ``ddl:`` tick tree:
+``tick`` > ``admit``, ``chunk_prepare`` / ``chunk_dispatch`` /
+``chunk_commit`` / ``chunk_wait``, ``decode_prepare`` / ``decode_dispatch``
+/ ``decode_wait`` / ``decode_commit``, ``hook``, ``tick_end``; the loader's
+``batch`` > ``batch_form``, ``h2d_enqueue``).  How to get the tick tree:
+
+    python -m distributed_deep_learning_tpu gpt ... --serve --paged \
+        --profile-dir DIR            # then: obs_report.py --xplane DIR
+    python -m distributed_deep_learning_tpu gpt ... --serve --paged \
+        --obs --obs-trace trace.json # the same spans as a Chrome file
 """
 
 from __future__ import annotations
@@ -205,6 +221,64 @@ def render_window(events: list[dict]) -> str:
     return "\n".join(out)
 
 
+def render_xplane(trace: dict, depth: int = 3, top: int = 24,
+                  ops: str | None = None) -> str:
+    """The operator's reading of one profiler trace (see
+    :mod:`distributed_deep_learning_tpu.obs.xplane`)."""
+    from distributed_deep_learning_tpu.obs import xplane
+
+    def share(part: float, whole: float) -> str:
+        return _fmt_frac(part / max(whole, 1e-12))
+
+    idle = xplane.idle_by_phase(trace)
+    spans = xplane.span_counts(trace)
+    out = [f"== profiler trace: window {idle['window_s']:.3f}s on "
+           f"{idle['plane']}, busy {idle['busy_s']:.3f}s, idle "
+           f"{idle['idle_s']:.3f}s "
+           f"({share(idle['idle_s'], idle['window_s']).strip()}) ==",
+           f"  file {trace['bytes'] / 2 ** 20:.1f} MiB, "
+           f"{sum(n for n, _ in spans.values())} ddl: spans of "
+           f"{len(spans)} kinds on {len(trace['spans'])} thread(s)"]
+    progs = xplane.programs(trace)
+    if progs:
+        out.append("== programs (XLA Modules) ==")
+        for mod, runs, secs in progs[:top]:
+            out.append(f"  {mod:<40} x{runs:<6} {secs:10.4f}s")
+    by = xplane.seconds_by_scope(trace, depth, ops)
+    what = f" of ops matching {ops!r}" if ops else ""
+    out.append(f"== device seconds by scope{what} "
+               f"({by['op_s']:.3f}s in all) ==")
+    for prog, scope, secs, n in by["rows"][:top]:
+        out.append(f"  {prog:<22} {scope:<44} {secs:9.4f}s "
+                   f"{share(secs, by['op_s'])}  x{n}")
+    if len(by["rows"]) > top:
+        rest = sum(r[2] for r in by["rows"][top:])
+        out.append(f"  ... {len(by['rows']) - top} more rows, {rest:.4f}s")
+    out.append(f"== device idle by host phase (thread {idle['thread']}): "
+               f"{idle['between_s']:.4f}s between program runs, "
+               f"{idle['inside_s']:.4f}s inside them ==")
+    out.append(f"  {'phase':<22} {'between':>10} {'inside':>10}  of idle")
+    for name, between, inside, n in idle["by_phase"]:
+        out.append(f"  {name:<22} {between:9.4f}s {inside:9.4f}s "
+                   f"{share(between + inside, idle['idle_s'])}"
+                   f"  in {n} pieces")
+    out.append(f"  under a named phase: "
+               f"{share(idle['named_s'], idle['idle_s']).strip()} of idle "
+               f"time")
+    if spans:
+        out.append("== ddl: spans (host seconds, all threads) ==")
+        for name, (n, secs) in sorted(spans.items(),
+                                      key=lambda kv: -kv[1][1]):
+            out.append(f"  {name:<22} x{n:<6} {secs:10.4f}s")
+    nest = xplane.nesting(trace)
+    if nest:
+        out.append("== one clock: ddl: spans inside the bench: annotation "
+                   "of the same call ==")
+        for kind, inside, total in nest:
+            out.append(f"  {kind:<22} {inside}/{total}")
+    return "\n".join(out)
+
+
 def render(events: list[dict], phases: bool = False) -> str:
     run_gp = None
     phase_gps = []
@@ -280,8 +354,25 @@ def render(events: list[dict], phases: bool = False) -> str:
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(
         description="render an --obs telemetry stream as a goodput/MFU/"
-                    "latency report")
-    p.add_argument("stream", help="JSONL event file written by --obs")
+                    "latency report",
+        epilog="The paged engine's tick tree (tick > admit, chunk_*, "
+               "decode_*, hook, tick_end): run the CLI with --profile-dir "
+               "DIR, then `obs_report.py --xplane DIR` (device idle time "
+               "by host phase, device seconds by named scope); or run it "
+               "with --obs --obs-trace PATH for the same spans as a "
+               "Chrome/Perfetto file.")
+    p.add_argument("stream", nargs="?",
+                   help="JSONL event file written by --obs")
+    p.add_argument("--xplane", metavar="DIR",
+                   help="read the profiler trace a --profile-dir DIR run "
+                        "wrote (or one .xplane.pb): device seconds by "
+                        "named scope, device idle time by the engine's "
+                        "ddl: host phase; needs no event stream")
+    p.add_argument("--depth", type=int, default=3,
+                   help="--xplane: scope segments kept (default 3)")
+    p.add_argument("--ops", metavar="REGEX",
+                   help="--xplane: count only ops whose HLO name matches "
+                        "(e.g. '^copy')")
     p.add_argument("--phases", action="store_true",
                    help="also print per-phase goodput breakdowns")
     p.add_argument("--prom", action="store_true",
@@ -299,6 +390,14 @@ def main(argv=None) -> int:
                    help="print the memory view (obs_memory rollup + "
                         "mem_*/kv-cache gauges) instead of the report")
     args = p.parse_args(argv)
+    if args.xplane:
+        from distributed_deep_learning_tpu.obs import xplane
+
+        print(render_xplane(xplane.load(xplane.newest(args.xplane)),
+                            depth=args.depth, ops=args.ops))
+        return 0
+    if not args.stream:
+        p.error("give the event stream of an --obs run, or --xplane DIR")
 
     from distributed_deep_learning_tpu.obs.export import (prometheus_text,
                                                           read_events)

@@ -1,0 +1,544 @@
+"""The span front door (``obs.trace.span``), the always-on phase sums the
+paged engine and the loader publish (``obs.last_run``), the compile log,
+and the readers and the report that consume them — all on the CPU, where
+JAX's profiler works too."""
+
+import contextlib
+import importlib
+import json
+import os
+import subprocess
+import sys
+import warnings
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from distributed_deep_learning_tpu import obs
+from distributed_deep_learning_tpu.obs import runlog, xplane
+from distributed_deep_learning_tpu.obs import trace as obs_trace
+from distributed_deep_learning_tpu.obs.trace import PhaseClock, Tracer, span
+from distributed_deep_learning_tpu.serve.bench import build_model, make_trace
+from distributed_deep_learning_tpu.serve.engine import (DISPATCH_PHASES,
+                                                        TICK_PHASES,
+                                                        PagedEngine)
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _engine(**kw):
+    model, params = build_model(seed=3, vocab_size=61, num_layers=2,
+                                d_model=32, num_heads=4, mlp_dim=64,
+                                max_len=96)
+    kw = {"max_slots": 3, "max_len": 96, "kv_block_size": 8,
+          "prefill_chunk": 8, **kw}
+    return PagedEngine(model, params, **kw)
+
+
+def _requests(seed=4, n=6):
+    return list(make_trace(n, vocab_size=61, seed=seed, prompt_lens=(4, 20),
+                           new_tokens=(3, 6)))
+
+
+class _Closed(Exception):
+    pass
+
+
+# ------------------------------------------------------------ front door
+
+def test_span_with_nothing_listening_is_the_shared_null_context():
+    assert obs_trace.installed_tracer() is None
+    a, b = span("x"), span("y", tick=3)
+    assert a is b                       # one object, nothing allocated
+    with a:
+        pass
+    pc = PhaseClock(("p", "q"))
+    with pc.tick(0) as tk:
+        with pc.phase("p"):
+            pass
+        tk.kind = "work"
+    assert pc.phase("p")._span is None  # no span object was ever made
+    assert pc.counts == [1, 0] and pc.n_ticks == 1
+
+
+def test_span_records_into_the_installed_tracer_with_its_parent():
+    tr = Tracer()
+    with obs_trace.use_tracer(tr):
+        assert obs_trace.installed_tracer() is tr
+        with span("outer", trace_id="engine", track="engine", tick=7):
+            with span("inner"):
+                pass
+            with span("cow", trace_id="req-5", track="req5", parent=None):
+                pass
+    assert obs_trace.installed_tracer() is None
+    by = {s.name: s for s in tr.spans}
+    assert by["inner"].parent_id == by["outer"].span_id
+    assert (by["inner"].trace_id, by["inner"].track) == ("engine", "engine")
+    assert by["outer"].attrs == {"tick": 7}
+    assert by["cow"].trace_id == "req-5"
+    assert by["outer"].t0 <= by["inner"].t0 <= by["inner"].t1 <= by["outer"].t1
+    # use_tracer(None) leaves an outer tracer in place
+    with obs_trace.use_tracer(tr), obs_trace.use_tracer(None):
+        assert obs_trace.installed_tracer() is tr
+
+
+def test_phase_clock_sums_ticks_and_aborted_ticks():
+    now = [0.0]
+    pc = PhaseClock(("a", "b"), spanless=("b",), ring=2,
+                    clock=lambda: now[0])
+
+    def spend(name, dt):
+        with pc.phase(name):
+            now[0] += dt
+
+    for i in range(3):
+        with pc.tick(i) as tk:
+            spend("a", 1.0)
+            spend("b", 0.5)
+            spend("a", 0.25)
+            tk.kind, tk.meta = "decode", (i, 1)
+    with pytest.raises(_Closed):
+        with pc.tick(3):
+            spend("a", 2.0)
+            raise _Closed
+    assert pc.seconds == [5.75, 1.5] and pc.counts == [7, 3]
+    assert pc.n_ticks == 4 and len(pc.ticks) == 2       # bounded ring
+    assert pc.ticks[0] == (2, "decode", (2, 1), 1.75, (1.25, 0.5))
+    assert pc.ticks[1][:2] == (3, "aborted") and pc.ticks[1][4] == (2.0, 0.0)
+    assert pc.summary() == {"a": {"seconds": 5.75, "count": 7},
+                            "b": {"seconds": 1.5, "count": 3}}
+
+
+# ------------------------------------------- the tick tree in the xplane
+
+def _as_the_probe_wraps(engine):
+    """Wrap the decode program as benchmark/runners/serve.py::_Probe
+    does: an object in the engine's attribute that annotates the call."""
+    prog = engine._decode
+
+    class Spy:
+        traces = property(lambda s: prog.traces)
+        _jit = prog._jit
+
+        def __call__(s, *args):
+            with jax.profiler.TraceAnnotation("bench:decode_dispatch"):
+                return prog(*args)
+
+    engine._decode = Spy()
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    """One tiny engine: an untraced run, then the same requests under the
+    profiler AND a Tracer, with the decode program wrapped as the
+    benchmark's probe wraps it."""
+    eng = _engine()
+    plain = eng.run(_requests())
+    _as_the_probe_wraps(eng)
+    eng.reset()
+    d = str(tmp_path_factory.mktemp("xplane"))
+    tr = Tracer()
+    jax.profiler.start_trace(d)
+    try:
+        with jax.profiler.TraceAnnotation("bench:window"), \
+                obs_trace.use_tracer(tr):
+            out = eng.run(_requests())
+    finally:
+        jax.profiler.stop_trace()
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")
+        trace = xplane.load(xplane.newest(d))
+    return {"plain": plain, "out": out, "trace": trace, "tracer": tr,
+            "dir": d}
+
+
+def test_tracing_changes_neither_outputs_nor_compile_counts(traced):
+    plain, out = traced["plain"], traced["out"]
+    assert sorted(plain["results"]) == sorted(out["results"])
+    for uid, toks in plain["results"].items():
+        assert list(toks) == list(out["results"][uid])
+    for stats in (plain["stats"], out["stats"]):
+        assert stats["chunk_compiles"] == 1 and stats["decode_compiles"] == 1
+
+
+def test_xplane_holds_the_tick_tree_on_one_clock(traced):
+    (line, rows), = traced["trace"]["spans"].items()
+    names = {n for _, _, n, _ in rows}
+    assert {"ddl:" + p for p in TICK_PHASES} - {"ddl:hook"} <= names
+    ticks = [(s, t) for s, t, n, _ in rows if n == "ddl:tick"]
+    assert len(ticks) == traced["out"]["stats"]["phases"]["admit"]["count"]
+    bench = [(s, t) for s, t, n, _ in rows if n == "bench:decode_dispatch"]
+    mine = [(s, t) for s, t, n, _ in rows if n == "ddl:decode_dispatch"]
+    assert mine and len(mine) == len(bench)
+    for s, t in mine:
+        assert any(a <= s and t <= b for a, b in ticks)
+        assert any(a <= s and t <= b for a, b in bench)
+    assert xplane.nesting(traced["trace"]) == [
+        ["decode_dispatch", len(mine), len(mine)]]
+    # every phase span lies inside a tick, and a tick's attrs arrive
+    for s, t, n, stats in rows:
+        if n.startswith("ddl:") and n not in ("ddl:tick", "ddl:submit"):
+            assert any(a <= s and t <= b for a, b in ticks), n
+        if n == "ddl:tick":
+            assert {"tick", "decoding", "prefilling", "queue"} <= set(stats)
+    progs = {p[0] for p in xplane.programs(traced["trace"])}
+    assert {"jit_paged_chunk", "jit_paged_decode"} <= progs
+    assert not any("counted" in p for p in progs)
+
+
+def test_tracer_gets_the_same_tree_and_the_request_chains(traced):
+    spans = list(traced["tracer"].spans)
+    by_id = {s.span_id: s for s in spans}
+    ticks = [s for s in spans if s.name == "tick"]
+    assert ticks and all(s.track == "engine" for s in ticks)
+    for s in spans:         # (a request's own ``admit`` is on its track)
+        if s.name in TICK_PHASES and s.track == "engine":
+            assert by_id[s.parent_id].name == "tick", s.name
+    roots = {s.trace_id: s for s in spans if s.name == "request"}
+    assert len(roots) == 6
+    for s in spans:
+        if s.name in ("queued", "prefill_chunk", "decode", "retire"):
+            assert s.parent_id == roots[s.trace_id].span_id
+
+
+def test_idle_falls_under_named_phases_and_the_report_renders(traced):
+    idle = xplane.idle_by_phase(traced["trace"])
+    assert idle["idle_s"] > 0
+    assert idle["named_s"] >= 0.9 * idle["idle_s"]
+    assert abs(idle["busy_s"] + idle["idle_s"] - idle["window_s"]) < 1e-6
+    assert abs(sum(r[1] + r[2] for r in idle["by_phase"])
+               - idle["idle_s"]) < 1e-6
+    assert abs(idle["between_s"] + idle["inside_s"] - idle["idle_s"]) < 1e-6
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "scripts", "obs_report.py"),
+         "--xplane", traced["dir"]], capture_output=True, text=True,
+        env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO),
+        timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    for needle in ("device idle by host phase", "decode_prepare",
+                   "jit_paged_decode", "under a named phase",
+                   "decode_dispatch", "kv_gather", "kv_write",
+                   "CausalLM/layer_*/self_attn"):
+        assert needle in proc.stdout, needle
+
+
+def test_innermost_and_scope_of():
+    rows = [(0, 100, "ddl:tick", {}), (10, 30, "ddl:admit", {}),
+            (12, 20, "ddl:cow", {}), (40, 60, "ddl:hook", {}),
+            (45, 50, "bench:on_tick", {}), (200, 210, "ddl:tick", {})]
+    assert xplane.innermost(rows) == [
+        (0, 10, "ddl:tick"), (10, 12, "ddl:admit"), (12, 20, "ddl:cow"),
+        (20, 30, "ddl:admit"), (30, 40, "ddl:tick"), (40, 60, "ddl:hook"),
+        (60, 100, "ddl:tick"), (200, 210, "ddl:tick")]
+    f = xplane.scope_of
+    assert f("jit(train_step)/transpose(jvp(CausalLM))/layer_7/self_attn/"
+             "q/dot_general") == "CausalLM/layer_*/self_attn"
+    assert f("jit(paged_decode)/vmap(kv_gather)/gather") == "kv_gather"
+    assert f("jit(train_step)/optimizer/mul") == "optimizer"
+    assert f("jit(train_step)/jvp(loss)/jit(_take)/gather", 1) == "loss"
+    assert f("jit(x)/add") == "(top level) add" and f("") == "(no op_name)"
+    assert f("args[1]['layer_0']['self_attn']['cached_key']") == \
+        "(argument) args[1]"
+
+
+# ------------------------------------- records that outlive a raising run
+
+def test_a_run_ended_by_its_hook_leaves_its_record():
+    eng = _engine()
+    eng.run(_requests(seed=9, n=2))             # an earlier run's record
+    before = obs.last_run("serve")
+    seen = []
+
+    def hook(report):
+        seen.append(report.tick)
+        if len(seen) == 7:
+            raise _Closed
+
+    with pytest.raises(_Closed):
+        eng.run(_requests(), on_tick=hook)
+    rec = obs.last_run("serve")
+    assert rec is not before and rec.kind == "serve"
+    assert rec.meta["max_slots"] == 3 and rec.registry is not None
+    pc = rec.phases
+    assert pc.names == TICK_PHASES
+    assert pc.n_ticks == len(pc.ticks) and pc.ticks[-1][1] == "aborted"
+    assert pc.ticks[-1][0] == seen[-1]          # that run's ticks
+    done = [t for t in pc.ticks if t[1] != "aborted"]
+    assert done and all(t[1] in ("decode", "prefill") for t in done)
+    sums = dict(zip(pc.names, pc.seconds))
+    assert sums["hook"] > 0 and pc.counts[pc.names.index("hook")] == 7
+    for name in ("admit", "decode_dispatch", "decode_wait", "tick_end"):
+        assert sums[name] > 0, name
+    # a tick's phases add up to its wall time (the rest is loop glue)
+    walls = sum(t[3] for t in pc.ticks)
+    inside = sum(sum(t[4]) for t in pc.ticks)
+    assert 0.8 * walls <= inside <= walls * (1 + 1e-9)
+    for t in done:
+        assert sum(t[4]) <= t[3] * (1 + 1e-9)
+    # and run totals = the ring's rows (nothing fell off this short ring)
+    for i, s in enumerate(pc.seconds):
+        assert abs(s - sum(t[4][i] for t in pc.ticks)) < 1e-9
+
+
+def test_stats_carry_the_phase_sums_when_run_returns():
+    out = _engine().run(_requests())
+    phases = out["stats"]["phases"]
+    assert set(phases) <= set(TICK_PHASES) and "hook" not in phases
+    assert phases["decode_dispatch"]["count"] == out["stats"]["decode_ticks"]
+    assert phases["chunk_dispatch"]["count"] == \
+        out["stats"]["prefill_chunks"]
+    assert set(DISPATCH_PHASES) <= set(phases)
+
+
+def test_loader_publishes_its_batches():
+    from distributed_deep_learning_tpu.data.datasets import ArrayDataset
+    from distributed_deep_learning_tpu.data.loader import DeviceLoader
+    from distributed_deep_learning_tpu.runtime.mesh import build_mesh
+
+    ds = ArrayDataset(np.arange(40 * 3, dtype=np.float32).reshape(40, 3),
+                      np.arange(40, dtype=np.int32))
+    mesh = build_mesh({"data": 1}, jax.devices()[:1])
+    loader = DeviceLoader(ds, np.arange(40), 8, mesh)
+    tr = Tracer()
+    with obs_trace.use_tracer(tr):
+        got = [np.asarray(x) for x, _ in loader]
+    assert len(got) == 5 and got[0].shape == (8, 3)
+    rec = obs.last_run("loader")
+    assert rec is loader.record and rec.meta["global_batch_size"] == 8
+    batches = [t for t in rec.phases.ticks if t[1] == "batch"]
+    assert len(batches) == 5 and rec.phases.counts == [6, 5]
+    assert all(t[4][0] > 0 and t[4][1] > 0 for t in batches)
+    list(loader)                                # a second epoch adds on
+    assert rec.phases.counts == [12, 10]
+    names = [s.name for s in tr.spans]
+    assert names.count("batch") == 6 and names.count("h2d_enqueue") == 5
+    by_id = {s.span_id: s for s in tr.spans}
+    assert all(by_id[s.parent_id].name == "batch" for s in tr.spans
+               if s.name in ("batch_form", "h2d_enqueue"))
+
+
+# ------------------------------------------------------------ compile log
+
+def test_compile_log_names_a_retraced_program():
+    from distributed_deep_learning_tpu.runtime.bootstrap import (
+        enable_compile_cache)
+
+    enable_compile_cache()
+    log = obs.compile_log
+    assert log.since_mark() == []               # marked at every start
+
+    def wobbly_step(x):
+        return x * 2 + 1
+
+    step = jax.jit(wobbly_step)
+    step(jnp.ones(3))
+    step(jnp.ones(3))                           # cached: nothing logged
+    step(jnp.ones(4))                           # a new shape: a retrace
+    mine = [e for e in log.since_mark() if e[1] in (
+        "wobbly_step", "jit(wobbly_step)")]
+    assert [e[0] for e in mine].count("trace") == 2
+    assert [e[0] for e in mine].count("lower") == 2
+    assert [e[0] for e in mine].count("compile") == 2
+    assert all(e[3] >= 0 and e[2] > 1e9 for e in mine)
+    got = log.seconds(["wobbly_step"], ("trace", "lower", "compile"))
+    assert all(v > 0 for v in got.values())
+    assert log.seconds(["never_ran"]) == {"trace": 0.0, "lower": 0.0}
+    enable_compile_cache()
+    assert log.since_mark() == []
+    eng = _engine()
+    eng.run(_requests(n=2))
+    named = {e[1] for e in log.since_mark() if e[0] == "trace"}
+    assert {"paged_chunk", "paged_decode"} <= named
+
+
+# ------------------------------------------------- scopes are metadata
+
+def _decode_args(eng):
+    s = eng.max_slots
+    z = jnp.zeros(s, jnp.int32)
+    return (eng.params, eng.pools,
+            jnp.zeros((s, eng.blocks_per_slot), jnp.int32), z, z, z, z,
+            eng._next_key())
+
+
+def _tiny_train_step():
+    from distributed_deep_learning_tpu.data.tokens import TokenArrayDataset
+    from distributed_deep_learning_tpu.runtime.mesh import build_mesh
+    from distributed_deep_learning_tpu.train.state import create_train_state
+    from distributed_deep_learning_tpu.utils.config import parse_args
+    from distributed_deep_learning_tpu.workloads import base as wb
+    from distributed_deep_learning_tpu.workloads import get_spec
+
+    config = parse_args("-l 2 -s 32 -b 4 -m sequential".split(),
+                        workload="gpt")
+    spec = get_spec("gpt")
+    tok = np.zeros((16, 33), np.int32)
+    tok[0, 0] = 60
+    ds = TokenArrayDataset(tok[:, :-1], tok[:, 1:], 61)
+    mesh = build_mesh({"data": 1}, jax.devices()[:1])
+    model = spec.build_model(config, ds)
+    state = create_train_state(model, jax.random.key(0),
+                               spec.example_input(config, ds),
+                               wb.build_optimizer(spec, config, 17))
+    sspec = wb.derive_state_spec(spec, config, mesh, state)
+    step, _ = wb.make_train_eval_steps(config, mesh, spec.build_loss(config),
+                                       sspec)
+    x = jnp.zeros((4, 32), jnp.int32)
+    return step.lower(state, x, x)
+
+
+def _analysis(lowered):
+    from distributed_deep_learning_tpu.utils.profiling import (
+        normalize_cost_analysis, normalize_memory_analysis)
+
+    c = lowered.compile()
+    return (normalize_cost_analysis(c.cost_analysis()).get("flops"),
+            normalize_memory_analysis(c.memory_analysis()), c.as_text())
+
+
+@pytest.mark.parametrize("program", ["decode", "train_step"])
+def test_named_scopes_change_no_cost_and_no_memory(program, monkeypatch):
+    def lower():
+        if program == "decode":
+            eng = _engine()
+            return eng._decode._jit.lower(*_decode_args(eng))
+        return _tiny_train_step()
+
+    flops, memory, text = _analysis(lower())
+    wanted = (("kv_gather", "kv_write", "sample") if program == "decode"
+              else ("loss", "optimizer", "head"))
+    for scope in wanted:
+        assert f"{scope}/" in text or f"({scope})" in text, scope
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    flops0, memory0, text0 = _analysis(lower())
+    assert "kv_gather" not in text0 and "optimizer/" not in text0
+    assert flops == flops0 and flops > 0
+    assert memory == memory0 and memory
+
+
+# ------------------------------------------------ the benchmark's readers
+
+def _spec(metric):
+    with open(os.path.join(REPO, "benchmark", "metrics",
+                           metric + ".json")) as f:
+        spec = json.load(f)
+    if REPO not in sys.path:
+        sys.path.insert(0, REPO)
+    return importlib.import_module(
+        "benchmark.readers." + spec["reader"]).read, spec["args"]
+
+
+def _serve_record():
+    pc = PhaseClock(TICK_PHASES)
+    i = {n: k for k, n in enumerate(TICK_PHASES)}
+
+    def row(**ms):
+        r = [0.0] * len(TICK_PHASES)
+        for k, v in ms.items():
+            r[i[k]] = v / 1e3
+        return tuple(r)
+
+    # two decode ticks with a chunk, one without, one prefill-only tick,
+    # one aborted: (index, kind, (decoding slots, chunks), wall, row)
+    with_chunk = dict(admit=0.2, chunk_prepare=1.0, chunk_dispatch=2.0,
+                      chunk_commit=0.3, chunk_wait=110.0, decode_prepare=1.5,
+                      decode_dispatch=1.0, decode_wait=160.0,
+                      decode_commit=0.6, hook=0.9, tick_end=0.1)
+    pc.ticks.extend([
+        (0, "prefill", (0, 1), 0.1135, row(admit=0.2, chunk_prepare=1.0,
+                                           chunk_dispatch=2.0,
+                                           chunk_wait=110.0)),
+        (1, "decode", (4, 1), 0.2780, row(**with_chunk)),
+        (2, "decode", (4, 1), 0.2800, row(**{**with_chunk,
+                                             "chunk_wait": 112.0})),
+        (3, "decode", (4, 0), 0.1650, row(admit=0.1, decode_prepare=1.5,
+                                          decode_dispatch=1.0,
+                                          decode_wait=160.0,
+                                          decode_commit=0.6, hook=0.9,
+                                          tick_end=0.1)),
+        (4, "aborted", (), 0.5, row(admit=0.1)),
+    ])
+    return runlog.RunRecord("serve", pc)
+
+
+def test_serve_readers_on_a_hand_made_record(monkeypatch):
+    monkeypatch.setitem(runlog._LAST, "serve", _serve_record())
+    read, args = _spec("serve_tick_host_ms")
+    # wall less the four program phases, median over the 3 decode ticks:
+    # 278.0 - 273.0, 280.0 - 275.0, 165.0 - 161.0
+    assert read({}, **args) == pytest.approx(5.0)
+    read, args = _spec("serve_sched_ms")
+    # admit + chunk_commit + decode_commit + tick_end: 1.2, 1.2, 0.8
+    assert read({}, **args) == pytest.approx(1.2)
+    read, args = _spec("serve_chunk_program_ms")
+    # dispatch + wait of the ticks that ran a chunk, prefill tick too
+    assert read({}, **args) == pytest.approx(112.0)
+
+
+def test_train_input_reader_on_a_hand_made_record(monkeypatch):
+    pc = PhaseClock(("batch_form", "h2d_enqueue"))
+    pc.ticks.extend([(0, "batch", (), 0.001, (0.0004, 0.0003)),
+                     (1, "batch", (), 0.001, (0.0002, 0.0003)),
+                     (2, "batch", (), 0.001, (0.0009, 0.0003)),
+                     (3, "idle", (), 0.001, (0.0001, 0.0))])
+    monkeypatch.setitem(runlog._LAST, "loader", runlog.RunRecord("loader", pc))
+    read, args = _spec("train_input_ms")
+    assert read({}, **args) == pytest.approx(0.7)
+
+
+def test_setup_trace_lower_reader_on_a_hand_made_log(monkeypatch):
+    log = runlog.CompileLog()
+    log.entries.extend([
+        ("trace", "train_step", 1.0, 9.0),      # before the mark: left out
+        ("mark", "enable_compile_cache", 2.0, 0.0),
+        ("trace", "paged_decode", 3.0, 4.0),
+        ("trace", "layer_norm", 3.5, 0.5),      # nested, not a program
+        ("lower", "jit(paged_decode)", 7.0, 1.5),
+        ("compile", "jit(paged_decode)", 8.5, 30.0),
+        ("retrieve", None, 9.0, 0.25),
+        ("trace", "token_gaps", 40.0, 6.0),     # the benchmark's reference
+        ("trace", "paged_chunk", 50.0, 2.0)])
+    monkeypatch.setattr(obs, "compile_log", log)
+    read, args = _spec("setup_trace_lower_s")
+    assert read({}, **args) == pytest.approx(7.5)
+
+
+@pytest.mark.parametrize("metric", [
+    "serve_tick_host_ms", "serve_sched_ms", "serve_chunk_program_ms",
+    "train_input_ms", "setup_trace_lower_s"])
+def test_readers_give_none_where_there_is_nothing_to_read(metric,
+                                                          monkeypatch):
+    monkeypatch.setattr(runlog, "_LAST", {})
+    monkeypatch.setattr(obs, "last_run", runlog._LAST.get)
+    monkeypatch.setattr(obs, "compile_log", runlog.CompileLog())
+    read, args = _spec(metric)
+    assert read({}, **args) is None
+    # a record with no tick of the kind read gives None too
+    empty = PhaseClock(TICK_PHASES if metric.startswith("serve")
+                       else ("batch_form", "h2d_enqueue"))
+    kind = "serve" if metric.startswith("serve") else "loader"
+    monkeypatch.setattr(obs, "last_run",
+                        {kind: runlog.RunRecord(kind, empty)}.get)
+    assert read({}, **args) is None
+    # and so does a program that has no such record at all (the parent)
+    monkeypatch.delattr(obs, "last_run")
+    monkeypatch.delattr(obs, "compile_log")
+    assert read({}, **args) is None
+
+
+@pytest.mark.parametrize("metric", [
+    "serve_tick_host_ms", "serve_sched_ms", "serve_chunk_program_ms",
+    "train_input_ms", "setup_trace_lower_s"])
+def test_readers_give_none_on_a_record_of_another_shape(metric,
+                                                        monkeypatch, capsys):
+    """A reader of the program's own records never ends the benchmark's
+    run: a program whose record or log is not what this reader knows
+    leaves the metric out, and says why."""
+    monkeypatch.setattr(obs, "last_run", lambda kind: object())
+    monkeypatch.setattr(obs, "compile_log", object())
+    read, args = _spec(metric)
+    assert read({}, **args) is None
+    assert "nothing to read (AttributeError" in capsys.readouterr().out

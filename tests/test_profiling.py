@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from distributed_deep_learning_tpu.utils.profiling import (
-    StepTimer, annotate, compiled_text, cost_analysis, hlo_text,
+    StepTimer, compiled_text, cost_analysis, hlo_text,
     memory_analysis, normalize_cost_analysis, normalize_memory_analysis,
     trace)
 
@@ -72,11 +72,6 @@ def test_trace_writes_files(tmp_path):
 def test_trace_none_is_noop():
     with trace(None):
         pass
-
-
-def test_annotate_nests():
-    with annotate("outer"), annotate("inner"):
-        jax.block_until_ready(_fn(jnp.ones((8, 8))))
 
 
 def test_step_timer_rates():
