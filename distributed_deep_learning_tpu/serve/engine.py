@@ -785,7 +785,11 @@ class PagedEngine:
             donate = jax.default_backend() != "cpu"
         dk = {"donate_argnums": (1,)} if donate else {}
         ck = {"donate_argnums": (0,)} if donate else {}
-        self.pools = self._new_pools(self.lm)
+        # one slot's at-rest cache in the model's layout: the pools are
+        # folded from it and every gather is unfolded by it
+        self._slot_like = paged.slot_template(self.lm, self.padded_len,
+                                              kv_dtype=kv_dtype)
+        self.pools = self._new_pools(self._slot_like)
         self._chunk_prog = CountingJit(self._chunk_impl, "paged_chunk",
                                        "chunk_dispatch", **dk)
         self._decode = CountingJit(self._decode_impl, "paged_decode",
@@ -835,7 +839,9 @@ class PagedEngine:
             # the draft pool INHERITS kv_dtype: speculation gathers and
             # scatters through the same shims, so a mixed-precision pair
             # would silently double the draft's footprint
-            self.draft_pools = self._new_pools(self.draft_lm)
+            self._draft_slot_like = paged.slot_template(
+                self.draft_lm, self.padded_len, kv_dtype=kv_dtype)
+            self.draft_pools = self._new_pools(self._draft_slot_like)
             self._draft = CountingJit(self._draft_impl, "paged_draft",
                                       "decode_dispatch", **dk)
             self._verify = CountingJit(self._verify_impl, "paged_verify",
@@ -856,14 +862,15 @@ class PagedEngine:
         self._base_chunks_per_tick = self.chunks_per_tick
         self._canary: Optional[_CanaryState] = None
 
-    def _new_pools(self, lm):
-        """Zeroed block pools for `lm`, placed where the weights live.
+    def _new_pools(self, like):
+        """Zeroed block pools for slots shaped `like`, placed where the
+        weights live.
         Weights that come out of a training run are committed to its mesh,
         and so is whatever a jit computes from them: pools left as plain
         arrays would change type on their first trip through a program
         and make it trace a second time."""
-        pools = paged.build_pools(lm, self.num_blocks + 1, self.block_size,
-                                  self.padded_len, kv_dtype=self.kv_dtype)
+        pools = paged.build_pools(like, self.num_blocks + 1,
+                                  self.block_size)
         sharding = getattr(jax.tree.leaves(self.params)[0], "sharding", None)
         if isinstance(sharding, NamedSharding):
             pools = jax.device_put(
@@ -879,10 +886,12 @@ class PagedEngine:
             return params
         return quant.dequantize_weights(params, self.compute_dtype)
 
-    def _gather(self, pools, table, pos):
-        """Gather one slot's logical cache and lift it to the model's
-        working precision (int8 pools dequantize ``q * s`` in f32)."""
-        got = paged.gather_slot(pools, table, pos)
+    def _gather(self, pools, table, pos, like=None):
+        """Gather one slot's logical cache (`like`: the draft's template
+        for the draft's pools) and lift it to the model's working
+        precision (int8 pools dequantize ``q * s`` in f32)."""
+        got = paged.gather_slot(
+            pools, table, pos, self._slot_like if like is None else like)
         if self.kv_dtype is None:
             return got
         return quant.dequant_cache(got, self.compute_dtype)
@@ -935,7 +944,7 @@ class PagedEngine:
         """The draft model's KV for the same chunk — speculation needs
         the draft's cache warm over the whole committed stream."""
         dparams = self._wp(dparams)
-        cache = self._gather(dpools, table, pos)
+        cache = self._gather(dpools, table, pos, self._draft_slot_like)
         _, new = cached_apply(self.draft_lm, dparams, cache, tokens[None])
         span = paged.extract_span(new, pos, self.chunk)
         return paged.scatter_span(dpools, self._qspan(span), wb, wo)
@@ -976,7 +985,7 @@ class PagedEngine:
         dparams = self._wp(dparams)
 
         def one(table, pos, tok):
-            cache = self._gather(dpools, table, pos)
+            cache = self._gather(dpools, table, pos, self._draft_slot_like)
 
             def step(carry, _):
                 c, t = carry
@@ -1029,7 +1038,7 @@ class PagedEngine:
         (no dequant — an int8 pool spills int8 + scales, so the round
         trip back through :meth:`_unspill_impl` is bit-exact by
         construction).  The preemption read path."""
-        return paged.gather_slot(pools, table, 0)
+        return paged.gather_slot(pools, table, 0, self._slot_like)
 
     def _unspill_impl(self, pools, kv, blocks, offsets):
         """Write a spilled slot image back: positions ``< committed``
@@ -1111,9 +1120,9 @@ class PagedEngine:
         self.manager = paged.BlockManager(self.num_blocks, self.block_size,
                                           self.max_slots,
                                           self.blocks_per_slot)
-        self.pools = self._new_pools(self.lm)
+        self.pools = self._new_pools(self._slot_like)
         if self.draft_layers is not None:
-            self.draft_pools = self._new_pools(self.draft_lm)
+            self.draft_pools = self._new_pools(self._draft_slot_like)
         self.restarts += 1
 
     def swap_params(self, new_params) -> None:

@@ -6,15 +6,29 @@ requests sharing one system prompt each re-prefill it, and each holds a
 private copy of identical KV.  This module re-hosts the cache one level
 lower, as vLLM-style PAGES:
 
-* **Device side** — each sequence-axis cache leaf becomes a pool
-  ``(num_blocks, block_size, ...)``; a slot's logical cache is the
-  concatenation of the physical blocks its BLOCK TABLE names.
-  :func:`gather_slot` materialises one slot back into the model's
-  ``B=1`` cache layout (so the engine still runs the model's own tested
-  cached decode — paging is invisible to the model), and
-  :func:`scatter_span` writes freshly-computed KV positions back into
-  their blocks.  All shapes are static; tables/positions are data, so
-  the compile-once contract survives intact.
+* **Device side** — each sequence-axis cache leaf ``(1, T, *trailing)``
+  becomes a pool ``(num_blocks, block_size, prod(trailing))``: K/V
+  ``(nb, bs, H*D)``, int8 scales ``(nb, bs, H)``, validity ``(nb, bs)``.
+  A slot's logical cache is the concatenation of the physical blocks
+  its BLOCK TABLE names.  :func:`gather_slot` materialises one slot
+  back into the model's ``B=1`` cache layout, trailing dims restored
+  from the slot's template (:func:`slot_template`) — so the engine
+  still runs the model's own tested cached decode, paging is invisible
+  to the model — and :func:`scatter_span` writes freshly-computed KV
+  positions back into their blocks.  All shapes are static;
+  tables/positions are data, so the compile-once contract survives
+  intact.
+
+  Why the trailing dims are merged AT REST: a TPU tiles the two minor
+  dims of a buffer (8 x 128 for bf16 pairs), and for a ``(H, D)`` minor
+  pair like GPT-2 XL's 25 x 64 it would pad 2.56x, so it rests a 4-D
+  pool leaf block-index-minor instead — while the gather and the
+  scatter compute row-major.  Every program then copied every whole
+  pool leaf into the computing layout and back to write a handful of
+  positions: 73-91% of the device time of a serving tick (PERF.md,
+  PR 24 / PR 25).  ``H*D`` minor (1600, 1024, ...) is lane-dense as it
+  is, rests row-major, and the scatter updates the donated pool in
+  place; ``tests/test_chip_compile.py`` holds the compiler to it.
 * **Host side** — :class:`BlockManager` owns the free list, per-block
   refcounts, per-slot tables, and a :class:`PrefixIndex` keyed by a
   ROLLING CHAIN HASH of token-prefix chunks: ``h_i = H(h_{i-1} ||
@@ -41,6 +55,7 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
+import math
 from typing import Optional, Sequence
 
 import jax
@@ -48,8 +63,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from distributed_deep_learning_tpu.models.transformer import init_cache
+from distributed_deep_learning_tpu.serve import quant
 from distributed_deep_learning_tpu.serve.cache import (COUNTER_LEAVES,
-                                                       KV_LEAVES,
                                                        _leaf_name)
 
 #: physical id of the write-discard / read-garbage block (never allocated)
@@ -73,64 +88,69 @@ def chain_hash(prev: bytes, tokens: Sequence[int]) -> bytes:
 # --- device-side pool ops (pure functions of pytrees) ---------------------
 
 
-def build_pools(lm, num_blocks: int, block_size: int, padded_len: int,
-                token_dtype=jnp.int32, kv_dtype: Optional[str] = None):
-    """Zeroed block pools shaped from the decode model's own cache.
-
-    ``eval_shape`` of a ``(1, padded_len)`` cache init gives the leaf
-    vocabulary; sequence-axis leaves (``cached_key/value/valid``) become
-    ``(num_blocks, block_size, ...)`` pools, counter leaves shrink to a
-    placeholder (positions are host-owned — the host scheduler must know
-    every slot's position anyway, so the device copy would only mirror
-    it; :func:`gather_slot` injects the host value instead).
+def slot_template(lm, padded_len: int, token_dtype=jnp.int32,
+                  kv_dtype: Optional[str] = None):
+    """Shapes and dtypes of ONE slot's cache in the model's ``B=1``
+    layout, KV payload leaves in their at-rest form — what
+    :func:`gather_slot` hands back and what :func:`build_pools` folds
+    into blocks.  ``eval_shape`` of a ``(1, padded_len)`` cache init: no
+    forward, no arrays.
 
     ``kv_dtype`` picks the at-rest precision of the KV payload leaves:
     ``None`` keeps the model's own dtype, ``"bf16"`` halves it, and
     ``"int8"`` stores each KV leaf as a :class:`.quant.QuantTensor`
-    (int8 pool + an f32 per-position-per-head scale pool with the same
-    leading dims, so every tree-mapped pool op below indexes both
-    coherently).  Bool validity and counters are exact regardless."""
-    if padded_len != (padded_len // block_size) * block_size:
-        raise ValueError(f"padded_len {padded_len} must be a multiple of "
-                         f"block_size {block_size}")
-    per_slot = init_cache(lm, 1, padded_len, token_dtype)
+    (int8 payload ``(1, T, H, D)`` + f32 per-position-per-head scales
+    ``(1, T, H, 1)``).  Bool validity and counters are exact regardless."""
+    def at_rest():
+        cache = init_cache(lm, 1, padded_len, token_dtype)
+        if kv_dtype is None:
+            return cache
+        return quant.quantize_cache_span(cache, kv_dtype)
 
+    return jax.eval_shape(at_rest)
+
+
+def build_pools(like, num_blocks: int, block_size: int):
+    """Zeroed block pools for slots shaped `like` (:func:`slot_template`).
+
+    Every sequence-axis leaf ``(1, T, *trailing)`` becomes a pool
+    ``(num_blocks, block_size, prod(trailing))`` — K/V ``(nb, bs, H*D)``,
+    int8 scales ``(nb, bs, H)``, ``cached_valid`` ``(nb, bs)`` — one rule,
+    read off the leaf's own shape (why merged: the module docstring).
+    Every pool op below indexes the two leading dims only, so a
+    :class:`.quant.QuantTensor`'s payload and scales move coherently.
+    Counter leaves shrink to a placeholder (positions are host-owned —
+    the host scheduler must know every slot's position anyway, so the
+    device copy would only mirror it; :func:`gather_slot` injects the
+    host value instead)."""
     def alloc(path, leaf):
         if is_counter(path):
             return jnp.zeros((), leaf.dtype)          # unused placeholder
-        shape = (num_blocks, block_size) + leaf.shape[2:]
-        if kv_dtype is not None and _leaf_name(path) in KV_LEAVES \
-                and jnp.issubdtype(leaf.dtype, jnp.floating):
-            if kv_dtype == "bf16":
-                return jnp.zeros(shape, jnp.bfloat16)
-            if kv_dtype == "int8":
-                from distributed_deep_learning_tpu.serve.quant import \
-                    QuantTensor
-                return QuantTensor(
-                    jnp.zeros(shape, jnp.int8),
-                    jnp.zeros(shape[:-1] + (1,), jnp.float32))
-            raise ValueError(f"unknown kv_dtype {kv_dtype!r}")
-        return jnp.zeros(shape, leaf.dtype)
+        if leaf.shape[1] % block_size:
+            raise ValueError(f"padded_len {leaf.shape[1]} must be a "
+                             f"multiple of block_size {block_size}")
+        merged = (math.prod(leaf.shape[2:]),) if leaf.ndim > 2 else ()
+        return jnp.zeros((num_blocks, block_size) + merged, leaf.dtype)
 
-    return jax.tree_util.tree_map_with_path(alloc, per_slot)
+    return jax.tree_util.tree_map_with_path(alloc, like)
 
 
-def gather_slot(pools, table, pos):
+def gather_slot(pools, table, pos, like):
     """One slot's logical cache in the model's ``B=1`` layout.
 
     ``table`` is the slot's ``(blocks_per_slot,)`` physical block ids and
     ``pos`` its position counter — both traced, so one compiled program
-    serves every slot, table and position.  Trash entries gather garbage
-    that the decode-path causal prefix mask (``kpos <= qpos``) keeps
-    causally unreachable."""
-    def g(path, leaf):
+    serves every slot, table and position.  `like` (the pools'
+    :func:`slot_template`) gives each leaf its trailing dims back, on
+    the gathered slot and never on the pool.  Trash entries gather
+    garbage that the decode-path causal prefix mask (``kpos <= qpos``)
+    keeps causally unreachable."""
+    def g(path, leaf, want):
         if is_counter(path):
             return jnp.asarray(pos, leaf.dtype)
-        got = leaf[table]                              # (Bps, bs, ...)
-        return got.reshape((1, got.shape[0] * got.shape[1])
-                           + got.shape[2:])
+        return leaf[table].reshape((1, -1) + want.shape[2:])
 
-    return jax.tree_util.tree_map_with_path(g, pools)
+    return jax.tree_util.tree_map_with_path(g, pools, like)
 
 
 def extract_span(cache, pos, n: int):
@@ -149,7 +169,8 @@ def scatter_span(pools, kv, blocks, offsets):
     """Write per-position KV back into the pools.
 
     ``blocks``/``offsets`` have shape ``(..., n)`` matching the leading
-    dims of the ``kv`` leaves; entries routed to :data:`TRASH` discard
+    dims of the ``kv`` leaves, whose trailing dims (the model's) are
+    merged into the pool's one; entries routed to :data:`TRASH` discard
     their write (pad tails, inactive slots).  The host guarantees no two
     REAL (block, offset) pairs collide in one call — only trash may be
     written more than once, and trash is never read as truth."""
@@ -163,7 +184,8 @@ def scatter_span(pools, kv, blocks, offsets):
                 f"{pool.dtype} pool — a bare astype would truncate "
                 "without a scale; quantize the span first "
                 "(serve.quant.quantize_cache_span)")
-        return pool.at[blocks, offsets].set(upd.astype(pool.dtype))
+        upd = upd.astype(pool.dtype).reshape(blocks.shape + pool.shape[2:])
+        return pool.at[blocks, offsets].set(upd)
 
     return jax.tree_util.tree_map_with_path(s, pools, kv)
 

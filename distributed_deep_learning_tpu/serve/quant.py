@@ -33,8 +33,10 @@ granularities chosen for the serving data layout:
 
 The quantized KV pool is a tree of :class:`QuantTensor` — a registered
 pytree node holding the int8 payload ``q`` and its f32 scales ``s``
-with IDENTICAL leading dims (``s`` is ``q.shape[:-1] + (1,)``).  That
-shape choice is the whole trick: every existing pool op in
+with IDENTICAL leading dims (in the model's layout ``s`` is
+``q.shape[:-1] + (1,)``; at rest in a pool both have their trailing
+dims merged like every pool leaf, ``(nb, bs, H*D)`` and ``(nb, bs,
+H)``).  That shape choice is the whole trick: every pool op in
 :mod:`.paged` (``gather_slot``'s ``leaf[table]``, ``scatter_span``'s
 ``.at[blocks, offsets]``, ``copy_block``'s block slice) indexes leading
 axes only, so ``jax.tree.map`` descending into ``q`` and ``s`` applies

@@ -9,11 +9,17 @@ topology at the head shapes ``chip_smoke.py`` runs (GPT-2 small: 12 heads of
 came out.  Nothing runs, so this says nothing about values or time: those
 are the smoke's job on the chip.  About 2 s a case; the file sorts before
 the tier-1 timeout cut.
+
+The paged engine's two hot programs are held to one more thing the chip's
+compiler decides: the KV pool rests in the layout the programs compute in,
+so neither copies a whole pool leaf to write a few positions into it.
 """
 
 import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # keep compiler logs out of /tmp
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -113,3 +119,58 @@ def test_paged_flash_decode_compiles_for_v5e(chip, kv_dtype, block):
 
     text = _compiled_text(decode, q, pool, pool, scale, scale, tables, lens)
     assert "tpu_custom_call" in text
+
+
+# the serve cells' engine (benchmark/traffic/*-heavy.json) at gpt2-xl
+# widths, one layer deep: the layout of a pool leaf does not depend on depth
+XL = dict(vocab_size=50257, num_layers=1, d_model=1600, num_heads=25,
+          mlp_dim=6400, max_len=1024, with_logits=True, dtype=jnp.bfloat16)
+CELL = dict(max_slots=16, max_len=1024, kv_block_size=16, num_blocks=1280,
+            prefill_chunk=128, kv_dtype="bf16", donate=True)
+
+
+@pytest.fixture(scope="module")
+def xl_engine(chip):
+    from distributed_deep_learning_tpu.models.transformer import CausalLM
+    from distributed_deep_learning_tpu.serve.engine import PagedEngine
+
+    def on_chip(x, dtype=None):
+        return jax.ShapeDtypeStruct(x.shape, dtype or x.dtype, sharding=chip)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
+
+    model = CausalLM(**XL)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.key(0),
+                           jnp.ones((1, 8), jnp.int32))["params"])
+    params = jax.tree.map(lambda s: on_chip(s, XL["dtype"]), params)
+    engine = PagedEngine(model, params, **CELL)
+    head = (params, jax.tree.map(on_chip, engine.pools))
+    key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
+    slots, chunk, bps = (engine.max_slots, engine.chunk,
+                         engine.blocks_per_slot)
+    return engine, {
+        "paged_chunk": (engine._chunk_prog, head + (
+            i32(chunk), i32(bps), i32(), i32(), i32(chunk), i32(chunk),
+            key)),
+        "paged_decode": (engine._decode, head + (
+            i32(slots, bps), i32(slots), i32(slots), i32(slots),
+            i32(slots), key)),
+    }
+
+
+@pytest.mark.parametrize("program", ["paged_chunk", "paged_decode"])
+def test_paged_program_copies_no_whole_pool_leaf(xl_engine, program):
+    """With the pools donated, the entry computation of the compiled
+    program holds no ``copy`` of a whole K/V pool leaf: 1,281 blocks
+    leading, bf16.  (A 4-D ``bf16[1281,16,25,64]`` leaf rests block-index
+    minor on a TPU and cost two such copies a leaf and call.)"""
+    engine, programs = xl_engine
+    prog, args = programs[program]
+    text = prog._jit.lower(*args).compile().as_text()
+    entry = text[text.index("ENTRY"):]
+    rows = engine.num_blocks + 1
+    assert f"bf16[{rows},16,1600]" in entry      # the pools are in there
+    copies = re.findall(rf"= (bf16\[{rows},[^ ]*) copy\(", entry)
+    assert not copies, copies

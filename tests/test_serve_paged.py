@@ -24,11 +24,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from distributed_deep_learning_tpu.models.transformer import (CausalLM,
-                                                              generate)
+from distributed_deep_learning_tpu.models.transformer import (
+    CausalLM, generate, make_decode_model)
+from distributed_deep_learning_tpu.serve import paged, quant
 from distributed_deep_learning_tpu.serve.engine import PagedEngine
 from distributed_deep_learning_tpu.serve.load import (LoadSpec, make_load,
                                                       slo_report)
+from distributed_deep_learning_tpu.serve.migrate import BlockMigrator
 from distributed_deep_learning_tpu.serve.paged import (TRASH, BlockManager,
                                                        chain_hash)
 from distributed_deep_learning_tpu.serve.prefill import (plan_chunks,
@@ -267,6 +269,104 @@ def test_request_longer_than_capacity_rejected():
 
 
 # --- unit layers --------------------------------------------------------
+
+
+KV_DTYPES = pytest.mark.parametrize("kv_dtype", [None, "bf16", "int8"],
+                                    ids=["f32", "bf16", "int8"])
+HEADS, HEAD_DIM = MODEL["num_heads"], MODEL["d_model"] // MODEL["num_heads"]
+
+
+def _written_pools(kv_dtype, blocks, offsets, seed=0):
+    """Pools of the test model (6 blocks of 8) with one random span
+    scattered at ``blocks``/``offsets``; returns the template, the pools
+    and the span as it rests (int8: payload + scales)."""
+    lm = make_decode_model(_shared()[0])
+    like = paged.slot_template(lm, 48, kv_dtype=kv_dtype)
+    rng = np.random.default_rng(seed)
+
+    def draw(path, leaf):
+        if paged.is_counter(path):
+            return jnp.zeros((), jnp.int32)
+        shape = (len(blocks),) + leaf.shape[2:]
+        if leaf.dtype == jnp.bool_:
+            return jnp.asarray(rng.integers(0, 2, shape), jnp.bool_)
+        return jnp.asarray(rng.normal(size=shape), leaf.dtype)
+
+    span = jax.tree_util.tree_map_with_path(
+        draw, paged.slot_template(lm, 48))
+    if kv_dtype is not None:
+        span = quant.quantize_cache_span(span, kv_dtype)
+    pools = paged.scatter_span(paged.build_pools(like, 6, 8), span,
+                               jnp.asarray(blocks), jnp.asarray(offsets))
+    return like, pools, span
+
+
+def _assert_slot_holds(like, pools, table, positions, span):
+    """The slot gathered through ``table`` comes back in the model's own
+    layout and holds ``span`` at ``positions``, bit for bit."""
+    def check(path, leaf, want, wrote):
+        if paged.is_counter(path):
+            assert int(leaf) == 7
+            return
+        assert leaf.shape == want.shape and leaf.dtype == want.dtype
+        np.testing.assert_array_equal(np.asarray(leaf[0, positions]),
+                                      np.asarray(wrote))
+
+    got = paged.gather_slot(pools, jnp.asarray(table), 7, like)
+    jax.tree_util.tree_map_with_path(check, got, like, span)
+
+
+@KV_DTYPES
+def test_pool_leaves_rest_merged(kv_dtype):
+    """Every K/V pool leaf is ``(blocks, block, H*D)`` (int8 scales
+    ``(blocks, block, H)``), validity stays ``(blocks, block)``, and the
+    bytes are what the model's 4-D layout held."""
+    eng = _engine(kv_dtype=kv_dtype)
+    nb = eng.num_blocks + 1
+    item, seen = {None: 4, "bf16": 2, "int8": 1}[kv_dtype], set()
+    for path, leaf in jax.tree_util.tree_leaves_with_path(eng.pools):
+        if paged.is_counter(path):
+            assert leaf.shape == ()
+            continue
+        seen.add(leaf.shape)
+        assert leaf.shape[:2] == (nb, 8) and leaf.ndim <= 3
+    merged = {(nb, 8, HEADS * HEAD_DIM)}
+    if kv_dtype == "int8":
+        merged.add((nb, 8, HEADS))
+    assert merged <= seen <= merged | {(nb, 8)}, seen
+    per_position = HEADS * HEAD_DIM * item + \
+        (HEADS * 4 if kv_dtype == "int8" else 0)
+    rest = sum(leaf.nbytes for leaf in jax.tree.leaves(eng.pools)
+               if leaf.ndim < 3)          # validity masks and counters
+    assert eng.kv_cache_bytes - rest == \
+        2 * MODEL["num_layers"] * nb * 8 * per_position
+
+
+@KV_DTYPES
+def test_gather_of_scatter_returns_the_span_bit_for_bit(kv_dtype):
+    blocks, offsets = [3, 3, 1, 5], [6, 7, 0, 2]
+    like, pools, span = _written_pools(kv_dtype, blocks, offsets)
+    # logical blocks 0, 1, 2 are physical 3, 1, 5
+    _assert_slot_holds(like, pools, [3, 1, 5, TRASH, TRASH, TRASH],
+                       np.asarray([6, 7, 8, 18]), span)
+
+
+@KV_DTYPES
+def test_copy_block_preserves_the_block(kv_dtype):
+    like, pools, span = _written_pools(kv_dtype, [2, 2, 2], [0, 3, 7])
+    pools = jax.jit(paged.copy_block)(pools, 2, 4)
+    for table in ([2] + [TRASH] * 5, [4] + [TRASH] * 5):
+        _assert_slot_holds(like, pools, table, np.asarray([0, 3, 7]), span)
+
+
+@KV_DTYPES
+def test_migrator_round_trip_preserves_the_span(kv_dtype):
+    like, pools, span = _written_pools(kv_dtype, [1, 4, 4], [5, 0, 1])
+    dst = BlockMigrator(2).migrate(
+        pools, paged.build_pools(like, 6, 8), np.asarray([1, 4]),
+        np.asarray([5, 2]), device=jax.local_devices()[1], verify=True)
+    _assert_slot_holds(like, dst, [5, 2] + [TRASH] * 4,
+                       np.asarray([5, 8, 9]), span)
 
 
 def test_chain_hash_commits_to_whole_prefix():

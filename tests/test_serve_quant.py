@@ -140,7 +140,7 @@ def test_write_slot_rejects_bare_float_into_int_slab():
 
 
 def test_scatter_span_rejects_bare_float_into_int_pool():
-    pools = {"cached_key": jnp.zeros((4, 8, 2, 3), jnp.int8)}
+    pools = {"cached_key": jnp.zeros((4, 8, 2 * 3), jnp.int8)}
     span = {"cached_key": jnp.ones((1, 1, 2, 3), jnp.float32)}
     with pytest.raises(TypeError, match="quantize the span"):
         paged.scatter_span(pools, span, jnp.zeros((1, 1), jnp.int32),
