@@ -11,9 +11,20 @@ Dispatch is the dense one-hot formulation: a (tokens, E, C) dispatch mask
 and combine weights, contracted with the token stream.  O(T·E·C) memory but
 fully static shapes (XLA-friendly; no sorting, no dynamic slots), the
 standard TPU formulation.
+
+:class:`RoutedExperts` is the other kind, the one today's large decoders
+ship: many narrow SwiGLU experts (256 of width 1,024), ten a token, no
+capacity and no dropped token, one shared expert beside them.  At 256
+experts the one-hot dispatch cannot be afforded; it sorts the (token,
+expert) assignments by expert and runs one grouped matrix product over the
+experts it HOLDS (:func:`jax.lax.ragged_dot`), which is also how one chip
+of an expert-parallel deployment computes its share of a layer.
 """
 
 from __future__ import annotations
+
+import dataclasses
+from typing import Optional
 
 import flax.linen as nn
 import jax
@@ -204,4 +215,156 @@ class MoELM(nn.Module):
                                      name=f"layer_{i}")(x, self_valid=valid,
                                                         train=train)
         x = nn.LayerNorm(dtype=self.dtype, name="final_norm")(x)
-        return Embed.logits(x, emb)
+        return Embed.logits(x, emb.embedding)
+
+
+# --- dropless top-k experts, the chip's share of them ----------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class ExpertSpec:
+    """The routed-expert MLP of one decoder layer, by what the published
+    configs call these numbers.  `num_experts` is how many experts are
+    HELD here, ids ``[expert_offset, expert_offset + num_experts)`` of the
+    `router_experts` the router scores (None: all are held)."""
+
+    num_experts: int
+    mlp_dim: int                      # moe_intermediate_size
+    top_k: int                        # num_experts_per_tok
+    router_experts: Optional[int] = None
+    expert_offset: int = 0
+    routed_scale: float = 1.0         # moe_routed_scaling_factor
+    norm_topk: bool = True            # norm_topk_prob
+    shared_dim: int = 0               # shared_expert_intermediate_size
+
+
+def route_top_k(logits, top_k: int, norm_topk: bool = True,
+                routed_scale: float = 1.0):
+    """``(weights, experts)``, each (N, top_k): softmax over ALL the
+    router's experts in float32, the `top_k` largest, renormalised over
+    the chosen (`norm_topk`) and scaled."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    w, experts = jax.lax.top_k(probs, top_k)
+    if norm_topk:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return w * routed_scale, experts
+
+
+def held_experts(x, w, experts, w_gate, w_up, w_down, offset: int = 0):
+    """What the held experts give: ``sum_e w[n, e] * E_e(x[n])`` over the
+    assignments ``experts[n, j]`` that fall in ``[offset, offset + E)``,
+    and how many assignments each held expert took, ``(E,)``.
+
+    No capacity, nothing dropped: the N * top_k assignments are sorted by
+    expert (those of absent experts last) and every held expert's rows go
+    through ONE grouped product per matrix, SwiGLU in between.  Rows of
+    absent experts lie past the last group; a grouped product leaves them
+    unspecified, so they are zeroed on the way out."""
+    n, k = experts.shape
+    E = w_gate.shape[0]
+    local = experts.reshape(-1) - offset
+    held = (local >= 0) & (local < E)
+    group = jnp.where(held, local, E)
+    order = jnp.argsort(group, stable=True)
+    load = jnp.zeros((E + 1,), jnp.int32).at[group].add(1)[:E]
+    rows = x[order // k]
+    h = jax.nn.silu(jax.lax.ragged_dot(rows, w_gate, load)) \
+        * jax.lax.ragged_dot(rows, w_up, load)
+    out = jax.lax.ragged_dot(h.astype(x.dtype), w_down, load)
+    taken = (jnp.arange(n * k) < jnp.sum(load))[:, None]
+    out = jnp.where(taken, out, 0).astype(jnp.float32)
+    # back to assignment order (a gather, not a scatter-add), then each
+    # token sums its top_k rows under their weights
+    back = jnp.zeros((n * k,), jnp.int32).at[order].set(jnp.arange(n * k))
+    y = jnp.einsum("nkd,nk->nd", out[back].reshape(n, k, -1),
+                   jnp.where(held, w.reshape(-1), 0.0).reshape(n, k))
+    return y.astype(x.dtype), load
+
+
+def _route_and_run(x, router, w_gate, w_up, w_down, spec: ExpertSpec):
+    """Tokens (N, d) through router and held experts: ``(y, load)``."""
+    with jax.named_scope("moe_route"):
+        logits = jnp.einsum("nd,de->ne", x.astype(jnp.float32),
+                            router.astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
+        w, experts = route_top_k(logits, spec.top_k, spec.norm_topk,
+                                 spec.routed_scale)
+    with jax.named_scope("moe_experts"):
+        return held_experts(x, w, experts, w_gate, w_up, w_down,
+                            spec.expert_offset)
+
+
+def _tokens_of_all_rows(spec: ExpertSpec):
+    """`_route_and_run` for use under ``vmap`` over sequences that share
+    the weights (the paged engine's decode program maps the model over
+    its slots): the mapped axis is folded into the token axis, so the
+    experts see ONE batch of tokens and read each weight once, and the
+    load comes out as the batch's total, unmapped."""
+    @jax.custom_batching.custom_vmap
+    def run(x, router, w_gate, w_up, w_down):
+        return _route_and_run(x, router, w_gate, w_up, w_down, spec)
+
+    @run.def_vmap
+    def rule(axis_size, in_batched, x, *weights):
+        if not in_batched[0] or any(in_batched[1:]):
+            raise NotImplementedError(
+                "RoutedExperts under vmap: only the tokens may be mapped "
+                "(weights shared by every row)")
+        y, load = run(x.reshape((-1, x.shape[-1])), *weights)
+        return (y.reshape(x.shape), load), (True, False)
+
+    return run
+
+
+class RoutedExperts(nn.Module):
+    """Router over all experts, the held experts' part of the result, and
+    the shared expert: ``sum_{e in top-k, e held} w_e E_e(x) + E_s(x)``.
+
+    Parameters: ``router`` (d, router_experts), ``w_gate`` / ``w_up``
+    (E, d, f), ``w_down`` (E, f, d), and ``shared``'s three matrices.  In
+    decode mode the held experts' loads of this call are sown as
+    ``moe_stats/load`` (E,) for whoever asks for that collection."""
+
+    spec: ExpertSpec
+    dtype: jnp.dtype = jnp.float32
+    decode: bool = False
+
+    @nn.compact
+    def __call__(self, x):
+        sp = self.spec
+        d, E, f = x.shape[-1], sp.num_experts, sp.mlp_dim
+        router = self.param("router", dense_init,
+                            (d, sp.router_experts or E), jnp.float32)
+        w_gate, w_up = (self.param(n, dense_init, (E, d, f), jnp.float32)
+                        .astype(self.dtype) for n in ("w_gate", "w_up"))
+        w_down = self.param("w_down", dense_init, (E, f, d),
+                            jnp.float32).astype(self.dtype)
+        tokens = x.reshape(-1, d).astype(self.dtype)
+        if self.decode:
+            y, load = _tokens_of_all_rows(sp)(tokens, router, w_gate, w_up,
+                                              w_down)
+            self.sow("moe_stats", "load", load)
+        else:       # the training path: differentiable as it stands
+            y, _ = _route_and_run(tokens, router, w_gate, w_up, w_down, sp)
+        y = y.reshape(x.shape)
+        if sp.shared_dim:
+            with jax.named_scope("moe_shared"):
+                y = y + GatedMLP(sp.shared_dim, self.dtype,
+                                 name="shared")(x)
+        return y
+
+
+class GatedMLP(nn.Module):
+    """SwiGLU: ``(silu(x W_gate) * (x W_up)) W_down``, no biases."""
+
+    mlp_dim: int
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        dense = lambda n, f: nn.Dense(  # noqa: E731
+            f, dtype=self.dtype, use_bias=False, kernel_init=dense_init,
+            name=n)
+        h = nn.silu(dense("gate", self.mlp_dim)(x)) \
+            * dense("up", self.mlp_dim)(x)
+        return dense("down", x.shape[-1])(h)

@@ -17,12 +17,17 @@ TPU-first design decisions:
 
 from __future__ import annotations
 
-from typing import Any, Callable, Optional
+import dataclasses
+import math
+from typing import Any, Callable, Optional, Union
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from distributed_deep_learning_tpu.models.moe import (ExpertSpec, GatedMLP,
+                                                      RoutedExperts)
 
 AttentionFn = Callable[..., jnp.ndarray]
 dense_init = nn.initializers.xavier_uniform()
@@ -37,10 +42,19 @@ def dot_product_attention(q, k, v, *, mask=None, key_valid=None,
     ``causal`` a flag, ``window`` an optional causal sliding-window size
     (each query sees its last ``window`` positions); a pre-built dense
     ``mask`` (broadcastable to (B, H, Tq, Tk)) is also accepted and
-    combined.
+    combined.  K/V may carry FEWER heads than q (grouped-query
+    attention): query head ``h`` reads KV head ``h // (H / Hkv)``, by a
+    grouped contraction: K/V are never repeated to the full head count.
     """
     depth = q.shape[-1]
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(depth)
+    B, Tq, H, _ = q.shape
+    group = H // k.shape[2]
+    if group > 1:
+        qg = q.reshape(B, Tq, k.shape[2], group, depth)
+        logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k).reshape(
+            B, H, Tq, k.shape[1]) / np.sqrt(depth)
+    else:
+        logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(depth)
     if key_valid is not None:
         kv = key_valid[:, None, None, :]
         mask = kv if mask is None else jnp.logical_and(mask, kv)
@@ -61,11 +75,61 @@ def dot_product_attention(q, k, v, *, mask=None, key_valid=None,
         # attention on such rows, which the loss masks out anyway.
         logits = jnp.where(mask, logits, jnp.asarray(-1e9, logits.dtype))
     weights = nn.softmax(logits.astype(jnp.float32)).astype(dtype)
+    if group > 1:
+        wg = weights.reshape(B, k.shape[2], group, Tq, k.shape[1])
+        return jnp.einsum("bhgqk,bkhd->bqhgd", wg, v).reshape(
+            B, Tq, H, v.shape[-1])
     return jnp.einsum("bhqk,bkhd->bqhd", weights, v)
 
 
+dot_product_attention.supports_gqa = True
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeSpec:
+    """Rotary positions of one kind of layer, by the published key names
+    (``rope_parameters``): `theta` the base, `rotary_dim` how many leading
+    dims of each head are rotated (None: the whole head), and for
+    ``rope_type: yarn`` the YaRN blend (arXiv:2309.00071): frequencies
+    whose wavelength fits `original_max_len` fewer than `beta_slow` times
+    are interpolated by `factor`, those that fit it more than `beta_fast`
+    times are kept, a linear ramp in between; `attention_factor`
+    multiplies cos and sin."""
+
+    theta: float = 10000.0
+    rotary_dim: Optional[int] = None
+    factor: Optional[float] = None      # set: YaRN
+    original_max_len: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def inv_freq(self, head_dim: int) -> np.ndarray:
+        """The ``rotary_dim / 2`` inverse frequencies, float64 on the
+        host (a compile-time constant of the program)."""
+        dim = self.rotary_dim or head_dim
+        pos_freqs = self.theta ** (np.arange(0, dim, 2, dtype=np.float64)
+                                   / dim)
+        if self.factor is None:
+            return 1.0 / pos_freqs
+
+        def correction_dim(rotations):
+            return (dim * math.log(self.original_max_len
+                                   / (rotations * 2 * math.pi))
+                    / (2 * math.log(self.theta)))
+
+        low = max(math.floor(correction_dim(self.beta_fast)), 0)
+        high = min(math.ceil(correction_dim(self.beta_slow)), dim - 1)
+        ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                       / ((high if high != low else high + 0.001) - low),
+                       0.0, 1.0)
+        return ((1.0 / (self.factor * pos_freqs)) * ramp
+                + (1.0 / pos_freqs) * (1.0 - ramp))
+
+
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
-               base: float = 10000.0) -> jnp.ndarray:
+               base: float = 10000.0,
+               spec: Optional[RopeSpec] = None) -> jnp.ndarray:
     """Rotary position embedding on ``(B, T, H, D)`` (D even).
 
     Rotates feature pairs ``(x[..., :D/2], x[..., D/2:])`` by
@@ -73,8 +137,21 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
     positions only.  Parameter-free, so tensor-parallel sharding rules
     and the weight-tied head are untouched; the KV-cache decode path
     passes ``positions = cache_index + arange(T)`` so cached keys carry
-    their absolute rotation.
+    their absolute rotation.  With a `spec` its frequencies, scale
+    and partial width replace the default's; dims past ``rotary_dim``
+    pass through unrotated.
     """
+    if spec is not None:
+        rot = spec.rotary_dim or x.shape[-1]
+        freqs = jnp.asarray(spec.inv_freq(x.shape[-1]), jnp.float32)
+        ang = positions.astype(jnp.float32)[:, None] * freqs[None]
+        cos = (jnp.cos(ang) * spec.attention_factor)[None, :, None, :]
+        sin = (jnp.sin(ang) * spec.attention_factor)[None, :, None, :]
+        x1 = x[..., :rot // 2].astype(jnp.float32)
+        x2 = x[..., rot // 2:rot].astype(jnp.float32)
+        return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos,
+                                x[..., rot:].astype(jnp.float32)],
+                               -1).astype(x.dtype)
     if x.shape[-1] % 2:
         raise ValueError(f"RoPE requires an even head_dim, got "
                          f"{x.shape[-1]} (pick num_heads so that "
@@ -89,6 +166,12 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
                             x1 * sin + x2 * cos], -1).astype(x.dtype)
 
 
+#: names of a layer's cache leaves: the whole sequence, or a window
+#: layer's ring (:mod:`..serve.paged` pools the two kinds apart by name)
+FULL_LEAVES = ("cached_key", "cached_value", "cached_valid")
+RING_LEAVES = ("ring_key", "ring_value", "ring_valid")
+
+
 class MultiHeadAttention(nn.Module):
     """Projections + pluggable attention; ``decode=True`` adds a KV cache.
 
@@ -97,27 +180,38 @@ class MultiHeadAttention(nn.Module):
     appends its K/V at ``cache_index`` and attends the single query
     against the filled prefix — autoregressive decode costs O(T) per
     token instead of O(T²) recompute.
+
+    A window layer given `cache_ring` keeps a RING instead: ``ring_key`` /
+    ``ring_value`` / ``ring_valid`` of `cache_ring` positions, position
+    ``p`` at index ``p % cache_ring``.  A call of ``T`` tokens overwrites
+    the ``T`` oldest entries, so the ring must hold ``window + T - 1``
+    positions for the call's first query to still find its whole window;
+    :class:`..serve.engine.PagedEngine` sizes it for its prefill chunk.
     """
 
     num_heads: int
     dtype: jnp.dtype = jnp.float32
     attention_fn: Optional[AttentionFn] = None
     decode: bool = False
-    rope: bool = False
+    rope: Union[bool, RopeSpec] = False   # True: the default RopeSpec()
     window: Optional[int] = None   # causal sliding-window size
     num_kv_heads: Optional[int] = None  # < num_heads = grouped-query attn
+    head_dim: Optional[int] = None      # None: d_model // num_heads
+    use_bias: bool = True
+    gate: bool = False   # per-head sigmoid gate on the attention output
+    cache_ring: Optional[int] = None    # window layers: ring length
 
     @nn.compact
     def __call__(self, x_q, x_kv, key_valid=None, *, causal: bool = False,
                  mask=None):
         d_model = x_q.shape[-1]
-        head_dim = d_model // self.num_heads
+        head_dim = self.head_dim or d_model // self.num_heads
         kv_heads = self.num_kv_heads or self.num_heads
         if self.num_heads % kv_heads:
             raise ValueError(f"num_kv_heads {kv_heads} must divide "
                              f"num_heads {self.num_heads}")
         proj = lambda name, h: nn.DenseGeneral(  # noqa: E731
-            (h, head_dim), dtype=self.dtype,
+            (h, head_dim), dtype=self.dtype, use_bias=self.use_bias,
             kernel_init=dense_init, name=name)
         q = proj("q", self.num_heads)(x_q)
         k = proj("k", kv_heads)(x_kv)
@@ -127,41 +221,62 @@ class MultiHeadAttention(nn.Module):
             if self.decode and self.has_variable("cache", "cache_index"):
                 start = self.get_variable("cache", "cache_index")
             positions = start + jnp.arange(q.shape[1])
-            q = apply_rope(q, positions)
-            k = apply_rope(k, positions)  # cached K carry their rotation
+            spec = None if self.rope is True else self.rope
+            q = apply_rope(q, positions, spec=spec)
+            k = apply_rope(k, positions, spec=spec)  # cached K stay rotated
         attn = self.attention_fn or dot_product_attention
         if self.decode:
-            is_init = not self.has_variable("cache", "cached_key")
-            ck = self.variable("cache", "cached_key", jnp.zeros, k.shape,
-                               k.dtype)
-            cv = self.variable("cache", "cached_value", jnp.zeros, v.shape,
-                               v.dtype)
+            is_init = not self.has_variable("cache", "cache_index")
+            # the init call is full-length and decides whether a ring pays;
+            # later calls find which cache they were given
+            ring = (self.window is not None and self.cache_ring is not None
+                    and self.cache_ring < k.shape[1]) if is_init \
+                else self.has_variable("cache", RING_LEAVES[0])
+            held = (k.shape[0], self.cache_ring) + k.shape[2:] if ring \
+                else k.shape
+            names = RING_LEAVES if ring else FULL_LEAVES
+            ck = self.variable("cache", names[0], jnp.zeros, held, k.dtype)
+            cv = self.variable("cache", names[1], jnp.zeros, held, v.dtype)
             # remember each cached position's padding validity too — the
             # full forward masks pad tokens, so decode must as well
             cvalid = self.variable(
-                "cache", "cached_valid",
-                lambda: jnp.zeros(k.shape[:2], jnp.bool_))
+                "cache", names[2],
+                lambda: jnp.zeros(held[:2], jnp.bool_))
             idx = self.variable("cache", "cache_index",
                                 lambda: jnp.zeros((), jnp.int32))
             if not is_init:
                 T = q.shape[1]
                 max_len = ck.value.shape[1]
-                ck.value = jax.lax.dynamic_update_slice(
-                    ck.value, k, (0, idx.value, 0, 0))
-                cv.value = jax.lax.dynamic_update_slice(
-                    cv.value, v, (0, idx.value, 0, 0))
                 step_valid = (key_valid if key_valid is not None
                               else jnp.ones(k.shape[:2], jnp.bool_))
-                cvalid.value = jax.lax.dynamic_update_slice(
-                    cvalid.value, step_valid, (0, idx.value))
+                qpos = idx.value + jnp.arange(T)
+                if ring:
+                    at = qpos % max_len
+                    ck.value = ck.value.at[:, at].set(k)
+                    cv.value = cv.value.at[:, at].set(v)
+                    cvalid.value = cvalid.value.at[:, at].set(step_valid)
+                    # entry r holds the newest position <= the call's
+                    # last that is r modulo the ring; below 0: never
+                    # written (a former tenant's leftovers)
+                    last = idx.value + T - 1
+                    kpos = last - (last - jnp.arange(max_len)) % max_len
+                    kpos = kpos[None, None, None, :]
+                    mask = jnp.logical_and(
+                        kpos <= qpos[None, None, :, None], kpos >= 0)
+                else:
+                    ck.value = jax.lax.dynamic_update_slice(
+                        ck.value, k, (0, idx.value, 0, 0))
+                    cv.value = jax.lax.dynamic_update_slice(
+                        cv.value, v, (0, idx.value, 0, 0))
+                    cvalid.value = jax.lax.dynamic_update_slice(
+                        cvalid.value, step_valid, (0, idx.value))
+                    # causal prefix: query j (global position idx+j) sees
+                    # key positions <= idx+j — correct for 1-token steps
+                    # AND multi-token prefill chunks
+                    kpos = jnp.arange(max_len)[None, None, None, :]
+                    mask = kpos <= qpos[None, None, :, None]
                 k, v = ck.value, cv.value
                 key_valid = cvalid.value
-                # causal prefix: query j (global position idx+j) sees key
-                # positions <= idx+j — correct for 1-token steps AND
-                # multi-token prefill chunks
-                qpos = idx.value + jnp.arange(T)
-                kpos = jnp.arange(max_len)[None, None, None, :]
-                mask = kpos <= qpos[None, None, :, None]
                 if self.window is not None:
                     # the trained model never attends beyond its window —
                     # decode must not either (train/inference parity)
@@ -175,12 +290,9 @@ class MultiHeadAttention(nn.Module):
                 attn = dot_product_attention
         if kv_heads != self.num_heads and \
                 not getattr(attn, "supports_gqa", False):
-            # GQA: K/V carry kv_heads (and the KV cache stores only those
-            # — the H/kv_heads memory win); expand to full heads for the
-            # attention contraction (XLA fuses the broadcast).  A
-            # GQA-native implementation (the flash kernel) takes the
-            # unexpanded K/V and maps heads internally — group× less K/V
-            # HBM traffic, which is the other half of the GQA win.
+            # an attention_fn that wants full heads gets K/V expanded;
+            # dot_product_attention and the flash kernel take the
+            # kv_heads K/V as they are and map heads inside
             group = self.num_heads // kv_heads
             k = jnp.repeat(k, group, axis=2)
             v = jnp.repeat(v, group, axis=2)
@@ -189,10 +301,29 @@ class MultiHeadAttention(nn.Module):
             # structured convention: window rides alongside causal so the
             # flash kernel can bound its key loops instead of masking
             kw["window"] = self.window
-        y = attn(q, k, v, mask=mask, key_valid=key_valid, causal=causal,
-                 dtype=self.dtype, **kw)
+        with jax.named_scope("attn_window" if self.window is not None
+                             else "attn_full"):
+            y = attn(q, k, v, mask=mask, key_valid=key_valid, causal=causal,
+                     dtype=self.dtype, **kw)
+        if self.gate:
+            # headwise gate of arXiv:2505.06708: a sigmoid of a per-head
+            # projection of the layer's input scales each head's output
+            a = nn.Dense(self.num_heads, dtype=self.dtype, use_bias=False,
+                         kernel_init=dense_init, name="gate")(x_q)
+            y = y * nn.sigmoid(a.astype(jnp.float32)).astype(
+                self.dtype)[..., None]
         return nn.DenseGeneral(d_model, axis=(-2, -1), dtype=self.dtype,
+                               use_bias=self.use_bias,
                                kernel_init=dense_init, name="out")(y)
+
+
+def _norm(kind: str, dtype, eps: float, name: Optional[str] = None):
+    """``layer``: LayerNorm (scale and bias); ``rms``: RMSNorm (scale)."""
+    if kind == "rms":
+        return nn.RMSNorm(dtype=dtype, epsilon=eps, name=name)
+    if kind != "layer":
+        raise ValueError(f"norm must be 'layer' or 'rms', got {kind!r}")
+    return nn.LayerNorm(dtype=dtype, epsilon=eps, name=name)
 
 
 class TransformerLayer(nn.Module):
@@ -201,6 +332,11 @@ class TransformerLayer(nn.Module):
     ``self_valid``/``cross_valid`` are (B, T) boolean padding masks handed
     to the attention implementation in structured form (never as a dense
     (T×T) tensor) so fused kernels can apply them in-block.
+
+    The defaults are GPT-2's block (LayerNorm, biases, a two-matrix GELU
+    MLP); `norm`, `use_bias`, `head_dim`, `gate` and `mlp` (``gelu`` |
+    ``swiglu`` | ``experts``, the last with an :class:`..moe.ExpertSpec`)
+    describe the others, one :class:`LayerSpec` a layer.
     """
 
     num_heads: int = 8
@@ -211,36 +347,76 @@ class TransformerLayer(nn.Module):
     dtype: jnp.dtype = jnp.float32
     attention_fn: Optional[AttentionFn] = None
     decode: bool = False
-    rope: bool = False
+    rope: Union[bool, RopeSpec] = False
     window: Optional[int] = None
     num_kv_heads: Optional[int] = None
     ln_eps: float = 1e-6   # 1e-5 matches torch/HF LayerNorm (GPT-2 import)
+    head_dim: Optional[int] = None
+    gate: bool = False
+    use_bias: bool = True
+    norm: str = "layer"
+    mlp: str = "gelu"
+    experts: Optional[ExpertSpec] = None
+    cache_ring: Optional[int] = None
 
     @nn.compact
     def __call__(self, x, encoded=None, *, self_valid=None, cross_valid=None,
                  train: bool = False):
-        h = nn.LayerNorm(dtype=self.dtype, epsilon=self.ln_eps)(x)
+        h = _norm(self.norm, self.dtype, self.ln_eps)(x)
         h = MultiHeadAttention(self.num_heads, self.dtype, self.attention_fn,
                                decode=self.decode, rope=self.rope,
                                window=self.window,
                                num_kv_heads=self.num_kv_heads,
+                               head_dim=self.head_dim,
+                               use_bias=self.use_bias, gate=self.gate,
+                               cache_ring=self.cache_ring,
                                name="self_attn")(h, h, self_valid,
                                                  causal=self.causal)
         h = nn.Dropout(self.dropout_rate, deterministic=not train)(h)
         x = x + h
         if self.cross_attention:
-            h = nn.LayerNorm(dtype=self.dtype, epsilon=self.ln_eps)(x)
+            h = _norm(self.norm, self.dtype, self.ln_eps)(x)
             h = MultiHeadAttention(self.num_heads, self.dtype,
                                    self.attention_fn,
                                    name="cross_attn")(h, encoded, cross_valid)
             h = nn.Dropout(self.dropout_rate, deterministic=not train)(h)
             x = x + h
-        h = nn.LayerNorm(dtype=self.dtype, epsilon=self.ln_eps)(x)
-        h = nn.Dense(self.mlp_dim, dtype=self.dtype, kernel_init=dense_init)(h)
-        h = nn.gelu(h)
-        h = nn.Dense(x.shape[-1], dtype=self.dtype, kernel_init=dense_init)(h)
+        h = _norm(self.norm, self.dtype, self.ln_eps)(x)
+        if self.mlp == "gelu":
+            h = nn.Dense(self.mlp_dim, dtype=self.dtype,
+                         use_bias=self.use_bias, kernel_init=dense_init)(h)
+            h = nn.gelu(h)
+            h = nn.Dense(x.shape[-1], dtype=self.dtype,
+                         use_bias=self.use_bias, kernel_init=dense_init)(h)
+        elif self.mlp == "swiglu":
+            h = GatedMLP(self.mlp_dim, self.dtype, name="mlp")(h)
+        elif self.mlp == "experts":
+            h = RoutedExperts(self.experts, self.dtype, self.decode,
+                              name="moe")(h)
+        else:
+            raise ValueError(f"mlp must be 'gelu', 'swiglu' or 'experts', "
+                             f"got {self.mlp!r}")
         h = nn.Dropout(self.dropout_rate, deterministic=not train)(h)
         return x + h
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerSpec:
+    """What one decoder layer of a :class:`CausalLM` is made of: the
+    fields of :class:`TransformerLayer` that a published config gives
+    layer by layer (head counts, window, RoPE and MLP by layer kind)."""
+
+    num_heads: int
+    mlp_dim: int
+    num_kv_heads: Optional[int] = None
+    head_dim: Optional[int] = None
+    window: Optional[int] = None
+    rope: Union[bool, RopeSpec] = False
+    gate: bool = False
+    use_bias: bool = True
+    norm: str = "layer"
+    mlp: str = "gelu"
+    experts: Optional[ExpertSpec] = None
 
 
 class Embed(nn.Module):
@@ -276,15 +452,16 @@ class Embed(nn.Module):
         return x, emb
 
     @staticmethod
-    def logits(x, emb):
-        """Weight-tied output projection, accumulated in f32.
+    def logits(x, table):
+        """Output projection through a (V, d) table, the token embedding
+        where the head is tied, accumulated in f32.
 
         Not ``emb.attend``: Flax's attend re-casts both operands to the
         module dtype, so under bf16 the vocab-wide matmul would accumulate
         in bf16 — here the cast to f32 happens *before* the contraction.
         """
         with jax.named_scope("head"):
-            table = jnp.asarray(emb.embedding, jnp.float32)
+            table = jnp.asarray(table, jnp.float32)
             return jnp.einsum("...d,vd->...v", x.astype(jnp.float32),
                               table)
 
@@ -338,7 +515,7 @@ class TransformerSeq2Seq(nn.Module):
                                                   cross_valid=src_valid,
                                                   train=train)
         y = nn.LayerNorm(dtype=self.dtype, name="dec_norm")(y)
-        return Embed.logits(y, emb)
+        return Embed.logits(y, emb.embedding)
 
 
 class CausalLM(nn.Module):
@@ -370,33 +547,58 @@ class CausalLM(nn.Module):
     attention_fn: Optional[AttentionFn] = None
     ln_eps: float = 1e-6   # 1e-5 matches torch/HF LayerNorm (GPT-2 import)
     pad_id: Optional[int] = 0   # None: no padding id (GPT-2's id 0 is "!")
+    #: one LayerSpec a layer, for a decoder whose layers differ or are not
+    #: GPT-2's block (``num_layers`` must be their count); None: every
+    #: layer is the block the fields above describe
+    layers: Optional[tuple] = None
+    tie_head: bool = True       # False: an output table of its own
+    #: serving only: window layers cache a ring of this many positions
+    #: (:class:`MultiHeadAttention`); set by the paged engine
+    cache_ring: Optional[int] = None
+
+    def layer_specs(self) -> tuple:
+        """One :class:`LayerSpec` a layer, GPT-2's block spelled out the
+        same way as any other."""
+        if self.layers is not None:
+            if len(self.layers) != self.num_layers:
+                raise ValueError(f"{len(self.layers)} layer specs for "
+                                 f"num_layers {self.num_layers}")
+            return self.layers
+        return (LayerSpec(self.num_heads, self.mlp_dim,
+                          num_kv_heads=self.num_kv_heads,
+                          window=self.attention_window,
+                          rope=self.pos_embedding == "rope"),
+                ) * self.num_layers
 
     @nn.compact
     def __call__(self, tokens, train: bool = False):
         valid = tokens != self.pad_id if self.pad_id is not None else None
-        rope = self.pos_embedding == "rope"
+        specs = self.layer_specs()
         x, emb = Embed(self.vocab_size, self.d_model, max_len=self.max_len,
                        dtype=self.dtype, decode=self.decode,
-                       use_pos=not rope, name="embed")(tokens)
-        for i in range(self.num_layers):
-            x = TransformerLayer(self.num_heads, self.mlp_dim,
-                                 self.dropout_rate, causal=True,
-                                 dtype=self.dtype,
+                       use_pos=self.pos_embedding != "rope",
+                       name="embed")(tokens)
+        for i, spec in enumerate(specs):
+            x = TransformerLayer(dropout_rate=self.dropout_rate,
+                                 causal=True, dtype=self.dtype,
                                  attention_fn=self.attention_fn,
-                                 decode=self.decode, rope=rope,
-                                 window=self.attention_window,
-                                 num_kv_heads=self.num_kv_heads,
-                                 ln_eps=self.ln_eps,
+                                 decode=self.decode, ln_eps=self.ln_eps,
+                                 cache_ring=self.cache_ring, **vars(spec),
                                  name=f"layer_{i}")(x, self_valid=valid,
                                                     train=train)
-        x = nn.LayerNorm(dtype=self.dtype, epsilon=self.ln_eps,
-                         name="final_norm")(x)
+        x = _norm(specs[-1].norm, self.dtype, self.ln_eps,   # as the layers'
+                  "final_norm")(x)
+        head = emb.embedding if self.tie_head else self.param(
+            "head", nn.initializers.normal(0.02),
+            (self.vocab_size, self.d_model))
         # the CLI/workload convention wants logits (token_cross_entropy +
         # argmax metrics); the bench path keeps hidden states and the
         # fused head (loss()) so (B·T, V) never materialises
-        return Embed.logits(x, emb) if self.with_logits else x
+        return Embed.logits(x, head) if self.with_logits else x
 
     def _table(self, params):
+        if not self.tie_head:
+            return params["params"]["head"]
         return params["params"]["embed"]["tok"]["embedding"]
 
     def loss(self, params, hidden, targets):
@@ -416,10 +618,7 @@ class CausalLM(nn.Module):
             ignore_id)
 
     def logits_from(self, params, hidden):
-        with jax.named_scope("head"):
-            table = jnp.asarray(self._table(params), jnp.float32)
-            return jnp.einsum("...d,vd->...v", hidden.astype(jnp.float32),
-                              table)
+        return Embed.logits(hidden, self._table(params))
 
 
 class BertEncoder(nn.Module):
@@ -455,7 +654,7 @@ class BertEncoder(nn.Module):
         h = nn.gelu(h)
         h = nn.LayerNorm(dtype=self.dtype, epsilon=self.ln_eps,
                          name="mlm_norm")(h)
-        return Embed.logits(h, emb)
+        return Embed.logits(h, emb.embedding)
 
 
 def transformer_base(**kw) -> TransformerSeq2Seq:
@@ -499,6 +698,21 @@ def cached_apply(lm: "CausalLM", params, cache, tokens):
     hidden, upd = lm.apply({"params": params, "cache": cache}, tokens,
                            mutable=["cache"])
     return hidden, upd["cache"]
+
+
+def cached_apply_counting(lm: "CausalLM", params, cache, tokens):
+    """:func:`cached_apply` that also hands back what the expert layers
+    counted: ``(hidden, new_cache, load)``, `load` the call's assignments
+    to each held expert, a row an expert layer in layer order (None for a
+    model without expert layers, whose program is cached_apply's own)."""
+    if not any(sp.experts for sp in lm.layer_specs()):
+        return cached_apply(lm, params, cache, tokens) + (None,)
+    hidden, upd = lm.apply({"params": params, "cache": cache}, tokens,
+                           mutable=["cache", "moe_stats"])
+    load = jnp.stack([upd["moe_stats"][f"layer_{i}"]["moe"]["load"][0]
+                      for i, sp in enumerate(lm.layer_specs())
+                      if sp.experts])
+    return hidden, upd["cache"], load
 
 
 def validate_sampling(top_k: int | None, top_p: float | None) -> None:

@@ -38,8 +38,9 @@ COUNTER_LEAVES = ("cache_index", "pos_index")
 
 #: cache-collection leaf names that hold actual key/value tensors — the
 #: leaves the serving quantization path (:mod:`.quant`) stores in reduced
-#: precision.  ``cached_valid`` (bool) and the counters stay exact.
-KV_LEAVES = ("cached_key", "cached_value")
+#: precision (``ring_*``: a window layer's ring under the paged engine).
+#: ``cached_valid`` / ``ring_valid`` (bool) and the counters stay exact.
+KV_LEAVES = ("cached_key", "cached_value", "ring_key", "ring_value")
 
 
 def _leaf_name(path) -> str:

@@ -183,6 +183,11 @@ class DisaggEngine:
                                    num_blocks=num_blocks, **kw),
                     devices[w])
             for w in range(prefill_workers)]
+        if self.prefill[0].eng.ring_blocks is not None:
+            # the prefill -> decode hand-off copies a slot's blocks by its
+            # one block table; a window layer's ring is not in it
+            raise ValueError(PagedEngine._two_kinds(
+                "disaggregated serving (--disagg)"))
         bs = int(kv_block_size)
         plen = self.prefill[0].eng.padded_len
         self.decode = [
@@ -605,7 +610,7 @@ class DisaggEngine:
                         wb[i] = mgr.tables[i, c // bs]
                         wo[i] = c % bs
                     t0 = time.perf_counter()
-                    dw.eng.pools, out, lp_h, ok_h = dw.eng._decode(
+                    dw.eng.pools, out, lp_h, ok_h, _ = dw.eng._decode(
                         dw.eng.params, dw.eng.pools,
                         jnp.asarray(mgr.tables), jnp.asarray(pos),
                         jnp.asarray(toks), jnp.asarray(wb),

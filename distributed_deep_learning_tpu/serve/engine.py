@@ -39,7 +39,8 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
 from distributed_deep_learning_tpu.models.transformer import (
-    CausalLM, cached_apply, make_decode_model, sample_tokens,
+    CausalLM, cached_apply, cached_apply_counting, make_decode_model,
+    sample_tokens,
     validate_sampling)
 from distributed_deep_learning_tpu.obs import memory as obs_memory
 from distributed_deep_learning_tpu.obs import runlog
@@ -667,10 +668,36 @@ class _SpillRecord:
 #: the program object itself (:class:`CountingJit`); the engine only
 #: clocks them.  ``chunk_commit`` runs between dispatch and wait (the host
 #: books the chunk while the device computes it) and again after the hook.
+#: A tick's record in the ring carries ``meta = (slots decoded, chunks run,
+#: counters)``: ``counters["kv_blocks"]`` the blocks in use by layer kind
+#: (:meth:`.paged.BlockManager.blocks_by_kind`) and, for a model with
+#: expert layers, ``counters["experts"]`` (:func:`expert_counters`), counted
+#: on the device and fetched with the tick's tokens.
 TICK_PHASES = ("admit", "chunk_prepare", "chunk_dispatch", "chunk_commit",
                "chunk_wait", "decode_prepare", "decode_dispatch",
                "decode_wait", "decode_commit", "hook", "tick_end")
 DISPATCH_PHASES = ("chunk_dispatch", "decode_dispatch")
+
+
+def _on_device(x):
+    """Host arrays, or a tuple of them (a ``(full, ring)`` pair among
+    them), as device arrays."""
+    return jax.tree.map(jnp.asarray, x)
+
+
+def expert_counters(load: np.ndarray) -> dict:
+    """What one decode program did to the experts held here, from its
+    load matrix (a row an expert layer, a column a held expert):
+    assignments in all, held experts touched and the largest expert's
+    load over the mean, both as the mean over the layers (the skew over
+    those that took any assignment; 0 where none did)."""
+    took = load.sum(axis=1)
+    busy = took > 0
+    skew = (load.max(axis=1)[busy] * load.shape[1] / took[busy]).mean() \
+        if busy.any() else 0.0
+    return {"assignments": int(took.sum()),
+            "touched": float((load > 0).sum(axis=1).mean()),
+            "held": int(load.shape[1]), "skew": float(skew)}
 
 
 class PagedEngine:
@@ -774,13 +801,32 @@ class PagedEngine:
             raise ValueError(f"prefill_chunk {self.chunk} exceeds the "
                              f"slot buffer {self.padded_len}")
         self.blocks_per_slot = self.padded_len // bs
+        # a model that mixes full and window layers: its window layers
+        # cache a ring that covers the window and one chunk (the oldest
+        # positions a chunk's first query needs outlive the chunk's own
+        # writes), in whole blocks and one to spare for a chunk that
+        # starts mid-block; paged.py's docstring has the layout
+        windows = {sp.window for sp in model.layer_specs()}
+        self.ring_blocks = None
+        if len(windows) > 1 and None in windows:
+            ring = -(-(max(w for w in windows if w) + self.chunk) // bs) + 1
+            if ring < self.blocks_per_slot:
+                self.ring_blocks = ring
+                self.lm = self.lm.clone(cache_ring=ring * bs)
+        if self.ring_blocks is not None:
+            for what, on in (("speculative decoding (draft_layers)",
+                              draft_layers is not None),
+                             ("preemption (spill / resume)", preempt)):
+                if on:
+                    raise ValueError(self._two_kinds(what))
         if num_blocks is None:
             # 1x for the live slots + 1x retention headroom so the
             # prefix index can keep blocks alive after their request
             num_blocks = 2 * self.max_slots * self.blocks_per_slot
         self.num_blocks = int(num_blocks)
         self.manager = paged.BlockManager(num_blocks, bs, self.max_slots,
-                                          self.blocks_per_slot)
+                                          self.blocks_per_slot,
+                                          self.ring_blocks)
         if donate is None:
             donate = jax.default_backend() != "cpu"
         dk = {"donate_argnums": (1,)} if donate else {}
@@ -862,6 +908,12 @@ class PagedEngine:
         self._base_chunks_per_tick = self.chunks_per_tick
         self._canary: Optional[_CanaryState] = None
 
+    @staticmethod
+    def _two_kinds(what: str) -> str:
+        return (f"{what} is not supported for a model that mixes full and "
+                "window layers: its window layers cache per-slot rings "
+                "(serve/paged.py) that this path neither moves nor shares")
+
     def _new_pools(self, like):
         """Zeroed block pools for slots shaped `like`, placed where the
         weights live.
@@ -869,8 +921,10 @@ class PagedEngine:
         and so is whatever a jit computes from them: pools left as plain
         arrays would change type on their first trip through a program
         and make it trace a second time."""
-        pools = paged.build_pools(like, self.num_blocks + 1,
-                                  self.block_size)
+        pools = paged.build_pools(
+            like, self.num_blocks + 1, self.block_size,
+            None if self.ring_blocks is None
+            else self.max_slots * self.ring_blocks + 1)
         sharding = getattr(jax.tree.leaves(self.params)[0], "sharding", None)
         if isinstance(sharding, NamedSharding):
             pools = jax.device_put(
@@ -955,25 +1009,29 @@ class PagedEngine:
         from the pools, run the model's single-sequence cached decode
         (vmapped), scatter each slot's new KV position back, one shared
         sampling.  Free/prefilling slots run on garbage and write to
-        trash; their sampled tokens are ignored by the host."""
+        trash; their sampled tokens are ignored by the host.  Last of the
+        results: the tick's load of each held expert, a row an expert
+        layer (None for a model without them)."""
         params = self._wp(params)
 
         def one(table, pos, tok):
             with jax.named_scope("kv_gather"):
                 cache = self._gather(pools, table, pos)
-            hidden, new = cached_apply(self.lm, params, cache,
-                                       tok[None, None])
+            hidden, new, load = cached_apply_counting(
+                self.lm, params, cache, tok[None, None])
             with jax.named_scope("kv_write"):
-                return hidden[0, 0], paged.extract_span(new, pos, 1)
+                return hidden[0, 0], paged.extract_span(new, pos, 1), load
 
-        h, spans = jax.vmap(one)(tables, positions, toks)
+        h, spans, load = jax.vmap(one)(tables, positions, toks)
+        if load is not None:
+            load = load[0]      # unmapped: every row holds the tick's total
         with jax.named_scope("kv_write"):
             kv = jax.tree_util.tree_map_with_path(
                 lambda p, x: x if paged.is_counter(p) else x[:, 0], spans)
             pools = paged.scatter_span(pools, self._qspan(kv), wb, wo)
         with jax.named_scope("sample"):
             toks, lp, ok = self._sample(params, h, key)
-        return pools, toks, lp, ok
+        return pools, toks, lp, ok, load
 
     def _draft_impl(self, dparams, dpools, tables, positions, toks,
                     wb, wo):
@@ -1119,7 +1177,8 @@ class PagedEngine:
         self._canary = None
         self.manager = paged.BlockManager(self.num_blocks, self.block_size,
                                           self.max_slots,
-                                          self.blocks_per_slot)
+                                          self.blocks_per_slot,
+                                          self.ring_blocks)
         self.pools = self._new_pools(self._slot_like)
         if self.draft_layers is not None:
             self.draft_pools = self._new_pools(self._draft_slot_like)
@@ -1170,6 +1229,8 @@ class PagedEngine:
             raise RuntimeError(
                 "canary mode requires a non-speculative engine (the "
                 "draft's shared cache cannot serve two weight sets)")
+        if self.ring_blocks is not None:
+            raise RuntimeError(self._two_kinds("canary mode"))
         if self.weight_dtype is not None:
             new_params = quant.quantize_weights(new_params,
                                                 self.weight_dtype)
@@ -1216,10 +1277,10 @@ class PagedEngine:
             wb_old, wb_new = jnp.asarray(wb_old), jnp.asarray(wb_new)
             key = self._next_key()
         with pc.phase("decode_dispatch"):
-            self.pools, out_o, lp_o, ok_o = self._decode(
+            self.pools, out_o, lp_o, ok_o, _ = self._decode(
                 self.params, self.pools, tables_dev, pos_dev, toks_dev,
                 wb_old, wo_dev, key)
-            self.pools, out_n, lp_n, ok_n = self._decode(
+            self.pools, out_n, lp_n, ok_n, _ = self._decode(
                 can.params, self.pools, tables_dev, pos_dev, toks_dev,
                 wb_new, wo_dev, key)
         with pc.phase("decode_wait"):
@@ -1415,12 +1476,16 @@ class PagedEngine:
                 toks = chunk_tokens(stream[idx], plan, self.chunk,
                                     self.pad_fill)
                 make_writable(idx, committed[idx], plan.commit_to - 1)
-                wb, wo, _ = write_targets(plan.feed_start, self.chunk,
-                                          committed[idx], L,
-                                          mgr.tables[idx], bs)
-                table_dev = jnp.asarray(mgr.tables[idx])
+                wb, wo, written = write_targets(plan.feed_start, self.chunk,
+                                                committed[idx], L,
+                                                mgr.tables[idx], bs)
+                ring_wb = mgr.ring_targets(
+                    idx, plan.feed_start + np.arange(self.chunk), written)
+                table_dev = _on_device(mgr.device_tables(idx))
                 toks_dev = jnp.asarray(toks, jnp.int32)
-                wb_dev, wo_dev = jnp.asarray(wb), jnp.asarray(wo)
+                wb_dev = _on_device(wb if ring_wb is None
+                                    else (wb, ring_wb))
+                wo_dev = jnp.asarray(wo)
                 pos = np.int32(plan.feed_start)
             t0 = time.perf_counter()
             with p_chunk_dispatch:
@@ -1712,6 +1777,7 @@ class PagedEngine:
                     sched.mark_arrivals(tick, time.perf_counter())
                     g_queue.set(depth)
                     admit_all(tick, ev)
+                    kv_blocks = mgr.blocks_by_kind()    # as the tick runs
 
                 if not sched.occupancy:
                     nxt = sched.next_arrival()
@@ -1741,7 +1807,8 @@ class PagedEngine:
                 # how much prefill work is queued — the stall bound
                 dec = sched.decoding_slots()
                 tk.kind = "decode" if dec else "prefill"
-                tk.meta = (len(dec), ran)
+                counters = {"kv_blocks": kv_blocks}
+                tk.meta = (len(dec), ran, counters)
                 if dec and not (self.draft_layers is not None
                                 and self._spec_enabled):
                     with p_decode_prepare:
@@ -1749,6 +1816,8 @@ class PagedEngine:
                         pos = np.zeros(self.max_slots, np.int32)
                         wb = np.full(self.max_slots, paged.TRASH, np.int32)
                         wo = np.zeros(self.max_slots, np.int32)
+                        ring_wb = (None if self.ring_blocks is None
+                                   else wb.copy())
                         for i in dec:
                             c = committed[i]
                             make_writable(i, c, c)
@@ -1756,22 +1825,27 @@ class PagedEngine:
                             pos[i] = c
                             wb[i] = mgr.tables[i, c // bs]
                             wo[i] = c % bs
+                            if ring_wb is not None:
+                                ring_wb[i] = mgr.ring_targets(i, c, True)
                         t0 = time.perf_counter()
                         if self._canary is None:
-                            dev = (jnp.asarray(mgr.tables),
-                                   jnp.asarray(pos), jnp.asarray(toks),
-                                   jnp.asarray(wb), jnp.asarray(wo),
-                                   self._next_key())
+                            dev = _on_device((
+                                mgr.device_tables(), pos, toks,
+                                wb if ring_wb is None else (wb, ring_wb),
+                                wo)) + (self._next_key(),)
                     if self._canary is not None:
                         out, lp_h, ok_h = self._canary_decode(
                             mgr, pos, toks, wb, wo, dec, pc)
                     else:
                         with p_decode_dispatch:
-                            self.pools, out, lp_h, ok_h = self._decode(
-                                self.params, self.pools, *dev)
+                            self.pools, out, lp_h, ok_h, load = \
+                                self._decode(self.params, self.pools, *dev)
                         with p_decode_wait:
                             out = np.asarray(out)   # host fetch = barrier
                             lp_h, ok_h = np.asarray(lp_h), np.asarray(ok_h)
+                            if load is not None:    # came with the tokens
+                                counters["experts"] = expert_counters(
+                                    np.asarray(load))
                     now = time.perf_counter()
                     t_decode += now - t0
                     decode_ticks += 1

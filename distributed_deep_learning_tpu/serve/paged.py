@@ -49,6 +49,24 @@ against ``generate()``.
 Physical block 0 is a TRASH block: gathers may read it (garbage in,
 discarded out — free slots, tail padding) and masked writes are routed
 to it, so real blocks only ever receive committed positions.
+
+**Two kinds of layer, two kinds of pool.**  A full-attention layer needs
+every position of a sequence; a sliding-window layer only its last
+``window``.  A model that mixes them (the decode model names a window
+layer's cache leaves ``ring_*``, :data:`..models.transformer.RING_LEAVES`)
+gets a pool family a kind: FULL leaves ``(num_blocks, bs, ...)`` addressed
+by the slot's block table, as ever, and RING leaves ``(max_slots *
+ring_blocks, bs, ...)`` addressed by the slot's ring table, logical block
+``j`` at ring entry ``j % ring_blocks``: position ``p`` rests at ring
+index ``p % (ring_blocks * bs)``, which is where the model's ring cache
+expects it.  Where a program argument is one array for a one-kind model
+(a block table, the scatter's block ids) it is a ``(full, ring)`` pair
+for a two-kind one; a uniform model never sees a pair, and its programs
+are what they were.  Each slot OWNS its ring (nothing to allocate, nothing
+to share), so a model with window layers indexes no prefix: the blocks
+behind an indexed boundary would have to keep every window layer's last
+``window`` positions alive as well, and no user of this engine has asked
+for it yet.
 """
 
 from __future__ import annotations
@@ -62,7 +80,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from distributed_deep_learning_tpu.models.transformer import init_cache
+from distributed_deep_learning_tpu.models.transformer import (RING_LEAVES,
+                                                              init_cache)
 from distributed_deep_learning_tpu.serve import quant
 from distributed_deep_learning_tpu.serve.cache import (COUNTER_LEAVES,
                                                        _leaf_name)
@@ -73,6 +92,18 @@ TRASH = 0
 
 def is_counter(path) -> bool:
     return _leaf_name(path) in COUNTER_LEAVES
+
+
+def is_ring(path) -> bool:
+    """Whether the cache leaf at `path` is (part of) a window layer's
+    ring: an int8 leaf's payload and scales lie one key below its name."""
+    return any(getattr(k, "key", None) in RING_LEAVES for k in path)
+
+
+def _of_kind(x, path):
+    """`x` for the leaf at `path`: the member of a ``(full, ring)`` pair
+    its kind picks, or `x` itself where one array serves every leaf."""
+    return x[is_ring(path)] if isinstance(x, tuple) else x
 
 
 def chain_hash(prev: bytes, tokens: Sequence[int]) -> bytes:
@@ -110,8 +141,10 @@ def slot_template(lm, padded_len: int, token_dtype=jnp.int32,
     return jax.eval_shape(at_rest)
 
 
-def build_pools(like, num_blocks: int, block_size: int):
-    """Zeroed block pools for slots shaped `like` (:func:`slot_template`).
+def build_pools(like, num_blocks: int, block_size: int,
+                ring_num_blocks: Optional[int] = None):
+    """Zeroed block pools for slots shaped `like` (:func:`slot_template`);
+    ring leaves, where the model has them, get `ring_num_blocks` blocks.
 
     Every sequence-axis leaf ``(1, T, *trailing)`` becomes a pool
     ``(num_blocks, block_size, prod(trailing))`` — K/V ``(nb, bs, H*D)``,
@@ -130,7 +163,8 @@ def build_pools(like, num_blocks: int, block_size: int):
             raise ValueError(f"padded_len {leaf.shape[1]} must be a "
                              f"multiple of block_size {block_size}")
         merged = (math.prod(leaf.shape[2:]),) if leaf.ndim > 2 else ()
-        return jnp.zeros((num_blocks, block_size) + merged, leaf.dtype)
+        n = ring_num_blocks if is_ring(path) else num_blocks
+        return jnp.zeros((n, block_size) + merged, leaf.dtype)
 
     return jax.tree_util.tree_map_with_path(alloc, like)
 
@@ -138,9 +172,10 @@ def build_pools(like, num_blocks: int, block_size: int):
 def gather_slot(pools, table, pos, like):
     """One slot's logical cache in the model's ``B=1`` layout.
 
-    ``table`` is the slot's ``(blocks_per_slot,)`` physical block ids and
-    ``pos`` its position counter — both traced, so one compiled program
-    serves every slot, table and position.  `like` (the pools'
+    ``table`` is the slot's ``(blocks_per_slot,)`` physical block ids
+    (with ring leaves, the pair of that and its ``(ring_blocks,)`` ring
+    table) and ``pos`` its position counter — all traced, so one compiled
+    program serves every slot, table and position.  `like` (the pools'
     :func:`slot_template`) gives each leaf its trailing dims back, on
     the gathered slot and never on the pool.  Trash entries gather
     garbage that the decode-path causal prefix mask (``kpos <= qpos``)
@@ -148,7 +183,7 @@ def gather_slot(pools, table, pos, like):
     def g(path, leaf, want):
         if is_counter(path):
             return jnp.asarray(pos, leaf.dtype)
-        return leaf[table].reshape((1, -1) + want.shape[2:])
+        return leaf[_of_kind(table, path)].reshape((1, -1) + want.shape[2:])
 
     return jax.tree_util.tree_map_with_path(g, pools, like)
 
@@ -156,10 +191,13 @@ def gather_slot(pools, table, pos, like):
 def extract_span(cache, pos, n: int):
     """Positions ``[pos, pos+n)`` of a model-layout cache — the freshly
     written KV a program hands to :func:`scatter_span`.  ``n`` is static
-    (the program's chunk width); ``pos`` is traced."""
+    (the program's chunk width); ``pos`` is traced.  A ring leaf holds
+    position ``p`` at index ``p`` modulo its length."""
     def e(path, leaf):
         if is_counter(path):
             return jnp.zeros((), jnp.int32)            # placeholder
+        if is_ring(path):
+            return leaf[0][(pos + jnp.arange(n)) % leaf.shape[1]]
         return jax.lax.dynamic_slice_in_dim(leaf[0], pos, n, axis=0)
 
     return jax.tree_util.tree_map_with_path(e, cache)
@@ -170,8 +208,10 @@ def scatter_span(pools, kv, blocks, offsets):
 
     ``blocks``/``offsets`` have shape ``(..., n)`` matching the leading
     dims of the ``kv`` leaves, whose trailing dims (the model's) are
-    merged into the pool's one; entries routed to :data:`TRASH` discard
-    their write (pad tails, inactive slots).  The host guarantees no two
+    merged into the pool's one (``blocks`` a ``(full, ring)`` pair where
+    the pools hold both kinds; the offsets are the same for both);
+    entries routed to :data:`TRASH` discard their write (pad tails,
+    inactive slots).  The host guarantees no two
     REAL (block, offset) pairs collide in one call — only trash may be
     written more than once, and trash is never read as truth."""
     def s(path, pool, upd):
@@ -184,8 +224,9 @@ def scatter_span(pools, kv, blocks, offsets):
                 f"{pool.dtype} pool — a bare astype would truncate "
                 "without a scale; quantize the span first "
                 "(serve.quant.quantize_cache_span)")
-        upd = upd.astype(pool.dtype).reshape(blocks.shape + pool.shape[2:])
-        return pool.at[blocks, offsets].set(upd)
+        to = _of_kind(blocks, path)
+        upd = upd.astype(pool.dtype).reshape(to.shape + pool.shape[2:])
+        return pool.at[to, offsets].set(upd)
 
     return jax.tree_util.tree_map_with_path(s, pools, kv)
 
@@ -193,9 +234,10 @@ def scatter_span(pools, kv, blocks, offsets):
 def copy_block(pools, src, dst):
     """Physical block copy ``dst <- src`` — the copy half of
     copy-on-write.  ``src``/``dst`` are traced scalars: one compiled
-    program covers every COW for the engine's lifetime."""
+    program covers every COW for the engine's lifetime.  They name blocks
+    of the full kind; rings are never shared, so never copied."""
     def c(path, pool):
-        if is_counter(path):
+        if is_counter(path) or is_ring(path):
             return pool
         return jax.lax.dynamic_update_slice_in_dim(
             pool, jax.lax.dynamic_slice_in_dim(pool, src, 1, axis=0),
@@ -289,7 +331,10 @@ class BlockManager:
     facts (these positions are now committed; this slot retired)."""
 
     def __init__(self, num_blocks: int, block_size: int, max_slots: int,
-                 blocks_per_slot: int):
+                 blocks_per_slot: int, ring_blocks: Optional[int] = None):
+        """`ring_blocks`: the model has window layers and each slot owns
+        a ring of that many blocks of the ring pools (module docstring);
+        such a manager indexes no prefix."""
         if num_blocks < blocks_per_slot:
             raise ValueError(
                 f"num_blocks {num_blocks} cannot hold even one slot "
@@ -301,6 +346,13 @@ class BlockManager:
         self.free: list[int] = list(range(num_blocks, 0, -1))
         self.refs = np.zeros(num_blocks + 1, np.int32)
         self.tables = np.full((max_slots, blocks_per_slot), TRASH, np.int32)
+        self.ring_blocks = ring_blocks
+        if ring_blocks is not None:
+            # slot s owns ring-pool blocks 1 + s * ring_blocks ..., for good
+            self.ring_tables = 1 + np.arange(
+                max_slots * ring_blocks, dtype=np.int32).reshape(
+                    max_slots, ring_blocks)
+        self._logical: dict[int, int] = {}     # slot -> blocks reserved
         self.index = PrefixIndex()
         self._reserve: dict[int, int] = {}     # slot -> COW reserve block
         # slot -> (blocks hashed so far, chain hash after them)
@@ -317,6 +369,37 @@ class BlockManager:
     @property
     def in_use(self) -> int:
         return self.num_blocks - len(self.free)
+
+    def blocks_by_kind(self) -> dict:
+        """Blocks holding live slots' positions, a count a kind: the
+        table's for full layers; for window layers what of each slot's
+        reservation its ring holds, and under ``window_released`` what it
+        does not: the blocks one shape for every layer would also keep."""
+        out = {"full": self.in_use}
+        if self.ring_blocks is not None:
+            out["window"] = sum(min(n, self.ring_blocks)
+                                for n in self._logical.values())
+            out["window_released"] = sum(max(0, n - self.ring_blocks)
+                                         for n in self._logical.values())
+        return out
+
+    def device_tables(self, slot: Optional[int] = None):
+        """What a program takes as its block table: every slot's (or
+        `slot`'s) table, paired with the ring table where there is one."""
+        pick = (lambda t: t) if slot is None else (lambda t: t[slot])
+        if self.ring_blocks is None:
+            return pick(self.tables)
+        return pick(self.tables), pick(self.ring_tables)
+
+    def ring_targets(self, slot: int, positions, live):
+        """The ring blocks that `positions` of `slot` are written to
+        (TRASH where not `live`), or None for a one-kind model; the
+        offsets are the full kind's."""
+        if self.ring_blocks is None:
+            return None
+        entry = (np.asarray(positions) // self.block_size) % self.ring_blocks
+        return np.where(live, self.ring_tables[slot][entry],
+                        TRASH).astype(np.int32)
 
     def _evictable(self) -> int:
         return int(sum(1 for h, e in self.index.entries.items()
@@ -379,6 +462,8 @@ class BlockManager:
         matched tail block.  Capped at ``len(prompt) - 1`` — the final
         prompt token is always recomputed, because sampling the first
         output token needs its hidden state, which no KV cache stores."""
+        if self.ring_blocks is not None:     # nothing is ever indexed
+            return SharedPrefix([], None, 0, b"")
         bs = self.block_size
         toks = np.asarray(prompt)
         L = len(toks)
@@ -467,6 +552,7 @@ class BlockManager:
             row[j] = self._alloc()
             j += 1
         self._chain[slot] = (len(sp.full_blocks), sp.chain)
+        self._logical[slot] = logical
         return self.shared_len(sp)
 
     def release(self, slot: int) -> None:
@@ -478,6 +564,7 @@ class BlockManager:
         if r is not None:
             self._deref(r)
         self._chain.pop(slot, None)
+        self._logical.pop(slot, None)
 
     # --- copy-on-write ----------------------------------------------------
     def writable(self, slot: int, logical: int) -> Optional[tuple[int, int]]:
@@ -519,7 +606,10 @@ class BlockManager:
         slot's whole stream (prompt + generated) as known to the host.
         The chain hash is a pure function of the token stream, so a
         COW-copied private block registers under its true prefix hash
-        like any other.  Returns how many new blocks were indexed."""
+        like any other.  Returns how many new blocks were indexed (none,
+        ever, for a model with window layers)."""
+        if self.ring_blocks is not None:
+            return 0
         bs = self.block_size
         done, h = self._chain[slot]
         toks = np.asarray(tokens)
@@ -551,7 +641,9 @@ class BlockManager:
         returned block with the exact at-rest KV for its positions
         before anything admits against the chain.  Returns None when
         the pool cannot free enough blocks (sharing is best-effort and
-        never steals from live slots)."""
+        never steals from live slots), or the model has window layers."""
+        if self.ring_blocks is not None:
+            return None
         bs = self.block_size
         toks = np.asarray(tokens)
         chain = []
@@ -622,6 +714,9 @@ class BlockManager:
 
     def stats(self) -> dict:
         return {
+            **({} if self.ring_blocks is None else
+               {"ring_blocks_per_slot": self.ring_blocks,
+                "blocks_by_kind": self.blocks_by_kind()}),
             "blocks_total": self.num_blocks,
             "blocks_in_use": self.in_use,
             "blocks_peak_in_use": self.peak_in_use,

@@ -49,12 +49,16 @@ def truncated_draft(decode_model, params, draft_layers: int):
     if not 1 <= draft_layers < n:
         raise ValueError(
             f"draft_layers must be in [1, {n - 1}], got {draft_layers}")
-    draft = decode_model.clone(num_layers=draft_layers)
+    layers = decode_model.layers
+    draft = decode_model.clone(
+        num_layers=draft_layers,
+        layers=None if layers is None else layers[:draft_layers])
     # accept either flavor: the engine's inner param dict (module names
     # at top level) or the full {"params": ...} variable dict
     wrapped = "params" in params and "embed" not in params
     src = params["params"] if wrapped else params
-    keep = {"embed": src["embed"], "final_norm": src["final_norm"]}
+    keep = {name: src[name] for name in ("embed", "final_norm", "head")
+            if name in src}        # "head": an untied model's own table
     for i in range(draft_layers):
         keep[f"layer_{i}"] = src[f"layer_{i}"]
     return draft, ({"params": keep} if wrapped else keep)

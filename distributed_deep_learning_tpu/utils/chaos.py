@@ -504,12 +504,18 @@ class ChaosPlan:
     @classmethod
     def truncate_checkpoint(cls, ckpt_dir: str, step: int,
                             keep_fraction: float = 0.5) -> str:
-        """The torn-write drill: cut the step's largest file short."""
-        target = cls._step_files(ckpt_dir, step)[0]
-        size = os.path.getsize(target)
-        with open(target, "r+b") as f:
-            f.truncate(max(1, int(size * keep_fraction)))
-        return target
+        """The torn-write drill: a save that died mid-write, every file of
+        the step cut short; returns the largest.  (Cutting the largest
+        alone was a coin toss: orbax splits a small payload into pieces
+        whose sizes vary from run to run, and when the 11 KB ``_sharding``
+        sidecar came out largest a restore with explicit shardings never
+        read the torn file and took the step as good: PR 26.)"""
+        files = cls._step_files(ckpt_dir, step)
+        for target in files:
+            size = os.path.getsize(target)
+            with open(target, "r+b") as f:
+                f.truncate(max(1, int(size * keep_fraction)))
+        return files[0]
 
     @classmethod
     def bitflip_checkpoint(cls, ckpt_dir: str, step: int,

@@ -245,6 +245,7 @@ class Config:
                                         #   reload (serve/reload.py)
     pos_embedding: str = "learned"      # learned | rope (gpt)
     num_kv_heads: int | None = None     # grouped-query attention (gpt)
+    model_file: str | None = None       # gpt: model description (JSON)
     label_smoothing: float = 0.0        # token-CE smoothing (LM families)
     pipeline_schedule: str = "gpipe"    # gpipe | 1f1b | interleaved
     virtual_stages: int = 2             # chunks/device (interleaved)
@@ -453,6 +454,12 @@ def build_parser(workload: str = "") -> argparse.ArgumentParser:
                    help="gpt grouped-query attention: K key/value heads "
                         "shared by the query heads (must divide them; "
                         "shrinks the KV cache by heads/K)")
+    p.add_argument("--model-file", default=None, metavar="JSON",
+                   help="gpt: build the decoder a model description file "
+                        "gives, layer by layer, under the key names "
+                        "published config.json files use "
+                        "(models/describe.py); replaces -l, -s, "
+                        "--kv-heads, --pos and --window")
     p.add_argument("--pos", dest="pos_embedding",
                    choices=["learned", "rope"], default="learned",
                    help="gpt position encoding: learned absolute table or "
@@ -1209,6 +1216,7 @@ def parse_args(argv: Sequence[str] | None = None, workload: str = "",
         publish_weights=args.publish_weights,
         pos_embedding=args.pos_embedding,
         num_kv_heads=args.num_kv_heads,
+        model_file=args.model_file,
         label_smoothing=args.label_smoothing,
         pipeline_schedule=args.pipeline_schedule,
         virtual_stages=args.virtual_stages,

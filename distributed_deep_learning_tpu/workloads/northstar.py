@@ -423,6 +423,15 @@ def _gpt_dataset(config: Config, seq_len: int = 64, vocab: int = 1024):
             return lm_dataset(tokens)
     from distributed_deep_learning_tpu.data.datasets import synthetic_lm
 
+    if config.model_file:       # the described model's own vocabulary
+        from distributed_deep_learning_tpu.data.tokens import (
+            TokenArrayDataset)
+        from distributed_deep_learning_tpu.models import describe
+
+        vocab = int(describe.read(config.model_file)["vocab_size"])
+        ds = synthetic_lm(seq_len=seq_len, vocab_size=vocab,
+                          seed=config.seed)
+        return TokenArrayDataset(ds.features, ds.targets, vocab)
     # vocab matches _vocab()'s synthetic default (1024)
     return synthetic_lm(seq_len=seq_len, vocab_size=vocab, seed=config.seed)
 
@@ -430,6 +439,15 @@ def _gpt_dataset(config: Config, seq_len: int = 64, vocab: int = 1024):
 def _gpt_model(config: Config, dataset):
     from distributed_deep_learning_tpu.models.transformer import CausalLM
 
+    if config.model_file:
+        from distributed_deep_learning_tpu.models import describe
+
+        return describe.causal_lm(
+            describe.read(config.model_file), vocab_size=_vocab(dataset),
+            max_len=max(dataset.features.shape[1], 8),
+            dropout_rate=config.dropout, with_logits=True,
+            dtype=config_dtype(config),
+            attention_fn=_attention_fn(config))
     d = config.size
     return CausalLM(vocab_size=_vocab(dataset),
                     num_layers=config.num_layers, d_model=d,
@@ -443,8 +461,17 @@ def _gpt_model(config: Config, dataset):
                     attention_fn=_attention_fn(config))
 
 
+def _no_staged_description(config: Config) -> None:
+    if config.model_file:
+        raise ValueError(
+            f"--model-file describes a whole decoder, layer by layer; -m "
+            f"{config.mode.value} builds uniform stages of its own (use -m "
+            "data or sequential)")
+
+
 def _gpt_layers(config: Config, dataset):
     """``-m model``: embed / causal blocks / full-sequence head."""
+    _no_staged_description(config)
     from distributed_deep_learning_tpu.models.pipelined_lm import (LMEmbed,
                                                                    LMHead)
     from distributed_deep_learning_tpu.models.transformer import (
@@ -467,6 +494,7 @@ def _gpt_layers(config: Config, dataset):
 def _gpt_pipelined(config: Config, dataset, mesh):
     from distributed_deep_learning_tpu.models.pipelined_lm import PipelinedLM
 
+    _no_staged_description(config)
     d = config.size
     return PipelinedLM(vocab_size=_vocab(dataset),
                        num_layers=config.num_layers, d_model=d,

@@ -174,3 +174,66 @@ def test_paged_program_copies_no_whole_pool_leaf(xl_engine, program):
     assert f"bf16[{rows},16,1600]" in entry      # the pools are in there
     copies = re.findall(rf"= (bf16\[{rows},[^ ]*) copy\(", entry)
     assert not copies, copies
+
+
+# the laguna cell's engine (benchmark/traffic/long-mixed.json) at the
+# configuration's widths, its first two layers: a full layer with the dense
+# MLP and a sliding layer with the 32 held experts, so both pool kinds and
+# the grouped product are in the programs
+LAGUNA_CELL = dict(max_slots=16, max_len=8192, kv_block_size=16,
+                   num_blocks=640, prefill_chunk=512, donate=True)
+
+
+@pytest.fixture(scope="module")
+def laguna_engine(chip):
+    from distributed_deep_learning_tpu.models import describe
+    from distributed_deep_learning_tpu.serve.engine import PagedEngine
+
+    def on_chip(x, dtype=None):
+        return jax.ShapeDtypeStruct(x.shape, dtype or x.dtype, sharding=chip)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
+
+    desc = dict(describe.read("benchmark/configs/laguna-s-2.1-ep8.json"),
+                num_hidden_layers=2)
+    model = describe.causal_lm(desc, max_len=8192, with_logits=True,
+                               dtype=jnp.bfloat16)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.key(0),
+                           jnp.ones((1, 8), jnp.int32))["params"])
+    params = jax.tree.map(lambda s: on_chip(s, jnp.bfloat16), params)
+    engine = PagedEngine(model, params, **LAGUNA_CELL)
+    head = (params, jax.tree.map(on_chip, engine.pools))
+    key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
+    slots, chunk = engine.max_slots, engine.chunk
+    bps, ring = engine.blocks_per_slot, engine.ring_blocks
+    return engine, {
+        "paged_chunk": (engine._chunk_prog, head + (
+            i32(chunk), (i32(bps), i32(ring)), i32(), i32(),
+            (i32(chunk), i32(chunk)), i32(chunk), key)),
+        "paged_decode": (engine._decode, head + (
+            (i32(slots, bps), i32(slots, ring)), i32(slots), i32(slots),
+            (i32(slots), i32(slots)), i32(slots), key)),
+    }
+
+
+@pytest.mark.parametrize("program", ["paged_chunk", "paged_decode"])
+def test_two_kind_paged_program_compiles_for_v5e(laguna_engine, program):
+    """Both pool kinds rest as they are computed in (``Hkv*D`` = 1,024
+    minor: no whole-leaf copy of either), and each expert layer's three
+    grouped products are the chip's own ragged-dot kernel, not a dense
+    product over every expert."""
+    engine, programs = laguna_engine
+    assert engine.ring_blocks == 65             # ceil((512 + 512) / 16) + 1
+    prog, args = programs[program]
+    text = prog._jit.lower(*args).compile().as_text()
+    entry = text[text.index("ENTRY"):]
+    full, ring = engine.num_blocks + 1, 16 * 65 + 1
+    assert f"bf16[{full},16,1024]" in entry and \
+        f"bf16[{ring},16,1024]" in entry
+    copies = re.findall(rf"= (bf16\[(?:{full}|{ring}),[^ ]*) copy\(", entry)
+    assert not copies, copies
+    kernels = re.findall(r"%(ragged-dot-none[\w.]*) = [^\n]*"
+                         r'custom_call_target="tpu_custom_call"', text)
+    assert len(kernels) == 3, kernels
