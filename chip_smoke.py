@@ -462,7 +462,7 @@ def phase_kernels(size: dict, seed: int) -> None:
         flash_attention)
     from distributed_deep_learning_tpu.ops.paged_decode_pallas import (
         paged_decode_reference, paged_flash_decode)
-    from distributed_deep_learning_tpu.serve import quant
+    from distributed_deep_learning_tpu.serve import paged, quant
 
     B, T = size["batch"], size["context"]
     H, D = size["heads"], size["d_model"] // size["heads"]
@@ -508,21 +508,32 @@ def phase_kernels(size: dict, seed: int) -> None:
                 f"flash {name} is {err:.2e} from f32 attention, more than "
                 f"twice dense bf16's {ref_err:.2e}")
 
+    # pool leaves as the paged engine rests them (trailing dims merged),
+    # the token's own row beside them, as its decode program calls it
     slots, block = size["slots"], size["block"]
     per_slot = size["context"] // block
     n_blocks = 2 * slots * per_slot + 1
     pool_k, pool_v = (jax.random.normal(
         kk, (n_blocks, block, H, D), jnp.bfloat16) for kk in keys[3:5])
-    dq = jax.random.normal(keys[5], (slots, H, 1, D), jnp.bfloat16)
+    dq, new_k, new_v = (jax.random.normal(kk, (slots, H, D), jnp.bfloat16)
+                        for kk in jax.random.split(keys[5], 3))
     tables = jax.random.permutation(keys[6], n_blocks - 1)[
         :slots * per_slot].reshape(slots, per_slot).astype(jnp.int32)
-    lens = jax.random.randint(keys[7], (slots,), 1, size["context"] + 1)
-    qk, qv = (quant.quantize_rows(p) for p in (pool_k, pool_v))
-    for name, pools in (("bf16", (pool_k, pool_v)), ("int8", (qk, qv))):
-        got = np.asarray(jax.jit(paged_flash_decode)(
-            dq, *pools, tables, lens), np.float32)
-        want = np.asarray(jax.jit(paged_decode_reference)(
-            dq, *pools, tables, lens), np.float32)
+    lens = jax.random.randint(keys[7], (slots,), 1, size["context"])
+
+    def at_rest(pool, int8):
+        return paged.merge_trailing(quant.quantize_rows(pool) if int8
+                                    else pool)
+
+    def attend(fn):
+        return jax.jit(lambda q, k, v, kn, vn: fn(
+            q, k, v, tables, lens, k_new=kn, v_new=vn))
+
+    for name, int8 in (("bf16", False), ("int8", True)):
+        args = (dq, at_rest(pool_k, int8), at_rest(pool_v, int8), new_k,
+                new_v)
+        got = np.asarray(attend(paged_flash_decode)(*args), np.float32)
+        want = np.asarray(attend(paged_decode_reference)(*args), np.float32)
         require(np.isfinite(got).all(), f"paged decode {name} not finite")
         err = np.abs(got - want).max() / np.abs(want).max()
         say(f"paged_flash_decode {name}: max error {err:.2e} of the "

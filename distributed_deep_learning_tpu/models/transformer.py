@@ -170,6 +170,10 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
 #: layer's ring (:mod:`..serve.paged` pools the two kinds apart by name)
 FULL_LEAVES = ("cached_key", "cached_value", "cached_valid")
 RING_LEAVES = ("ring_key", "ring_value", "ring_valid")
+#: beside FULL_LEAVES that are the paged engine's POOL leaves themselves
+#: (``(num_blocks, block, Hkv*D)``, not a slot's ``(1, T, Hkv, D)``): the
+#: slot's block table, ``(blocks_per_slot,)`` physical ids
+BLOCK_TABLE = "block_table"
 
 
 class MultiHeadAttention(nn.Module):
@@ -187,6 +191,12 @@ class MultiHeadAttention(nn.Module):
     the ``T`` oldest entries, so the ring must hold ``window + T - 1``
     positions for the call's first query to still find its whole window;
     :class:`..serve.engine.PagedEngine` sizes it for its prefill chunk.
+
+    A cache that holds a ``block_table`` beside its leaves is the paged
+    engine's one-token decode program: the leaves are the engine's pools as
+    they rest and the layer attends over them in place
+    (:func:`..ops.paged_decode_pallas.paged_slot_attention`), handing back
+    the token's own K/V row, not an updated cache.
     """
 
     num_heads: int
@@ -225,6 +235,7 @@ class MultiHeadAttention(nn.Module):
             q = apply_rope(q, positions, spec=spec)
             k = apply_rope(k, positions, spec=spec)  # cached K stay rotated
         attn = self.attention_fn or dot_product_attention
+        y = None
         if self.decode:
             is_init = not self.has_variable("cache", "cache_index")
             # the init call is full-length and decides whether a ring pays;
@@ -244,7 +255,10 @@ class MultiHeadAttention(nn.Module):
                 lambda: jnp.zeros(held[:2], jnp.bool_))
             idx = self.variable("cache", "cache_index",
                                 lambda: jnp.zeros((), jnp.int32))
-            if not is_init:
+            if not is_init and self.has_variable("cache", BLOCK_TABLE):
+                y = self._attend_in_place(q, k, v, key_valid, ck, cv, cvalid,
+                                          idx)
+            elif not is_init:
                 T = q.shape[1]
                 max_len = ck.value.shape[1]
                 step_valid = (key_valid if key_valid is not None
@@ -288,7 +302,7 @@ class MultiHeadAttention(nn.Module):
                 # dense direct: the flash adapter would route this dense
                 # mask to the same path anyway, minus a spurious warning
                 attn = dot_product_attention
-        if kv_heads != self.num_heads and \
+        if y is None and kv_heads != self.num_heads and \
                 not getattr(attn, "supports_gqa", False):
             # an attention_fn that wants full heads gets K/V expanded;
             # dot_product_attention and the flash kernel take the
@@ -301,10 +315,11 @@ class MultiHeadAttention(nn.Module):
             # structured convention: window rides alongside causal so the
             # flash kernel can bound its key loops instead of masking
             kw["window"] = self.window
-        with jax.named_scope("attn_window" if self.window is not None
-                             else "attn_full"):
-            y = attn(q, k, v, mask=mask, key_valid=key_valid, causal=causal,
-                     dtype=self.dtype, **kw)
+        if y is None:
+            with jax.named_scope("attn_window" if self.window is not None
+                                 else "attn_full"):
+                y = attn(q, k, v, mask=mask, key_valid=key_valid,
+                         causal=causal, dtype=self.dtype, **kw)
         if self.gate:
             # headwise gate of arXiv:2505.06708: a sigmoid of a per-head
             # projection of the layer's input scales each head's output
@@ -315,6 +330,32 @@ class MultiHeadAttention(nn.Module):
         return nn.DenseGeneral(d_model, axis=(-2, -1), dtype=self.dtype,
                                use_bias=self.use_bias,
                                kernel_init=dense_init, name="out")(y)
+
+    def _attend_in_place(self, q, k, v, key_valid, ck, cv, cvalid, idx):
+        """One token of one slot against pool leaves where they rest: the
+        cached positions below ``cache_index`` through the slot's block
+        table, the token's own row beside them.  The cache variables leave
+        holding that row (what the engine scatters), not a cache."""
+        from distributed_deep_learning_tpu.ops.paged_decode_pallas import (
+            paged_slot_attention)
+
+        if q.shape[:2] != (1, 1):
+            raise ValueError(
+                f"a cache of pool leaves serves one token of one slot, got "
+                f"queries {q.shape[:2]}; programs with more gather the slot")
+        step_valid = (key_valid if key_valid is not None
+                      else jnp.ones((1, 1), jnp.bool_))
+        with jax.named_scope("attn_window" if self.window is not None
+                             else "attn_full"), \
+                jax.named_scope("kv_paged_attn"):
+            y = paged_slot_attention(
+                q[0, 0], k[0, 0], v[0, 0], step_valid[0, 0], ck.value,
+                cv.value, cvalid.value,
+                self.get_variable("cache", BLOCK_TABLE), idx.value,
+                window=self.window)
+        ck.value, cv.value, cvalid.value = k, v, step_valid
+        idx.value = idx.value + 1
+        return y[None, None]
 
 
 def _norm(kind: str, dtype, eps: float, name: Optional[str] = None):

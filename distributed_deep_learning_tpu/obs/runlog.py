@@ -75,7 +75,8 @@ class CompileLog:
     """Bounded log of ``(event, fun_name, start, seconds)``: event is
     ``trace`` / ``lower`` / ``compile`` (the backend compile, a cache
     fetch included) / ``retrieve`` (the fetch alone; JAX gives it no
-    name) / ``mark``; `start` is ``time.time()``.  ``trace`` carries the
+    name) / ``mark`` / a program's own :meth:`note`; `start` is
+    ``time.time()``.  ``trace`` carries the
     Python function's name, ``lower`` and ``compile`` the module's
     (``jit(<name>)``; the profiler writes it ``jit_<name>``).  Only a
     program's own trace is kept, not those of the functions it calls."""
@@ -85,6 +86,12 @@ class CompileLog:
 
     def mark(self, label: str) -> None:
         self.entries.append((MARK, label, time.time(), 0.0))
+
+    def note(self, event: str, fun_name: str, text: str) -> None:
+        """A fact a program states about itself as it is traced (which of
+        its paths each layer took), in the log beside its compile events:
+        `text` where they carry seconds."""
+        self.entries.append((event, fun_name, time.time(), text))
 
     def _span(self, event, start, end, fun_name=None, **_):
         short = _SPANS.get(event)

@@ -1,40 +1,63 @@
-"""Paged flash-decode Pallas kernel: block-table indexing IN the kernel.
+"""Paged flash-decode Pallas kernel: K and V are read where they rest.
 
-The paged engine's decode path today materialises each slot's logical KV
-with a host-shaped gather (``paged.gather_slot``: ``leaf[table]`` then
-reshape) before the attention matmul ever runs — at long context that
-gather IS the decode bill: it copies the slot's entire KV history
-through HBM once per token just to linearise it.  This kernel deletes
-the copy.  The grid walks ``(slot, logical_block)`` and the BLOCK TABLE
-rides in scalar-prefetch memory (SMEM), so each program's index map
-points Pallas' own pipeline DMA at physical block ``tables[b, j]`` of
-the resident pool — K/V stream straight from where they live, the
-"gather" degenerates to address arithmetic, and the online-softmax
+The paged engine's one-token decode program (``jit_paged_decode``) hands
+each full-attention layer its K and V POOL leaves as they rest,
+``(num_blocks, block, Hkv*D)`` with the trailing dims merged
+(:mod:`..serve.paged`), and the slot's block table beside them; this kernel
+attends over them in place, over the blocks that hold live positions only.
+Who still gathers (``paged.gather_slot``: ``leaf[table]`` then reshape) and
+why: the programs with several queries a slot (``paged_chunk``,
+``paged_verify``, ``paged_draft*``), which run the model's own multi-token
+cached forward; ``paged_spill``, which wants the at-rest image; and a
+window layer's ring in the decode program itself, 65 blocks a slot and
+bounded by the window already.
+
+The grid is DATA: the slots' live steps laid end to end (``_work_list``),
+a step ``blocks_per_step`` logical blocks of one slot, the grid's length a
+traced value (a slot of 40 positions at block 16 and 16 blocks a step has
+one step; one of length 0, free or still prefilling, has one too, for its
+result, and reads nothing).  What a grid step is rides in scalar-prefetch
+memory (SMEM): its slot, its step within the slot, and the physical block
+of each of its tiles.  Each of a step's blocks is an operand of its own
+whose index map points Pallas' pipeline DMA at that block of the resident
+pool: K/V stream straight from where they live.  Past a slot's last live
+block a tile's entry repeats what the tile already holds, so no DMA is
+issued, and the table's trash-padded tail is never touched.  Why not a
+``(slot, step)`` grid over the whole table: the pipeline's bookkeeping
+costs the scalar core ~0.09 us an operand and grid step, more than a
+block's DMA takes, dead steps included (my chip runs, PR 27: 1.4 ms a call
+on a table of 512 blocks with every slot empty).  The online-softmax
 running statistics (max ``m``, denominator ``l``, accumulator ``acc``)
-carry across the block loop in VMEM scratch exactly like the training
-flash kernel (:mod:`.attention_pallas`), O(D) memory per query.
+carry across a slot's steps in VMEM scratch like the training flash kernel
+(:mod:`.attention_pallas`).
 
-Quantization composes in-register: int8 pools arrive with their
-per-position-per-head f32 scales (:class:`..serve.quant.QuantTensor`
-payload + ``s``), the scale tile rides the same block index map as its
-payload tile, and ``k.astype(f32) * scale`` happens on the VPU between
-the DMA and the MXU contraction — the dequantized KV never touches HBM.
-That pairing is what turns the 3.5-4x at-rest shrink into 3.5-4x less
-decode wire traffic, which on a memory-bound decode is throughput.
+The leaf is never relaid into ``(Hkv, D)`` tiles, in HBM or in VMEM (64-wide
+heads in a 1,600-wide minor dim are 12.5 lane tiles).  The query is made
+BLOCK-DIAGONAL instead: row ``h`` of ``q_bd (H, Hkv*D)`` holds ``q[h]`` in
+the columns of KV head ``h // G`` and zero elsewhere, so ``q_bd @ K^T`` is
+every head's scores in one matmul a step, ``P @ V`` one more, and the
+head's own ``D`` columns are picked off the ``(H, Hkv*D)`` result by the
+same mask.  That wastes ``Hkv`` x the FLOPs on a program that is bound by
+bytes, and serves grouped queries (``G > 1``) unchanged.
 
-GQA-native like the training kernel: q arrives grouped ``(B, Hkv, G,
-D)`` and contracts against unexpanded ``Hkv``-headed K/V tiles — the
-group-times-smaller pool is what streams.
+Precision: scores, running max and denominator in float32; ``P`` in the
+query's dtype for ``P @ V`` with float32 accumulation, as
+:func:`..models.transformer.dot_product_attention` does.  int8 pools
+(:class:`..serve.quant.QuantTensor`: payload ``(N, bs, Hkv*D)`` + f32
+scales ``(N, bs, Hkv)``) dequantise in register: the scale tile rides the
+same block index map as its payload tile and multiplies the scores (K) and
+the probabilities (V), a head's scale being constant over its ``D`` columns.
 
-Masking: position ``j*bs + i`` attends iff it is ``< seq_lens[b]``, so
-trash-backed tail entries of the table are read (garbage) and masked —
-the same causal-prefix discipline as ``gather_slot``.  One padded slot
-(``seq_lens == 0``) degrades to uniform weights over garbage, never
-NaN; callers ignore those rows (the engine's free slots).
+Masking: cached position ``p`` attends iff ``p < seq_lens[b]``, it is valid
+(``valid_pool``, gathered through the same table: a byte a position) and
+inside the window.  The new token's own K/V row, not yet in the pool, comes
+beside it (``k_new`` / ``v_new``) and is the softmax's last term.  A slot
+with nothing to attend gives zeros, never NaN; callers ignore those rows.
 
 Off-TPU the dispatcher (:func:`paged_flash_decode`) routes to
-:func:`paged_decode_reference` — the same gather-then-mask lax math the
-engine compiles today — and the CPU parity tests run the REAL kernel in
+:func:`paged_decode_reference`: gather, insert the new row, mask, and the
+model's own dense attention, which is what the engine compiled before this
+kernel was on its path.  The CPU parity tests run the REAL kernel in
 interpreter mode against it.
 """
 
@@ -44,201 +67,396 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+#: positions a grid step attends (the default ``blocks_per_step`` covers
+#: them): fewer, larger steps than a block a step, each one DMA a block
+STEP_POSITIONS = 256
 
 
-def _contract_qk(q, k):
-    """(Hkv, G, D) x (bs, Hkv, D) -> (Hkv, G, bs), f32 accumulate."""
-    return lax.dot_general(q, k, (((2,), (2,)), ((0,), (1,))),
+def _nt(a, b):
+    """``a (M, K) @ b (N, K)^T`` -> ``(M, N)``, f32 accumulate."""
+    return lax.dot_general(a, b, (((1,), (1,)), ((), ())),
                            preferred_element_type=jnp.float32)
 
 
-def _contract_pv(p, v):
-    """(Hkv, G, bs) x (bs, Hkv, D) -> (Hkv, G, D), f32 accumulate."""
-    return lax.dot_general(p, v, (((2,), (0,)), ((0,), (1,))),
+def _nn(a, b):
+    return lax.dot_general(a, b, (((1,), (0,)), ((), ())),
                            preferred_element_type=jnp.float32)
 
 
-def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, ks_ref,
-                   vs_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                   sm_scale: float, block_size: int, n_blocks: int):
-    """One (slot, logical block) step of the online softmax.
+def _exact(x, onehot, dot=_nn):
+    """``dot(x, onehot)`` for a 0/1 bf16 matrix, exact in `x`'s dtype at
+    one MXU pass a bf16 term of `x` (one for bf16, three for float32):
+    a float32 product at full precision costs six."""
+    if x.dtype == jnp.bfloat16:
+        return dot(x, onehot)
+    rest, out = x.astype(jnp.float32), 0.0
+    for _ in range(3):
+        term = rest.astype(jnp.bfloat16)
+        out = out + dot(term, onehot)
+        rest = rest - term.astype(jnp.float32)
+    return out
 
-    ``tables_ref``/``lens_ref`` are the scalar-prefetch refs (SMEM);
-    the BlockSpec index maps below already used ``tables_ref`` to land
-    ``k_ref``/``v_ref`` on physical block ``tables[b, j]``, so the
-    kernel body never sees a physical id — only its tile.  ``ks_ref``/
-    ``vs_ref`` are the per-position-per-head scale tiles (None on the
-    full-precision variant; the tile dequantizes in-register)."""
-    b, j = pl.program_id(0), pl.program_id(1)
+
+def _rows(refs, dtype):
+    """A step's block tiles ``(1, bs, W)`` as one ``(n * bs, W)`` array in
+    `dtype`.  Tiles that do not fill a packed sublane tile of their own
+    type (bf16: 16 rows, int8: 32) go through float32, whose 8 rows they
+    do fill."""
+    tiles = [r[0] for r in refs]
+    packed = 32 // tiles[0].dtype.itemsize
+    if tiles[0].shape[0] % packed or tiles[0].dtype == jnp.int8:
+        tiles = [t.astype(jnp.float32) for t in tiles]
+    got = tiles[0] if len(tiles) == 1 else jnp.concatenate(tiles, axis=0)
+    return got.astype(dtype)
+
+
+def _decode_kernel(slot_ref, step_ref, phys_ref, lens_ref, newv_ref, q_ref,
+                   valid_ref, pick_ref, tile_ref, fold_ref, *rest, n: int,
+                   block_size: int, sm_scale: float, window,
+                   quantized: bool, has_new: bool):
+    """One step of one slot of the online softmax: grid step ``t`` is step
+    ``step_ref[t]`` of slot ``slot_ref[t]``, the slots' live steps laid
+    end to end (a slot with nothing cached has one, for its result).
+
+    The BlockSpec index maps below already used ``phys_ref`` to land the
+    step's `n` K and `n` V tiles (then their scale tiles) on their
+    physical blocks, so the body never sees a physical id.  ``rest``: the
+    new row's K and V (with `has_new`), the head-to-scale matrix (with
+    `quantized`), those tiles, the output, and the scratch: the
+    block-diagonal query, ``m``, ``l``, ``acc``."""
+    rest = list(rest)
+    kn_ref, vn_ref = (rest.pop(0), rest.pop(0)) if has_new else (None, None)
+    spread_ref = rest.pop(0) if quantized else None
+    k_refs, v_refs = rest[:n], rest[n:2 * n]
+    rest = rest[2 * n:]
+    if quantized:
+        ks_refs, vs_refs = rest[:n], rest[n:2 * n]
+        rest = rest[2 * n:]
+    o_ref, qbd_ref, m_ref, l_ref, acc_ref = rest
+    t = pl.program_id(0)
+    b, j = slot_ref[t], step_ref[t]
+    length = lens_ref[b]
+    step = n * block_size
+    cdt = q_ref.dtype
 
     @pl.when(j == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
+        # q (H, D) tiled Hkv times along the columns, its own head's kept
+        qbd_ref[...] = (_exact(q_ref[0], tile_ref[...])
+                        * pick_ref[...].astype(jnp.float32)).astype(cdt)
 
-    q = q_ref[0].astype(jnp.float32)                  # (Hkv, G, D)
-    k = k_ref[0]                                      # (bs, Hkv, D)
-    v = v_ref[0]
-    if ks_ref is not None:
-        k = k.astype(jnp.float32) * ks_ref[0]         # in-register dequant
-        v = v.astype(jnp.float32) * vs_ref[0]
+    @pl.when(j * step < length)
+    def _attend():
+        s = _nt(qbd_ref[...], _rows(k_refs, cdt)) * sm_scale     # (H, step)
+        if quantized:
+            s = s * _exact(_rows(ks_refs, jnp.float32), spread_ref[...],
+                           lambda scales, heads: _nt(heads, scales))
+        kpos = j * step + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        live = jnp.logical_and(kpos < length, valid_ref[0, 0] > 0)
+        if window is not None:
+            live = jnp.logical_and(live, length - kpos < window)
+        s = jnp.where(live, s, NEG_INF)
+        m = m_ref[...]
+        new_m = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        corr = jnp.exp(m - new_m)
+        p = jnp.where(live, jnp.exp(s - new_m), 0.0)
+        m_ref[...] = new_m
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        if quantized:
+            p = p * _exact(_rows(vs_refs, jnp.float32), spread_ref[...],
+                           lambda scales, heads: _nt(heads, scales))
+        acc_ref[...] = acc_ref[...] * corr + _nn(p.astype(cdt),
+                                                 _rows(v_refs, cdt))
 
-    s = _contract_qk(q, k.astype(q.dtype)) * sm_scale   # (Hkv, G, bs)
-    kpos = j * block_size + lax.broadcasted_iota(
-        jnp.int32, s.shape, dimension=2)
-    s = jnp.where(kpos < lens_ref[b], s, NEG_INF)
-
-    m = m_ref[...]                                    # (Hkv, G, 1)
-    l = l_ref[...]
-    blk_max = jnp.max(s, axis=-1, keepdims=True)
-    new_m = jnp.maximum(m, blk_max)
-    corr = jnp.exp(m - new_m)
-    p = jnp.exp(s - new_m)
-    m_ref[...] = new_m
-    l_ref[...] = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * corr + _contract_pv(
-        p.astype(v.dtype), v.astype(p.dtype))
-
-    @pl.when(j == n_blocks - 1)
+    @pl.when((j + 1) * step >= length)
     def _writeout():
-        denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+        m, l, acc = m_ref[...], l_ref[...], acc_ref[...]
+        if has_new:
+            # the token's own row: the last term of the softmax
+            s = jnp.sum(qbd_ref[...].astype(jnp.float32)
+                        * kn_ref[0].astype(jnp.float32),
+                        axis=1, keepdims=True) * sm_scale        # (H, 1)
+            ok = newv_ref[b] > 0
+            s = jnp.where(ok, s, NEG_INF)
+            new_m = jnp.maximum(m, s)
+            corr = jnp.exp(m - new_m)
+            p = jnp.where(ok, jnp.exp(s - new_m), 0.0)
+            l = l * corr + p
+            acc = acc * corr + (p.astype(cdt).astype(jnp.float32)
+                                * vn_ref[0].astype(jnp.float32))
+        full = acc / jnp.maximum(l, 1e-30)                       # (H, Hkv*D)
+        own = (full * pick_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
+        o_ref[0] = _exact(own, fold_ref[...]).astype(o_ref.dtype)
 
 
-def _drop_scales(kern):
-    def wrapped(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest, **kw):
-        return kern(tables_ref, lens_ref, q_ref, k_ref, v_ref, None, None,
-                    *rest, **kw)
-    return wrapped
+def _work_list(tables, lens, n: int, block_size: int):
+    """The grid as data: the slots' live steps laid end to end.
+
+    ``(total, slot, step, phys)``: `total` steps in all (a slot's share is
+    its cached positions in steps of ``n * block_size``, and one where it
+    has none); for grid step ``t`` its slot and its step within the slot;
+    ``phys[i, t]`` the physical block the step's `i`-th tile holds: the
+    table's entry while the slot has a block there, else what that tile
+    held the step before (an index map that repeats itself issues no
+    DMA), so a table's tail is never read."""
+    B, Bps = tables.shape
+    span = n * block_size
+    n_steps = -(-Bps // n)
+    steps = jnp.maximum(1, (lens + span - 1) // span)
+    ends = jnp.cumsum(steps)
+    t = jnp.arange(B * n_steps, dtype=jnp.int32)
+    slot = jnp.minimum(jnp.searchsorted(ends, t, side="right"),
+                       B - 1).astype(jnp.int32)
+    step = jnp.clip(t - (ends - steps)[slot], 0, n_steps - 1)
+    at = step[None] * n + jnp.arange(n, dtype=jnp.int32)[:, None]   # (n, T)
+    held = jnp.logical_and(at * block_size < lens[slot][None],
+                           (t < ends[-1])[None])
+    since = lax.cummax(jnp.where(held, t[None], -1), axis=1)
+    phys = tables[slot[None], jnp.minimum(at, Bps - 1)]
+    phys = jnp.where(since < 0, 0, jnp.take_along_axis(
+        phys, jnp.maximum(since, 0), axis=1))
+    return ends[-1], slot, step, phys.astype(jnp.int32)
 
 
-def _split_quant(pool, scale):
-    """Accept either a raw array + explicit scale or a
-    :class:`..serve.quant.QuantTensor` carrying both."""
+def _split_quant(pool):
+    """``(payload, scales or None)`` of a pool leaf."""
     from distributed_deep_learning_tpu.serve.quant import is_quant
 
-    if is_quant(pool):
-        if scale is not None:
-            raise ValueError("pass scales either inside the QuantTensor "
-                             "or as an explicit argument, not both")
-        return pool.q, pool.s
-    return pool, scale
+    return (pool.q, pool.s) if is_quant(pool) else (pool, None)
+
+
+def _head_matrices(H: int, Hp: int, Hkv: int, D: int):
+    """The 0/1 matrices that stand in for a relayout: `pick` ``(Hp, Hkv*D)``
+    row ``h`` marks the columns of KV head ``h // G`` (padding rows none),
+    `tile` ``(D, Hkv*D)`` is ``Hkv`` identities side by side, `spread`
+    ``(Hp, Hkv)`` row ``h`` marks KV head ``h // G``."""
+    G = H // Hkv
+    head = np.arange(Hp) // G
+    live = (np.arange(Hp) < H)[:, None]
+    cols = np.arange(Hkv * D)
+    pick = (head[:, None] == cols[None] // D) & live
+    tile = np.arange(D)[:, None] == cols[None] % D
+    spread = (head[:, None] == np.arange(Hkv)[None]) & live
+    return pick, tile, spread
 
 
 def paged_flash_decode(q, k_pool, v_pool, block_tables, seq_lens, *,
-                       k_scale=None, v_scale=None,
-                       sm_scale: float | None = None,
+                       k_new=None, v_new=None, new_valid=None,
+                       valid_pool=None, window=None, blocks_per_step=None,
                        interpret: bool | None = None):
-    """Decode attention straight off the paged pools.
+    """One token's attention for every slot, straight off the paged pools.
 
-    ``q``: ``(B, Hkv, G, D)`` grouped queries (``H = Hkv * G``; pass
-    ``G = 1`` slices for plain MHA).  ``k_pool``/``v_pool``: the
-    engine's resident ``(N, bs, Hkv, D)`` block pools — floating, or
-    int8 with ``(N, bs, Hkv, 1)`` f32 scales (explicit ``k_scale``/
-    ``v_scale`` or a :class:`..serve.quant.QuantTensor` per pool).
-    ``block_tables``: ``(B, Bps)`` int32 physical ids (trash-padded
-    tails fine); ``seq_lens``: ``(B,)`` int32 valid KV positions per
-    slot.  Returns ``(B, Hkv, G, D)`` in ``q``'s dtype.
+    ``q``: ``(B, H, D)``, one query a slot.  ``k_pool`` / ``v_pool``: the
+    engine's resident ``(N, bs, Hkv*D)`` pool leaves as they rest (``H``
+    a multiple of ``Hkv``), floating, or int8 as a
+    :class:`..serve.quant.QuantTensor` with ``(N, bs, Hkv)`` f32 scales.
+    ``block_tables``: ``(B, Bps)`` int32 physical ids (trash-padded tails
+    fine); ``seq_lens``: ``(B,)`` int32 cached positions a slot attends.
+    ``valid_pool``: the ``(N, bs)`` bool leaf of cached validity, or None
+    (all valid).  ``k_new`` / ``v_new`` ``(B, Hkv, D)``: the token's own
+    row, attended as position ``seq_lens[b]`` (then ``seq_lens < Bps *
+    bs``) where ``new_valid[b]`` (default: everywhere).  `window`: a
+    causal sliding window in positions.  Returns ``(B, H, D)`` in
+    ``q``'s dtype.
 
-    On TPU this is the scalar-prefetch Pallas kernel (the gather
-    disappears into block index maps); elsewhere it falls back to
-    :func:`paged_decode_reference` — identical math on the engine's
-    existing gather-then-mask lax path.  ``interpret=True`` forces the
-    kernel through the Pallas interpreter (the CPU parity tests).
+    On TPU this is the scalar-prefetch Pallas kernel; elsewhere it is
+    :func:`paged_decode_reference`.  ``interpret=True`` forces the kernel
+    through the Pallas interpreter (the CPU parity tests).
     """
-    k_pool, k_scale = _split_quant(k_pool, k_scale)
-    v_pool, v_scale = _split_quant(v_pool, v_scale)
-    if (k_scale is None) != (v_scale is None):
+    if (k_new is None) != (v_new is None):
+        raise ValueError("k_new and v_new come together")
+    kq, ks = _split_quant(k_pool)
+    vq, vs = _split_quant(v_pool)
+    if (ks is None) != (vs is None):
         raise ValueError("k and v pools must agree on quantization")
+    kw = dict(k_new=k_new, v_new=v_new, new_valid=new_valid,
+              valid_pool=valid_pool, window=window)
     if interpret is None:
         if jax.default_backend() != "tpu":
-            return paged_decode_reference(
-                q, k_pool, v_pool, block_tables, seq_lens,
-                k_scale=k_scale, v_scale=v_scale, sm_scale=sm_scale)
+            return paged_decode_reference(q, k_pool, v_pool, block_tables,
+                                          seq_lens, **kw)
         interpret = False
+    return _kernel_call(q, k_pool, v_pool, block_tables, seq_lens, **kw,
+                        blocks_per_step=blocks_per_step, interpret=interpret)
 
-    B, Hkv, G, D = q.shape
-    N, bs = k_pool.shape[:2]
-    Bps = block_tables.shape[1]
-    if sm_scale is None:
-        sm_scale = 1.0 / (D ** 0.5)
 
-    quantized = k_scale is not None
-    kern = functools.partial(
-        _decode_kernel if quantized else _drop_scales(_decode_kernel),
-        sm_scale=sm_scale, block_size=bs, n_blocks=Bps)
+@functools.partial(jax.jit, static_argnames=("window", "blocks_per_step",
+                                             "interpret"))
+def _kernel_call(q, k_pool, v_pool, block_tables, seq_lens, *, k_new, v_new,
+                 new_valid, valid_pool, window, blocks_per_step, interpret):
+    """The kernel's call, jitted: every layer of a model calls it with the
+    same shapes, so it is traced and lowered once a program, not once a
+    layer (48 Mosaic modules cost half a minute of set-up)."""
+    kq, ks = _split_quant(k_pool)
+    vq, vs = _split_quant(v_pool)
+    B, H, D = q.shape
+    bs, HD = kq.shape[1:]
+    Hkv, Bps = HD // D, block_tables.shape[1]
+    if Hkv * D != HD or H % Hkv:
+        raise ValueError(f"{H} query heads of {D} against a pool leaf "
+                         f"{HD} wide")
+    n = blocks_per_step or max(1, STEP_POSITIONS // bs)
+    n = min(n, Bps)
+    n_steps = -(-Bps // n)
+    step = n * bs
+    quantized, has_new = ks is not None, k_new is not None
+    Hp = -(-H // 8) * 8                      # whole sublane tiles of rows
 
-    # index maps see (*grid_indices, *scalar_refs); the pool tiles chase
-    # the block table through scalar-prefetch memory — this line is the
-    # whole kernel, everything else is flash bookkeeping
-    def pool_map(b, j, tables_ref, lens_ref):
-        return (tables_ref[b, j], 0, 0, 0)
+    tables = block_tables.astype(jnp.int32)
+    lens = seq_lens.astype(jnp.int32)
+    newv = (jnp.ones((B,), jnp.int32) if new_valid is None
+            else new_valid.astype(jnp.int32))
+    if valid_pool is None:
+        valid = jnp.ones((B, n_steps, 1, step), jnp.float32)
+    else:
+        valid = valid_pool[tables].reshape(B, Bps * bs).astype(jnp.float32)
+        valid = jnp.pad(valid, ((0, 0), (0, n_steps * step - Bps * bs)))
+        valid = valid.reshape(B, n_steps, 1, step)
+    pick, tile, spread = _head_matrices(H, Hp, Hkv, D)
+    qp = jnp.pad(q, ((0, 0), (0, Hp - H), (0, 0)))
 
-    def q_map(b, j, tables_ref, lens_ref):
-        return (b, 0, 0, 0)
+    total, slot, step_of, phys = _work_list(tables, lens, n, bs)
 
-    in_specs = [
-        pl.BlockSpec((1, Hkv, G, D), q_map),
-        pl.BlockSpec((1, bs, Hkv, D), pool_map),
-        pl.BlockSpec((1, bs, Hkv, D), pool_map),
-    ]
-    args = [q, k_pool, v_pool]
+    def slot_map(t, slot_ref, *_):
+        return (slot_ref[t], 0, 0)
+
+    def const_map(t, *_):
+        return (0, 0)
+
+    def valid_map(t, slot_ref, step_ref, *_):
+        return (slot_ref[t], step_ref[t], 0, 0)
+
+    def pool_map(i):
+        return lambda t, slot_ref, step_ref, phys_ref, *_: (
+            phys_ref[i, t], 0, 0)
+
+    in_specs = [pl.BlockSpec((1, Hp, D), slot_map),
+                pl.BlockSpec((1, 1, 1, step), valid_map),
+                pl.BlockSpec((Hp, HD), const_map),
+                pl.BlockSpec((D, HD), const_map),
+                pl.BlockSpec((HD, D), const_map)]
+    args = [qp, valid] + [jnp.asarray(m, jnp.bfloat16)
+                          for m in (pick, tile, tile.T)]
+    if has_new:
+        in_specs += [pl.BlockSpec((1, 1, HD), slot_map)] * 2
+        args += [k_new.reshape(B, 1, HD), v_new.reshape(B, 1, HD)]
     if quantized:
-        in_specs += [pl.BlockSpec((1, bs, Hkv, 1), pool_map),
-                     pl.BlockSpec((1, bs, Hkv, 1), pool_map)]
-        args += [k_scale, v_scale]
+        in_specs.append(pl.BlockSpec((Hp, Hkv), const_map))
+        args.append(jnp.asarray(spread, jnp.bfloat16))
+    for pool, width in ((kq, HD), (vq, HD)) + (
+            ((ks, Hkv), (vs, Hkv)) if quantized else ()):
+        in_specs += [pl.BlockSpec((1, bs, width), pool_map(i))
+                     for i in range(n)]
+        args += [pool] * n
 
+    kern = functools.partial(
+        _decode_kernel, n=n, block_size=bs, sm_scale=1.0 / (D ** 0.5),
+        window=window, quantized=quantized, has_new=has_new)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, Bps),
+        num_scalar_prefetch=5,
+        grid=(total,),          # as many steps as the slots hold, no more
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, Hkv, G, D), q_map),
-        scratch_shapes=[pltpu.VMEM((Hkv, G, 1), jnp.float32),
-                        pltpu.VMEM((Hkv, G, 1), jnp.float32),
-                        pltpu.VMEM((Hkv, G, D), jnp.float32)],
+        out_specs=pl.BlockSpec((1, Hp, D), slot_map),
+        scratch_shapes=[pltpu.VMEM((Hp, HD), q.dtype),
+                        pltpu.VMEM((Hp, 1), jnp.float32),
+                        pltpu.VMEM((Hp, 1), jnp.float32),
+                        pltpu.VMEM((Hp, HD), jnp.float32)],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
-    )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32), *args)
+        out_shape=jax.ShapeDtypeStruct((B, Hp, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret, name="paged_flash_decode",
+    )(slot, step_of, phys, lens, newv, *args)
+    return out[:, :H]
+
+
+def paged_slot_attention(q, k_new, v_new, new_valid, k_pool, v_pool,
+                         valid_pool, table, length, *, window=None):
+    """:func:`paged_flash_decode` for ONE slot (``q (H, D)``, ``k_new`` /
+    ``v_new (Hkv, D)``, scalars `new_valid` and `length`, ``table
+    (Bps,)``), for use under ``vmap`` over slots that share the pools (the
+    paged engine's decode program maps the model over its slots): the
+    mapped axis becomes the kernel's slot axis, so the pools stay one
+    resident copy and the block tables reach the kernel as scalars, which
+    a plain ``vmap`` of the kernel cannot give."""
+    def attend(q, k_new, v_new, new_valid, table, length, *pools):
+        return paged_flash_decode(
+            q, pools[0], pools[1], table, length, k_new=k_new, v_new=v_new,
+            new_valid=new_valid, valid_pool=pools[2], window=window)
+
+    @jax.custom_batching.custom_vmap
+    def one(*args):
+        return attend(*(x[None] for x in args[:6]), *args[6:])[0]
+
+    @one.def_vmap
+    def rule(axis_size, in_batched, *args):
+        if any(jax.tree.leaves(in_batched[6:])):
+            raise NotImplementedError(
+                "paged attention under vmap: only the slots' queries, rows, "
+                "tables and lengths may be mapped (one pool for all)")
+        rows = [x if mapped else jnp.broadcast_to(x, (axis_size,) + x.shape)
+                for x, mapped in zip(args[:6], in_batched[:6])]
+        return attend(*rows, *args[6:]), True
+
+    return one(q, k_new, v_new, jnp.asarray(new_valid),
+               table, jnp.asarray(length), k_pool, v_pool, valid_pool)
 
 
 def paged_decode_reference(q, k_pool, v_pool, block_tables, seq_lens, *,
-                           k_scale=None, v_scale=None,
-                           sm_scale: float | None = None):
-    """The existing lax path: gather the logical KV (``leaf[table]``,
-    exactly :func:`..serve.paged.gather_slot`'s move), dequantize, mask
-    to ``seq_lens`` and take one dense softmax — the semantics the
-    kernel must reproduce and the off-TPU execution path."""
-    k_pool, k_scale = _split_quant(k_pool, k_scale)
-    v_pool, v_scale = _split_quant(v_pool, v_scale)
-    B, Hkv, G, D = q.shape
-    bs = k_pool.shape[1]
-    Bps = block_tables.shape[1]
-    if sm_scale is None:
-        sm_scale = 1.0 / (D ** 0.5)
+                           k_new=None, v_new=None, new_valid=None,
+                           valid_pool=None, window=None):
+    """The gather path's arithmetic, step for step: gather each slot's
+    logical K/V (``leaf[table]``, :func:`..serve.paged.gather_slot`'s
+    move), lift it to the query's dtype, put the new row at its position,
+    mask to the causal prefix (and window, and validity) and run the
+    model's dense attention.  The semantics the kernel must reproduce,
+    and the off-TPU execution path."""
+    from distributed_deep_learning_tpu.models.transformer import (
+        dot_product_attention)
+    from distributed_deep_learning_tpu.serve import quant
 
-    def logical(pool, scale):
-        got = pool[block_tables]                 # (B, Bps, bs, Hkv, D)
-        got = got.reshape(B, Bps * bs, Hkv, D)
-        if scale is not None:
-            sc = scale[block_tables].reshape(B, Bps * bs, Hkv, 1)
-            got = got.astype(jnp.float32) * sc
-        return got
+    B, H, D = q.shape
+    bs = _split_quant(k_pool)[0].shape[1]
+    T = block_tables.shape[1] * bs
+    lens = seq_lens.astype(jnp.int32)
 
-    k = logical(k_pool, k_scale)
-    v = logical(v_pool, v_scale)
-    s = jnp.einsum("bhgd,bthd->bhgt", q.astype(jnp.float32),
-                   k.astype(jnp.float32)) * sm_scale
-    kpos = jnp.arange(Bps * bs)[None, None, None, :]
-    s = jnp.where(kpos < seq_lens[:, None, None, None], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhgt,bthd->bhgd", p, v.astype(jnp.float32))
-    return out.astype(q.dtype)
+    def logical(pool):
+        payload, scale = _split_quant(pool)
+        got = payload[block_tables].reshape(B, T, -1, D)
+        if scale is None:
+            return got.astype(q.dtype)
+        scale = scale[block_tables].reshape(B, T, -1, 1)
+        return quant.dequant(quant.QuantTensor(got, scale), q.dtype)
+
+    k, v = logical(k_pool), logical(v_pool)
+    valid = jnp.ones((B, T), jnp.bool_) if valid_pool is None \
+        else valid_pool[block_tables].reshape(B, T)
+    kpos = jnp.arange(T)[None]
+    if k_new is None:
+        mask = kpos < lens[:, None]
+    else:
+        def put(row, new, at):
+            return lax.dynamic_update_slice_in_dim(row, new[None], at, 0)
+
+        k = jax.vmap(put)(k, k_new.astype(q.dtype), lens)
+        v = jax.vmap(put)(v, v_new.astype(q.dtype), lens)
+        valid = jax.vmap(put)(
+            valid, jnp.ones((B,), jnp.bool_) if new_valid is None
+            else new_valid.astype(jnp.bool_), lens)
+        mask = kpos <= lens[:, None]
+    if window is not None:
+        mask = jnp.logical_and(mask, lens[:, None] - kpos < window)
+    return dot_product_attention(q[:, None], k, v,
+                                 mask=mask[:, None, None, :],
+                                 key_valid=valid, dtype=q.dtype)[:, 0]

@@ -836,6 +836,9 @@ class PagedEngine:
         self._slot_like = paged.slot_template(self.lm, self.padded_len,
                                               kv_dtype=kv_dtype)
         self.pools = self._new_pools(self._slot_like)
+        #: attention layers the decode program serves in place through the
+        #: block table / by gathering (rings), read off the slot template
+        self.decode_attn_paths = paged.attention_paths(self._slot_like)
         self._chunk_prog = CountingJit(self._chunk_impl, "paged_chunk",
                                        "chunk_dispatch", **dk)
         self._decode = CountingJit(self._decode_impl, "paged_decode",
@@ -944,11 +947,14 @@ class PagedEngine:
         """Gather one slot's logical cache (`like`: the draft's template
         for the draft's pools) and lift it to the model's working
         precision (int8 pools dequantize ``q * s`` in f32)."""
-        got = paged.gather_slot(
-            pools, table, pos, self._slot_like if like is None else like)
+        return self._lift(paged.gather_slot(
+            pools, table, pos, self._slot_like if like is None else like))
+
+    def _lift(self, cache):
+        """A gathered cache at the model's working precision."""
         if self.kv_dtype is None:
-            return got
-        return quant.dequant_cache(got, self.compute_dtype)
+            return cache
+        return quant.dequant_cache(cache, self.compute_dtype)
 
     def _qspan(self, span):
         """Freshly-computed floating KV span -> the pools' at-rest
@@ -1005,22 +1011,29 @@ class PagedEngine:
 
     def _decode_impl(self, params, pools, tables, positions, toks,
                      wb, wo, key):
-        """One token for every slot: gather each slot's logical cache
-        from the pools, run the model's single-sequence cached decode
-        (vmapped), scatter each slot's new KV position back, one shared
-        sampling.  Free/prefilling slots run on garbage and write to
+        """One token for every slot: run the model's single-sequence
+        cached decode (vmapped) over each slot's view of the pools
+        (:func:`.paged.decode_view`: a full-kind layer attends the pool
+        leaves in place through the slot's block table, over its live
+        blocks only; a ring is gathered), scatter each slot's new KV
+        position back, one shared sampling.  Free/prefilling slots come
+        with position 0, so they read nothing, run on garbage and write to
         trash; their sampled tokens are ignored by the host.  Last of the
         results: the tick's load of each held expert, a row an expert
         layer (None for a model without them)."""
+        runlog.compile_log.note(    # first: before any inner trace event
+            "attn_paths", "jit(paged_decode)",
+            " ".join(f"{k}={n}" for k, n in self.decode_attn_paths.items()))
         params = self._wp(params)
 
         def one(table, pos, tok):
-            with jax.named_scope("kv_gather"):
-                cache = self._gather(pools, table, pos)
+            with jax.named_scope("kv_gather"):      # rings only
+                cache = paged.decode_view(pools, table, pos,
+                                          self._slot_like, self._lift)
             hidden, new, load = cached_apply_counting(
                 self.lm, params, cache, tok[None, None])
             with jax.named_scope("kv_write"):
-                return hidden[0, 0], paged.extract_span(new, pos, 1), load
+                return hidden[0, 0], paged.view_span(new, pos), load
 
         h, spans, load = jax.vmap(one)(tables, positions, toks)
         if load is not None:
@@ -1761,6 +1774,9 @@ class PagedEngine:
                     recorder.record("admit", uid=req.uid, slot=idx,
                                     shared_len=shared)
 
+        in_place = self.decode_attn_paths["block_table"]
+        table_blocks = in_place * self.max_slots * self.blocks_per_slot
+        attn_read = attn_tables = 0
         t_start = time.perf_counter()
         tick = 0
         while sched.pending or sched.occupancy or spilled:
@@ -1812,6 +1828,16 @@ class PagedEngine:
                 if dec and not (self.draft_layers is not None
                                 and self._spec_enabled):
                     with p_decode_prepare:
+                        # what the decode program reads of what the tables
+                        # hold, in blocks over the block-table layers: a
+                        # slot attends the blocks its committed positions
+                        # fill
+                        read = in_place * sum(-(-committed[i] // bs)
+                                              for i in dec)
+                        counters["attn_blocks"] = {"read": read,
+                                                   "tables": table_blocks}
+                        attn_read += read
+                        attn_tables += table_blocks
                         toks = np.zeros(self.max_slots, np.int32)
                         pos = np.zeros(self.max_slots, np.int32)
                         wb = np.full(self.max_slots, paged.TRASH, np.int32)
@@ -2040,6 +2066,11 @@ class PagedEngine:
                 "shared_tokens": shared_tokens,
                 "prompt_tokens": prompt_tokens,
                 "prefill_tokens_computed": chunk_calls * self.chunk,
+                "decode_attn": {
+                    "paths": dict(self.decode_attn_paths),
+                    "blocks_read": attn_read,
+                    "blocks_in_tables": attn_tables,
+                },
             },
             "spec": spec_stats,
             "preempt": {

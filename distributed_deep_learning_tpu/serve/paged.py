@@ -12,12 +12,16 @@ lower, as vLLM-style PAGES:
   A slot's logical cache is the concatenation of the physical blocks
   its BLOCK TABLE names.  :func:`gather_slot` materialises one slot
   back into the model's ``B=1`` cache layout, trailing dims restored
-  from the slot's template (:func:`slot_template`) — so the engine
-  still runs the model's own tested cached decode, paging is invisible
-  to the model — and :func:`scatter_span` writes freshly-computed KV
-  positions back into their blocks.  All shapes are static;
-  tables/positions are data, so the compile-once contract survives
-  intact.
+  from the slot's template (:func:`slot_template`) — so the programs
+  with several queries a slot (chunk prefill, verify, the draft's) run
+  the model's own tested cached forward, paging invisible to the model —
+  and :func:`scatter_span` writes freshly-computed KV positions back
+  into their blocks.  The one-token decode program does not gather
+  full-kind leaves: :func:`decode_view` hands the model the pool leaves
+  themselves and the slot's block table, and attention reads the live
+  blocks in place (:mod:`..ops.paged_decode_pallas`).  All shapes are
+  static; tables/positions are data, so the compile-once contract
+  survives intact.
 
   Why the trailing dims are merged AT REST: a TPU tiles the two minor
   dims of a buffer (8 x 128 for bf16 pairs), and for a ``(H, D)`` minor
@@ -80,7 +84,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from distributed_deep_learning_tpu.models.transformer import (RING_LEAVES,
+from distributed_deep_learning_tpu.models.transformer import (BLOCK_TABLE,
+                                                              FULL_LEAVES,
+                                                              RING_LEAVES,
                                                               init_cache)
 from distributed_deep_learning_tpu.serve import quant
 from distributed_deep_learning_tpu.serve.cache import (COUNTER_LEAVES,
@@ -169,6 +175,13 @@ def build_pools(like, num_blocks: int, block_size: int,
     return jax.tree_util.tree_map_with_path(alloc, like)
 
 
+def merge_trailing(blocks):
+    """``(N, block, *trailing)`` blocks, an array or a
+    :class:`.quant.QuantTensor`, as a pool leaf rests them: the trailing
+    dims merged into one (:func:`build_pools` has why)."""
+    return jax.tree.map(lambda a: a.reshape(a.shape[:2] + (-1,)), blocks)
+
+
 def gather_slot(pools, table, pos, like):
     """One slot's logical cache in the model's ``B=1`` layout.
 
@@ -179,13 +192,85 @@ def gather_slot(pools, table, pos, like):
     :func:`slot_template`) gives each leaf its trailing dims back, on
     the gathered slot and never on the pool.  Trash entries gather
     garbage that the decode-path causal prefix mask (``kpos <= qpos``)
-    keeps causally unreachable."""
+    keeps causally unreachable.
+
+    It copies ``blocks_per_slot`` blocks of every leaf whatever the slot
+    holds, so the one-token decode program does not use it for full-kind
+    leaves (:func:`decode_view`).  Who still gathers: the programs with
+    several queries a slot (chunk prefill, verify, the draft's), which run
+    the model's multi-token cached forward over a slot-shaped cache; spill,
+    which wants the slot's at-rest image; and the decode program for ring
+    leaves, bounded by the window as they are."""
     def g(path, leaf, want):
         if is_counter(path):
             return jnp.asarray(pos, leaf.dtype)
         return leaf[_of_kind(table, path)].reshape((1, -1) + want.shape[2:])
 
     return jax.tree_util.tree_map_with_path(g, pools, like)
+
+
+def _by_layer(tree, layer, other, *rest):
+    """`tree` rebuilt: `layer` on each dict that holds one attention
+    layer's cache leaves, `other` on every other innermost dict (the
+    embedding's counter), each with the matching nodes of `rest`."""
+    def walk(node, *more):
+        if FULL_LEAVES[0] in node or RING_LEAVES[0] in node:
+            return layer(node, *more)
+        if any(isinstance(v, dict) for v in node.values()):
+            return {k: walk(v, *(m[k] for m in more))
+                    for k, v in node.items()}
+        return other(node, *more)
+
+    return walk(tree, *rest)
+
+
+def decode_view(pools, table, pos, like, lift=lambda cache: cache):
+    """One slot's cache for the ONE-TOKEN decode program.
+
+    A full-kind layer gets its pool leaves THEMSELVES, as they rest, with
+    the slot's block table beside them (``block_table``): the layer attends
+    over the blocks that hold live positions in place
+    (:mod:`..ops.paged_decode_pallas`) and nothing of ``blocks_per_slot x
+    block`` is copied.  A ring layer's leaves are gathered as
+    :func:`gather_slot` gathers them, and lifted by `lift` (the engine's
+    dequantisation)."""
+    full = table[0] if isinstance(table, tuple) else table
+
+    def layer(node, want):
+        if RING_LEAVES[0] in node:
+            return lift(gather_slot(node, table, pos, want))
+        return {**{k: jnp.asarray(pos, v.dtype) if k in COUNTER_LEAVES else v
+                   for k, v in node.items()}, BLOCK_TABLE: full}
+
+    return _by_layer(pools, layer,
+                     lambda node, want: gather_slot(node, table, pos, want),
+                     like)
+
+
+def view_span(cache, pos):
+    """What :func:`extract_span` gives for one position, of a cache that
+    went into the model as a :func:`decode_view`: a full-kind layer's
+    leaves came back as the token's own row."""
+    def layer(node):
+        if BLOCK_TABLE not in node:
+            return extract_span(node, pos, 1)
+        return {k: jnp.zeros((), jnp.int32) if k in COUNTER_LEAVES else v[0]
+                for k, v in node.items() if k != BLOCK_TABLE}
+
+    return _by_layer(cache, layer, lambda node: extract_span(node, pos, 1))
+
+
+def attention_paths(like) -> dict:
+    """How many attention layers of slots shaped `like` the one-token
+    decode program serves in place through the block table, and how many
+    by gathering (:func:`decode_view`)."""
+    paths = {"block_table": 0, "gather": 0}
+
+    def layer(node):
+        paths["gather" if RING_LEAVES[0] in node else "block_table"] += 1
+
+    _by_layer(like, layer, lambda node: None)
+    return paths
 
 
 def extract_span(cache, pos, n: int):

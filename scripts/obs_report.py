@@ -339,6 +339,17 @@ def render(events: list[dict], phases: bool = False) -> str:
                    f"tokens/sec {st.get('tokens_per_sec'):.1f}  "
                    f"occupancy {st.get('mean_slot_occupancy'):.2f}"
                    f"/{st.get('max_slots')}")
+        attn = (st.get("paged") or {}).get("decode_attn")
+        if attn:
+            # the decode program's attention: layers that read K/V in
+            # place through the block table, and what they read of what
+            # the tables hold (the share of capacity a tick still pays)
+            read, held = attn["blocks_read"], attn["blocks_in_tables"]
+            share = f"{100.0 * read / held:.1f}%" if held else "n/a"
+            out.append(f"  decode attention: {attn['paths']['block_table']} "
+                       f"layers through the block table, "
+                       f"{attn['paths']['gather']} gathered; blocks read "
+                       f"{read} of {held} in the tables ({share})")
         if lat.get("measured_requests"):
             out.append(f"  ttft  p50 {1e3 * lat['ttft_p50_s']:8.2f}ms   "
                        f"p99 {1e3 * lat['ttft_p99_s']:8.2f}ms")

@@ -436,13 +436,18 @@ def serving_quant(n_requests=48, max_slots=16):
     Bps = (model_kw["max_len"] // bs)
     N = B * Bps + 1
     rng = np.random.default_rng(0)
-    q = jnp.asarray(rng.normal(size=(B, Hkv, 1, D)), jnp.float32)
+    from distributed_deep_learning_tpu.serve.paged import merge_trailing
+
+    # pool leaves as the paged engine rests them: trailing dims merged
+    q = jnp.asarray(rng.normal(size=(B, Hkv, D)), jnp.float32)
     kp = jnp.asarray(rng.normal(size=(N, bs, Hkv, D)), jnp.float32)
     vp = jnp.asarray(rng.normal(size=(N, bs, Hkv, D)), jnp.float32)
     tables = jnp.asarray(
         rng.permutation(N - 1)[:B * Bps].reshape(B, Bps).astype(np.int32))
     lens = jnp.asarray(rng.integers(1, Bps * bs + 1, B), jnp.int32)
-    kq, vq = quantize_rows(kp), quantize_rows(vp)
+
+    kq, vq = (merge_trailing(quantize_rows(p)) for p in (kp, vp))
+    kp, vp = merge_trailing(kp), merge_trailing(vp)
 
     def timed(fn, *a, **kw):
         out = jax.block_until_ready(fn(*a, **kw))   # compile

@@ -103,21 +103,31 @@ def test_flash_attention_compiles_for_v5e(chip, case):
 @pytest.mark.parametrize("block", [8, 16])
 @pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
 def test_paged_flash_decode_compiles_for_v5e(chip, kv_dtype, block):
+    """On pool leaves as they rest (``Hkv*D`` merged, int8 with a scale a
+    position and head), the token's own row and the validity leaf beside
+    them, at the default blocks a step."""
+    from distributed_deep_learning_tpu.serve.quant import QuantTensor
+
     n_blocks = 2 * SLOTS * BPS + 1
-    q = jax.ShapeDtypeStruct((SLOTS, H, 1, D), jnp.bfloat16, sharding=chip)
-    pool = jax.ShapeDtypeStruct((n_blocks, block, H, D), jnp.dtype(kv_dtype),
-                                sharding=chip)
-    scale = jax.ShapeDtypeStruct((n_blocks, block, H, 1), jnp.float32,
+    q = jax.ShapeDtypeStruct((SLOTS, H, D), jnp.bfloat16, sharding=chip)
+    pool = jax.ShapeDtypeStruct((n_blocks, block, H * D),
+                                jnp.dtype(kv_dtype), sharding=chip)
+    scale = jax.ShapeDtypeStruct((n_blocks, block, H), jnp.float32,
                                  sharding=chip)
+    valid = jax.ShapeDtypeStruct((n_blocks, block), jnp.bool_, sharding=chip)
+    new = jax.ShapeDtypeStruct((SLOTS, H, D), jnp.bfloat16, sharding=chip)
     tables = jax.ShapeDtypeStruct((SLOTS, BPS), jnp.int32, sharding=chip)
     lens = jax.ShapeDtypeStruct((SLOTS,), jnp.int32, sharding=chip)
 
-    def decode(q, k, v, ks, vs, tables, lens):
-        scales = dict(k_scale=ks, v_scale=vs) if kv_dtype == "int8" else {}
-        return paged_flash_decode(q, k, v, tables, lens, interpret=False,
-                                  **scales)
+    def decode(q, k, v, ks, vs, valid, k_new, v_new, tables, lens):
+        if kv_dtype == "int8":
+            k, v = QuantTensor(k, ks), QuantTensor(v, vs)
+        return paged_flash_decode(q, k, v, tables, lens, k_new=k_new,
+                                  v_new=v_new, valid_pool=valid,
+                                  interpret=False)
 
-    text = _compiled_text(decode, q, pool, pool, scale, scale, tables, lens)
+    text = _compiled_text(decode, q, pool, pool, scale, scale, valid, new,
+                          new, tables, lens)
     assert "tpu_custom_call" in text
 
 
@@ -160,8 +170,22 @@ def xl_engine(chip):
     }
 
 
+def _decode_kernels(text: str) -> list:
+    """The block-table attention kernel's calls in a compiled program."""
+    return re.findall(r"%(paged_flash_decode[\w.]*) = [^\n]*"
+                      r'custom_call_target="tpu_custom_call"', text)
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """What the program would find on the chip where it asks for the
+    backend: the dispatcher of the paged decode kernel does, and a program
+    lowered here for the described chip has to take the chip's branch."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
 @pytest.mark.parametrize("program", ["paged_chunk", "paged_decode"])
-def test_paged_program_copies_no_whole_pool_leaf(xl_engine, program):
+def test_paged_program_copies_no_whole_pool_leaf(xl_engine, on_tpu, program):
     """With the pools donated, the entry computation of the compiled
     program holds no ``copy`` of a whole K/V pool leaf: 1,281 blocks
     leading, bf16.  (A 4-D ``bf16[1281,16,25,64]`` leaf rests block-index
@@ -174,6 +198,30 @@ def test_paged_program_copies_no_whole_pool_leaf(xl_engine, program):
     assert f"bf16[{rows},16,1600]" in entry      # the pools are in there
     copies = re.findall(rf"= (bf16\[{rows},[^ ]*) copy\(", entry)
     assert not copies, copies
+
+
+def test_paged_decode_attends_the_pools_in_place(xl_engine, on_tpu):
+    """The one-token decode program at gpt2-xl widths reads K and V where
+    they rest: the block-table kernel is in it, nothing of the size of the
+    gathered slots (16 x 1,024 positions of 25 x 64) is, its temporaries
+    stay under 1 GiB (they were 4.58 GiB of gathered caches a layer deep
+    program scaled to 48), and the pools still leave through the write
+    they came in by."""
+    engine, programs = xl_engine
+    assert engine.decode_attn_paths == {"block_table": 1, "gather": 0}
+    prog, args = programs["paged_decode"]
+    compiled = prog._jit.lower(*args).compile()
+    text = compiled.as_text()
+    assert len(_decode_kernels(text)) == 1
+    assert not re.findall(r"bf16\[16,1024,(?:25,64|1600)\]", text)
+    assert not re.findall(r"bf16\[16,64,16,1600\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+    rows = engine.num_blocks + 1
+    aliased = re.findall(r"input_output_alias=\{([^\n]*)\}, entry", text) \
+        or re.findall(r"input_output_alias=\{([^\n]*)", text)
+    assert aliased, "no input/output aliasing in the module header"
+    entry = text[text.index("ENTRY"):]
+    assert not re.findall(rf"= (bf16\[{rows},[^ ]*) copy\(", entry)
 
 
 # the laguna cell's engine (benchmark/traffic/long-mixed.json) at the
@@ -219,13 +267,17 @@ def laguna_engine(chip):
 
 
 @pytest.mark.parametrize("program", ["paged_chunk", "paged_decode"])
-def test_two_kind_paged_program_compiles_for_v5e(laguna_engine, program):
+def test_two_kind_paged_program_compiles_for_v5e(laguna_engine, on_tpu,
+                                                 program):
     """Both pool kinds rest as they are computed in (``Hkv*D`` = 1,024
     minor: no whole-leaf copy of either), and each expert layer's three
     grouped products are the chip's own ragged-dot kernel, not a dense
-    product over every expert."""
+    product over every expert.  The decode program attends the full
+    layer's pool in place (the block-table kernel) and gathers the
+    sliding layer's ring; the chunk program gathers both."""
     engine, programs = laguna_engine
     assert engine.ring_blocks == 65             # ceil((512 + 512) / 16) + 1
+    assert engine.decode_attn_paths == {"block_table": 1, "gather": 1}
     prog, args = programs[program]
     text = prog._jit.lower(*args).compile().as_text()
     entry = text[text.index("ENTRY"):]
@@ -237,3 +289,9 @@ def test_two_kind_paged_program_compiles_for_v5e(laguna_engine, program):
     kernels = re.findall(r"%(ragged-dot-none[\w.]*) = [^\n]*"
                          r'custom_call_target="tpu_custom_call"', text)
     assert len(kernels) == 3, kernels
+    in_place = _decode_kernels(text)
+    gathered = re.findall(r"bf16\[16,(?:8192|512,16),(?:8,128|1024)\]", text)
+    if program == "paged_decode":
+        assert in_place and not gathered, (in_place, gathered)
+    else:
+        assert not in_place

@@ -106,6 +106,116 @@ def test_paged_matches_generate_and_compiles_once():
     assert s2["chunk_compiles"] == 1 and s2["decode_compiles"] == 1, s2
 
 
+@pytest.mark.parametrize("kw,paths", [
+    (dict(), {"block_table": 2, "gather": 0}),
+    (dict(kv_dtype="int8"), {"block_table": 2, "gather": 0}),
+    (dict(draft_layers=1, spec_k=2, max_len=80),
+     {"block_table": 2, "gather": 0}),
+])
+def test_decode_program_attends_live_blocks_through_the_table(kw, paths):
+    """The decode program hands every full-kind layer its pool leaves and
+    the slot's block table (the build says how many layers took which
+    path, the compile log repeats it), and the tick ring counts the blocks
+    it reads over the blocks the tables hold: a slot of 40 cached
+    positions at block 16 reads 3 blocks a layer, not `blocks_per_slot`."""
+    from distributed_deep_learning_tpu import obs
+
+    model, params = _shared(max_len=96)
+    obs.compile_log.mark("test")
+    eng = PagedEngine(model, params, max_slots=2, kv_block_size=16,
+                      prefill_chunk=8, **kw)
+    assert eng.decode_attn_paths == paths
+    assert eng.blocks_per_slot == 6
+    rng = np.random.default_rng(2)
+    reqs = [Request(0, rng.integers(1, 61, 40).astype(np.int32), 3),
+            Request(1, rng.integers(1, 61, 5).astype(np.int32), 2,
+                    arrival_tick=20)]
+    out = eng.run(reqs)
+    _check_parity(out, reqs, max_len=96) if not kw.get("kv_dtype") else None
+    attn = out["stats"]["paged"]["decode_attn"]
+    assert attn["paths"] == paths
+    ticks = [t[2][2] for t in obs.last_run("serve").phases.ticks
+             if t[1] == "decode"]
+    if kw.get("draft_layers"):      # speculation verifies: the gather path
+        assert all("attn_blocks" not in c for c in ticks)
+        assert attn["blocks_read"] == 0
+        return
+    assert out["stats"]["decode_compiles"] == 1
+    held = 2 * 2 * eng.blocks_per_slot      # layers x slots x blocks a slot
+    reads = [c["attn_blocks"] for c in ticks]
+    assert all(c["tables"] == held for c in reads)
+    # the first token of each comes with its last chunk; request 0 then
+    # decodes at 40 and 41 cached positions: 3 blocks a layer; request 1
+    # (alone by then) at 5: 1 block a layer
+    assert [c["read"] for c in reads] == [6, 6, 2]
+    assert attn["blocks_read"] == 14 and \
+        attn["blocks_in_tables"] == 3 * held
+    notes = [e for e in obs.compile_log.since_mark() if e[0] == "attn_paths"]
+    assert notes == [("attn_paths", "jit(paged_decode)", notes[0][2],
+                      "block_table=2 gather=0")]
+
+
+def test_obs_report_prints_the_decode_attention_line():
+    import importlib.util
+    import os
+
+    spec = importlib.util.spec_from_file_location(
+        "obs_report", os.path.join(os.path.dirname(__file__), os.pardir,
+                                   "scripts", "obs_report.py"))
+    report = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(report)
+    stats = _engine().run(_trace(n=3))["stats"]
+    attn = stats["paged"]["decode_attn"]
+    assert 0 < attn["blocks_read"] < attn["blocks_in_tables"]
+    text = report.render([{"event": "obs_serve", "stats": stats}])
+    assert ("decode attention: 2 layers through the block table, 0 "
+            f"gathered; blocks read {attn['blocks_read']} of "
+            f"{attn['blocks_in_tables']} in the tables") in text
+
+
+def test_decode_view_keeps_pool_leaves_and_gathers_rings():
+    """`decode_view` hands a full-kind layer the pool leaves themselves and
+    the table, gathers a ring layer's leaves; `view_span` takes the
+    token's own row from the first and position `pos` from the second."""
+    like = {"layer_0": {"self_attn": {
+                "cached_key": jax.ShapeDtypeStruct((1, 8, 2, 4), jnp.float32),
+                "cached_valid": jax.ShapeDtypeStruct((1, 8), jnp.bool_),
+                "cache_index": jax.ShapeDtypeStruct((), jnp.int32)}},
+            "layer_1": {"self_attn": {
+                "ring_key": jax.ShapeDtypeStruct((1, 4, 2, 4), jnp.float32),
+                "cache_index": jax.ShapeDtypeStruct((), jnp.int32)}},
+            "embed": {"pos_index": jax.ShapeDtypeStruct((), jnp.int32)}}
+    assert paged.attention_paths(like) == {"block_table": 1, "gather": 1}
+    pools = paged.build_pools(like, 5, 2, ring_num_blocks=3)
+    pools = jax.tree.map(
+        lambda x: jnp.arange(x.size, dtype=jnp.float32).reshape(
+            x.shape).astype(x.dtype), pools)
+    table = (jnp.asarray([3, 1, 0, 0]), jnp.asarray([2, 1]))
+    view = paged.decode_view(pools, table, 5, like)
+    full = view["layer_0"]["self_attn"]
+    assert full["cached_key"] is pools["layer_0"]["self_attn"]["cached_key"]
+    np.testing.assert_array_equal(full["block_table"], table[0])
+    assert int(full["cache_index"]) == 5 and \
+        int(view["embed"]["pos_index"]) == 5
+    ring = view["layer_1"]["self_attn"]
+    assert "block_table" not in ring and ring["ring_key"].shape == (1, 4, 2, 4)
+    np.testing.assert_array_equal(
+        ring["ring_key"][0, :2],
+        pools["layer_1"]["self_attn"]["ring_key"][2].reshape(2, 2, 4))
+    # what the model hands back: the token's own row where the pool went in
+    row = jnp.full((1, 1, 2, 4), 7.0)
+    new = {**view, "layer_0": {"self_attn": {
+        **full, "cached_key": row, "cached_valid": jnp.ones((1, 1), bool)}}}
+    span = paged.view_span(new, 5)
+    assert set(span["layer_0"]["self_attn"]) == {
+        "cached_key", "cached_valid", "cache_index"}
+    np.testing.assert_array_equal(span["layer_0"]["self_attn"]["cached_key"],
+                                  row[0])
+    np.testing.assert_array_equal(           # position 5 of a ring of 4
+        span["layer_1"]["self_attn"]["ring_key"][0],
+        ring["ring_key"][0, 1])
+
+
 def test_prefix_reuse_skips_prefill_same_tokens_out():
     """Requests opening with one shared system prompt: the paged engine
     prefills the shared blocks ONCE, later requests reference them

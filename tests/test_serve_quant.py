@@ -280,46 +280,167 @@ def test_weight_bytes_shrink():
 # --- kernel parity (interpret mode on CPU) ------------------------------
 
 
-@pytest.mark.parametrize("quantized", [False, True])
-def test_paged_flash_decode_matches_reference(quantized):
+def _merged_pool(rng, N, bs, Hkv, D, dtype, quantized):
+    """One pool leaf as the engine rests it: ``(N, bs, Hkv*D)``, int8
+    with ``(N, bs, Hkv)`` scales where `quantized`."""
+    x = jnp.asarray(rng.normal(size=(N, bs, Hkv, D)), jnp.float32)
+    return paged.merge_trailing(quant.quantize_rows(x) if quantized
+                                else x.astype(dtype))
+
+
+#: heads x width, block, blocks a slot, blocks a grid step; every case has
+#: a slot of each length in `_lengths` and a table longer than it needs
+KERNEL_CASES = {
+    # the first two are what this test held before the pools were merged
+    "small-f32": dict(H=8, Hkv=4, D=16, bs=8, Bps=3, step=1),
+    "small-int8": dict(H=8, Hkv=4, D=16, bs=8, Bps=3, step=1,
+                       quantized=True),
+    # gpt2-xl's heads (plain MHA, 64 wide: 12.5 lane tiles a row)
+    "mha-25x64-bf16": dict(H=25, Hkv=25, D=64, bs=16, Bps=5, step=2,
+                           dtype=jnp.bfloat16),
+    "mha-25x64-int8": dict(H=25, Hkv=25, D=64, bs=16, Bps=5, step=2,
+                           quantized=True),
+    # laguna's full layers: 48 query heads on 8 KV heads of 128, G = 6
+    "gqa-8x128-bf16": dict(H=48, Hkv=8, D=128, bs=16, Bps=5, step=2,
+                           dtype=jnp.bfloat16),
+    "gqa-8x128-int8": dict(H=48, Hkv=8, D=128, bs=16, Bps=5, step=3,
+                           quantized=True),
+    # a table of 5 blocks in steps of 4, of 5 in one step, of 7 in steps
+    # of 3: none a multiple of its step
+    "step-4-of-5": dict(H=4, Hkv=4, D=8, bs=4, Bps=5, step=4),
+    "one-step": dict(H=4, Hkv=2, D=8, bs=4, Bps=5, step=None),
+    "step-3-of-7": dict(H=6, Hkv=2, D=8, bs=4, Bps=7, step=3),
+    "window": dict(H=6, Hkv=2, D=16, bs=8, Bps=6, step=4, window=9),
+}
+
+
+def _lengths(bs, Bps):
+    """Cached positions a slot: none, one, mid-block, a whole block, whole
+    blocks, and all but the one the token's own row takes (max_len)."""
+    return [0, 1, bs + bs // 2, bs, 2 * bs, Bps * bs - 1]
+
+
+@pytest.mark.parametrize("new_row", [True, False], ids=["new-row", "cached"])
+@pytest.mark.parametrize("case", KERNEL_CASES)
+def test_paged_flash_decode_matches_reference(case, new_row):
+    """The kernel in interpret mode against the gather-then-mask reference,
+    on pool leaves as they rest: every slot length, tables whose tails are
+    trash, and an invalid cached position in the last slot's first block."""
+    cfg = dict(KERNEL_CASES[case])
+    H, Hkv, D, bs, Bps = (cfg[k] for k in ("H", "Hkv", "D", "bs", "Bps"))
+    dtype = cfg.get("dtype", jnp.float32)
+    quantized = cfg.get("quantized", False)
     rng = np.random.default_rng(3)
-    B, Hkv, G, D = 2, 4, 2, 16
-    N, bs, Bps = 12, 8, 3
-    q = jnp.asarray(rng.normal(size=(B, Hkv, G, D)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(N, bs, Hkv, D)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(N, bs, Hkv, D)), jnp.float32)
-    tables = jnp.asarray(rng.choice(N, (B, Bps), replace=False)
-                         .astype(np.int32))
-    lens = jnp.asarray([5, 24], jnp.int32)
-    if quantized:
-        kp, vp = quant.quantize_rows(kp), quant.quantize_rows(vp)
-    ref = paged_decode_reference(q, kp, vp, tables, lens)
-    out = paged_flash_decode(q, kp, vp, tables, lens, interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=1e-5)
+    lens = _lengths(bs, Bps)
+    B, N = len(lens), len(lens) * Bps + 1
+    q = jnp.asarray(rng.normal(size=(B, H, D)), dtype)
+    kp = _merged_pool(rng, N, bs, Hkv, D, dtype, quantized)
+    vp = _merged_pool(rng, N, bs, Hkv, D, dtype, quantized)
+    tables = np.full((B, Bps), paged.TRASH, np.int32)
+    free = iter(rng.permutation(np.arange(1, N)))
+    for b, n in enumerate(lens):                # only the blocks it needs
+        for j in range(-(-(n + new_row) // bs)):
+            tables[b, j] = next(free)
+    valid = np.ones((N, bs), bool)
+    valid[tables[-1, 0], 1] = False             # a cached_valid hole
+    valid[paged.TRASH] = rng.random(bs) < 0.5   # trash holds anything
+    kw = dict(valid_pool=jnp.asarray(valid), window=cfg.get("window"))
+    if new_row:
+        kw.update(k_new=jnp.asarray(rng.normal(size=(B, Hkv, D)), dtype),
+                  v_new=jnp.asarray(rng.normal(size=(B, Hkv, D)), dtype),
+                  new_valid=jnp.asarray([True] * (B - 1) + [False]))
+    tables, lens = jnp.asarray(tables), jnp.asarray(lens, jnp.int32)
+    ref = paged_decode_reference(q, kp, vp, tables, lens, **kw)
+    out = paged_flash_decode(q, kp, vp, tables, lens, interpret=True,
+                             blocks_per_step=cfg["step"], **kw)
+    assert out.shape == q.shape and out.dtype == q.dtype
+    # a slot with nothing to attend: the reference averages garbage, the
+    # kernel gives zeros; both finite, both ignored by the engine
+    some = slice(0 if new_row else 1, None)
+    assert bool(jnp.all(jnp.isfinite(out)))
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32)[some], np.asarray(ref, np.float32)[some],
+        atol=4e-2 if dtype == jnp.bfloat16 else 1e-5)
     # off-TPU dispatch (no interpret flag) routes to the reference
-    disp = paged_flash_decode(q, kp, vp, tables, lens)
+    disp = paged_flash_decode(q, kp, vp, tables, lens, **kw)
     np.testing.assert_array_equal(np.asarray(disp), np.asarray(ref))
+
+
+def test_kernel_work_list_holds_live_blocks_only():
+    """The kernel's grid as data: the slots' live steps end to end, one
+    step for a slot with nothing cached, and for each of a step's tiles
+    the table's block while the slot has one there, else what the tile
+    held before: no entry of a table's tail is ever named."""
+    from distributed_deep_learning_tpu.ops.paged_decode_pallas import (
+        _work_list)
+
+    lens = np.asarray([0, 1, 40, 64, 127, 0], np.int32)
+    tail = 999                              # never to be read
+    tables = np.full((6, 8), tail, np.int32)
+    for b, n in enumerate(lens):
+        tables[b, :-(-n // 16)] = 100 * (b + 1) + np.arange(-(-n // 16))
+    total, slot, step, phys = (np.asarray(x) for x in _work_list(
+        jnp.asarray(tables), jnp.asarray(lens), 2, 16))
+    assert total == 1 + 1 + 2 + 2 + 4 + 1
+    np.testing.assert_array_equal(slot[:total],
+                                  [0, 1, 2, 2, 3, 3, 4, 4, 4, 4, 5])
+    np.testing.assert_array_equal(step[:total],
+                                  [0, 0, 0, 1, 0, 1, 0, 1, 2, 3, 0])
+    assert tail not in phys
+    np.testing.assert_array_equal(          # tile 0, then tile 1, by step
+        phys[:, :total],
+        [[0, 200, 300, 302, 400, 402, 500, 502, 504, 506, 506],
+         [0, 0, 301, 301, 401, 403, 501, 503, 505, 507, 507]])
+    # past the live steps nothing moves: a static grid would issue no DMA
+    assert (phys[:, total:] == phys[:, total - 1:total]).all()
 
 
 def test_paged_flash_decode_zero_length_slot_is_finite():
     rng = np.random.default_rng(4)
-    q = jnp.asarray(rng.normal(size=(1, 2, 1, 8)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(4, 4, 2, 8)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(4, 4, 2, 8)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(1, 2, 8)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(4, 4, 16)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(4, 4, 16)), jnp.float32)
     tables = jnp.zeros((1, 2), jnp.int32)
     out = paged_flash_decode(q, kp, vp, tables,
                              jnp.zeros((1,), jnp.int32), interpret=True)
-    assert bool(jnp.all(jnp.isfinite(out)))
+    assert bool(jnp.all(out == 0))
 
 
 def test_kernel_rejects_mismatched_quantization():
-    q = jnp.zeros((1, 2, 1, 8), jnp.float32)
-    kp = jnp.zeros((4, 4, 2, 8), jnp.float32)
+    q = jnp.zeros((1, 2, 8), jnp.float32)
+    kp = jnp.zeros((4, 4, 16), jnp.float32)
+    kq = QuantTensor(jnp.zeros((4, 4, 16), jnp.int8), jnp.ones((4, 4, 2)))
     with pytest.raises(ValueError, match="agree on quantization"):
-        paged_flash_decode(q, kp, kp, jnp.zeros((1, 1), jnp.int32),
-                           jnp.ones((1,), jnp.int32),
-                           k_scale=jnp.ones((4, 4, 2, 1)))
+        paged_flash_decode(q, kq, kp, jnp.zeros((1, 1), jnp.int32),
+                           jnp.ones((1,), jnp.int32))
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"])
+def test_engine_decodes_through_the_interpreted_kernel(monkeypatch, kv_dtype):
+    """The whole path the chip runs, with the kernel interpreted: the
+    decode program hands the attention layers the pool leaves and the
+    block tables, the slots' ``vmap`` folds into the kernel's slot axis,
+    and the tokens are those of the gather path (the dispatcher's choice
+    off a TPU)."""
+    from distributed_deep_learning_tpu.ops import paged_decode_pallas as pdp
+
+    want = _engine(kv_dtype=kv_dtype).run(_trace(seed=5))
+    calls = []
+
+    def interpreted(*args, **kw):
+        calls.append(args[0].shape)
+        return kernel(*args, **{**kw, "interpret": True})
+
+    kernel = pdp.paged_flash_decode
+    monkeypatch.setattr(pdp, "paged_flash_decode", interpreted)
+    eng = _engine(kv_dtype=kv_dtype)
+    got = eng.run(_trace(seed=5))
+    # what reaches the program is one call a layer, every slot a row of it
+    # (custom_vmap also traces the one-slot form it then sets aside)
+    assert [c for c in calls if c[0] != 1] == \
+        [(eng.max_slots, 4, 8)] * MODEL["num_layers"]
+    assert got["stats"]["decode_compiles"] == 1
+    assert _agreement(want["results"], got["results"]) == 1.0
 
 
 # --- CLI + plan lattice -------------------------------------------------
