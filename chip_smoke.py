@@ -1,6 +1,6 @@
 """Chip smoke: the gpt train -> serve path on a TPU, through the normal CLI.
 
-    python chip_smoke.py             # one chip: train, serve, serve_bench, kernels
+    python chip_smoke.py             # one chip: train, serve, serve_trace, kernels
     python chip_smoke.py --chips 4   # one host of four: FSDP training vs one chip
 
 The quickest proof that the system still starts on the chip, and that what
@@ -9,13 +9,13 @@ ran there was the chip.  With no arguments it trains a GPT-2-small
 50,257, context 1,024, bf16) for two short epochs by calling the package's
 own ``main()`` with the argv a user would type, serves a few requests from
 those weights through the paged engine on the same command line, pushes a
-longer trace through ``scripts/serve_bench.py --paged`` at the same widths,
-and compares the two Pallas kernels with their references, compiled.  Every
-phase checks what came out, and that it came out of a TPU; the first check
-that fails names its phase and the script exits 1.  ``--chips 4`` runs the
-multi-chip path instead — the same model, seed and global batch under
-``-m data --zero fsdp --mesh data=2,fsdp=2`` against a one-chip run in the
-same process — and nothing else.
+longer trace through a ``PagedEngine`` at the same widths, and compares the
+two Pallas kernels with their references, compiled.  Every phase checks
+what came out, and that it came out of a TPU; the first check that fails
+names its phase and the script exits 1.  ``--chips 4`` runs the multi-chip
+path instead — the same model, seed and global batch under ``-m data --zero
+fsdp --mesh data=2,fsdp=2`` against a one-chip run in the same process — and
+nothing else.
 
 Everything runs in this one process (a chip belongs to one process at a
 time).  Times and rates printed above the last line are smoke readings, not
@@ -30,7 +30,6 @@ from __future__ import annotations
 
 import argparse
 import contextlib
-import importlib.util
 import io
 import json
 import os
@@ -47,10 +46,10 @@ PLATFORM = "tpu"
 #: only to rehearse the control flow off the chip
 REAL = dict(layers=12, d_model=768, heads=12, mlp=3072, vocab=50257,
             context=1024, batch=8, rows=200, slots=8, block=16, chunk=128,
-            bench_requests=8, bench_prompt_max=512, bench_new_max=64)
+            trace_requests=8, trace_prompt_max=512, trace_new_max=64)
 TINY = dict(layers=2, d_model=64, heads=2, mlp=256, vocab=257,
             context=64, batch=8, rows=80, slots=2, block=8, chunk=16,
-            bench_requests=4, bench_prompt_max=24, bench_new_max=8)
+            trace_requests=4, trace_prompt_max=24, trace_new_max=8)
 
 #: engine vs generate(): greedy tokens counted up to each request's first
 #: divergence.  Both run the same bf16 weights but at different shapes
@@ -412,41 +411,43 @@ def phase_train_serve(size: dict, data_dir: str, spies: Spies) -> None:
     check_parity(*check_engine(spies, "serve(paged)"))
 
 
-def phase_serve_bench(size: dict, seed: int, spies: Spies) -> None:
-    spec = importlib.util.spec_from_file_location(
-        "serve_bench", os.path.join(ROOT, "scripts", "serve_bench.py"))
-    serve_bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(serve_bench)
-    argv = ["--paged", "--skip-v1", "--seed", str(seed),
-            "--layers", str(size["layers"]), "--d-model",
-            str(size["d_model"]), "--heads", str(size["heads"]),
-            "--mlp-dim", str(size["mlp"]), "--vocab", str(size["vocab"]),
-            "--max-len", str(size["context"]), "--prompt-max",
-            str(size["bench_prompt_max"]), "--new-max",
-            str(size["bench_new_max"]), "--requests",
-            str(size["bench_requests"]), "--max-slots", str(size["slots"]),
-            "--kv-block-size", str(size["block"]), "--prefill-chunk",
-            str(size["chunk"]),
-            "--shared-prefix-len", str(min(32, size["context"] // 8))]
-    say("$ python scripts/serve_bench.py " + " ".join(argv))
-    tee = Tee(sys.stdout)
-    with contextlib.redirect_stdout(tee):
-        rc = serve_bench.main(argv)
-    require(rc == 0, f"serve_bench exited {rc}")
-    record = json.loads(tee.text.strip().splitlines()[-1])
-    require(record["device"]["platform"] == PLATFORM,
-            f"serve_bench names device {record['device']}")
-    require(record["errors"] == 0, f"serve_bench errors {record['errors']}")
-    _, requests, _ = check_engine(spies, "serve_bench")
+def phase_serve_trace(size: dict, seed: int, spies: Spies) -> None:
+    """A longer trace than the CLI's, prompts up to half the context and
+    six in ten behind one shared system prompt, through a fresh paged
+    engine on randomly initialised weights of the same widths."""
+    from distributed_deep_learning_tpu.models.transformer import (
+        random_causal_lm)
+    from distributed_deep_learning_tpu.serve.engine import PagedEngine
+    from distributed_deep_learning_tpu.serve.load import LoadSpec, make_load
+    from distributed_deep_learning_tpu.serve.paged import paged_max_len
+
+    model, params = random_causal_lm(
+        seed, vocab_size=size["vocab"], num_layers=size["layers"],
+        d_model=size["d_model"], num_heads=size["heads"],
+        mlp_dim=size["mlp"], max_len=size["context"])
+    on_platform(params, "serve_trace parameters", committed=False)
+    cap = paged_max_len(model.max_len, size["block"], False, 0)
+    mid = (4 + size["trace_prompt_max"]) // 2
+    trace = make_load(LoadSpec(
+        n_requests=size["trace_requests"], arrival="poisson", rate=2.0,
+        prompt_short=(4, mid), prompt_long=(mid, size["trace_prompt_max"]),
+        long_frac=0.3, shared_prefix_len=min(32, size["context"] // 8),
+        shared_frac=0.6, new_tokens=(4, size["trace_new_max"])),
+        vocab_size=size["vocab"], seed=seed)
+    PagedEngine(model, params, max_slots=size["slots"], max_len=cap,
+                kv_block_size=size["block"],
+                prefill_chunk=min(size["chunk"], cap)).run(trace)
+    _, requests, out = check_engine(spies, "serve_trace")
     longest = max(len(r.prompt) for r in requests)
-    require(longest > size["bench_prompt_max"] // 2,
+    require(longest > size["trace_prompt_max"] // 2,
             f"longest prompt {longest}: the long half of the trace is "
             "missing")
-    pe = record["paged_engine"]
-    say(f"serve_bench: {len(requests)} requests, longest prompt {longest} "
-        f"tokens, {pe['prefill_chunks']} prefill chunks, "
-        f"{pe['decode_ticks']} decode ticks, {pe['tokens_per_sec']} "
-        f"tokens/s (smoke reading), prefix hit {pe['prefix_hit_rate']:.3f}")
+    stats = out["stats"]
+    say(f"serve_trace: {len(requests)} requests, longest prompt {longest} "
+        f"tokens, {stats['prefill_chunks']} prefill chunks, "
+        f"{stats['decode_ticks']} decode ticks, "
+        f"{stats['tokens_per_sec']:.2f} tokens/s (smoke reading), "
+        f"prefix hit {stats['paged']['prefix_hit_rate']:.3f}")
 
 
 def phase_kernels(size: dict, seed: int) -> None:
@@ -639,8 +640,8 @@ def main(argv=None) -> int:
                 phase = "train+serve"
                 phase_train_serve(size, args.out, spies)
                 say(f"compile (train+serve): {meter.line()}")
-                phase = "serve_bench"
-                phase_serve_bench(size, args.seed, spies)
+                phase = "serve_trace"
+                phase_serve_trace(size, args.seed, spies)
             finally:
                 spies.remove()
             phase = "kernels"

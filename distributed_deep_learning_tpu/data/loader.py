@@ -107,8 +107,7 @@ class DeviceLoader:
         path (gather/decode, no device transfer), skipping the first
         ``skip`` without materialising them (mid-epoch resume: the skipped
         batches were already trained before the checkpoint — no gather, no
-        decode, no device transfer for them).  ``scripts/feed_bench.py``
-        times exactly this iterator."""
+        decode, no device transfer for them)."""
         idx = self._epoch_indices()
         for start in range(skip * self.global_batch_size, len(idx),
                            self.global_batch_size):

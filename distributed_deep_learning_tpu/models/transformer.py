@@ -706,6 +706,15 @@ def bert_base(**kw) -> BertEncoder:
     return BertEncoder(**kw)
 
 
+def random_causal_lm(seed: int = 0, **geometry):
+    """A randomly initialised :class:`CausalLM` and its params: what the
+    serve drills and engine tests drive when no trained weights exist
+    (scheduling, recovery and compile counts do not care)."""
+    model = CausalLM(**geometry)
+    toks = jnp.ones((1, 8), jnp.int32)
+    return model, model.init(jax.random.key(seed), toks)["params"]
+
+
 def make_decode_model(model: "CausalLM") -> "CausalLM":
     """The KV-cached inference twin of a trained :class:`CausalLM`:
     decode mode on, hidden-state output (the weight-tied head projects

@@ -14,7 +14,6 @@ Layout:
 * :mod:`obs.timeline` — per-step spans → goodput breakdown.
 * :mod:`obs.mfu`      — model-FLOP accounting + chip peak table.
 * :mod:`obs.export`   — JSONL event stream + Prometheus exposition.
-* :mod:`obs.bench`    — instrumentation-overhead harness (bench.py).
 * :mod:`obs.trace`    — spans: the ``span()`` front door (profiler +
   Tracer), the Tracer ring and its Chrome export, ``PhaseClock``.
 * :mod:`obs.runlog`   — ``last_run(kind)``: the record a run publishes

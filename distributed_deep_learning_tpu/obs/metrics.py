@@ -1,7 +1,7 @@
 """Process-local metrics registry: counters, gauges, log-bucketed histograms.
 
 The repo's subsystems each grew their own ad-hoc numbers (``StepTimer``
-rates, serve ``stats`` dicts, bench sub-records); this is the one place
+rates, serve ``stats`` dicts); this is the one place
 they all report into.  Design constraints, in order:
 
 1. **Near-zero hot-path cost.**  ``Counter.inc`` is a float add,

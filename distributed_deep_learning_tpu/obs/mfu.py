@@ -6,8 +6,7 @@ step* (``utils.profiling.cost_analysis``), not an analytic 6ND guess —
 so remat recompute, fused losses, and optimizer math are all counted the
 way the compiler actually scheduled them.
 
-The chip-peak table lives here (bench.py re-exports it for backward
-compatibility).  On CPU there is no meaningful peak, so ``peak_flops``
+The chip-peak table lives here.  On CPU there is no meaningful peak, so ``peak_flops``
 is None and MFU is reported as None — unless ``DDL_OBS_PEAK_FLOPS`` is
 set, which tests and CPU smoke runs use to exercise the full path.
 """
@@ -59,8 +58,7 @@ def measure_step_flops(step_fn: Callable, *args, n_devices: int | None = None,
     ``cost_analysis`` reports the per-executable flops of the SPMD
     program — i.e. one device's share — so the global number is
     flops × n_devices (the devices the step's mesh actually spans, which
-    on a partial-mesh run is fewer than ``jax.device_count()``; bench.py
-    uses the same flops × n_chips convention).  Returns None when the
+    on a partial-mesh run is fewer than ``jax.device_count()``).  Returns None when the
     backend reports no flops key (some CPU builds).  NOTE: this
     lowers+compiles the step once; jit keeps its own dispatch cache, so
     the training run pays one extra compile when flop accounting is

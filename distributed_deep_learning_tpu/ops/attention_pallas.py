@@ -419,22 +419,13 @@ def _flash_vjp_bwd(sm_scale, causal, block_q, block_k, interpret, window,
 _flash_bhtd.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
-@functools.cache
-def _recorded_blocks() -> tuple[int, int] | None:
-    """Data-driven default (block_q, block_k): the best config the
-    validation sweep measured on THIS repo's hardware history; None (→
-    128×128) until a sweep has run.  Cached per process — the datum is
-    static for a training run's lifetime, and re-reading the JSON per
-    trace would both cost on the hot path and let a mid-run rewrite
-    compile different traces with different blocks."""
-    import jax
-
-    if jax.default_backend() != "tpu":
-        return None
-    from distributed_deep_learning_tpu.utils.bench_records import (
-        read_flash_blocks)
-
-    return read_flash_blocks()
+#: default (block_q, block_k) on a TPU backend, and on any other (where the
+#: kernels run interpreted).  Constants, not a claim: 512 x 512 is what both
+#: train cells have compiled since PR 23, and the ledger reads
+#: ``flash_roofline`` 12.07% / 13.33% at it; re-deciding it from traced runs
+#: is ROADMAP S3's.
+DEFAULT_BLOCKS_TPU = (512, 512)
+DEFAULT_BLOCKS_ELSEWHERE = (128, 128)
 
 
 @functools.cache
@@ -473,9 +464,11 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     if block_q is None or block_k is None:
-        rec = _recorded_blocks()
-        block_q = block_q or (rec[0] if rec else 128)
-        block_k = block_k or (rec[1] if rec else 128)
+        default_q, default_k = (
+            DEFAULT_BLOCKS_TPU if jax.default_backend() == "tpu"
+            else DEFAULT_BLOCKS_ELSEWHERE)
+        block_q = block_q or default_q
+        block_k = block_k or default_k
     if window is not None:
         if not causal:
             raise ValueError("window (sliding-window attention) requires "

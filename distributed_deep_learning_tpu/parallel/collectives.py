@@ -30,8 +30,7 @@ control.  This module owns that dataflow instead, three layers deep:
    collective rendition of ZeRO-3: gather params → forward/backward →
    reduce-scatter grads → sharded optimizer update, with the residual
    threaded through ``TrainState.comm_residual``.  ``method="none"``
-   reproduces the :mod:`.zero` annotation path's numerics (the parity
-   gate bench.py's ``collectives`` record measures).
+   reproduces the :mod:`.zero` annotation path's numerics.
 
 In uncompressed mode every variant is value-equal to its XLA primitive
 (``lax.all_gather`` / ``lax.psum_scatter``); ring reductions only
@@ -226,8 +225,7 @@ def gather_matmul(a_block, b, axis: str, *, size: int, method: str = "none",
     ``a_block (m, k)`` per shard, ``b (k, n)`` replicated →
     ``(size*m, n)``.  With ``overlap`` each arriving chunk's matmul runs
     while the next chunk's ppermute is already issued — the gathered
-    operand is never materialised.  The tentpole overlap demo
-    ``scripts/comm_bench.py`` times."""
+    operand is never materialised."""
     wire, scale = quantize(a_block, method, axis)
     S = size
     if S == 1 or not overlap:
@@ -294,7 +292,7 @@ def fsdp_wire_stats(params, dims, axis_size: int, method: str) -> dict:
     """Per-step analytic wire bytes for the explicit FSDP dataflow (one
     param all-gather + one grad reduce-scatter over the leaves ``dims``
     marks as sharded), plus the fp32 bytes the same collectives would
-    move — the ratio the bench's >=3x acceptance gate checks."""
+    move."""
     gather = scatter = gather_fp32 = scatter_fp32 = 0
     for leaf, d in zip(jax.tree.leaves(params), jax.tree.leaves(dims)):
         if d < 0:
@@ -365,8 +363,8 @@ def make_fsdp_step_fns(mesh: Mesh, loss_fn: Callable, *, state_spec,
     replicated (small/indivisible) skip the gather and psum their grads
     uncompressed.
 
-    ``method="none"`` is loss-parity with the annotation path (the
-    bench gate); the optimizer must be elementwise (sgd/momentum/adam —
+    ``method="none"`` is loss-parity with the annotation path; the
+    optimizer must be elementwise (sgd/momentum/adam —
     a global-norm clip would need its own psum, which shard-local
     ``tx.update`` does not insert).  ``registry`` (an
     ``obs.metrics.MetricsRegistry``) gets per-step ``comm_bytes{op,

@@ -6,9 +6,8 @@ TPUv4"): a slot-based static KV cache (:mod:`.cache`), a host-side slot
 scheduler (:mod:`.scheduler`), and an engine (:mod:`.engine`) whose
 decode hot path is ONE compiled XLA program for its whole lifetime —
 requests of any length enter and leave slots without changing a shape.
-:mod:`.bench` drives mixed-length request traces through the engine and
-the naive run-to-completion :func:`..models.transformer.generate`
-baseline.
+Batch-synchronous :func:`..models.transformer.generate` is the parity
+reference.
 
 Second generation, same discipline, planet-scale tricks:
 :class:`.engine.PagedEngine` serves from block pools (:mod:`.paged` —

@@ -389,8 +389,7 @@ class ServeEngine:
         """Serve a whole trace; returns ``{"results", "errors", "stats"}``.
 
         ``results`` maps uid -> generated token array; ``stats`` carries
-        the throughput/occupancy/compile accounting the serving bench
-        reports, plus a ``latency`` sub-dict (p50/p99 TTFT, inter-token,
+        the throughput/occupancy/compile accounting the CLI logs, plus a ``latency`` sub-dict (p50/p99 TTFT, inter-token,
         end-to-end seconds) from per-request histograms.  Latency anchors
         at the wall time a request's arrival tick is first REACHED — so
         TTFT includes queue wait under load, the user-visible number.

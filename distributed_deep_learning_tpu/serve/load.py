@@ -3,7 +3,7 @@
 The serving claims this repo makes (prefix reuse pays, chunked prefill
 bounds stalls, speculation speeds decode) are claims about BEHAVIOR
 UNDER LOAD, so the load itself has to be a first-class, seeded,
-replayable object — not an ad-hoc loop in each bench script.  A
+replayable object — not an ad-hoc loop in each caller.  A
 :class:`LoadSpec` describes a traffic mix the way a production trace
 would: an arrival process (everything-up-front, Poisson, or bursty), a
 bimodal prompt-length mix (chat-short vs document-long), an optional
@@ -11,7 +11,7 @@ shared system prompt carried by a fraction of requests (the prefix-
 cache's bread and butter), and per-request TTFT / end-to-end SLOs.
 :func:`make_load` turns a spec into concrete ``Request`` objects;
 :func:`slo_report` scores measured latencies into the attainment
-numbers the bench records and ``bench.py`` baselines track.
+numbers the engines' ``stats["slo"]`` carry.
 
 Everything is driven by one ``numpy`` generator seed: the same spec +
 seed is the same trace, tokens and arrival ticks included, which is
@@ -136,6 +136,32 @@ def make_load(spec: LoadSpec, vocab_size: int, seed: int = 0,
             slo_ttft_ms=spec.slo_ttft_ms, slo_e2e_ms=spec.slo_e2e_ms,
             priority=prio))
     reqs.sort(key=lambda r: (r.arrival_tick, r.uid))
+    return reqs
+
+
+#: the fleet drills' priority mix: a quarter interactive (priority 0,
+#: never preempted or shed), half standard, a quarter batch
+DEFAULT_PRIORITY_CLASSES = ((0, 0.25), (1, 0.5), (2, 0.25))
+
+
+def make_trace(n_requests: int, *, vocab_size: int, seed: int = 0,
+               prompt_lens: tuple[int, int] = (4, 48),
+               new_tokens: tuple[int, int] = (4, 64),
+               stagger: int = 0) -> list[Request]:
+    """Seeded mixed-length trace, the plain sibling of :func:`make_load`
+    (uniform lengths, no shared prefix, no SLOs): what the CLI's
+    ``--serve`` pushes through an engine.  ``prompt_lens``/``new_tokens``
+    are inclusive uniform ranges; ``stagger`` is the mean inter-arrival
+    gap in decode ticks (0 = every request queued at tick 0)."""
+    rng = np.random.default_rng(seed)
+    reqs, tick = [], 0
+    for uid in range(n_requests):
+        p = int(rng.integers(prompt_lens[0], prompt_lens[1] + 1))
+        n = int(rng.integers(new_tokens[0], new_tokens[1] + 1))
+        prompt = rng.integers(1, vocab_size, p).astype(np.int32)
+        reqs.append(Request(uid, prompt, n, arrival_tick=tick))
+        if stagger:
+            tick += int(rng.integers(0, 2 * stagger + 1))
     return reqs
 
 

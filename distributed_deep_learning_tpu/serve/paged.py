@@ -811,6 +811,21 @@ class BlockManager:
         }
 
 
+def paged_max_len(model_max_len: int, kv_block_size: int,
+                  draft: bool, spec_k: int) -> int:
+    """Largest engine ``max_len`` a model geometry supports: the paged
+    cache rounds capacity up to whole blocks and, with speculation on,
+    needs ``spec_k + 1`` positions of verify headroom — all of which
+    must still fit the model's learned position range."""
+    head = (spec_k + 1) if draft else 0
+    cap = (model_max_len // kv_block_size) * kv_block_size - head
+    if cap < kv_block_size:
+        raise ValueError(
+            f"model max_len {model_max_len} too small for block size "
+            f"{kv_block_size} (+{head} speculative headroom)")
+    return cap
+
+
 def predict_shared_len(summary, prompt, block_size: int) -> int:
     """Predicted prefix-cache hit for ``prompt`` against a replica's
     :meth:`BlockManager.prefix_summary`: tokens covered by the longest

@@ -31,11 +31,13 @@ supervisor itself (a crash-looping engine eventually re-raises).
 from __future__ import annotations
 
 import time
-from typing import Iterable, Optional
+from typing import Iterable, Optional, Sequence
 
 import numpy as np
 
-from distributed_deep_learning_tpu.serve.engine import TickReport
+from distributed_deep_learning_tpu.serve.engine import (PagedEngine,
+                                                        ServeEngine,
+                                                        TickReport)
 from distributed_deep_learning_tpu.serve.scheduler import Request
 
 
@@ -373,3 +375,38 @@ class ServeSupervisor:
         if self.admission is not None:
             stats["admission"] = self.admission.stats()
         return {"results": results, "errors": errors, "stats": stats}
+
+
+def run_supervised(model, params, requests: Sequence[Request], *,
+                   paged: bool = False,
+                   deadline_ms: Optional[float] = None, retries: int = 2,
+                   reload_watch: Optional[str] = None,
+                   canary_slots: int = 2,
+                   admission: Optional[dict] = None,
+                   **engine_kw) -> dict:
+    """One SUPERVISED engine lifetime over the trace: the same
+    ``{"results", "errors", "stats"}`` contract as ``Engine.run``, with
+    the engine run under :class:`ServeSupervisor` — tick watchdog, crash
+    containment with zero-loss replay, per-request deadlines and bounded
+    retries.  ``reload_watch`` additionally wires hot weight reload
+    (:class:`..serve.reload.ReloadManager` watching that directory, with
+    ``canary_slots`` of canary before promote); ``admission`` is a
+    kwargs dict for :class:`..serve.admission.AdmissionController`
+    (``utils/config.parse_admission_arg`` produces it from the CLI).
+    The engine-level stats land under ``stats["engine"]``."""
+    eng = (PagedEngine if paged else ServeEngine)(model, params,
+                                                  **engine_kw)
+    rm = None
+    if reload_watch is not None:
+        from distributed_deep_learning_tpu.serve.reload import ReloadManager
+
+        rm = ReloadManager(reload_watch, canary_slots=canary_slots)
+    adm = None
+    if admission is not None:
+        from distributed_deep_learning_tpu.serve.admission import (
+            AdmissionController)
+
+        adm = AdmissionController(**admission)
+    sup = ServeSupervisor(eng, deadline_ms=deadline_ms, retries=retries,
+                          reload=rm, admission=adm)
+    return sup.run(requests)

@@ -6,7 +6,7 @@ would silently train the wrong configuration.  So every artifact carries a
 ``key``: a hash over exactly those inputs, recomputed at load time and
 rejected on mismatch (:class:`StalePlanError`), the same way the packed
 sample cache rejects a stale source.  ``plan_hash`` fingerprints the plan
-itself so bench records can track plan churn across commits.
+itself so plan churn can be tracked across commits.
 """
 
 from __future__ import annotations

@@ -23,8 +23,7 @@ static tables remain the fallback for uncalibrated corners and
 workloads, so calibration only ever sharpens the model.
 
 Predicted-vs-measured error for both the analytic and the calibrated
-model rides in the artifact (and bench.py's ``memory_model``
-sub-record), which is what makes "the planner's memory predictions are
+model rides in the artifact, which is what makes "the planner's memory predictions are
 trustworthy" a measured, regression-guarded claim.
 """
 

@@ -25,7 +25,7 @@ Two kinds of injection:
   monitor — faults that strike between steps, not inside them.
 
 ``run_resilience_drill()`` chains the whole gauntlet on a tiny MLP and
-returns the ``resilience`` record ``bench.py`` reports (detection latency,
+returns the ``resilience`` record (detection latency,
 recovery wall-time, restarts used, sentinel overhead).
 """
 
@@ -810,7 +810,7 @@ def run_blackbox_drill(seed: int = 0,
 
 def run_serve_resilience_drill(seed: int = 0) -> dict:
     """Exercise the serve-side self-healing chain end to end; return the
-    ``serve_resilience`` record ``bench.py`` reports.
+    ``serve_resilience`` record.
 
     ONE small :class:`..serve.engine.PagedEngine` survives the whole
     gauntlet — every scenario warm-restarts it (``reset()``) rather than
@@ -842,16 +842,17 @@ def run_serve_resilience_drill(seed: int = 0) -> dict:
     from distributed_deep_learning_tpu.serve import reload as reload_mod
     from distributed_deep_learning_tpu.serve.admission import (
         AdmissionController)
-    from distributed_deep_learning_tpu.serve.bench import (build_model,
-                                                           make_trace,
-                                                           paged_max_len)
+    from distributed_deep_learning_tpu.models.transformer import (
+        random_causal_lm)
     from distributed_deep_learning_tpu.serve.engine import PagedEngine
+    from distributed_deep_learning_tpu.serve.load import make_trace
+    from distributed_deep_learning_tpu.serve.paged import paged_max_len
     from distributed_deep_learning_tpu.serve.scheduler import Request
     from distributed_deep_learning_tpu.serve.supervisor import ServeSupervisor
 
     model_kw = dict(vocab_size=128, num_layers=1, d_model=64, num_heads=2,
                     mlp_dim=128, max_len=96)
-    model, params = build_model(seed, **model_kw)
+    model, params = random_causal_lm(seed, **model_kw)
     cap = paged_max_len(model.max_len, 8, False, 0)
     eng = PagedEngine(model, params, max_slots=4, max_len=cap,
                       kv_block_size=8, prefill_chunk=16)
@@ -1031,7 +1032,7 @@ def run_serve_resilience_drill(seed: int = 0) -> dict:
 
 def run_fleet_resilience_drill(seed: int = 0) -> dict:
     """Exercise the FLEET tier end to end; return the
-    ``fleet_resilience`` record ``bench.py`` reports.
+    ``fleet_resilience`` record.
 
     THREE small :class:`..serve.engine.PagedEngine` replicas survive the
     whole gauntlet — every scenario reuses them (a crashed replica is
@@ -1041,7 +1042,7 @@ def run_fleet_resilience_drill(seed: int = 0) -> dict:
 
     1. **clean** — the no-fault fleet reference outputs every fault
        scenario must reproduce bit-identically, plus the per-priority
-       SLO report the bench baselines track.
+       SLO report.
     2. **replica_crash** — kill replica 1 mid-round under the
        shared-prefix Poisson trace: the router quarantines it, replays
        its in-flight requests from the fleet ledger onto the survivors;
@@ -1057,17 +1058,19 @@ def run_fleet_resilience_drill(seed: int = 0) -> dict:
        outputs are bit-identical to uncontended runs and priority 0 is
        never preempted (timeline-asserted).
     """
-    from distributed_deep_learning_tpu.serve.bench import (
-        DEFAULT_PRIORITY_CLASSES, build_model, paged_max_len)
+    from distributed_deep_learning_tpu.models.transformer import (
+        random_causal_lm)
     from distributed_deep_learning_tpu.serve.engine import PagedEngine
     from distributed_deep_learning_tpu.serve.fleet import (FleetRouter,
                                                            QUARANTINED)
-    from distributed_deep_learning_tpu.serve.load import LoadSpec, make_load
+    from distributed_deep_learning_tpu.serve.load import (
+        DEFAULT_PRIORITY_CLASSES, LoadSpec, make_load)
+    from distributed_deep_learning_tpu.serve.paged import paged_max_len
     from distributed_deep_learning_tpu.serve.scheduler import Request
 
     model_kw = dict(vocab_size=128, num_layers=1, d_model=64, num_heads=2,
                     mlp_dim=128, max_len=96)
-    model, params = build_model(seed, **model_kw)
+    model, params = random_causal_lm(seed, **model_kw)
     cap = paged_max_len(model.max_len, 8, False, 0)
     engines = [PagedEngine(model, params, max_slots=4, max_len=cap,
                            kv_block_size=8, prefill_chunk=16)
@@ -1285,7 +1288,7 @@ def run_fleet_resilience_drill(seed: int = 0) -> dict:
 
 def run_rebalance_drill(seed: int = 0) -> dict:
     """Exercise live fleet REBALANCING end to end; return the
-    ``fleet_rebalance`` record ``bench.py`` reports.
+    ``fleet_rebalance`` record.
 
     Sections (fault scenarios are compared bit-for-bit against a clean
     no-fault fleet reference on the same trace — greedy decode is
@@ -1325,17 +1328,19 @@ def run_rebalance_drill(seed: int = 0) -> dict:
     """
     from distributed_deep_learning_tpu.serve.autoscaler import (
         FleetAutoscaler)
-    from distributed_deep_learning_tpu.serve.bench import (
-        DEFAULT_PRIORITY_CLASSES, build_model, paged_max_len)
+    from distributed_deep_learning_tpu.models.transformer import (
+        random_causal_lm)
     from distributed_deep_learning_tpu.serve.engine import PagedEngine
     from distributed_deep_learning_tpu.serve.fleet import (DEGRADED,
                                                            FleetRouter,
                                                            RETIRED)
-    from distributed_deep_learning_tpu.serve.load import LoadSpec, make_load
+    from distributed_deep_learning_tpu.serve.load import (
+        DEFAULT_PRIORITY_CLASSES, LoadSpec, make_load)
+    from distributed_deep_learning_tpu.serve.paged import paged_max_len
 
     model_kw = dict(vocab_size=128, num_layers=1, d_model=64, num_heads=2,
                     mlp_dim=128, max_len=96)
-    model, params = build_model(seed, **model_kw)
+    model, params = random_causal_lm(seed, **model_kw)
     cap = paged_max_len(model.max_len, 8, False, 0)
 
     def engine(**kw):

@@ -182,39 +182,17 @@ def _transformer_model(config: Config, dataset):
     return Seq2SeqAdapter(inner, src_len)
 
 
-def _measured_flash_speedup() -> float | None:
-    """The last RECORDED flash-vs-dense ratio from the bench's attention
-    micro; None when never measured (``utils.bench_records`` owns the key
-    and file)."""
-    from distributed_deep_learning_tpu.utils.bench_records import (
-        read_flash_speedup)
-
-    return read_flash_speedup()
-
-
 def _attention_fn(config: Config):
     """Resolve ``--attention``: the Pallas flash kernel is the TPU default
     for the transformer family (in-kernel causal + padding masks, no (T×T)
     score materialisation); dense elsewhere, and either can be forced.
-
-    ``auto`` is DATA-GATED (VERDICT r4 item 8): if the benchmark has
-    recorded a flash-vs-dense ratio meaningfully below parity on this
-    repo's own hardware history, auto resolves to dense even on TPU — the
-    default must never be slower than what it replaced.  The cutoff is
-    0.9, not 1.0 (ADVICE r4): the gate is latest-wins, so a single noisy
-    run measuring e.g. 0.98 must not flip the fleet default over
-    measurement jitter.  Forcing ``--attention flash`` bypasses the gate.
+    ``auto`` is a function of the backend alone.
     """
     choice = config.attention
     if choice == "auto":
         import jax
 
-        if jax.default_backend() == "tpu":
-            speedup = _measured_flash_speedup()
-            choice = "dense" if speedup is not None and speedup < 0.9 \
-                else "flash"
-        else:
-            choice = "dense"
+        choice = "flash" if jax.default_backend() == "tpu" else "dense"
     if choice == "flash":
         from distributed_deep_learning_tpu.ops.attention_pallas import (
             make_attention_fn)
@@ -609,9 +587,9 @@ def _gpt_serve(config: Config, state, logger, dataset) -> None:
     sample.  With ``--paged`` the trace goes through the paged engine
     instead (block KV + prefix reuse + chunked prefill, ``--draft N``
     speculation) and the log line adds hit rate / acceptance / SLOs."""
-    from distributed_deep_learning_tpu.serve.bench import (make_trace,
-                                                           run_engine,
-                                                           run_supervised)
+    from distributed_deep_learning_tpu.serve.engine import ServeEngine
+    from distributed_deep_learning_tpu.serve.load import make_trace
+    from distributed_deep_learning_tpu.serve.supervisor import run_supervised
 
     params = getattr(state, "params", None)
     if isinstance(params, dict) and "params" in params:
@@ -639,10 +617,9 @@ def _gpt_serve(config: Config, state, logger, dataset) -> None:
     quant_kw = dict(kv_dtype=config.kv_dtype,
                     weight_dtype=config.weight_dtype)
     if sup_kw is None:
-        out = run_engine(model, params, trace,
-                         max_slots=config.max_slots,
-                         prefill_buckets=config.prefill_buckets,
-                         **quant_kw)
+        out = ServeEngine(model, params, max_slots=config.max_slots,
+                          prefill_buckets=config.prefill_buckets,
+                          **quant_kw).run(trace)
         s = out["stats"]
     else:
         out = run_supervised(model, params, trace,
@@ -666,10 +643,10 @@ def _gpt_serve_paged(config: Config, model, params, logger, dataset,
     engine, with the config's block/chunk/draft/SLO knobs applied."""
     import dataclasses
 
-    from distributed_deep_learning_tpu.serve.bench import (make_trace,
-                                                           paged_max_len,
-                                                           run_paged,
-                                                           run_supervised)
+    from distributed_deep_learning_tpu.serve.engine import PagedEngine
+    from distributed_deep_learning_tpu.serve.load import make_trace
+    from distributed_deep_learning_tpu.serve.paged import paged_max_len
+    from distributed_deep_learning_tpu.serve.supervisor import run_supervised
 
     draft = config.draft or None
     if draft is not None and not 1 <= draft < model.num_layers:
@@ -714,7 +691,7 @@ def _gpt_serve_paged(config: Config, model, params, logger, dataset,
         return
     sup_kw = _serve_supervision_kw(config)
     if sup_kw is None:
-        out = run_paged(model, params, trace, **engine_kw)
+        out = PagedEngine(model, params, **engine_kw).run(trace)
         s = out["stats"]
     else:
         out = run_supervised(model, params, trace, paged=True,

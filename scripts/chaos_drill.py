@@ -53,9 +53,7 @@ Four scenarios, selected with ``--scenario``:
   engine reassigns a worker between the prefill and decode pools.
 
 All are CPU-runnable (the chains are host+XLA logic, not
-accelerator-specific); ``bench.py`` embeds the same records as its
-``resilience``, ``reshard``, ``serve_resilience``,
-``fleet_resilience`` and ``fleet_rebalance`` sections.
+accelerator-specific).
 
 Usage::
 

@@ -15,8 +15,7 @@ DeviceLoader) and prints throughput + peak RSS as JSON lines.  Run:
 
     JAX_PLATFORMS=cpu python scripts/data_soak.py [--small]
 
-(--small shrinks corpora ~10x for CI smoke; the recorded numbers in
-PERFORMANCE.md come from the full run.)
+(--small shrinks corpora ~10x for CI smoke.)
 """
 
 import argparse

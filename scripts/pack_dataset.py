@@ -5,9 +5,8 @@ workload's dataset exactly as training would — ImageFolder / PCB decode
 through the threaded decoder, PdM/MQTT CSV windows, token rows — stream
 it through ``batch()`` in chunks, and write a ``data/packed.py`` cache.
 Training then runs with ``--packed-cache`` and assembles batches from the
-memory-mapped file with zero per-sample Python work (~2 orders of
-magnitude faster than per-epoch JPEG decode; ``scripts/feed_bench.py``
-measures it).
+memory-mapped file with zero per-sample Python work (no per-epoch JPEG
+decode).
 
     JAX_PLATFORMS=cpu python scripts/pack_dataset.py \\
         --workload resnet --data-dir /data/imagenet --image-size 224 \\

@@ -314,59 +314,38 @@ def test_gqa_indivisible_heads_rejected():
         flash_attention(q, k, v, block_q=8, block_k=8)
 
 
-def test_flash_blocks_records_roundtrip(tmp_path, monkeypatch):
-    """record/read of the tuned (block_q, block_k) datum, isolated from
-    the repo's real bench_baseline.json."""
-    from distributed_deep_learning_tpu.utils import bench_records as br
-
-    monkeypatch.setattr(br, "baseline_path",
-                        lambda: str(tmp_path / "b.json"))
-    assert br.read_flash_blocks() is None
-    br.record_flash_blocks(256, 512)
-    assert br.read_flash_blocks() == (256, 512)
-    br.record_flash_speedup(1.3)  # other keys coexist
-    assert br.read_flash_blocks() == (256, 512)
-    assert br.read_flash_speedup() == 1.3
-    # corrupt values degrade to None, never crash or mis-block
-    import json
-
-    for bad in ({"bq": 1}, "512", [128], [0, 128], None):
-        (tmp_path / "b.json").write_text(
-            json.dumps({br.FLASH_BLOCKS_KEY: bad}))
-        assert br.read_flash_blocks() is None, bad
-
-
-def test_flash_default_blocks_resolve_from_records(monkeypatch):
-    """On TPU the kernel's default blocks come from the recorded sweep;
-    _fit_block clamps oversized records to the sequence length, so the
-    call still works (and matches) at small T."""
+@pytest.mark.parametrize("backend,blocks", [("tpu", (512, 512)),
+                                            ("cpu", (128, 128))])
+def test_flash_default_blocks_follow_the_backend(monkeypatch, backend,
+                                                 blocks):
+    """The default (block_q, block_k) is the module's own constant, chosen
+    by the backend alone: read off the call the kernel entry receives."""
     from distributed_deep_learning_tpu.ops import attention_pallas as ap
 
+    seen = []
+
+    def spy(q, k, v, kvalid, sm_scale, causal, block_q, block_k, interpret,
+            window):
+        seen.append((block_q, block_k))
+        return q
+
+    monkeypatch.setattr(ap, "_flash_bhtd", spy)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    q, k, v = _qkv(T=32, seed=50)
+    flash_attention(q, k, v, causal=True, interpret=True)
+    flash_attention(q, k, v, causal=True, interpret=True, block_k=64)
+    assert seen == [blocks, (blocks[0], 64)]
+
+
+def test_flash_oversized_default_blocks_clamp_to_the_sequence(monkeypatch):
+    """_fit_block clamps the TPU default (512) to a 32-token sequence, so
+    the call still works and matches 8 x 8 blocks."""
     q, k, v = _qkv(T=32, seed=50)
     expected = flash_attention(q, k, v, causal=True, block_q=8, block_k=8)
-
-    monkeypatch.setattr("jax.default_backend", lambda: "tpu")
-    monkeypatch.setattr(
-        "distributed_deep_learning_tpu.utils.bench_records"
-        ".read_flash_blocks", lambda: (256, 512))
-    ap._recorded_blocks.cache_clear()  # per-process memo (review finding)
-    try:
-        got = flash_attention(q, k, v, causal=True, interpret=True)
-    finally:
-        ap._recorded_blocks.cache_clear()  # don't leak the patched datum
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    got = flash_attention(q, k, v, causal=True, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(expected),
                                rtol=1e-5, atol=1e-5)
-
-
-def test_northstar_attention_flag_resolution():
-    from distributed_deep_learning_tpu.utils.config import Config
-    from distributed_deep_learning_tpu.workloads.northstar import (
-        _attention_fn)
-
-    assert _attention_fn(Config(attention="dense")) is None
-    assert callable(_attention_fn(Config(attention="flash")))
-    # auto on the CPU test platform resolves to dense
-    assert _attention_fn(Config(attention="auto")) is None
 
 
 def test_transformer_layer_with_flash_attention():

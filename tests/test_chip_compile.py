@@ -35,9 +35,9 @@ SLOTS, BPS = 8, 64                    # the smoke's paged engine
 
 
 @pytest.fixture(scope="module")
-def chip():
-    """One described v5e device, with the persistent compilation cache off
-    around the module: a compile for a described chip is written to the
+def v5e():
+    """The four described v5e devices, with the persistent compilation cache
+    off around the module: a compile for a described chip is written to the
     cache but cannot be read back without one, and the next run would warn."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
@@ -50,9 +50,15 @@ def chip():
     enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield list(topo.devices)
     jax.config.update("jax_enable_compilation_cache", enabled)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(v5e):
+    """One of them."""
+    return SingleDeviceSharding(v5e[0])
 
 
 def _compiled_text(fn, *shapes) -> str:
@@ -60,10 +66,9 @@ def _compiled_text(fn, *shapes) -> str:
 
 
 FLASH_CASES = {
-    # blocks: 128x128 is the kernel's own default, 512x512 what
-    # bench_baseline.json records and a TPU run therefore resolves to
+    # blocks: the kernel's default off a TPU (128x128) and on one (512x512)
     "default-128": dict(blocks=(128, 128)),
-    "recorded-512": dict(blocks=(512, 512)),
+    "tpu-default-512": dict(blocks=(512, 512)),
     "gqa": dict(blocks=(128, 128), kv_heads=4),
     "window": dict(blocks=(128, 128), window=256),
     "key_valid": dict(blocks=(128, 128), key_valid=True),
@@ -295,3 +300,115 @@ def test_two_kind_paged_program_compiles_for_v5e(laguna_engine, on_tpu,
         assert in_place and not gathered, (in_place, gathered)
     else:
         assert not in_place
+
+
+# the two train cells' steps (benchmark/configs/gpt2-{medium,xl}.json +
+# traffic train-1024{,-fsdp}) at their real widths, batch and mesh, two
+# layers deep: the blocks do not depend on depth.  argv, chips, heads
+TRAIN_CELLS = {
+    "gpt2m-train-1chip": (
+        "-l 2 -s 1024 -b 16 --dtype bfloat16 -m sequential --lr 0.001 "
+        "--schedule none", 1, 16),
+    "gpt2xl-train-fsdp4": (
+        "-l 2 -s 1600 -b 8 --dtype bfloat16 -m data --zero fsdp "
+        "--mesh fsdp=4 --lr 0.001 --schedule none", 4, 25),
+}
+
+
+def _pallas_calls(jaxpr, out):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append(eqn)
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _pallas_calls(sub, out)
+    return out
+
+
+@pytest.fixture(scope="module", params=TRAIN_CELLS)
+def train_flash_calls(v5e, request):
+    """The flash kernel's calls in the CLI's ``gpt`` train step, traced once
+    and lowered for the described chips under ``--attention auto`` with no
+    ``block_q`` / ``block_k`` given: ``(rows a chip, heads, [(kernel name,
+    grid, block shapes)])``.  The program asks for the backend twice on the way (the
+    ``auto`` rule, the kernel's default blocks): it is told what it would
+    find on the chip."""
+    argv, chips, heads = TRAIN_CELLS[request.param]
+    with pytest.MonkeyPatch.context() as on_tpu:
+        on_tpu.setattr(jax, "default_backend", lambda: "tpu")
+        rows, calls = _trace_train_step(argv, v5e[:chips])
+    return rows // chips, heads, calls
+
+
+def _trace_train_step(argv, devices):
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from distributed_deep_learning_tpu.data.loader import BATCH_AXES
+    from distributed_deep_learning_tpu.data.tokens import TokenArrayDataset
+    from distributed_deep_learning_tpu.runtime.mesh import build_mesh
+    from distributed_deep_learning_tpu.train.state import create_train_state
+    from distributed_deep_learning_tpu.train.step import _state_sharding
+    from distributed_deep_learning_tpu.utils.config import Mode, parse_args
+    from distributed_deep_learning_tpu.workloads import base, get_spec
+
+    config = parse_args(argv.split(), workload="gpt")
+    assert config.attention == "auto"
+    spec = get_spec("gpt")
+    rows = config.batch_size
+    tokens = np.zeros((rows, T + 1), np.int32)
+    tokens[0, 0] = 50256
+    ds = TokenArrayDataset(tokens[:, :-1], tokens[:, 1:], 50257)
+    if config.mode is Mode.SEQUENTIAL:
+        mesh = build_mesh({"data": 1}, devices)
+    else:
+        mesh = build_mesh(config.mesh_shape,
+                          base.mesh_devices(config.mesh_shape, devices))
+    model = spec.build_model(config, ds)
+    state = jax.eval_shape(lambda: create_train_state(
+        model, jax.random.key(0), spec.example_input(config, ds),
+        base.build_optimizer(spec, config, 17)))
+    sspec = base.derive_state_spec(spec, config, mesh, state)
+    train_step, _ = base.make_train_eval_steps(
+        config, mesh, spec.build_loss(config), sspec)
+    sharding = _state_sharding(mesh, sspec)
+    if isinstance(sharding, NamedSharding):
+        sharding = jax.tree.map(lambda _: sharding, state)
+    state = jax.tree.map(lambda s, sh: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=sh), state, sharding)
+    x = jax.ShapeDtypeStruct((rows, T), jnp.int32, sharding=NamedSharding(
+        mesh, PartitionSpec(BATCH_AXES)))
+    traced = train_step.trace(state, x, x)
+    assert traced.lower().as_text().count("tpu_custom_call") == 6
+    calls = []
+    for eqn in _pallas_calls(traced.jaxpr.jaxpr, []):
+        assert eqn.params["interpret"] is False
+        mapping = eqn.params["grid_mapping"]
+        calls.append((
+            eqn.params["jaxpr"].debug_info.func_name, tuple(mapping.grid),
+            [tuple(getattr(d, "block_size", d) for d in m.block_shape)
+             for m in mapping.block_mappings]))
+    return rows, calls
+
+
+@pytest.mark.parametrize("kernels", [("_fwd_kernel",),
+                                     ("_dq_kernel", "_dkv_kernel")],
+                         ids=["forward", "backward"])
+def test_train_step_lowers_flash_at_512_blocks(train_flash_calls, kernels):
+    """What cells 1 and 4 compile: heads of 64 at T = 1,024 walk two
+    512-wide query (or key) blocks a head, on each chip's own rows (cell 4
+    calls the kernel per shard); each kernel's q / k / v / dO operands come
+    whole or 512 rows at a time, and the padding mask is blocked 512 keys
+    wide.  A default that moved would show here, before any chip does."""
+    rows, heads, calls = train_flash_calls
+    calls = [c for c in calls if c[0] in kernels]
+    assert sorted({c[0] for c in calls}) == sorted(kernels)
+    assert len(calls) == 2 * len(kernels)         # one a layer
+    for _, grid, blocks in calls:
+        assert grid == (rows * heads, T // 512)
+        rows_at_a_time = {b[1] for b in blocks if len(b) == 3}
+        assert rows_at_a_time == {512, T}, blocks
+        masks = [b for b in blocks if len(b) == 4]
+        assert masks and all(b[-1] == 512 for b in masks), blocks

@@ -49,7 +49,7 @@ def test_chip_smoke_walks_every_phase_when_the_check_is_steered(tmp_path):
     assert "chip_smoke: model: vocab 257 context 64" in out
     assert "chip_smoke: engine vs generate():" in out
     # the longer trace, then both kernels against their references
-    assert "chip_smoke: serve_bench: 4 requests" in out
+    assert "chip_smoke: serve_trace: 4 requests" in out
     assert "chip_smoke: flash_attention dv:" in out
     assert "chip_smoke: paged_flash_decode int8:" in out
     assert "chip_smoke: native: built" in out

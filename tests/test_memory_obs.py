@@ -1,6 +1,6 @@
 """Memory observability + measured memory-model calibration (ISSUE 12).
 
-Four load-bearing claims:
+Three load-bearing claims:
 
 * the OOM postmortem drill is DETERMINISTIC — a seeded fake
   ``RESOURCE_EXHAUSTED`` through ``TrialHarness``'s ``oom_hook`` seam
@@ -12,16 +12,13 @@ Four load-bearing claims:
 * calibration (``tune/calibrate.py``) fits ``ACT_FRACTION`` /
   ``RECOMPUTE_COST`` from measured corners and drives predicted-vs-
   measured error under the 25% acceptance bar, behind the same
-  versioned-artifact gating the plan artifact uses;
-* ``scripts/check_baselines.py`` keeps ``bench_baseline.json`` and
-  ``REGRESSION_BANDS`` from drifting apart (run here as a tier-1 test).
+  versioned-artifact gating the plan artifact uses.
 
 Nothing in this file compiles a training step: calibration tests inject
 a fake ``runner``, the postmortem drill OOMs before any build, and the
 serve tests reuse the tiny CPU model the serve suite already pays for.
 """
 
-import importlib.util
 import json
 import os
 import subprocess
@@ -563,88 +560,6 @@ def test_mfu_record_carries_source(monkeypatch):
     monkeypatch.setenv("DDL_OBS_PEAK_FLOPS", "3e12")
     assert mfu_record(1e12, 100, 10.0, 4,
                       "cpu")["peak_flops_source"] == "env_override"
-
-
-# ------------------------------------- baseline/band drift gate (c)
-
-def _check_baselines():
-    spec = importlib.util.spec_from_file_location(
-        "check_baselines", os.path.join(REPO, "scripts",
-                                        "check_baselines.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_check_baselines_repo_is_consistent():
-    # the tier-1 wiring of scripts/check_baselines.py: the repo's own
-    # baseline file and bands must be drift-free on every commit
-    out = subprocess.run(
-        [sys.executable, os.path.join("scripts", "check_baselines.py")],
-        cwd=REPO, capture_output=True, text=True, timeout=120)
-    assert out.returncode == 0, out.stderr
-    rec = json.loads(out.stdout.strip().splitlines()[-1])
-    assert rec["problems"] == 0 and rec["baselines"] > 0
-
-
-def test_check_baselines_detects_drift():
-    cb = _check_baselines()
-    bands = {"thing_v1": ("higher", 0.1)}
-    base = {"cpu:thing_v1": 5.0}
-    assert cb.check(base, bands, allow_unbanded=frozenset()) == []
-    # unguarded baseline key
-    p = cb.check({"cpu:new_v1": 1.0, **base}, bands,
-                 allow_unbanded=frozenset())
-    assert len(p) == 1 and "no REGRESSION_BANDS" in p[0]
-    # stale allowlist entry
-    p = cb.check(base, bands, allow_unbanded=frozenset({"tpu:gone_v1"}))
-    assert len(p) == 1 and "stale allowlist" in p[0]
-    # orphaned band
-    p = cb.check(base, {**bands, "ghost_v1": ("higher", 0.1)},
-                 allow_unbanded=frozenset())
-    assert len(p) == 1 and "orphaned" in p[0]
-    # malformed mode / non-positive value
-    p = cb.check(base, {"thing_v1": ("sideways", 0.1)},
-                 allow_unbanded=frozenset())
-    assert len(p) >= 1 and "malformed" in p[0]
-    p = cb.check(base, {"thing_v1": ("higher", 0.0)},
-                 allow_unbanded=frozenset())
-    assert any("non-positive" in s for s in p)
-
-
-# --------------------------------------- regression sentry: mem model
-
-def test_sentry_mem_model_error_band():
-    sys.path.insert(0, REPO)
-    import bench
-
-    assert bench.REGRESSION_BANDS["mem_model_error_v1"] \
-        == ("lower_abs", 0.25)
-    breach = bench.regression_sentry(
-        {}, {"cpu:mem_model_error_v1": 0.40})
-    assert len(breach) == 1 and breach[0]["kind"] \
-        == "absolute ceiling exceeded"
-    assert bench.regression_sentry(
-        {}, {"cpu:mem_model_error_v1": 0.10}) == []
-
-
-def test_regress_from_judges_memory_record(tmp_path):
-    sys.path.insert(0, REPO)
-    import bench
-
-    good = tmp_path / "good.json"
-    good.write_text(json.dumps(
-        {"measured": {"cpu:mem_model_error_v1": 0.05}}) + "\n")
-    assert bench.regress_from(str(good)) == 0
-
-    bad = tmp_path / "bad.json"
-    bad.write_text(json.dumps(
-        {"measured": {"cpu:mem_model_error_v1": 0.60}}) + "\n")
-    assert bench.regress_from(str(bad)) == 3
-
-    empty = tmp_path / "empty.json"
-    empty.write_text("not json\n")
-    assert bench.regress_from(str(empty)) == 2
 
 
 # ------------------------------------------------ obs_report --memory

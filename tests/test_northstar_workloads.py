@@ -343,41 +343,19 @@ def test_label_smoothing_validated():
                         "--label-smoothing", "0.1"], limit=128)
 
 
-def test_attention_auto_gated_on_measured_speedup(monkeypatch):
-    """VERDICT r4 item 8 + ADVICE r4: --attention auto must resolve to
-    dense on TPU when the recorded flash-vs-dense ratio is meaningfully
-    below parity (< 0.9 — hysteresis so one noisy 0.98 run can't flip the
-    default), flash when near/above parity or unmeasured."""
+@pytest.mark.parametrize("backend,choice,flash", [
+    ("tpu", "auto", True), ("tpu", "flash", True), ("tpu", "dense", False),
+    ("cpu", "auto", False), ("cpu", "flash", True), ("cpu", "dense", False)],
+    ids=lambda v: str(v))
+def test_attention_resolution(monkeypatch, backend, choice, flash):
+    """--attention auto is a function of the backend alone (flash on a
+    TPU, dense elsewhere); flash and dense are forced whatever it is."""
     import distributed_deep_learning_tpu.workloads.northstar as ns
     from distributed_deep_learning_tpu.utils.config import Config
 
-    monkeypatch.setattr("jax.default_backend", lambda: "tpu")
-
-    monkeypatch.setattr(ns, "_measured_flash_speedup", lambda: 0.54)
-    assert ns._attention_fn(Config(attention="auto")) is None  # dense
-
-    # jitter band: 0.9 <= ratio < 1.0 keeps flash (ADVICE r4 hysteresis)
-    monkeypatch.setattr(ns, "_measured_flash_speedup", lambda: 0.95)
-    assert callable(ns._attention_fn(Config(attention="auto")))
-
-    monkeypatch.setattr(ns, "_measured_flash_speedup", lambda: 1.8)
-    assert callable(ns._attention_fn(Config(attention="auto")))
-
-    monkeypatch.setattr(ns, "_measured_flash_speedup", lambda: None)
-    assert callable(ns._attention_fn(Config(attention="auto")))
-
-    # forcing flash bypasses the gate
-    monkeypatch.setattr(ns, "_measured_flash_speedup", lambda: 0.5)
-    assert callable(ns._attention_fn(Config(attention="flash")))
-
-
-def test_measured_flash_speedup_reads_repo_baseline():
-    """The reader parses the repo's own bench_baseline.json (None until
-    the bench has recorded the key on hardware)."""
-    import distributed_deep_learning_tpu.workloads.northstar as ns
-
-    v = ns._measured_flash_speedup()
-    assert v is None or isinstance(v, float)
+    monkeypatch.setattr("jax.default_backend", lambda: backend)
+    fn = ns._attention_fn(Config(attention=choice))
+    assert callable(fn) if flash else fn is None
 
 
 def test_generate_pre_check_exempts_staged_modes():

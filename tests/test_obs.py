@@ -10,9 +10,8 @@ The load-bearing claims:
 * goodput fractions sum to <= 1.0 whatever the span bookkeeping did;
 * a staggered-arrival serve trace yields per-request TTFT/e2e
   percentiles anchored at arrival (queue wait counts);
-* telemetry on the real train loop costs < a noise-tolerant bound of
-  steps/sec (bench.py records the tight number under
-  ``obs_overhead_fraction_v1``; the acceptance bar there is 2%).
+* the per-step telemetry sequence costs microseconds a step (what it
+  costs a real train loop on the chip is ``PERF.md``'s to say).
 """
 
 import json
@@ -308,17 +307,18 @@ def test_run_telemetry_close_emits_and_is_idempotent(tmp_path,
 # --- serve latency under staggered arrivals -------------------------------
 
 def test_serve_latency_staggered_arrivals():
-    from distributed_deep_learning_tpu.serve.bench import (build_model,
-                                                           make_trace,
-                                                           run_engine)
+    from distributed_deep_learning_tpu.models.transformer import (
+        random_causal_lm)
+    from distributed_deep_learning_tpu.serve.engine import ServeEngine
+    from distributed_deep_learning_tpu.serve.load import make_trace
 
-    model, params = build_model(
+    model, params = random_causal_lm(
         seed=3, vocab_size=61, num_layers=1, d_model=32, num_heads=4,
         mlp_dim=64, max_len=48)
     trace = make_trace(8, vocab_size=61, seed=3, prompt_lens=(4, 12),
                        new_tokens=(4, 8), stagger=2)
     assert any(r.arrival_tick > 0 for r in trace)  # genuinely staggered
-    out = run_engine(model, params, trace, max_slots=3)
+    out = ServeEngine(model, params, max_slots=3).run(trace)
     lat = out["stats"]["latency"]
     assert lat["measured_requests"] == 8
     for k in ("ttft", "e2e"):
@@ -329,17 +329,18 @@ def test_serve_latency_staggered_arrivals():
 
 
 def test_serve_stream_records_obs_serve(tmp_path):
-    from distributed_deep_learning_tpu.serve.bench import (build_model,
-                                                           make_trace,
-                                                           run_engine)
+    from distributed_deep_learning_tpu.models.transformer import (
+        random_causal_lm)
+    from distributed_deep_learning_tpu.serve.engine import ServeEngine
+    from distributed_deep_learning_tpu.serve.load import make_trace
 
     t = RunTelemetry(path=str(tmp_path / "serve.jsonl"))
-    model, params = build_model(
+    model, params = random_causal_lm(
         seed=3, vocab_size=61, num_layers=1, d_model=32, num_heads=4,
         mlp_dim=64, max_len=48)
     trace = make_trace(4, vocab_size=61, seed=4, prompt_lens=(4, 8),
                        new_tokens=(4, 6))
-    run_engine(model, params, trace, max_slots=2, telemetry=t)
+    ServeEngine(model, params, max_slots=2).run(trace, telemetry=t)
     t.close()
     ev = next(read_events(str(tmp_path / "serve.jsonl"),
                           event="obs_serve"))
@@ -388,10 +389,7 @@ def test_per_step_instrumentation_cost_bounded():
     # dispatch_kind, two Timeline.add calls, step() — measured raw.
     # ~1.4 us/step on the CI box; the bound leaves >10x headroom so the
     # test never flakes, yet catches a regression that puts formatting,
-    # allocation, or I/O on the hot path.  The wall-clock A/B against
-    # the real train loop (the <2% acceptance bar) lives in bench.py's
-    # ``observability`` section, where shared-runner noise is handled by
-    # interleaved repeats + recorded baselines rather than an assert.
+    # allocation, or I/O on the hot path.
     import time
 
     t = RunTelemetry()
@@ -408,16 +406,6 @@ def test_per_step_instrumentation_cost_bounded():
         tl.step()
     per_step_us = (time.perf_counter() - t0) / n * 1e6
     assert per_step_us < 25.0, per_step_us
-
-
-def test_overhead_bench_record_shape():
-    from distributed_deep_learning_tpu.obs.bench import overhead_bench
-
-    rec = overhead_bench(steps=16, repeats=3, dim=64, depth=2, batch=16)
-    assert rec["steps_per_sec_off"] > 0 and rec["steps_per_sec_on"] > 0
-    # catastrophe guard only — tight numbers are bench.py's job (wall
-    # clock A/B on a 2-core shared box swings a few percent either way)
-    assert rec["obs_overhead_fraction"] < 0.5, rec
 
 
 # --- satellite regressions (utils/profiling, utils/logging) ---------------

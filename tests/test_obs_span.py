@@ -20,18 +20,19 @@ from distributed_deep_learning_tpu import obs
 from distributed_deep_learning_tpu.obs import runlog, xplane
 from distributed_deep_learning_tpu.obs import trace as obs_trace
 from distributed_deep_learning_tpu.obs.trace import PhaseClock, Tracer, span
-from distributed_deep_learning_tpu.serve.bench import build_model, make_trace
+from distributed_deep_learning_tpu.models.transformer import random_causal_lm
 from distributed_deep_learning_tpu.serve.engine import (DISPATCH_PHASES,
                                                         TICK_PHASES,
                                                         PagedEngine)
+from distributed_deep_learning_tpu.serve.load import make_trace
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _engine(**kw):
-    model, params = build_model(seed=3, vocab_size=61, num_layers=2,
-                                d_model=32, num_heads=4, mlp_dim=64,
-                                max_len=96)
+    model, params = random_causal_lm(seed=3, vocab_size=61, num_layers=2,
+                                     d_model=32, num_heads=4, mlp_dim=64,
+                                     max_len=96)
     kw = {"max_slots": 3, "max_len": 96, "kv_block_size": 8,
           "prefill_chunk": 8, **kw}
     return PagedEngine(model, params, **kw)

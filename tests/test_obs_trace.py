@@ -1,6 +1,6 @@
 """Observability generation 2 (ISSUE 11): per-request causal tracing,
-rolling-window live signals, the crash flight recorder, sidecar
-rotation, and the bench regression sentry.
+rolling-window live signals, the crash flight recorder and sidecar
+rotation.
 
 Clock-sensitive pieces (span causality, window expiry, recorder
 determinism) run against INJECTED clocks so every assertion is exact —
@@ -331,39 +331,6 @@ def test_prometheus_counter_type_and_native_histogram():
     assert float(sum_line.split()[-1]) == pytest.approx(0.53, rel=0.15)
 
 
-# --- bench regression sentry ----------------------------------------------
-
-def test_regression_sentry_bands():
-    import bench
-
-    baselines = {"cpu:resnet50_224_train_v1": 100.0,
-                 "cpu:obs_trace_overhead_fraction_v1": 0.015,
-                 "cpu:serving_prefix_hit_rate_v1": 0.8}
-    measured = {"cpu:resnet50_224_train_v1": 60.0,        # -40% < band
-                "cpu:obs_trace_overhead_fraction_v1": 0.05,  # > ceiling
-                "cpu:serving_prefix_hit_rate_v1": 0.75}   # -6% inside
-    regs = bench.regression_sentry(baselines, measured)
-    assert {r["key"] for r in regs} == {
-        "cpu:resnet50_224_train_v1",
-        "cpu:obs_trace_overhead_fraction_v1"}
-    kinds = {r["key"]: r["kind"] for r in regs}
-    assert kinds["cpu:obs_trace_overhead_fraction_v1"] == \
-        "absolute ceiling exceeded"
-
-
-def test_regression_sentry_fresh_seed_and_unknown_keys_pass():
-    import bench
-
-    measured = {"cpu:resnet50_224_train_v1": 50.0,
-                "cpu:some_future_metric_v1": 0.001}
-    # freshly seeded: baseline == measured => ratio 1.0, never fails;
-    # unknown keys have no band and are skipped
-    assert bench.regression_sentry(
-        {"cpu:resnet50_224_train_v1": 50.0}, measured) == []
-    # missing baseline entry entirely: skipped, not a crash
-    assert bench.regression_sentry({}, measured) == []
-
-
 def test_obs_gen2_cli_flags():
     from distributed_deep_learning_tpu.utils.config import parse_args
 
@@ -381,35 +348,17 @@ def test_obs_gen2_cli_flags():
             parse_args(argv, workload="mlp")
 
 
-def test_regress_from_record_file(tmp_path):
-    """BENCH_REGRESS_FROM: judge an existing bench record without
-    running benches — exit 3 on breach, 0 clean, 2 unusable."""
-    import bench
-
-    bad = tmp_path / "bad.json"
-    bad.write_text(json.dumps({"measured": {
-        "cpu:obs_trace_overhead_fraction_v1": 0.9}}) + "\n")
-    good = tmp_path / "good.json"
-    good.write_text(json.dumps({"measured": {
-        "cpu:obs_trace_overhead_fraction_v1": 0.005}}) + "\n")
-    empty = tmp_path / "empty.json"
-    empty.write_text(json.dumps({"platform": "cpu"}) + "\n")
-    assert bench.regress_from(str(bad)) == 3
-    assert bench.regress_from(str(good)) == 0
-    assert bench.regress_from(str(empty)) == 2
-    assert bench.regress_from(str(tmp_path / "missing.json")) == 2
-
-
 # --- engine integration: the causal chain out of a real run ---------------
 
 def test_paged_engine_emits_causal_trace(tmp_path):
     from distributed_deep_learning_tpu.obs import RunTelemetry
-    from distributed_deep_learning_tpu.serve.bench import (build_model,
-                                                           run_paged)
+    from distributed_deep_learning_tpu.models.transformer import (
+        random_causal_lm)
+    from distributed_deep_learning_tpu.serve.engine import PagedEngine
     from distributed_deep_learning_tpu.serve.load import (LoadSpec,
                                                           make_load)
 
-    model, params = build_model(
+    model, params = random_causal_lm(
         seed=3, vocab_size=61, num_layers=1, d_model=32, num_heads=4,
         mlp_dim=64, max_len=96)
     spec = LoadSpec(n_requests=6, arrival="front", prompt_short=(4, 8),
@@ -419,10 +368,9 @@ def test_paged_engine_emits_causal_trace(tmp_path):
     trace_path = str(tmp_path / "trace.json")
     t = RunTelemetry(path=str(tmp_path / "ev.jsonl"),
                      trace_path=trace_path)
-    out = run_paged(model, params,
-                    make_load(spec, vocab_size=61, seed=3),
-                    telemetry=t, max_slots=3, max_len=96,
-                    kv_block_size=8, prefill_chunk=8)
+    out = PagedEngine(model, params, max_slots=3, max_len=96,
+                      kv_block_size=8, prefill_chunk=8).run(
+        make_load(spec, vocab_size=61, seed=3), telemetry=t)
     summary = t.close()
     assert summary["trace"]["spans"] > 0
     assert out["stats"]["window"]["ttft_count"] >= 1

@@ -1,5 +1,5 @@
 """Packed sample cache: round-trip parity, resume determinism, error
-paths, and the pack/feed-bench script surfaces.
+paths, and the pack script's surface.
 
 The contract under test (``data/packed.py``): packing a dataset and
 reading it back through the mmap'd ``PackedDataset`` is invisible to
@@ -316,33 +316,3 @@ def test_pack_dataset_script_smoke(image_root, tmp_path):
     assert os.path.getsize(out) == line["bytes"]
     assert len(PackedDataset(out)) == 6
 
-
-def test_feed_bench_script_smoke(image_root, tmp_path):
-    report = str(tmp_path / "feed.json")
-    proc = _run_script("feed_bench.py", "--data-dir", image_root,
-                       "--image-size", "8", "--batch", "4",
-                       "--epochs", "2", "--out", report)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    with open(report) as f:
-        line = json.load(f)
-    assert line["packed_images_per_sec"] > 0
-    assert line["eager_images_per_sec"] > 0
-    # the tiny PNG fixture already shows a multiple; the 20x floor is
-    # asserted on the JPEG bench fixture (bench.py / acceptance runs),
-    # not here where 24 images make timing noisy
-    assert line["speedup"] is not None
-
-
-# --- bench satellite: recorded TPU MFU fallback -----------------------------
-
-def test_bench_recorded_mfu_helper():
-    sys.path.insert(0, REPO)
-    import bench
-
-    assert bench._recorded_mfu({}) is None
-    assert bench._recorded_mfu({"tpu:resnet50_mfu_v1": 0.29}) == 0.29
-    assert bench._recorded_mfu({"tpu:resnet50_mfu_v1": None}) is None
-    # the shipped baseline file carries the r5 validation datum, so the
-    # driver's CPU-fallback line gets a non-null mfu (VERDICT #5b)
-    with open(os.path.join(REPO, "bench_baseline.json")) as f:
-        assert bench._recorded_mfu(json.load(f)) is not None

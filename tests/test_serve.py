@@ -1,5 +1,5 @@
 """Continuous-batching engine: compile-once proof, generate() parity,
-scheduler semantics, and the serve_bench script smoke.
+and scheduler semantics.
 
 The two load-bearing guarantees (ISSUE 2 acceptance):
 
@@ -15,10 +15,6 @@ The two load-bearing guarantees (ISSUE 2 acceptance):
 """
 
 import functools
-import json
-import os
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -27,8 +23,6 @@ import pytest
 
 from distributed_deep_learning_tpu.models.transformer import (CausalLM,
                                                               generate)
-from distributed_deep_learning_tpu.serve.bench import (make_trace,
-                                                       run_naive)
 from distributed_deep_learning_tpu.serve.engine import (ServeEngine,
                                                         default_buckets)
 from distributed_deep_learning_tpu.serve.scheduler import (Request,
@@ -245,59 +239,3 @@ def test_config_serve_flags():
     with pytest.raises(SystemExit, match="prefill-buckets"):
         parse_args(["--prefill-buckets", "8,x"], workload="gpt")
 
-
-def test_serve_bench_script_smoke(tmp_path):
-    """Micro-shape end-to-end run of scripts/serve_bench.py: one JSON
-    line with the engine/naive/speedup record and the compile-once
-    datum (heavy default shapes run under -m slow below)."""
-    out_file = tmp_path / "serve.json"
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    proc = subprocess.run(
-        [sys.executable, os.path.join(os.path.dirname(__file__), os.pardir,
-                                      "scripts", "serve_bench.py"),
-         "--requests", "4", "--max-slots", "2", "--prompt-min", "2",
-         "--prompt-max", "8", "--new-min", "2", "--new-max", "6",
-         "--layers", "1", "--d-model", "32", "--heads", "2",
-         "--mlp-dim", "64", "--vocab", "64", "--max-len", "32",
-         "--out", str(out_file)],
-        env=env, capture_output=True, text=True, timeout=300)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    rec = json.loads(out_file.read_text())
-    assert rec["engine"]["decode_compiles"] == 1
-    assert rec["engine"]["tokens_per_sec"] > 0
-    assert rec["naive"]["tokens_per_sec"] > 0
-    assert rec["speedup"] is not None
-    assert 0 < rec["engine"]["mean_slot_occupancy"] <= 2
-
-
-@pytest.mark.slow
-def test_serve_bench_engine_beats_naive_at_default_shapes():
-    """The acceptance datum: at the default CPU-CI trace the engine's
-    tokens/sec beats run-to-completion generate() (measured ~1.8x; the
-    assert leaves headroom for a loaded box)."""
-    from distributed_deep_learning_tpu.serve.bench import serving_bench
-
-    rec = serving_bench()
-    assert rec["engine"]["decode_compiles"] == 1
-    assert rec["speedup"] > 1.1, rec
-
-
-def test_naive_baseline_counts_and_results():
-    """run_naive: per-shape compiles, useful-token accounting, trimmed
-    per-request outputs."""
-    model, params = _shared()
-    reqs = make_trace(3, vocab_size=61, seed=2, prompt_lens=(4, 4),
-                      new_tokens=(3, 6))
-    out = run_naive(model, params, reqs, batch_size=2)
-    s = out["stats"]
-    assert s["generated_tokens"] == sum(r.max_new_tokens for r in reqs)
-    assert s["compiles"] >= 1
-    assert 0 <= s["wasted_fraction"] < 1
-    # equal prompt lengths: the naive batch path IS generate(), so rows
-    # must match the per-request reference exactly (trimmed to budget)
-    for r in reqs:
-        assert len(out["results"][r.uid]) == r.max_new_tokens
-        ref = generate(model, params, jnp.asarray(r.prompt)[None],
-                       max_new_tokens=r.max_new_tokens)
-        np.testing.assert_array_equal(out["results"][r.uid],
-                                      np.asarray(ref)[0, :r.max_new_tokens])

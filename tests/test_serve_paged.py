@@ -635,25 +635,17 @@ def test_cli_accepts_paged_flags():
     assert cfg.slo_ttft_ms == 500.0 and cfg.slo_e2e_ms is None
 
 
-# --- bench harness (one place defines the load shapes) -----------------
+# --- the paged cache's capacity rule -----------------------------------
 
 
-def test_paged_serving_bench_record_fields():
-    from distributed_deep_learning_tpu.serve.bench import \
-        paged_serving_bench
-
-    rec = paged_serving_bench(
-        model_kw=MODEL, max_slots=2, kv_block_size=8, prefill_chunk=8,
-        load_kw=dict(n_requests=4, arrival="front", rate=None,
-                     prompt_short=(3, 6), prompt_long=(10, 16),
-                     shared_prefix_len=6, shared_frac=0.5,
-                     new_tokens=(2, 6), slo_ttft_ms=60000.0,
-                     slo_e2e_ms=60000.0),
-        compare_engine=False)
-    pe = rec["paged_engine"]
-    for key in ("prefix_hit_rate", "slo_attainment", "spec_acceptance",
-                "chunk_compiles", "decode_compiles", "latency"):
-        assert key in pe, key
-    assert pe["decode_compiles"] == 1
-    assert rec["errors"] == 0
-    assert pe["slo"]["slo_checked"] == 4
+@pytest.mark.parametrize("args,want", [
+    ((100, 16, False, 4), 96),      # whole blocks; spec_k idle without a draft
+    ((100, 16, True, 4), 91),       # spec_k + 1 positions of verify headroom
+    ((20, 16, True, 4), None),      # 16 - 5 leaves less than one block
+], ids=["plain", "draft-headroom", "too-small"])
+def test_paged_max_len(args, want):
+    if want is None:
+        with pytest.raises(ValueError, match="too small for block size 16"):
+            paged.paged_max_len(*args)
+    else:
+        assert paged.paged_max_len(*args) == want

@@ -464,14 +464,6 @@ def test_cli_accepts_quant_flags():
     assert parse_args([]).kv_dtype is None
 
 
-def test_serve_bench_cli_rejects_int8_kv_without_paged(capsys):
-    import scripts.serve_bench as sb
-
-    with pytest.raises(SystemExit):
-        sb.main(["--kv-dtype", "int8"])
-    assert "requires --paged" in capsys.readouterr().err
-
-
 def test_plan_lattice_quant_axes():
     from distributed_deep_learning_tpu.tune.space import (Plan,
                                                           enumerate_plans)
@@ -499,22 +491,3 @@ def test_plan_lattice_quant_axes():
 
 # --- bench record -------------------------------------------------------
 
-
-def test_quantized_bench_record_fields():
-    from distributed_deep_learning_tpu.serve.bench import (
-        quantized_serving_bench)
-
-    rec = quantized_serving_bench(
-        load_kw=dict(n_requests=3, shared_prefix_len=8,
-                     prompt_short=(3, 6), prompt_long=(8, 12),
-                     new_tokens=(2, 6)),
-        model_kw=MODEL, max_slots=2, kv_block_size=8)
-    for key in ("kv_shrink_x", "token_agreement", "logprob_drift",
-                "declared_drift_bound", "baseline", "quantized"):
-        assert key in rec, key
-    assert rec["quantized"]["decode_compiles"] == 1
-    assert rec["baseline"]["decode_compiles"] == 1
-    assert rec["kv_shrink_x"] > 1.5   # tiny head_dim: scales cost more
-    assert rec["quantized"]["max_context_at_budget"] > \
-        rec["baseline"]["max_context_at_budget"]
-    assert rec["logprob_drift"] <= rec["declared_drift_bound"]

@@ -32,8 +32,8 @@ import pytest
 from distributed_deep_learning_tpu.models.transformer import CausalLM
 from distributed_deep_learning_tpu.serve.admission import (
     AdmissionController)
-from distributed_deep_learning_tpu.serve.bench import make_trace
 from distributed_deep_learning_tpu.serve.engine import PagedEngine
+from distributed_deep_learning_tpu.serve.load import make_trace
 from distributed_deep_learning_tpu.serve.reload import (CanaryRollback,
                                                         CheckpointCorruption,
                                                         ReloadManager,
@@ -350,6 +350,32 @@ def test_shed_burst_cannot_starve_admitted_interactive_request():
     assert adm.stats()["shed_total"] == len(shed)
 
 
+# --- the CLI's supervised serve driver ---------------------------------
+
+
+@pytest.mark.parametrize("engine_argv,line", [
+    (["--prefill-buckets", "4,8"], '"serve: '),
+    (["--paged", "--kv-block-size", "8", "--prefill-chunk", "8"],
+     '"serve(paged): 8 requests (8 completed, 0 errors)'),
+], ids=["v1", "paged"])
+def test_cli_serves_under_the_supervisor(capsys, monkeypatch, engine_argv,
+                                         line):
+    """``--serve-deadline-ms`` puts the engine under ``run_supervised``:
+    the supervisor's line, then the engine's own with one decode compile."""
+    from distributed_deep_learning_tpu.workloads import (get_spec,
+                                                         run_workload)
+
+    monkeypatch.setenv("DDL_DATA_LIMIT", "128")
+    config = parse_args(["-l", "1", "-s", "32", "-e", "1", "-b", "16",
+                         "--serve", "--max-slots", "2",
+                         "--serve-deadline-ms", "600000", *engine_argv],
+                        workload="gpt")
+    run_workload(get_spec("gpt"), config)
+    out = capsys.readouterr().out
+    assert '"serve(supervised): restarts=0, lost=0, deadline_misses=0' in out
+    assert line in out and "tok/s" in out and "decode=1" in out
+
+
 # --- CLI validation (satellite: parse-time, clear SystemExit) ----------
 
 
@@ -389,34 +415,6 @@ def test_parse_admission_arg_none_passthrough():
     assert parse_admission_arg(None) is None
     assert parse_admission_arg("patience=2,cool=4") == {"patience": 2,
                                                        "cool": 4}
-
-
-# --- baseline hygiene (satellite: finite-numeric gate) -----------------
-
-
-def test_check_baselines_rejects_nonfinite_and_stringly_values():
-    import importlib.util
-    import sys
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location(
-        "check_baselines", os.path.join(repo, "scripts",
-                                        "check_baselines.py"))
-    mod = importlib.util.module_from_spec(spec)
-    sys.path.insert(0, repo)
-    try:
-        spec.loader.exec_module(mod)
-    finally:
-        sys.path.remove(repo)
-    bands = {"x_v1": ("higher", 0.5)}
-    assert mod.check({"cpu:x_v1": 1.0}, bands, frozenset()) == []
-    probs = mod.check({"cpu:x_v1": float("nan")}, bands, frozenset())
-    assert any("non-finite" in p for p in probs)
-    probs = mod.check({"cpu:x_v1": "fast"}, bands, frozenset())
-    assert any("non-numeric" in p for p in probs)
-    # allowlisted history keys may carry non-scalar records
-    assert mod.check({"cpu:x_v1": 1.0, "tpu:hist": [1, 2]}, bands,
-                     frozenset({"tpu:hist"})) == []
 
 
 # --- the full drill (slow: every scenario end to end) ------------------
