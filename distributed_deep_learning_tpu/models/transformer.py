@@ -28,6 +28,7 @@ import numpy as np
 
 from distributed_deep_learning_tpu.models.moe import (ExpertSpec, GatedMLP,
                                                       RoutedExperts)
+from distributed_deep_learning_tpu.runtime.batch_pin import pin_batch
 
 AttentionFn = Callable[..., jnp.ndarray]
 dense_init = nn.initializers.xavier_uniform()
@@ -234,6 +235,9 @@ class MultiHeadAttention(nn.Module):
             spec = None if self.rope is True else self.rope
             q = apply_rope(q, positions, spec=spec)
             k = apply_rope(k, positions, spec=spec)  # cached K stay rotated
+        # under a sharded step every value a module hands on stays on the
+        # batch axes (runtime.batch_pin): the weights come to the rows
+        q, k, v = pin_batch(q), pin_batch(k), pin_batch(v)
         attn = self.attention_fn or dot_product_attention
         y = None
         if self.decode:
@@ -318,8 +322,8 @@ class MultiHeadAttention(nn.Module):
         if y is None:
             with jax.named_scope("attn_window" if self.window is not None
                                  else "attn_full"):
-                y = attn(q, k, v, mask=mask, key_valid=key_valid,
-                         causal=causal, dtype=self.dtype, **kw)
+                y = pin_batch(attn(q, k, v, mask=mask, key_valid=key_valid,
+                                   causal=causal, dtype=self.dtype, **kw))
         if self.gate:
             # headwise gate of arXiv:2505.06708: a sigmoid of a per-head
             # projection of the layer's input scales each head's output
@@ -403,6 +407,8 @@ class TransformerLayer(nn.Module):
     @nn.compact
     def __call__(self, x, encoded=None, *, self_valid=None, cross_valid=None,
                  train: bool = False):
+        # x comes in pinned to the batch axes: the embedding and every
+        # layer pin what they hand on (runtime.batch_pin)
         h = _norm(self.norm, self.dtype, self.ln_eps)(x)
         h = MultiHeadAttention(self.num_heads, self.dtype, self.attention_fn,
                                decode=self.decode, rope=self.rope,
@@ -414,19 +420,19 @@ class TransformerLayer(nn.Module):
                                name="self_attn")(h, h, self_valid,
                                                  causal=self.causal)
         h = nn.Dropout(self.dropout_rate, deterministic=not train)(h)
-        x = x + h
+        x = pin_batch(x + h)
         if self.cross_attention:
             h = _norm(self.norm, self.dtype, self.ln_eps)(x)
             h = MultiHeadAttention(self.num_heads, self.dtype,
                                    self.attention_fn,
                                    name="cross_attn")(h, encoded, cross_valid)
             h = nn.Dropout(self.dropout_rate, deterministic=not train)(h)
-            x = x + h
+            x = pin_batch(x + h)
         h = _norm(self.norm, self.dtype, self.ln_eps)(x)
         if self.mlp == "gelu":
             h = nn.Dense(self.mlp_dim, dtype=self.dtype,
                          use_bias=self.use_bias, kernel_init=dense_init)(h)
-            h = nn.gelu(h)
+            h = nn.gelu(pin_batch(h))
             h = nn.Dense(x.shape[-1], dtype=self.dtype,
                          use_bias=self.use_bias, kernel_init=dense_init)(h)
         elif self.mlp == "swiglu":
@@ -438,7 +444,7 @@ class TransformerLayer(nn.Module):
             raise ValueError(f"mlp must be 'gelu', 'swiglu' or 'experts', "
                              f"got {self.mlp!r}")
         h = nn.Dropout(self.dropout_rate, deterministic=not train)(h)
-        return x + h
+        return pin_batch(x + pin_batch(h))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -474,7 +480,7 @@ class Embed(nn.Module):
                        embedding_init=nn.initializers.normal(0.02),
                        dtype=self.dtype, name="tok")
         if not self.use_pos:
-            return emb(tokens), emb
+            return pin_batch(emb(tokens)), emb
         pos = self.param("pos", nn.initializers.normal(0.02),
                          (self.max_len, self.d_model))
         T = tokens.shape[1]
@@ -490,7 +496,7 @@ class Embed(nn.Module):
                               lambda: jnp.zeros((), jnp.int32))
             p = pos[:T]
         x = emb(tokens) + p[None].astype(self.dtype)
-        return x, emb
+        return pin_batch(x), emb
 
     @staticmethod
     def logits(x, table):
@@ -503,8 +509,8 @@ class Embed(nn.Module):
         """
         with jax.named_scope("head"):
             table = jnp.asarray(table, jnp.float32)
-            return jnp.einsum("...d,vd->...v", x.astype(jnp.float32),
-                              table)
+            return pin_batch(jnp.einsum(
+                "...d,vd->...v", pin_batch(x).astype(jnp.float32), table))
 
 
 class TransformerSeq2Seq(nn.Module):
@@ -635,7 +641,7 @@ class CausalLM(nn.Module):
         # the CLI/workload convention wants logits (token_cross_entropy +
         # argmax metrics); the bench path keeps hidden states and the
         # fused head (loss()) so (B·T, V) never materialises
-        return Embed.logits(x, head) if self.with_logits else x
+        return Embed.logits(x, head) if self.with_logits else pin_batch(x)
 
     def _table(self, params):
         if not self.tie_head:

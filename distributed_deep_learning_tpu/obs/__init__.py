@@ -147,6 +147,13 @@ class RunTelemetry:
         self.writer.emit("obs_mfu", **rec)
         self.writer.emit("obs_snapshot", snapshot=snap)
         summary = {"goodput": gp, "mfu": rec, "snapshot": snap}
+        # what the run's programs said of themselves as they were traced
+        # (batch_pins of a train step, attn_paths of a decode program)
+        notes = [{"program": fun, "note": event, "text": text}
+                 for event, fun, text in compile_log.notes()]
+        if notes:
+            self.writer.emit("obs_programs", notes=notes)
+            summary["programs"] = notes
         if self.memory.samples or self.memory.steps:
             mem = self.memory.summary()
             self.writer.emit("obs_memory", **mem)

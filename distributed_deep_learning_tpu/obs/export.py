@@ -10,6 +10,8 @@ reader (:func:`read_events`) serves both.  Obs-specific events:
 * ``obs_mfu``      — an ``mfu.mfu_record`` dict.
 * ``obs_snapshot`` — a full ``MetricsRegistry.snapshot()``.
 * ``obs_serve``    — serve engine stats (latency percentiles included).
+* ``obs_programs`` — the compile log's notes: what each program said of
+  itself as it was traced (``batch_pins``, ``attn_paths``).
 
 :func:`prometheus_text` renders a registry snapshot in the Prometheus
 text exposition format (cumulative ``le`` buckets, ``_sum``/``_count``)

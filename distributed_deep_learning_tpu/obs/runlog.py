@@ -100,11 +100,15 @@ class CompileLog:
         if short == "trace":
             # JAX reports the trace of every jitted function a program
             # calls (thousands in a 48-layer model), each before the
-            # program's own: keep the outermost, which contains them
-            entries = self.entries
-            while entries and entries[-1][0] == "trace" \
+            # program's own: keep the outermost, which contains them, and
+            # what the program noted about itself while it was traced
+            entries, kept = self.entries, []
+            while entries and entries[-1][0] != MARK \
                     and entries[-1][2] >= start:
-                entries.pop()
+                inner = entries.pop()
+                if inner[0] != "trace":
+                    kept.append(inner)
+            entries.extend(reversed(kept))
         self.entries.append((short, fun_name, start, end - start))
 
     def _duration(self, event, seconds, **_):
@@ -120,6 +124,12 @@ class CompileLog:
                 break
             out.append(e)
         return out[::-1]
+
+    def notes(self) -> list:
+        """``(event, fun_name, text)`` of every :meth:`note` since the
+        newest mark."""
+        return [(event, fun, text) for event, fun, _, text
+                in self.since_mark() if isinstance(text, str)]
 
     def seconds(self, names: Iterable[str],
                 events: Iterable[str] = ("trace", "lower"),

@@ -568,15 +568,13 @@ def _per_shard(kernel, q, k, v, key_valid):
     enclosing ``shard_map``) or of size 1 need nothing, and with none left
     the kernel is called directly — one device, or no mesh at all.
     """
-    from jax.sharding import AxisType, PartitionSpec as P
+    from jax.sharding import PartitionSpec as P
 
-    from distributed_deep_learning_tpu.data.loader import BATCH_AXES
+    from distributed_deep_learning_tpu.runtime.batch_pin import (
+        split_axes, split_batch_axes)
 
-    mesh = jax.sharding.get_abstract_mesh()
-    split = {name for name, kind in zip(mesh.axis_names, mesh.axis_types)
-             if kind == AxisType.Auto and mesh.shape[name] > 1}
-    batch = tuple(a for a in BATCH_AXES if a in split) or None
-    heads = "model" if "model" in split else None
+    batch = split_batch_axes() or None
+    heads = "model" if "model" in split_axes() else None
     args = (q, k, v) if key_valid is None else (q, k, v, key_valid)
     if batch is None and heads is None:
         return kernel(*args)

@@ -7,7 +7,11 @@ train step (:mod:`..train.step`) is already one jitted program threading a
 XLA's SPMD partitioner reduce-scatter gradients into the shard, update
 sharded, and all-gather updated params — the ZeRO-1 dataflow — entirely via
 compiler-inserted ICI collectives.  Sharding params too (``fsdp_spec``)
-gives the ZeRO-3/FSDP dataflow the same way.
+gives the ZeRO-3/FSDP dataflow the same way, provided the activations stay
+on the batch axes: the transformer pins them there
+(:func:`..runtime.batch_pin.pin_batch`), or the partitioner trades the
+batch sharding for a feature sharding over the same axis and reshards
+activations with ``all-to-all`` between a layer's products.
 
 Rules are computed per-leaf: shard the largest dimension divisible by the
 ``fsdp`` axis size, leave small leaves (below ``min_leaf_size`` elements)

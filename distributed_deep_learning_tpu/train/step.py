@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributed_deep_learning_tpu.data.loader import BATCH_AXES
+from distributed_deep_learning_tpu.runtime.batch_pin import pins_noted
 from distributed_deep_learning_tpu.train.objectives import prediction_metrics
 from distributed_deep_learning_tpu.train.state import TrainState
 from distributed_deep_learning_tpu.utils.config import REMAT_POLICIES
@@ -41,11 +42,14 @@ def _state_sharding(mesh: Mesh, state_spec):
 def under_mesh(mesh: Mesh):
     """Decorator for a step body: trace it with `mesh` as the ambient one,
     so code deep in the model can see how its inputs are split — the flash
-    kernel must run per shard (``ops.attention_pallas._per_shard``)."""
+    kernel must run per shard (``ops.attention_pallas._per_shard``) and
+    the activations stay on the batch axes (``runtime.batch_pin``, whose
+    note in the compile log says what this trace pinned)."""
     def decorate(step):
         @functools.wraps(step)
         def traced(*args):
-            with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            with jax.sharding.use_abstract_mesh(mesh.abstract_mesh), \
+                    pins_noted(f"jit({step.__name__})"):
                 return step(*args)
         return traced
     return decorate

@@ -285,6 +285,7 @@ def render(events: list[dict], phases: bool = False) -> str:
     mfu = None
     serve = []
     snapshot = None
+    programs = []
     for ev in events:
         kind = ev.get("event")
         if kind == "obs_goodput":
@@ -298,6 +299,8 @@ def render(events: list[dict], phases: bool = False) -> str:
             serve.append(ev.get("stats", {}))
         elif kind == "obs_snapshot":
             snapshot = ev.get("snapshot", {})
+        elif kind == "obs_programs":
+            programs += ev.get("notes", [])
 
     out = []
     if run_gp is not None:
@@ -327,6 +330,13 @@ def render(events: list[dict], phases: bool = False) -> str:
         else:
             out.append("  MFU             n/a (no peak-FLOPs table entry "
                        "for this device; set DDL_OBS_PEAK_FLOPS)")
+    if programs:
+        # what each program said of itself as it was traced: the batch
+        # axes a train step's activations were pinned to and at how many
+        # sites, the paths a decode program's attention layers took
+        out.append("== programs, as traced ==")
+        out += [f"  {n.get('program')}: {n.get('note')} {n.get('text')}"
+                for n in programs]
     if snapshot is not None:
         comm = _comm_block(snapshot)
         if comm:

@@ -338,11 +338,22 @@ def train_flash_calls(v5e, request):
     argv, chips, heads = TRAIN_CELLS[request.param]
     with pytest.MonkeyPatch.context() as on_tpu:
         on_tpu.setattr(jax, "default_backend", lambda: "tpu")
-        rows, calls = _trace_train_step(argv, v5e[:chips])
+        rows, traced = _trace_train_step(argv, v5e[:chips])
+    assert traced.lower().as_text().count("tpu_custom_call") == 6
+    calls = []
+    for eqn in _pallas_calls(traced.jaxpr.jaxpr, []):
+        assert eqn.params["interpret"] is False
+        mapping = eqn.params["grid_mapping"]
+        calls.append((
+            eqn.params["jaxpr"].debug_info.func_name, tuple(mapping.grid),
+            [tuple(getattr(d, "block_size", d) for d in m.block_shape)
+             for m in mapping.block_mappings]))
     return rows // chips, heads, calls
 
 
 def _trace_train_step(argv, devices):
+    """``(rows, the CLI's train step traced for `devices`)``, state and
+    batch as shapes with the shardings the CLI gives them."""
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec
 
@@ -380,17 +391,7 @@ def _trace_train_step(argv, devices):
         s.shape, s.dtype, sharding=sh), state, sharding)
     x = jax.ShapeDtypeStruct((rows, T), jnp.int32, sharding=NamedSharding(
         mesh, PartitionSpec(BATCH_AXES)))
-    traced = train_step.trace(state, x, x)
-    assert traced.lower().as_text().count("tpu_custom_call") == 6
-    calls = []
-    for eqn in _pallas_calls(traced.jaxpr.jaxpr, []):
-        assert eqn.params["interpret"] is False
-        mapping = eqn.params["grid_mapping"]
-        calls.append((
-            eqn.params["jaxpr"].debug_info.func_name, tuple(mapping.grid),
-            [tuple(getattr(d, "block_size", d) for d in m.block_shape)
-             for m in mapping.block_mappings]))
-    return rows, calls
+    return rows, train_step.trace(state, x, x)
 
 
 @pytest.mark.parametrize("kernels", [("_fwd_kernel",),
@@ -412,3 +413,101 @@ def test_train_step_lowers_flash_at_512_blocks(train_flash_calls, kernels):
         assert rows_at_a_time == {512, T}, blocks
         masks = [b for b in blocks if len(b) == 4]
         assert masks and all(b[-1] == 512 for b in masks), blocks
+
+
+# --- cell 4's step under FSDP: the weights come to the rows ---------------
+
+_COLLECTIVE = re.compile(
+    r"^\s*(?:ROOT )?%\S+ = (?P<type>.*?) (?P<kind>all-gather|all-reduce|"
+    r"reduce-scatter|all-to-all|collective-permute)(?:-start)?\((?P<rest>.*)$",
+    re.M)
+
+
+@pytest.fixture(scope="module")
+def fsdp_collectives(v5e):
+    """The collectives of the two-layer ``gpt2xl-train-fsdp4`` step COMPILED
+    for the four described chips, one entry a channel: ``(kind, dtype,
+    dims, op_name)`` (about half a minute)."""
+    argv, chips, _ = TRAIN_CELLS["gpt2xl-train-fsdp4"]
+    with pytest.MonkeyPatch.context() as on_tpu:
+        on_tpu.setattr(jax, "default_backend", lambda: "tpu")
+        _, traced = _trace_train_step(argv, v5e[:chips])
+    text = traced.lower().compile().as_text()
+    found = {}
+    for m in _COLLECTIVE.finditer(text):
+        channel = re.search(r"channel_id=(\d+)", m["rest"])
+        op_name = re.search(r'op_name="([^"]*)"', m["rest"])
+        dtype, dims = re.search(r"(\w+)\[([\d,]*)\]", m["type"]).groups()
+        found.setdefault(
+            (m["kind"], channel[1] if channel else m.start()),
+            (m["kind"], dtype, tuple(int(d) for d in dims.split(",") if d),
+             op_name[1] if op_name else ""))
+    return list(found.values())
+
+
+def _no_activation_is_resharded(request):
+    """Between the embedding and the loss no ``all-to-all`` is left (the
+    one pair that stays is the lookup's own: rows out of a table split on
+    its features) and nothing that holds a sequence is permuted.  The
+    permutes that stay are the gradients' reduce-scatter: XLA's windowed
+    einsum passes a SHARD of a kernel's gradient round the ring inside
+    the backward's weight-gradient product."""
+    found = request.getfixturevalue("fsdp_collectives")
+    stray = [c for c in found if c[0] == "all-to-all"
+             and "/embed/" not in c[3]]
+    assert not stray, stray
+    permutes = [c for c in found if c[0] == "collective-permute"]
+    grads = re.compile(r"transpose\(jvp\(CausalLM\)\)/layer_\d+/"
+                       r"(self_attn/(q|k|v|out)|Dense_[01])/dot_general$")
+    stray = [c for c in permutes if not grads.search(c[3]) or T in c[2]
+             or c[2] not in {(400, 25, 64), (25, 64, 400), (1600, 1600)}]
+    assert not stray, stray
+    assert not [c for c in found if c[0] == "reduce-scatter"]
+
+
+def _layer_kernels_are_gathered_in_bf16(request):
+    """Each of a layer's six kernels is all-gathered whole for the forward
+    product (and again for the backward, unless the scheduler still holds
+    it), as the bf16 cast the model computes in; no f32 all-gather is left
+    in the layer stack."""
+    found = request.getfixturevalue("fsdp_collectives")
+    gathers = [c for c in found if c[0] == "all-gather" and "/layer_" in c[3]]
+    assert {c[1] for c in gathers} == {"bf16"}, gathers
+    whole = {"q": (1600, 25, 64), "k": (1600, 25, 64), "v": (1600, 25, 64),
+             "out": (25, 64, 1600), "Dense_0": (1600, 6400),
+             "Dense_1": (6400, 1600)}
+    for layer in range(2):
+        for name, dims in whole.items():
+            assert [c for c in gathers if c[2] == dims and re.search(
+                rf"jvp\(CausalLM\)/layer_{layer}/(self_attn/)?{name}"
+                r"/dot_general$", c[3])], (layer, name, gathers)
+    assert len(gathers) > 12       # and most of them again for the backward
+
+
+def _one_chip_step_is_the_same_program(request):
+    """Where no batch axis is split the helper emits nothing: the
+    ``gpt2m-train-1chip`` step lowers to the same text with it as with it
+    stubbed to the identity."""
+    from distributed_deep_learning_tpu.models import transformer
+
+    v5e = request.getfixturevalue("v5e")
+    argv, chips, _ = TRAIN_CELLS["gpt2m-train-1chip"]
+    texts = []
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        for stubbed in (False, True):
+            if stubbed:
+                patch.setattr(transformer, "pin_batch", lambda x: x)
+            _, traced = _trace_train_step(argv, v5e[:chips])
+            texts.append(traced.lower().as_text())
+    assert "sharding_constraint" not in texts[0]
+    assert texts[0] == texts[1]
+
+
+@pytest.mark.parametrize("held", [
+    _no_activation_is_resharded, _layer_kernels_are_gathered_in_bf16,
+    _one_chip_step_is_the_same_program], ids=lambda f: f.__name__.strip("_"))
+def test_fsdp_step_brings_the_weights_to_the_rows(request, held):
+    """What ``runtime.batch_pin`` buys cell 4, read off the program the
+    chip's compiler makes of it, and what it must not cost cell 1."""
+    held(request)

@@ -23,12 +23,13 @@ IMPORTS = {
     "obs": {"utils"},
     "ops": {
         # no "utils": the flash kernel's block sizes are its own constants
-        "data",      # UP: attention_pallas._per_shard reads data.loader.BATCH_AXES (D14)
         "models",    # UP: the dense fallback and the cache-leaf names (D14)
         "serve",     # UP: paged_decode_pallas reads serve.quant.is_quant (D14)
+        "runtime",   # attention_pallas._per_shard asks runtime.batch_pin what the mesh splits
     },
     "models": {
         "ops",
+        "runtime",   # models.transformer pins activations: runtime.batch_pin
         "parallel",  # UP: models.pipelined_lm builds on parallel.spmd_pipeline (D8)
     },
     "parallel": {

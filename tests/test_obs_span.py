@@ -354,6 +354,28 @@ def test_compile_log_names_a_retraced_program():
     assert {"paged_chunk", "paged_decode"} <= named
 
 
+def test_compile_log_keeps_a_note_made_anywhere_in_the_trace():
+    """A program may note a fact about itself at the END of its trace (a
+    count it only then knows): the note stays, before the program's own
+    trace entry, and the inner programs' traces before it still go."""
+    log = obs.compile_log
+    log.mark("test")
+
+    @jax.jit
+    def inner_piece(x):
+        return x + 1
+
+    def noting_step(x):
+        y = inner_piece(inner_piece(x) * 2)
+        log.note("sites", "jit(noting_step)", "n=2")
+        return y
+
+    jax.jit(noting_step)(jnp.ones(3))
+    mine = [e[:2] for e in log.since_mark()
+            if e[0] in ("trace", "sites")]
+    assert mine == [("sites", "jit(noting_step)"), ("trace", "noting_step")]
+
+
 # ------------------------------------------------- scopes are metadata
 
 def _decode_args(eng):

@@ -4,6 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+import pytest
 
 from distributed_deep_learning_tpu.models.mlp import MLP
 from distributed_deep_learning_tpu.parallel.zero import (
@@ -82,3 +83,133 @@ class TestFsdp:
             s_fs, m_fs = step_fs(s_fs, x, y)
         np.testing.assert_allclose(float(m_dp["loss"]), float(m_fs["loss"]),
                                    rtol=1e-5)
+
+
+# --- activations pinned to the batch axes (runtime.batch_pin) -------------
+
+def _tiny_lm():
+    from distributed_deep_learning_tpu.models.transformer import CausalLM
+
+    return CausalLM(vocab_size=64, num_layers=2, d_model=32, num_heads=4,
+                    mlp_dim=64, max_len=16, with_logits=True, pad_id=None)
+
+
+def _lm_step(mesh_shape, devices, fsdp: bool):
+    """One SGD step at rate 1 of the tiny LM through the jitted step: the
+    loss, and the parameters after it (start minus the gradient)."""
+    from distributed_deep_learning_tpu.train.objectives import (
+        token_cross_entropy)
+
+    mesh = build_mesh(mesh_shape, devices)
+    tokens = jnp.asarray(np.random.default_rng(1).integers(1, 64, (8, 17)),
+                         jnp.int32)
+    state = create_train_state(_tiny_lm(), jax.random.key(0),
+                               tokens[:1, :-1], optax.sgd(1.0))
+    spec = fsdp_state_spec(state, mesh, min_leaf_size=16) if fsdp else P()
+    if fsdp:
+        assert spec.params["layer_0"]["Dense_0"]["kernel"] != P()
+    step, _ = make_step_fns(mesh, token_cross_entropy, state_spec=spec)
+    state, metrics = step(place_state(state, mesh, spec), tokens[:, :-1],
+                          tokens[:, 1:])
+    return float(metrics["loss"]), jax.tree.map(np.asarray, state.params)
+
+
+@pytest.mark.parametrize("mesh_shape", [{"fsdp": 4}, {"data": 2, "fsdp": 2}],
+                         ids=["fsdp4", "data2-fsdp2"])
+def test_pinned_fsdp_step_matches_the_unsharded_step(mesh_shape):
+    """With every activation held to the batch axes the sharded step is
+    still the same arithmetic: loss and gradient (SGD at rate 1 leaves
+    start - gradient) equal one device's, by this file's tolerances."""
+    from distributed_deep_learning_tpu import obs
+
+    loss_1, after_1 = _lm_step({"data": 1}, jax.devices()[:1], fsdp=False)
+    obs.compile_log.mark("test")
+    loss_n, after_n = _lm_step(mesh_shape, jax.devices()[:4], fsdp=True)
+    axes = ",".join(a for a in ("data", "fsdp") if a in mesh_shape)
+    notes = [e[3] for e in obs.compile_log.since_mark()
+             if e[:2] == ("batch_pins", "jit(train_step)")]
+    assert notes == [f"axes={axes} sites=19"]
+    np.testing.assert_allclose(loss_n, loss_1, rtol=1e-5)
+    for a, b in zip(jax.tree.leaves(after_n), jax.tree.leaves(after_1)):
+        np.testing.assert_allclose(a, b, atol=1e-5)
+
+
+def _constraints(jaxpr) -> list:
+    """The spec of every ``sharding_constraint`` equation in `jaxpr`,
+    nested ones too."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "sharding_constraint":
+            out.append(eqn.params["sharding"].spec)
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    out += _constraints(sub)
+    return out
+
+
+@pytest.mark.parametrize("mesh_shape, batch", [
+    (None, None), ({"data": 1}, None), ({"data": 1, "model": 2}, None),
+    ({"fsdp": 4}, "fsdp"), ({"data": 2, "model": 2}, "data")],
+    ids=["no-mesh", "one-device", "model-axis", "fsdp4", "data2-model2"])
+def test_pin_emits_nothing_where_no_batch_axis_is_split(monkeypatch,
+                                                        mesh_shape, batch):
+    """The helper adapts to what the ambient mesh splits: no mesh, one
+    device, a ``model`` axis alone -> the jaxpr is the one the model gives
+    with the helper stubbed to the identity, no ``sharding_constraint`` in
+    it; a split batch axis -> every site is pinned, its batch dimension
+    to that axis and every other dimension left to the partitioner."""
+    import contextlib
+
+    from distributed_deep_learning_tpu.models import transformer
+
+    model = _tiny_lm()
+    tokens = jnp.ones((8, 16), jnp.int32)
+    params = model.init(jax.random.key(0), tokens[:1])
+
+    def trace():
+        ambient = contextlib.nullcontext() if mesh_shape is None else \
+            jax.sharding.use_abstract_mesh(build_mesh(
+                mesh_shape,
+                jax.devices()[:int(np.prod(list(mesh_shape.values())))]
+            ).abstract_mesh)
+        with ambient:
+            return jax.make_jaxpr(
+                lambda p, t: model.apply(p, t))(params, tokens)
+
+    specs = _constraints(trace().jaxpr)
+    if batch is None:
+        assert specs == []
+        with_helper = str(trace())
+        monkeypatch.setattr(transformer, "pin_batch", lambda x: x)
+        assert with_helper == str(trace())
+    else:
+        assert len(specs) == 19
+        assert all(spec[0] == batch and set(spec[1:]) == {P.UNCONSTRAINED}
+                   for spec in specs), specs
+
+
+def test_obs_report_prints_what_the_step_pinned(tmp_path):
+    """The compile log's ``batch_pins`` note reaches the ``--obs`` stream
+    (``obs_programs``) and ``scripts/obs_report.py`` prints it: the axes
+    and the sites under a split batch axis, ``none`` and 0 on one device."""
+    import os
+    import subprocess
+    import sys
+
+    from distributed_deep_learning_tpu import obs
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for mesh_shape, devices, said in (
+            ({"fsdp": 4}, jax.devices()[:4], "axes=fsdp sites=19"),
+            ({"data": 1}, jax.devices()[:1], "axes=none sites=0")):
+        obs.compile_log.mark("test")
+        path = str(tmp_path / f"{len(devices)}.jsonl")
+        telemetry = obs.RunTelemetry(path)
+        _lm_step(mesh_shape, devices, fsdp=len(devices) > 1)
+        telemetry.close()
+        text = subprocess.run(
+            [sys.executable, os.path.join(repo, "scripts", "obs_report.py"),
+             path], capture_output=True, text=True, check=True).stdout
+        assert f"jit(train_step): batch_pins {said}" in text, text
