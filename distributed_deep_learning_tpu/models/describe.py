@@ -10,11 +10,21 @@ serves, one :class:`~.transformer.LayerSpec` a layer.
 What is read, by mechanism: RMSNorm pre-norm blocks without biases;
 grouped-query attention with a head size of its own and a head count,
 window and RoPE by layer kind (``full_attention`` / ``sliding_attention``;
-``default`` and ``yarn`` RoPE, partial rotary width); the per-head output
-gate (``gating``); a dense SwiGLU MLP on ``mlp_only_layers`` and routed
-experts elsewhere (softmax router, top-k, renormalised, scaled, one
-shared expert); a tied or untied head.  Per-layer lists may be longer
-than ``num_hidden_layers`` (a cut in depth keeps the leading layers).
+``default`` and ``yarn`` RoPE, partial rotary width), the RoPE under
+``rope_parameters`` or as a bare ``rope_theta``; latent attention
+(``kv_lora_rank`` with ``q_lora_rank``, ``qk_nope_head_dim``,
+``qk_rope_head_dim``, ``v_head_dim``: every layer, whole sequences); the
+per-head output gate (``gating``); a dense SwiGLU MLP on
+``mlp_only_layers`` or the first ``first_k_dense_replace`` layers and
+routed experts elsewhere, counted by ``num_experts`` or
+``n_routed_experts``: top-k, renormalised, scaled, with
+``shared_expert_intermediate_size`` or ``n_shared_experts`` shared
+width; the router a softmax, or with ``topk_method: noaux_tc`` a sigmoid
+whose choice adds a learned bias; a tied or untied head.  Per-layer lists
+may be longer than ``num_hidden_layers`` (a cut in depth keeps the
+leading layers).  ``num_nextn_predict_layers`` counts multi-token
+prediction modules behind the last layer, which the next-token forward
+never runs: none is built, whatever the count.
 
 Three keys are this package's own, for a chip's share of a layer:
 ``num_experts`` counts the experts HELD, ``router_experts`` the experts
@@ -32,6 +42,7 @@ import jax.numpy as jnp
 
 from distributed_deep_learning_tpu.models.moe import ExpertSpec
 from distributed_deep_learning_tpu.models.transformer import (CausalLM,
+                                                              LatentSpec,
                                                               LayerSpec,
                                                               RopeSpec)
 
@@ -40,7 +51,10 @@ from distributed_deep_learning_tpu.models.transformer import (CausalLM,
 _ONLY = {"moe_router_logit_softcapping": 0,
          "moe_apply_router_weight_on_input": False,
          "decoder_sparse_step": 1, "attention_bias": False,
-         "hidden_act": "silu"}
+         "hidden_act": "silu",
+         # position scaling comes as rope_parameters' rope_type, or not at
+         # all; grouped (node-limited) top-k is not computed
+         "rope_scaling": None, "n_group": 1, "topk_group": 1}
 
 
 def read(path: str) -> dict:
@@ -91,20 +105,22 @@ def layer_specs(desc: dict) -> tuple:
     if "mlp_layer_types" in desc:
         dense = {i for i, t in enumerate(desc["mlp_layer_types"][:n])
                  if t == "dense"}
-    ropes = desc["rope_parameters"]
+    if "first_k_dense_replace" in desc:
+        dense = set(range(int(desc["first_k_dense_replace"])))
+    ropes = desc.get("rope_parameters") or {
+        "rope_theta": desc["rope_theta"],
+        "partial_rotary_factor": desc.get("partial_rotary_factor", 1)}
     if "rope_theta" in ropes:           # one RoPE for every layer kind
         ropes = {"full_attention": ropes, "sliding_attention": ropes}
-    experts = None
-    if desc.get("num_experts"):
-        experts = ExpertSpec(
-            num_experts=int(desc["num_experts"]),
-            mlp_dim=int(desc["moe_intermediate_size"]),
-            top_k=int(desc["num_experts_per_tok"]),
-            router_experts=desc.get("router_experts"),
-            expert_offset=int(desc.get("expert_offset", 0)),
-            routed_scale=float(desc.get("moe_routed_scaling_factor", 1.0)),
-            norm_topk=bool(desc.get("norm_topk_prob", True)),
-            shared_dim=int(desc.get("shared_expert_intermediate_size", 0)))
+    latent = _latent(desc)
+    if latent is not None:
+        # the rotated part of a latent layer's head is a head of its own
+        if "sliding_attention" in kinds[:n]:
+            raise ValueError("latent attention (kv_lora_rank) with "
+                             "sliding_attention layers is not computed "
+                             "here: the latent cache keeps whole sequences")
+        head_dim = latent.rope_dim
+    experts = _experts(desc)
     out = []
     for i in range(n):
         if kinds[i] not in ("full_attention", "sliding_attention"):
@@ -124,8 +140,53 @@ def layer_specs(desc: dict) -> tuple:
             gate=gates[i] == "per_head", use_bias=False, norm="rms",
             mlp="experts" if routed else "swiglu",
             mlp_dim=int(desc["intermediate_size"]),
-            experts=experts if routed else None))
+            experts=experts if routed else None, latent=latent))
     return tuple(out)
+
+
+def _latent(desc: dict) -> Optional[LatentSpec]:
+    """The description's latent attention, where it has one."""
+    if not desc.get("kv_lora_rank"):
+        return None
+    if desc.get("gating") or desc.get("gating_types"):
+        raise ValueError("an output gate on latent attention is not "
+                         "computed here")
+    if desc.get("q_lora_rank") is None:
+        raise ValueError("model description: q_lora_rank null (queries "
+                         "projected without a bottleneck) is not computed "
+                         "here")
+    return LatentSpec(kv_rank=int(desc["kv_lora_rank"]),
+                      nope_dim=int(desc["qk_nope_head_dim"]),
+                      rope_dim=int(desc["qk_rope_head_dim"]),
+                      v_dim=int(desc["v_head_dim"]),
+                      q_rank=int(desc["q_lora_rank"]))
+
+
+def _experts(desc: dict) -> Optional[ExpertSpec]:
+    """The routed-expert MLP of the description's expert layers, under
+    either family's key names; None for a dense model."""
+    held = desc.get("num_experts") or desc.get("n_routed_experts")
+    if not held:
+        return None
+    method = desc.get("topk_method")
+    if method not in (None, "noaux_tc"):
+        raise ValueError(f"model description: topk_method={method!r} is not "
+                         "computed here (only 'noaux_tc', or none: a "
+                         "softmax router)")
+    width = int(desc["moe_intermediate_size"])
+    return ExpertSpec(
+        num_experts=int(held), mlp_dim=width,
+        top_k=int(desc["num_experts_per_tok"]),
+        router_experts=desc.get("router_experts"),
+        expert_offset=int(desc.get("expert_offset", 0)),
+        routed_scale=float(desc.get("moe_routed_scaling_factor",
+                                    desc.get("routed_scaling_factor", 1.0))),
+        norm_topk=bool(desc.get("norm_topk_prob", True)),
+        shared_dim=int(desc.get("shared_expert_intermediate_size",
+                                width * int(desc.get("n_shared_experts",
+                                                     0)))),
+        score="sigmoid" if method == "noaux_tc" else "softmax",
+        choice_bias=method == "noaux_tc")
 
 
 def causal_lm(desc: dict, *, max_len: int, vocab_size: Optional[int] = None,

@@ -236,15 +236,36 @@ class ExpertSpec:
     routed_scale: float = 1.0         # moe_routed_scaling_factor
     norm_topk: bool = True            # norm_topk_prob
     shared_dim: int = 0               # shared_expert_intermediate_size
+    #: how a router logit becomes a score: ``softmax`` over all the
+    #: router's experts, or ``sigmoid`` of each alone
+    score: str = "softmax"
+    #: the router holds a learned per-expert bias (``router_bias``) that
+    #: is added to the scores for the CHOICE only, never to the weights
+    #: (``topk_method: noaux_tc``, arXiv:2408.15664)
+    choice_bias: bool = False
 
 
 def route_top_k(logits, top_k: int, norm_topk: bool = True,
-                routed_scale: float = 1.0):
-    """``(weights, experts)``, each (N, top_k): softmax over ALL the
-    router's experts in float32, the `top_k` largest, renormalised over
-    the chosen (`norm_topk`) and scaled."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    w, experts = jax.lax.top_k(probs, top_k)
+                routed_scale: float = 1.0, score: str = "softmax",
+                bias=None):
+    """``(weights, experts)``, each (N, top_k): a score for ALL the
+    router's experts in float32 (`score`: their softmax, or each one's
+    sigmoid), the `top_k` largest of score + `bias` (where the router has
+    one), and as weights the chosen experts' scores WITHOUT the bias,
+    renormalised over the chosen (`norm_topk`) and scaled."""
+    logits = logits.astype(jnp.float32)
+    if score == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    elif score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"router score must be 'softmax' or 'sigmoid', "
+                         f"got {score!r}")
+    if bias is None:
+        w, experts = jax.lax.top_k(scores, top_k)
+    else:
+        _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        w = jnp.take_along_axis(scores, experts, axis=-1)
     if norm_topk:
         w = w / jnp.sum(w, axis=-1, keepdims=True)
     return w * routed_scale, experts
@@ -281,14 +302,15 @@ def held_experts(x, w, experts, w_gate, w_up, w_down, offset: int = 0):
     return y.astype(x.dtype), load
 
 
-def _route_and_run(x, router, w_gate, w_up, w_down, spec: ExpertSpec):
+def _route_and_run(x, router, w_gate, w_up, w_down, spec: ExpertSpec,
+                   bias=None):
     """Tokens (N, d) through router and held experts: ``(y, load)``."""
     with jax.named_scope("moe_route"):
         logits = jnp.einsum("nd,de->ne", x.astype(jnp.float32),
                             router.astype(jnp.float32),
                             precision=jax.lax.Precision.HIGHEST)
         w, experts = route_top_k(logits, spec.top_k, spec.norm_topk,
-                                 spec.routed_scale)
+                                 spec.routed_scale, spec.score, bias)
     with jax.named_scope("moe_experts"):
         return held_experts(x, w, experts, w_gate, w_up, w_down,
                             spec.expert_offset)
@@ -301,12 +323,12 @@ def _tokens_of_all_rows(spec: ExpertSpec):
     experts see ONE batch of tokens and read each weight once, and the
     load comes out as the batch's total, unmapped."""
     @jax.custom_batching.custom_vmap
-    def run(x, router, w_gate, w_up, w_down):
-        return _route_and_run(x, router, w_gate, w_up, w_down, spec)
+    def run(x, router, w_gate, w_up, w_down, bias=None):
+        return _route_and_run(x, router, w_gate, w_up, w_down, spec, bias)
 
     @run.def_vmap
     def rule(axis_size, in_batched, x, *weights):
-        if not in_batched[0] or any(in_batched[1:]):
+        if not in_batched[0] or any(jax.tree.leaves(in_batched[1:])):
             raise NotImplementedError(
                 "RoutedExperts under vmap: only the tokens may be mapped "
                 "(weights shared by every row)")
@@ -320,8 +342,9 @@ class RoutedExperts(nn.Module):
     """Router over all experts, the held experts' part of the result, and
     the shared expert: ``sum_{e in top-k, e held} w_e E_e(x) + E_s(x)``.
 
-    Parameters: ``router`` (d, router_experts), ``w_gate`` / ``w_up``
-    (E, d, f), ``w_down`` (E, f, d), and ``shared``'s three matrices.  In
+    Parameters: ``router`` (d, router_experts), with `choice_bias`
+    ``router_bias`` (router_experts,), ``w_gate`` / ``w_up`` (E, d, f),
+    ``w_down`` (E, f, d), and ``shared``'s three matrices.  In
     decode mode the held experts' loads of this call are sown as
     ``moe_stats/load`` (E,) for whoever asks for that collection."""
 
@@ -339,13 +362,19 @@ class RoutedExperts(nn.Module):
                         .astype(self.dtype) for n in ("w_gate", "w_up"))
         w_down = self.param("w_down", dense_init, (E, f, d),
                             jnp.float32).astype(self.dtype)
+        # a router with a choice bias passes it on; one without passes
+        # nothing, and its call is what it was
+        bias = (self.param("router_bias", nn.initializers.zeros,
+                           (sp.router_experts or E,), jnp.float32),
+                ) if sp.choice_bias else ()
         tokens = x.reshape(-1, d).astype(self.dtype)
         if self.decode:
             y, load = _tokens_of_all_rows(sp)(tokens, router, w_gate, w_up,
-                                              w_down)
+                                              w_down, *bias)
             self.sow("moe_stats", "load", load)
         else:       # the training path: differentiable as it stands
-            y, _ = _route_and_run(tokens, router, w_gate, w_up, w_down, sp)
+            y, _ = _route_and_run(tokens, router, w_gate, w_up, w_down, sp,
+                                  *bias)
         y = y.reshape(x.shape)
         if sp.shared_dim:
             with jax.named_scope("moe_shared"):

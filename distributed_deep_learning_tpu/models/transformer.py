@@ -171,6 +171,12 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
 #: layer's ring (:mod:`..serve.paged` pools the two kinds apart by name)
 FULL_LEAVES = ("cached_key", "cached_value", "cached_valid")
 RING_LEAVES = ("ring_key", "ring_value", "ring_valid")
+#: a latent-attention layer's cache: ONE row ``[c | k_r]`` a position
+#: (``kv_rank + rope_dim`` values: the normed latent and the rotated key
+#: every head shares; zero-padded to whole lane tiles,
+#: :attr:`LatentSpec.row_at_rest`) and its validity; a whole-sequence kind
+#: like FULL_LEAVES, told apart by name as the rings are
+LATENT_LEAVES = ("latent_kv", "latent_valid")
 #: beside FULL_LEAVES that are the paged engine's POOL leaves themselves
 #: (``(num_blocks, block, Hkv*D)``, not a slot's ``(1, T, Hkv, D)``): the
 #: slot's block table, ``(blocks_per_slot,)`` physical ids
@@ -362,6 +368,286 @@ class MultiHeadAttention(nn.Module):
         return y[None, None]
 
 
+@dataclasses.dataclass(frozen=True)
+class LatentSpec:
+    """Multi-head latent attention (arXiv:2405.04434), by the published
+    key names: queries through a `q_rank`-wide normed bottleneck
+    (``q_lora_rank``), keys and values through a
+    shared `kv_rank`-wide normed latent (``kv_lora_rank``) plus ONE
+    `rope_dim`-wide rotated key for all heads (``qk_rope_head_dim``); a
+    head's query / key is `nope_dim` + `rope_dim` wide
+    (``qk_nope_head_dim``), its value `v_dim` (``v_head_dim``)."""
+
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    q_rank: int
+
+    @property
+    def row(self) -> int:
+        """Values cached a position and layer: ``[c | k_r]``."""
+        return self.kv_rank + self.rope_dim
+
+    @property
+    def row_at_rest(self) -> int:
+        """Width of the cache leaf: the row, zero-padded to whole lane
+        tiles of 128 (576 -> 640).  A TPU tiles a buffer's two minor dims
+        8 x 128, so a 576-wide row occupies 640 lanes wherever it rests
+        row-major; left 576 wide, XLA rests the pool leaf block-index
+        minor to save those lanes and every program that touches it copies
+        the whole leaf into the computing layout and back (7.7 s of a 20 s
+        window, my chip trace, PR 30; :mod:`..serve.paged` has PR 25's
+        account of the same for per-head K and V)."""
+        return -(-self.row // 128) * 128
+
+    @property
+    def scale(self) -> float:
+        return 1.0 / math.sqrt(self.nope_dim + self.rope_dim)
+
+
+#: keys a step of :func:`latent_expanded_attention` rebuilds and scores
+LATENT_KEY_BLOCK = 512
+_NEG = -1e30
+
+
+def latent_expanded_attention(q, rows, valid, kv_up, q_pos, spec: LatentSpec,
+                              dtype, block: int = LATENT_KEY_BLOCK):
+    """EXPANDED latent attention of one sequence over its cache rows, the
+    keys walked in blocks: ``q (Tq, H, nope + rope)`` at absolute
+    positions ``q_pos (Tq,)`` (ascending) against ``rows (Tk, >= kv_rank +
+    rope)`` (position ``p`` at index ``p``; columns past the row are the
+    cache's padding and are not read), ``valid (Tk,)``, ``kv_up
+    (kv_rank, H, nope + v)``.  Each block's per-head keys and values are
+    rebuilt from its latents (shared by all `Tq` queries), scored, and
+    folded into a running softmax (float32 max, denominator and
+    accumulator), so no more than ``H x Tq x block`` scores ever exist;
+    blocks past the last query's position are never visited.  Returns
+    ``(Tq, H, v)`` in `dtype`."""
+    Tq, H, _ = q.shape
+    Tk = rows.shape[0]
+    blk = min(block, Tk)
+    rank, nope = spec.kv_rank, spec.nope_dim
+    f32 = jnp.float32
+    q_nope, q_rope = q[..., :nope], q[..., nope:]
+
+    def step(j, carry):
+        m, l, acc = carry
+        # the last block may start early to stay inside the cache; the
+        # positions it shares with the one before are masked out
+        start = jnp.minimum(j * blk, Tk - blk)
+        r = jax.lax.dynamic_slice_in_dim(rows, start, blk)
+        kpos = start + jnp.arange(blk)
+        live = jnp.logical_and(
+            jax.lax.dynamic_slice_in_dim(valid, start, blk),
+            kpos >= j * blk)
+        kv = jnp.einsum("kc,chd->khd", r[:, :rank], kv_up,
+                        preferred_element_type=f32).astype(dtype)
+        s = (jnp.einsum("qhd,khd->hqk", q_nope, kv[..., :nope],
+                        preferred_element_type=f32)
+             + jnp.einsum("qhr,kr->hqk", q_rope, r[:, rank:spec.row],
+                          preferred_element_type=f32)) * spec.scale
+        seen = jnp.logical_and(kpos[None, :] <= q_pos[:, None],
+                               live[None, :])[None]
+        s = jnp.where(seen, s, _NEG)
+        new_m = jnp.maximum(m, jnp.max(s, axis=-1))
+        corr = jnp.exp(m - new_m)
+        p = jnp.where(seen, jnp.exp(s - new_m[..., None]), 0.0)
+        l = l * corr + jnp.sum(p, axis=-1)
+        acc = acc * corr[..., None] + jnp.einsum(
+            "hqk,khd->hqd", p.astype(dtype), kv[..., nope:],
+            preferred_element_type=f32)
+        return new_m, l, acc
+
+    init = (jnp.full((H, Tq), _NEG, f32), jnp.zeros((H, Tq), f32),
+            jnp.zeros((H, Tq, spec.v_dim), f32))
+    n_live = (q_pos[-1] + blk) // blk       # blocks up to the last query
+    _, l, acc = jax.lax.fori_loop(0, jnp.minimum(n_live, -(-Tk // blk)),
+                                  step, init)
+    out = acc / jnp.maximum(l, 1e-30)[..., None]
+    return out.transpose(1, 0, 2).astype(dtype)
+
+
+def latent_absorbed_attention(q_abs, rows, seen, *, kv_rank: int,
+                              scale: float, dtype):
+    """ABSORBED latent attention, plainly: ``q_abs (..., H, W)`` (the key
+    up-projection folded into the query; ``W >= kv_rank + rope``, zero
+    past the row) against ``rows (..., Tk, W)`` where ``seen (..., Tk)``; scores (times
+    `scale`) and softmax in float32, probabilities in `dtype` as
+    :func:`dot_product_attention` has them; the values ARE the rows'
+    first `kv_rank` columns.  Returns ``(..., H, kv_rank)``: the value
+    up-projection is the caller's."""
+    s = jnp.einsum("...hc,...kc->...hk", q_abs, rows,
+                   preferred_element_type=jnp.float32) * scale
+    s = jnp.where(seen[..., None, :], s, -1e9)
+    p = nn.softmax(s.astype(jnp.float32)).astype(dtype)
+    return jnp.einsum("...hk,...kc->...hc", p, rows[..., :kv_rank])
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (:class:`LatentSpec`), computed two
+    ways over ONE cache of ``[c | k_r]`` rows:
+
+    * EXPANDED (no cache; a cached call of several tokens, the prefill
+      chunk): per-head keys and values are rebuilt from the latents,
+      ``s = q_h . [k_nope_h | k_r]``, ``o_h = sum p v_h``.  Cheaper when
+      many queries share the rebuilt keys.
+    * ABSORBED (a cached call of one token): ``W_kvb``'s key columns are
+      folded into the query (``qt_h = W_UK_h q_nope_h``) and its value
+      columns into the output (``o_h = (sum p c) W_UV_h``), so nothing is
+      expanded a cached position and only the rows are read.  A cache
+      that holds a ``block_table`` is the paged engine's decode program:
+      the rows are read where they rest, through the table
+      (:func:`..ops.paged_decode_pallas.paged_latent_slot_attention`).
+
+    The same function both ways, up to rounding."""
+
+    num_heads: int
+    latent: LatentSpec
+    dtype: jnp.dtype = jnp.float32
+    attention_fn: Optional[AttentionFn] = None
+    decode: bool = False
+    rope: Union[bool, RopeSpec] = True
+    ln_eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x, key_valid=None, *, causal: bool = True):
+        sp, H = self.latent, self.num_heads
+        B, T, d_model = x.shape
+        dense = lambda name, f: nn.DenseGeneral(  # noqa: E731
+            f, dtype=self.dtype, use_bias=False, kernel_init=dense_init,
+            name=name)
+        rope = None if self.rope is True else self.rope
+        start = jnp.zeros((), jnp.int32)
+        if self.decode and self.has_variable("cache", "cache_index"):
+            start = self.get_variable("cache", "cache_index")
+        positions = start + jnp.arange(T)
+        with jax.named_scope("attn_latent"):
+            with jax.named_scope("mla_q"):
+                c_q = nn.RMSNorm(dtype=self.dtype, epsilon=self.ln_eps,
+                                 name="q_norm")(dense("q_a", sp.q_rank)(x))
+                q = dense("q_b", (H, sp.nope_dim + sp.rope_dim))(c_q)
+                q = jnp.concatenate(
+                    [q[..., :sp.nope_dim],
+                     apply_rope(q[..., sp.nope_dim:], positions, spec=rope)],
+                    axis=-1)
+            with jax.named_scope("mla_kv_down"):
+                kv = dense("kv_a", sp.row)(x)
+                c = nn.RMSNorm(dtype=self.dtype, epsilon=self.ln_eps,
+                               name="kv_norm")(kv[..., :sp.kv_rank])
+                k_r = apply_rope(kv[..., None, sp.kv_rank:], positions,
+                                 spec=rope)[..., 0, :]
+                # (B, T, row_at_rest): [c | k_r | zeros to whole lane tiles]
+                rows = jnp.concatenate(
+                    [c, k_r, jnp.zeros(c.shape[:2] + (sp.row_at_rest
+                                                      - sp.row,), c.dtype)],
+                    axis=-1)
+            kv_up = self.param("kv_b", dense_init,
+                               (sp.kv_rank, H, sp.nope_dim + sp.v_dim),
+                               jnp.float32).astype(self.dtype)
+            q, rows = pin_batch(q), pin_batch(rows)
+            is_init = self.decode and \
+                not self.has_variable("cache", "cache_index")
+            if self.decode:
+                ckv = self.variable("cache", LATENT_LEAVES[0], jnp.zeros,
+                                    rows.shape, rows.dtype)
+                cvalid = self.variable(
+                    "cache", LATENT_LEAVES[1],
+                    lambda: jnp.zeros(rows.shape[:2], jnp.bool_))
+                idx = self.variable("cache", "cache_index",
+                                    lambda: jnp.zeros((), jnp.int32))
+            step_valid = (key_valid if key_valid is not None
+                          else jnp.ones((B, T), jnp.bool_))
+            if not self.decode or is_init:
+                y = self._expanded_whole(q, rows, kv_up, key_valid, causal)
+            elif self.has_variable("cache", BLOCK_TABLE):
+                y = self._absorbed_in_place(q, rows, kv_up, step_valid,
+                                            ckv, cvalid, idx)
+            else:
+                ckv.value = jax.lax.dynamic_update_slice(
+                    ckv.value, rows, (0, idx.value, 0))
+                cvalid.value = jax.lax.dynamic_update_slice(
+                    cvalid.value, step_valid, (0, idx.value))
+                if T == 1:
+                    with jax.named_scope("mla_absorb"):
+                        seen = jnp.logical_and(
+                            jnp.arange(ckv.value.shape[1])[None]
+                            <= idx.value, cvalid.value)
+                        y = self._absorbed(
+                            q[:, 0], kv_up,
+                            lambda q_abs: latent_absorbed_attention(
+                                q_abs, ckv.value, seen, kv_rank=sp.kv_rank,
+                                scale=sp.scale, dtype=self.dtype)
+                        )[:, None]
+                else:
+                    with jax.named_scope("mla_expand"):
+                        y = jax.vmap(
+                            lambda q1, rows1, valid1:
+                            latent_expanded_attention(
+                                q1, rows1, valid1, kv_up, positions, sp,
+                                self.dtype))(q, ckv.value, cvalid.value)
+                idx.value = idx.value + T
+        y = pin_batch(y)
+        return nn.DenseGeneral(d_model, axis=(-2, -1), dtype=self.dtype,
+                               use_bias=False, kernel_init=dense_init,
+                               name="out")(y)
+
+    def _expanded_whole(self, q, rows, kv_up, key_valid, causal):
+        """No cache: every head's keys and values of the whole call, then
+        the pluggable attention (the training path: differentiable)."""
+        sp = self.latent
+        with jax.named_scope("mla_expand"):
+            kv = jnp.einsum("btc,chd->bthd", rows[..., :sp.kv_rank], kv_up)
+            k = jnp.concatenate(
+                [kv[..., :sp.nope_dim],
+                 jnp.broadcast_to(rows[..., None, sp.kv_rank:sp.row],
+                                  kv.shape[:3] + (sp.rope_dim,))], axis=-1)
+            k, v = pin_batch(k), pin_batch(kv[..., sp.nope_dim:])
+        attn = self.attention_fn or dot_product_attention
+        return attn(q, k, v, mask=None, key_valid=key_valid, causal=causal,
+                    dtype=self.dtype)
+
+    def _absorbed(self, q, kv_up, attend):
+        """``q (..., H, nope + rope)`` through `attend` in the latent's
+        own space: the key columns of `kv_up` folded into the query, the
+        value columns applied to what comes back."""
+        sp = self.latent
+        qt = jnp.einsum("...hd,chd->...hc", q[..., :sp.nope_dim],
+                        kv_up[..., :sp.nope_dim])
+        pad = jnp.zeros(qt.shape[:-1] + (sp.row_at_rest - sp.row,), qt.dtype)
+        ot = attend(jnp.concatenate([qt, q[..., sp.nope_dim:], pad], axis=-1))
+        return jnp.einsum("...hc,chd->...hd", ot, kv_up[..., sp.nope_dim:])
+
+    def _absorbed_in_place(self, q, rows, kv_up, step_valid, ckv, cvalid,
+                           idx):
+        """One token of one slot against the latent POOL leaf where it
+        rests: the rows below ``cache_index`` through the slot's block
+        table, each read once for its score and its value, the token's
+        own row beside them.  The cache variables leave holding that row
+        (what the engine scatters), not a cache."""
+        from distributed_deep_learning_tpu.ops.paged_decode_pallas import (
+            paged_latent_slot_attention)
+
+        if q.shape[:2] != (1, 1):
+            raise ValueError(
+                f"a cache of pool leaves serves one token of one slot, got "
+                f"queries {q.shape[:2]}; programs with more gather the slot")
+        sp = self.latent
+        with jax.named_scope("mla_absorb"):
+            def attend(q_abs):
+                with jax.named_scope("kv_paged_attn"):
+                    return paged_latent_slot_attention(
+                        q_abs, rows[0, 0], step_valid[0, 0], ckv.value,
+                        cvalid.value,
+                        self.get_variable("cache", BLOCK_TABLE), idx.value,
+                        spec=sp)
+
+            y = self._absorbed(q[0, 0], kv_up, attend)
+        ckv.value, cvalid.value = rows, step_valid
+        idx.value = idx.value + 1
+        return y[None, None]
+
+
 def _norm(kind: str, dtype, eps: float, name: Optional[str] = None):
     """``layer``: LayerNorm (scale and bias); ``rms``: RMSNorm (scale)."""
     if kind == "rms":
@@ -379,9 +665,11 @@ class TransformerLayer(nn.Module):
     (T×T) tensor) so fused kernels can apply them in-block.
 
     The defaults are GPT-2's block (LayerNorm, biases, a two-matrix GELU
-    MLP); `norm`, `use_bias`, `head_dim`, `gate` and `mlp` (``gelu`` |
-    ``swiglu`` | ``experts``, the last with an :class:`..moe.ExpertSpec`)
-    describe the others, one :class:`LayerSpec` a layer.
+    MLP); `norm`, `use_bias`, `head_dim`, `gate`, `latent` (a
+    :class:`LatentSpec`: latent attention in the place of per-head K and V)
+    and `mlp` (``gelu`` | ``swiglu`` | ``experts``, the last with an
+    :class:`..moe.ExpertSpec`) describe the others, one :class:`LayerSpec`
+    a layer.
     """
 
     num_heads: int = 8
@@ -403,6 +691,7 @@ class TransformerLayer(nn.Module):
     mlp: str = "gelu"
     experts: Optional[ExpertSpec] = None
     cache_ring: Optional[int] = None
+    latent: Optional[LatentSpec] = None     # set: latent attention
 
     @nn.compact
     def __call__(self, x, encoded=None, *, self_valid=None, cross_valid=None,
@@ -410,15 +699,20 @@ class TransformerLayer(nn.Module):
         # x comes in pinned to the batch axes: the embedding and every
         # layer pin what they hand on (runtime.batch_pin)
         h = _norm(self.norm, self.dtype, self.ln_eps)(x)
-        h = MultiHeadAttention(self.num_heads, self.dtype, self.attention_fn,
-                               decode=self.decode, rope=self.rope,
-                               window=self.window,
-                               num_kv_heads=self.num_kv_heads,
-                               head_dim=self.head_dim,
-                               use_bias=self.use_bias, gate=self.gate,
-                               cache_ring=self.cache_ring,
-                               name="self_attn")(h, h, self_valid,
-                                                 causal=self.causal)
+        if self.latent is not None:
+            h = LatentAttention(self.num_heads, self.latent, self.dtype,
+                                self.attention_fn, decode=self.decode,
+                                rope=self.rope, ln_eps=self.ln_eps,
+                                name="self_attn")(h, self_valid,
+                                                  causal=self.causal)
+        else:
+            h = MultiHeadAttention(
+                self.num_heads, self.dtype, self.attention_fn,
+                decode=self.decode, rope=self.rope, window=self.window,
+                num_kv_heads=self.num_kv_heads, head_dim=self.head_dim,
+                use_bias=self.use_bias, gate=self.gate,
+                cache_ring=self.cache_ring,
+                name="self_attn")(h, h, self_valid, causal=self.causal)
         h = nn.Dropout(self.dropout_rate, deterministic=not train)(h)
         x = pin_batch(x + h)
         if self.cross_attention:
@@ -464,6 +758,7 @@ class LayerSpec:
     norm: str = "layer"
     mlp: str = "gelu"
     experts: Optional[ExpertSpec] = None
+    latent: Optional[LatentSpec] = None
 
 
 class Embed(nn.Module):

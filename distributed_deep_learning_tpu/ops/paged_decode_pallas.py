@@ -227,6 +227,25 @@ def _work_list(tables, lens, n: int, block_size: int):
     return ends[-1], slot, step, phys.astype(jnp.int32)
 
 
+def _scalars_and_validity(block_tables, seq_lens, new_valid, valid_pool,
+                          bs: int, n_steps: int, step: int):
+    """What both kernels prefetch and mask by: the tables and lengths as
+    int32, the new rows' validity (default: all), and the cached validity
+    gathered through the tables, a ``(1, step)`` float tile a slot and
+    step (all ones without a `valid_pool`)."""
+    B, Bps = block_tables.shape
+    tables = block_tables.astype(jnp.int32)
+    newv = (jnp.ones((B,), jnp.int32) if new_valid is None
+            else new_valid.astype(jnp.int32))
+    if valid_pool is None:
+        valid = jnp.ones((B, n_steps, 1, step), jnp.float32)
+    else:
+        valid = valid_pool[tables].reshape(B, Bps * bs).astype(jnp.float32)
+        valid = jnp.pad(valid, ((0, 0), (0, n_steps * step - Bps * bs)))
+        valid = valid.reshape(B, n_steps, 1, step)
+    return tables, seq_lens.astype(jnp.int32), newv, valid
+
+
 def _split_quant(pool):
     """``(payload, scales or None)`` of a pool leaf."""
     from distributed_deep_learning_tpu.serve.quant import is_quant
@@ -311,16 +330,8 @@ def _kernel_call(q, k_pool, v_pool, block_tables, seq_lens, *, k_new, v_new,
     quantized, has_new = ks is not None, k_new is not None
     Hp = -(-H // 8) * 8                      # whole sublane tiles of rows
 
-    tables = block_tables.astype(jnp.int32)
-    lens = seq_lens.astype(jnp.int32)
-    newv = (jnp.ones((B,), jnp.int32) if new_valid is None
-            else new_valid.astype(jnp.int32))
-    if valid_pool is None:
-        valid = jnp.ones((B, n_steps, 1, step), jnp.float32)
-    else:
-        valid = valid_pool[tables].reshape(B, Bps * bs).astype(jnp.float32)
-        valid = jnp.pad(valid, ((0, 0), (0, n_steps * step - Bps * bs)))
-        valid = valid.reshape(B, n_steps, 1, step)
+    tables, lens, newv, valid = _scalars_and_validity(
+        block_tables, seq_lens, new_valid, valid_pool, bs, n_steps, step)
     pick, tile, spread = _head_matrices(H, Hp, Hkv, D)
     qp = jnp.pad(q, ((0, 0), (0, Hp - H), (0, 0)))
 
@@ -460,3 +471,253 @@ def paged_decode_reference(q, k_pool, v_pool, block_tables, seq_lens, *,
     return dot_product_attention(q[:, None], k, v,
                                  mask=mask[:, None, None, :],
                                  key_valid=valid, dtype=q.dtype)[:, 0]
+
+
+# --- the latent layout: one row a position, read once ----------------------
+#
+# A latent-attention layer (:class:`..models.transformer.LatentSpec`) caches
+# ONE row ``[c | k_r]`` a position, ``kv_rank + rope`` values zero-padded to
+# whole lane tiles (512 + 64 -> 640), and in its absorbed form a head's key
+# IS that row and its value the row's first ``kv_rank`` columns: one "KV
+# head" under every query head.  Handing :func:`paged_flash_decode` the leaf
+# as its K pool and again as its V pool would land every live row in VMEM
+# twice, doubling the only traffic the latent was made to shrink.  The
+# sibling below takes the leaf ONCE: a tile is one operand, scored whole (the
+# absorbed query is zero where the row is padding) and sliced, at a
+# lane-tile boundary, for the values.  Same grid-as-data (`_work_list`),
+# same running softmax; no head matrices, since with one KV head the query
+# needs no block-diagonal form.
+
+def latent_bytes_a_row(pool, slots: int, blocks_per_slot: int) -> int:
+    """Bytes :func:`paged_latent_decode`'s kernel lands in VMEM a live
+    position, read off the call as it is TRACED for these shapes, not
+    declared: the ``pallas_call``'s operands that are `pool` (the layer's
+    ``(N, bs, W)`` leaf; each is one ``(1, bs, W)`` tile a grid step, a
+    row's padding included: a tile is moved whole) against the positions
+    a grid step covers (the last dim of its validity tile).  One operand a
+    tile gives the row's own bytes; K and V handed apart would give twice
+    them."""
+    B, W = slots, pool.shape[-1]
+    like = jax.ShapeDtypeStruct
+    jaxpr = jax.make_jaxpr(functools.partial(
+        _latent_call, v_width=W, sm_scale=1.0, new_valid=None,
+        valid_pool=None, blocks_per_step=None, interpret=False))(
+        like((B, 8, W), pool.dtype), like(pool.shape, pool.dtype),
+        like((B, blocks_per_slot), jnp.int32), like((B,), jnp.int32),
+        like((B, W), pool.dtype))
+    return _pool_bytes_a_position(jaxpr.jaxpr, pool)
+
+
+def _pool_bytes_a_position(jaxpr, pool) -> int:
+    """Over the ``pallas_call`` s of `jaxpr` (inner jits included)."""
+    def calls(jp):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(sub)
+
+    total = 0
+    for call in calls(jaxpr):
+        shapes = [v.aval.shape for v in call.invars]
+        (step,) = {s[-1] for s in shapes if len(s) == 4}     # validity tile
+        tiles = sum(s == tuple(pool.shape) for s in shapes)
+        total += (tiles * pool.shape[1] * pool.shape[2]
+                  * pool.dtype.itemsize) // step
+    return total
+
+
+def _latent_kernel(slot_ref, step_ref, phys_ref, lens_ref, newv_ref, q_ref,
+                   valid_ref, new_ref, *rest, n: int, block_size: int,
+                   sm_scale: float, v_width: int):
+    """One step of one slot, as `_decode_kernel`: ``rest`` is the step's
+    `n` row tiles ``(1, bs, W)``, the output ``(1, Hp, v_width)`` and the
+    scratch ``m``, ``l``, ``acc``."""
+    tiles, (o_ref, m_ref, l_ref, acc_ref) = rest[:n], rest[n:]
+    t = pl.program_id(0)
+    b, j = slot_ref[t], step_ref[t]
+    length = lens_ref[b]
+    step = n * block_size
+    cdt = q_ref.dtype
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(j * step < length)
+    def _attend():
+        rows = _rows(tiles, cdt)                                 # (step, W)
+        s = _nt(q_ref[0], rows) * sm_scale                       # (Hp, step)
+        kpos = j * step + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        live = jnp.logical_and(kpos < length, valid_ref[0, 0] > 0)
+        s = jnp.where(live, s, NEG_INF)
+        m = m_ref[...]
+        new_m = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        corr = jnp.exp(m - new_m)
+        p = jnp.where(live, jnp.exp(s - new_m), 0.0)
+        m_ref[...] = new_m
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + _nn(p.astype(cdt),
+                                                 rows[:, :v_width])
+
+    @pl.when((j + 1) * step >= length)
+    def _writeout():
+        m, l, acc = m_ref[...], l_ref[...], acc_ref[...]
+        new = new_ref[0].astype(jnp.float32)                     # (1, W)
+        s = jnp.sum(q_ref[0].astype(jnp.float32) * new, axis=1,
+                    keepdims=True) * sm_scale                    # (Hp, 1)
+        ok = newv_ref[b] > 0
+        s = jnp.where(ok, s, NEG_INF)
+        new_m = jnp.maximum(m, s)
+        corr = jnp.exp(m - new_m)
+        p = jnp.where(ok, jnp.exp(s - new_m), 0.0)
+        l = l * corr + p
+        acc = acc * corr + (p.astype(cdt).astype(jnp.float32)
+                            * new[:, :v_width])
+        o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+
+def paged_latent_decode(q, pool, block_tables, seq_lens, row_new, *,
+                        v_width: int, sm_scale: float, new_valid=None,
+                        valid_pool=None, blocks_per_step=None,
+                        interpret: bool | None = None):
+    """One token's ABSORBED latent attention for every slot, straight off
+    the latent pool, each live row read once.
+
+    ``q``: ``(B, H, W)`` absorbed queries, `W` the leaf's width (``kv_rank
+    + rope`` and its padding, where the query is zero).  `pool`:
+    the resident ``(N, bs, W)`` leaf.  ``block_tables`` ``(B, Bps)``,
+    ``seq_lens`` ``(B,)``, ``valid_pool`` ``(N, bs)`` or None as for
+    :func:`paged_flash_decode`.  ``row_new`` ``(B, W)``: the token's own
+    row, attended as position ``seq_lens[b]`` where ``new_valid[b]``.
+    Scores are ``q . row * sm_scale``; the values are a row's first
+    `v_width` columns.  Returns ``(B, H, v_width)`` in ``q``'s dtype.
+
+    On TPU the Pallas kernel; elsewhere :func:`paged_latent_reference`;
+    ``interpret=True`` forces the kernel through the interpreter."""
+    kw = dict(v_width=v_width, sm_scale=sm_scale, new_valid=new_valid,
+              valid_pool=valid_pool)
+    if interpret is None:
+        if jax.default_backend() != "tpu":
+            return paged_latent_reference(q, pool, block_tables, seq_lens,
+                                          row_new, **kw)
+        interpret = False
+    return _latent_call(q, pool, block_tables, seq_lens, row_new, **kw,
+                        blocks_per_step=blocks_per_step, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "v_width", "sm_scale", "blocks_per_step", "interpret"))
+def _latent_call(q, pool, block_tables, seq_lens, row_new, *, v_width,
+                 sm_scale, new_valid, valid_pool, blocks_per_step,
+                 interpret):
+    """The latent kernel's call, jitted so that every layer of a program
+    shares one trace and one lowering."""
+    B, H, W = q.shape
+    bs = pool.shape[1]
+    Bps = block_tables.shape[1]
+    if pool.shape[2] != W or not 0 < v_width <= W:
+        raise ValueError(f"absorbed queries {W} wide, values {v_width}, "
+                         f"against a pool leaf {pool.shape[2]} wide")
+    n = min(blocks_per_step or max(1, STEP_POSITIONS // bs), Bps)
+    n_steps = -(-Bps // n)
+    step = n * bs
+    Hp = -(-H // 8) * 8
+
+    tables, lens, newv, valid = _scalars_and_validity(
+        block_tables, seq_lens, new_valid, valid_pool, bs, n_steps, step)
+    total, slot, step_of, phys = _work_list(tables, lens, n, bs)
+
+    def slot_map(t, slot_ref, *_):
+        return (slot_ref[t], 0, 0)
+
+    def pool_map(i):
+        return lambda t, slot_ref, step_ref, phys_ref, *_: (
+            phys_ref[i, t], 0, 0)
+
+    in_specs = [pl.BlockSpec((1, Hp, W), slot_map),
+                pl.BlockSpec((1, 1, 1, step),
+                             lambda t, slot_ref, step_ref, *_: (
+                                 slot_ref[t], step_ref[t], 0, 0)),
+                pl.BlockSpec((1, 1, W), slot_map)]
+    in_specs += [pl.BlockSpec((1, bs, W), pool_map(i)) for i in range(n)]
+    kern = functools.partial(_latent_kernel, n=n, block_size=bs,
+                             sm_scale=sm_scale, v_width=v_width)
+    out = pl.pallas_call(
+        kern,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5, grid=(total,), in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, Hp, v_width), slot_map),
+            scratch_shapes=[pltpu.VMEM((Hp, 1), jnp.float32),
+                            pltpu.VMEM((Hp, 1), jnp.float32),
+                            pltpu.VMEM((Hp, v_width), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, Hp, v_width), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret, name="paged_latent_decode",
+    )(slot, step_of, phys, lens, newv,
+      jnp.pad(q, ((0, 0), (0, Hp - H), (0, 0))), valid,
+      row_new.reshape(B, 1, W), *([pool] * n))   # ONE operand a tile
+    return out[:, :H]
+
+
+def paged_latent_slot_attention(q, row_new, new_valid, pool, valid_pool,
+                                table, length, *, spec):
+    """:func:`paged_latent_decode` for ONE slot (``q (H, W)``, ``row_new
+    (W,)``, scalars `new_valid` and `length`, ``table (Bps,)``) under
+    ``vmap`` over slots that share the pool, as
+    :func:`paged_slot_attention`; `spec` is the layer's
+    :class:`..models.transformer.LatentSpec`."""
+    def attend(q, row_new, new_valid, table, length, pool, valid_pool):
+        return paged_latent_decode(
+            q, pool, table, length, row_new, v_width=spec.kv_rank,
+            sm_scale=spec.scale, new_valid=new_valid, valid_pool=valid_pool)
+
+    @jax.custom_batching.custom_vmap
+    def one(*args):
+        return attend(*(x[None] for x in args[:5]), *args[5:])[0]
+
+    @one.def_vmap
+    def rule(axis_size, in_batched, *args):
+        if any(jax.tree.leaves(in_batched[5:])):
+            raise NotImplementedError(
+                "paged latent attention under vmap: only the slots' "
+                "queries, rows, tables and lengths may be mapped (one pool "
+                "for all)")
+        rows = [x if mapped else jnp.broadcast_to(x, (axis_size,) + x.shape)
+                for x, mapped in zip(args[:5], in_batched[:5])]
+        return attend(*rows, *args[5:]), True
+
+    return one(q, row_new, jnp.asarray(new_valid), table,
+               jnp.asarray(length), pool, valid_pool)
+
+
+def paged_latent_reference(q, pool, block_tables, seq_lens, row_new, *,
+                           v_width: int, sm_scale: float, new_valid=None,
+                           valid_pool=None):
+    """What :func:`paged_latent_decode` must reproduce, and the off-TPU
+    path: gather each slot's rows (``leaf[table]``), put the new row at
+    its position, mask to the causal prefix and validity, and the model's
+    own plain absorbed attention."""
+    from distributed_deep_learning_tpu.models.transformer import (
+        latent_absorbed_attention)
+
+    B, H, W = q.shape
+    T = block_tables.shape[1] * pool.shape[1]
+    lens = seq_lens.astype(jnp.int32)
+    rows = pool[block_tables].reshape(B, T, W).astype(q.dtype)
+    valid = jnp.ones((B, T), jnp.bool_) if valid_pool is None \
+        else valid_pool[block_tables].reshape(B, T)
+
+    def put(row, new, at):
+        return lax.dynamic_update_slice_in_dim(row, new[None], at, 0)
+
+    rows = jax.vmap(put)(rows, row_new.astype(q.dtype), lens)
+    valid = jax.vmap(put)(
+        valid, jnp.ones((B,), jnp.bool_) if new_valid is None
+        else new_valid.astype(jnp.bool_), lens)
+    seen = jnp.logical_and(jnp.arange(T)[None] <= lens[:, None], valid)
+    return latent_absorbed_attention(q, rows, seen, kv_rank=v_width,
+                                     scale=sm_scale, dtype=q.dtype)

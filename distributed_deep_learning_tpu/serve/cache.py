@@ -38,9 +38,11 @@ COUNTER_LEAVES = ("cache_index", "pos_index")
 
 #: cache-collection leaf names that hold actual key/value tensors — the
 #: leaves the serving quantization path (:mod:`.quant`) stores in reduced
-#: precision (``ring_*``: a window layer's ring under the paged engine).
+#: precision (``ring_*``: a window layer's ring under the paged engine;
+#: ``latent_kv``: a latent-attention layer's one row a position).
 #: ``cached_valid`` / ``ring_valid`` (bool) and the counters stay exact.
-KV_LEAVES = ("cached_key", "cached_value", "ring_key", "ring_value")
+KV_LEAVES = ("cached_key", "cached_value", "ring_key", "ring_value",
+             "latent_kv")
 
 
 def _leaf_name(path) -> str:

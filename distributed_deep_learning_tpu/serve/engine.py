@@ -47,6 +47,8 @@ from distributed_deep_learning_tpu.obs import runlog
 from distributed_deep_learning_tpu.obs import trace as obs_trace
 from distributed_deep_learning_tpu.obs.metrics import MetricsRegistry
 from distributed_deep_learning_tpu.obs.window import LiveSignals
+from distributed_deep_learning_tpu.ops.paged_decode_pallas import (
+    latent_bytes_a_row)
 from distributed_deep_learning_tpu.serve import cache as slot_cache
 from distributed_deep_learning_tpu.serve import paged
 from distributed_deep_learning_tpu.serve import quant
@@ -818,6 +820,13 @@ class PagedEngine:
                              ("preemption (spill / resume)", preempt)):
                 if on:
                     raise ValueError(self._two_kinds(what))
+        if kv_dtype == "int8" and any(sp.latent
+                                      for sp in model.layer_specs()):
+            raise ValueError(
+                "kv_dtype='int8' is not supported for a model with latent "
+                "attention layers: a latent row [c | k_r] has no heads to "
+                "scale by, and the decode program's latent kernel reads "
+                "floating rows (use kv_dtype='bf16' or None)")
         if num_blocks is None:
             # 1x for the live slots + 1x retention headroom so the
             # prefix index can keep blocks alive after their request
@@ -836,8 +845,16 @@ class PagedEngine:
                                               kv_dtype=kv_dtype)
         self.pools = self._new_pools(self._slot_like)
         #: attention layers the decode program serves in place through the
-        #: block table / by gathering (rings), read off the slot template
+        #: block table (per-head K and V / a latent row) and by gathering
+        #: (rings), read off the slot template
         self.decode_attn_paths = paged.attention_paths(self._slot_like)
+        #: bytes the decode program's attention kernel lands in VMEM a
+        #: live position and latent layer, counted off the kernel's call as
+        #: traced for this engine's shapes (0: no latent layer), for
+        #: ``counters["latent"]``
+        leaf = paged.latent_leaf(self.pools)
+        self.latent_row_bytes = 0 if leaf is None else latent_bytes_a_row(
+            leaf, self.max_slots, self.blocks_per_slot)
         self._chunk_prog = CountingJit(self._chunk_impl, "paged_chunk",
                                        "chunk_dispatch", **dk)
         self._decode = CountingJit(self._decode_impl, "paged_decode",
@@ -1773,7 +1790,8 @@ class PagedEngine:
                     recorder.record("admit", uid=req.uid, slot=idx,
                                     shared_len=shared)
 
-        in_place = self.decode_attn_paths["block_table"]
+        n_latent = self.decode_attn_paths["latent"]
+        in_place = self.decode_attn_paths["block_table"] + n_latent
         table_blocks = in_place * self.max_slots * self.blocks_per_slot
         attn_read = attn_tables = 0
         t_start = time.perf_counter()
@@ -1835,6 +1853,13 @@ class PagedEngine:
                                               for i in dec)
                         counters["attn_blocks"] = {"read": read,
                                                    "tables": table_blocks}
+                        if n_latent:
+                            # live rows the latent layers' attention reads
+                            # this tick, and the bytes it reads of each
+                            counters["latent"] = {
+                                "rows": n_latent * sum(committed[i]
+                                                       for i in dec),
+                                "row_bytes": self.latent_row_bytes}
                         attn_read += read
                         attn_tables += table_blocks
                         toks = np.zeros(self.max_slots, np.int32)
@@ -2069,6 +2094,8 @@ class PagedEngine:
                     "paths": dict(self.decode_attn_paths),
                     "blocks_read": attn_read,
                     "blocks_in_tables": attn_tables,
+                    **({"latent_row_bytes": self.latent_row_bytes}
+                       if n_latent else {}),
                 },
             },
             "spec": spec_stats,

@@ -54,6 +54,26 @@ Physical block 0 is a TRASH block: gathers may read it (garbage in,
 discarded out — free slots, tail padding) and masked writes are routed
 to it, so real blocks only ever receive committed positions.
 
+**A latent layer's row is one more leaf under the one rule.**  A
+latent-attention layer (:class:`..models.transformer.LatentSpec`) caches a
+single ``(1, T, row_at_rest)`` leaf, ``latent_kv``, and its validity
+(:data:`..models.transformer.LATENT_LEAVES`); :func:`build_pools` folds it
+like any other whole-sequence leaf, into ``(num_blocks, bs, row_at_rest)``.
+ONE leaf, PADDED by the model to whole lane tiles (576 -> 640), not split:
+left 576 wide, XLA rests the pool leaf block-index minor to save the 64
+lanes a row-major tile would waste, and every program copies every whole
+leaf into the computing layout and back (two copies of 478 MB a layer and
+program, 7.7 s of a 20 s window: my chip trace, PR 30; the same fault PR 25
+cured for per-head K and V); a 512 and a 64 leaf would be two DMAs a block
+where the decode kernel's time is its DMA count, and the 64-wide one would
+itself rest padded to 128 lanes or block-index minor.  The 64 zero columns
+are what a row-major (8, 128)-tiled buffer holds anyway.  The leaf is of
+the full kind: addressed by the slot's block table, shared through the
+prefix index, copied on write, spilled and migrated by the ops below as
+they stand.  :func:`decode_view` hands the layer the pool leaf itself and
+the absorbed attention reads each live row once
+(:func:`..ops.paged_decode_pallas.paged_latent_decode`).
+
 **Two kinds of layer, two kinds of pool.**  A full-attention layer needs
 every position of a sequence; a sliding-window layer only its last
 ``window``.  A model that mixes them (the decode model names a window
@@ -86,6 +106,7 @@ import numpy as np
 
 from distributed_deep_learning_tpu.models.transformer import (BLOCK_TABLE,
                                                               FULL_LEAVES,
+                                                              LATENT_LEAVES,
                                                               RING_LEAVES,
                                                               init_cache)
 from distributed_deep_learning_tpu.serve import quant
@@ -214,7 +235,8 @@ def _by_layer(tree, layer, other, *rest):
     layer's cache leaves, `other` on every other innermost dict (the
     embedding's counter), each with the matching nodes of `rest`."""
     def walk(node, *more):
-        if FULL_LEAVES[0] in node or RING_LEAVES[0] in node:
+        if any(kind[0] in node
+               for kind in (FULL_LEAVES, RING_LEAVES, LATENT_LEAVES)):
             return layer(node, *more)
         if any(isinstance(v, dict) for v in node.values()):
             return {k: walk(v, *(m[k] for m in more))
@@ -227,8 +249,9 @@ def _by_layer(tree, layer, other, *rest):
 def decode_view(pools, table, pos, like, lift=lambda cache: cache):
     """One slot's cache for the ONE-TOKEN decode program.
 
-    A full-kind layer gets its pool leaves THEMSELVES, as they rest, with
-    the slot's block table beside them (``block_table``): the layer attends
+    A full-kind layer (per-head K and V, or a latent row) gets its pool
+    leaves THEMSELVES, as they rest, with the slot's block table beside
+    them (``block_table``): the layer attends
     over the blocks that hold live positions in place
     (:mod:`..ops.paged_decode_pallas`) and nothing of ``blocks_per_slot x
     block`` is copied.  A ring layer's leaves are gathered as
@@ -262,15 +285,26 @@ def view_span(cache, pos):
 
 def attention_paths(like) -> dict:
     """How many attention layers of slots shaped `like` the one-token
-    decode program serves in place through the block table, and how many
-    by gathering (:func:`decode_view`)."""
-    paths = {"block_table": 0, "gather": 0}
+    decode program serves in place through the block table (per-head K
+    and V: ``block_table``; a latent row: ``latent``), and how many by
+    gathering (:func:`decode_view`)."""
+    paths = {"block_table": 0, "gather": 0, "latent": 0}
 
     def layer(node):
-        paths["gather" if RING_LEAVES[0] in node else "block_table"] += 1
+        paths["gather" if RING_LEAVES[0] in node else
+              "latent" if LATENT_LEAVES[0] in node else "block_table"] += 1
 
     _by_layer(like, layer, lambda node: None)
     return paths
+
+
+def latent_leaf(like):
+    """The latent row leaf of slots shaped `like` (every latent layer's is
+    alike), or None where no layer has one."""
+    found = []
+    _by_layer(like, lambda node: found.append(node.get(LATENT_LEAVES[0])),
+              lambda node: None)
+    return next((leaf for leaf in found if leaf is not None), None)
 
 
 def extract_span(cache, pos, n: int):
@@ -349,6 +383,7 @@ class _IndexEntry:
     block: int
     tokens: tuple
     last_used: int
+    parent: bytes       # the chain hash this entry extends
 
 
 class PrefixIndex:
@@ -383,7 +418,7 @@ class PrefixIndex:
         if h in self.entries or block in self.by_block:
             return False
         self._clock += 1
-        self.entries[h] = _IndexEntry(block, tokens, self._clock)
+        self.entries[h] = _IndexEntry(block, tokens, self._clock, parent)
         self.children.setdefault(parent, []).append(h)
         self.by_block[block] = h
         return True
@@ -391,10 +426,15 @@ class PrefixIndex:
     def remove(self, h: bytes) -> int:
         e = self.entries.pop(h)
         del self.by_block[e.block]
-        for sibs in self.children.values():
-            if h in sibs:
-                sibs.remove(h)
-                break
+        # by the entry's own parent, not by a walk over every parent's
+        # list: evicting 1,200 of 20,000 indexed blocks for one admission
+        # took the host 1.3 s (a stall in every window of the glm cell,
+        # my chip runs, PR 30)
+        sibs = self.children.get(e.parent)
+        if sibs is not None and h in sibs:
+            sibs.remove(h)
+            if not sibs:
+                del self.children[e.parent]
         self.children.pop(h, None)
         return e.block
 

@@ -356,9 +356,13 @@ def render(events: list[dict], phases: bool = False) -> str:
             # the tables hold (the share of capacity a tick still pays)
             read, held = attn["blocks_read"], attn["blocks_in_tables"]
             share = f"{100.0 * read / held:.1f}%" if held else "n/a"
+            latent = attn["paths"].get("latent", 0)
             out.append(f"  decode attention: {attn['paths']['block_table']} "
                        f"layers through the block table, "
-                       f"{attn['paths']['gather']} gathered; blocks read "
+                       + (f"{latent} latent layers through it (a row read "
+                          f"{attn.get('latent_row_bytes', 0)} bytes), "
+                          if latent else "")
+                       + f"{attn['paths']['gather']} gathered; blocks read "
                        f"{read} of {held} in the tables ({share})")
         if lat.get("measured_requests"):
             out.append(f"  ttft  p50 {1e3 * lat['ttft_p50_s']:8.2f}ms   "

@@ -213,7 +213,7 @@ def test_paged_decode_attends_the_pools_in_place(xl_engine, on_tpu):
     program scaled to 48), and the pools still leave through the write
     they came in by."""
     engine, programs = xl_engine
-    assert engine.decode_attn_paths == {"block_table": 1, "gather": 0}
+    assert engine.decode_attn_paths == {"block_table": 1, "gather": 0, "latent": 0}
     prog, args = programs["paged_decode"]
     compiled = prog._jit.lower(*args).compile()
     text = compiled.as_text()
@@ -282,7 +282,7 @@ def test_two_kind_paged_program_compiles_for_v5e(laguna_engine, on_tpu,
     sliding layer's ring; the chunk program gathers both."""
     engine, programs = laguna_engine
     assert engine.ring_blocks == 65             # ceil((512 + 512) / 16) + 1
-    assert engine.decode_attn_paths == {"block_table": 1, "gather": 1}
+    assert engine.decode_attn_paths == {"block_table": 1, "gather": 1, "latent": 0}
     prog, args = programs[program]
     text = prog._jit.lower(*args).compile().as_text()
     entry = text[text.index("ENTRY"):]
@@ -511,3 +511,114 @@ def test_fsdp_step_brings_the_weights_to_the_rows(request, held):
     """What ``runtime.batch_pin`` buys cell 4, read off the program the
     chip's compiler makes of it, and what it must not cost cell 1."""
     held(request)
+
+
+# --- the latent layout (GLM-4.7-Flash on the paged engine) -----------------
+
+def _latent_kernels(text: str) -> list:
+    return re.findall(r"%(paged_latent_decode[\w.]*) = [^\n]*"
+                      r'custom_call_target="tpu_custom_call"', text)
+
+
+@pytest.mark.parametrize("width", [640, 576])
+def test_paged_latent_decode_compiles_for_v5e(chip, width):
+    """The latent kernel at the published widths (20 heads, 512 + 64 values
+    a row, padded to 640 lanes as the model rests it; and the bare 576, a
+    leaf no wider than its row), 16 slots of 1,536 blocks of 16."""
+    from distributed_deep_learning_tpu.ops.paged_decode_pallas import (
+        paged_latent_decode)
+
+    slots, bps, n_blocks = 16, 1536, 2049
+    q = jax.ShapeDtypeStruct((slots, 20, width), jnp.bfloat16, sharding=chip)
+    pool = jax.ShapeDtypeStruct((n_blocks, 16, width), jnp.bfloat16,
+                                sharding=chip)
+    valid = jax.ShapeDtypeStruct((n_blocks, 16), jnp.bool_, sharding=chip)
+    new = jax.ShapeDtypeStruct((slots, width), jnp.bfloat16, sharding=chip)
+    tables = jax.ShapeDtypeStruct((slots, bps), jnp.int32, sharding=chip)
+    lens = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=chip)
+
+    def decode(q, pool, valid, new, tables, lens):
+        return paged_latent_decode(q, pool, tables, lens, new, v_width=512,
+                                   sm_scale=1 / 16, valid_pool=valid,
+                                   interpret=False)
+
+    text = _compiled_text(decode, q, pool, valid, new, tables, lens)
+    assert len(_latent_kernels(text)) == 1
+
+
+# the glm cell's engine (benchmark/traffic/long-context.json) at the
+# configuration's widths, its first two layers: the dense layer and an
+# expert layer, both with latent attention, on a pool a tenth as long
+GLM_CELL = dict(max_slots=16, max_len=24576, kv_block_size=16,
+                num_blocks=2560, prefill_chunk=1024, donate=True)
+
+
+@pytest.fixture(scope="module")
+def glm_engine(chip):
+    from distributed_deep_learning_tpu.models import describe
+    from distributed_deep_learning_tpu.serve.engine import PagedEngine
+
+    def on_chip(x, dtype=None):
+        return jax.ShapeDtypeStruct(x.shape, dtype or x.dtype, sharding=chip)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
+
+    desc = dict(describe.read("benchmark/configs/glm-4.7-flash-d7.json"),
+                num_hidden_layers=2)
+    model = describe.causal_lm(desc, max_len=24576, with_logits=True,
+                               dtype=jnp.bfloat16)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.key(0),
+                           jnp.ones((1, 8), jnp.int32))["params"])
+    params = jax.tree.map(lambda s: on_chip(s, jnp.bfloat16), params)
+    engine = PagedEngine(model, params, **GLM_CELL)
+    head = (params, jax.tree.map(on_chip, engine.pools))
+    key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
+    slots, chunk, bps = (engine.max_slots, engine.chunk,
+                         engine.blocks_per_slot)
+    return engine, {
+        "paged_chunk": (engine._chunk_prog, head + (
+            i32(chunk), i32(bps), i32(), i32(), i32(chunk), i32(chunk),
+            key)),
+        "paged_decode": (engine._decode, head + (
+            i32(slots, bps), i32(slots), i32(slots), i32(slots),
+            i32(slots), key)),
+    }
+
+
+@pytest.mark.parametrize("program", ["paged_chunk", "paged_decode"])
+def test_latent_paged_program_compiles_for_v5e(glm_engine, on_tpu, program):
+    """The latent pool leaf rests as it is computed in (640 lanes: no copy
+    of a whole leaf; a 576-wide leaf rests block-index minor and costs two
+    such copies a layer and program).  The decode program attends ABSORBED
+    through the block table, one latent kernel a layer, and holds nothing
+    of the size of the gathered slots (16 x 24,576 rows); the chunk program
+    attends EXPANDED over one gathered slot with the keys walked in blocks:
+    no array of scores wider than a block of 512 keys, temporaries under
+    1 GiB (whole scores, 20 x 1,024 x 24,576 in float32, are 1.9 GiB)."""
+    engine, programs = glm_engine
+    assert engine.ring_blocks is None
+    assert engine.decode_attn_paths == {"block_table": 0, "gather": 0,
+                                        "latent": 2}
+    assert engine.latent_row_bytes == 1280
+    prog, args = programs[program]
+    compiled = prog._jit.lower(*args).compile()
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY"):]
+    rows = engine.num_blocks + 1
+    assert f"bf16[{rows},16,640]" in entry
+    copies = re.findall(rf"= (bf16\[{rows},[^ ]*) copy\(", entry)
+    assert not copies, copies
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+    kernels = re.findall(r"%(ragged-dot-none[\w.]*) = [^\n]*"
+                         r'custom_call_target="tpu_custom_call"', text)
+    assert len(kernels) == 3, kernels
+    gathered = re.findall(r"bf16\[16,(?:24576|1536,16),640\]", text)
+    scores = {int(k) for k in re.findall(r"f32\[20,1024,(\d+)\]", text)}
+    if program == "paged_decode":
+        assert len(_latent_kernels(text)) == 2 and not gathered
+    else:
+        assert not _latent_kernels(text)
+        assert "bf16[1,24576,640]" in text          # one slot, gathered
+        assert scores and max(scores) <= 512, scores

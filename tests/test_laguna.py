@@ -382,7 +382,8 @@ def test_gpt2_tree_pools_and_served_tokens_are_what_they_were():
     assert dataclasses.asdict(model.layer_specs()[0]) == {
         "num_heads": 4, "mlp_dim": 64, "num_kv_heads": None,
         "head_dim": None, "window": None, "rope": False, "gate": False,
-        "use_bias": True, "norm": "layer", "mlp": "gelu", "experts": None}
+        "use_bias": True, "norm": "layer", "mlp": "gelu", "experts": None,
+        "latent": None}
     eng = PagedEngine(model, params, max_slots=3, max_len=48,
                       kv_block_size=4, prefill_chunk=8)
     assert eng.ring_blocks is None and eng.manager.ring_blocks is None

@@ -107,10 +107,10 @@ def test_paged_matches_generate_and_compiles_once():
 
 
 @pytest.mark.parametrize("kw,paths", [
-    (dict(), {"block_table": 2, "gather": 0}),
-    (dict(kv_dtype="int8"), {"block_table": 2, "gather": 0}),
+    (dict(), {"block_table": 2, "gather": 0, "latent": 0}),
+    (dict(kv_dtype="int8"), {"block_table": 2, "gather": 0, "latent": 0}),
     (dict(draft_layers=1, spec_k=2, max_len=80),
-     {"block_table": 2, "gather": 0}),
+     {"block_table": 2, "gather": 0, "latent": 0}),
 ])
 def test_decode_program_attends_live_blocks_through_the_table(kw, paths):
     """The decode program hands every full-kind layer its pool leaves and
@@ -152,7 +152,7 @@ def test_decode_program_attends_live_blocks_through_the_table(kw, paths):
         attn["blocks_in_tables"] == 3 * held
     notes = [e for e in obs.compile_log.since_mark() if e[0] == "attn_paths"]
     assert notes == [("attn_paths", "jit(paged_decode)", notes[0][2],
-                      "block_table=2 gather=0")]
+                      "block_table=2 gather=0 latent=0")]
 
 
 def test_obs_report_prints_the_decode_attention_line():
@@ -185,7 +185,7 @@ def test_decode_view_keeps_pool_leaves_and_gathers_rings():
                 "ring_key": jax.ShapeDtypeStruct((1, 4, 2, 4), jnp.float32),
                 "cache_index": jax.ShapeDtypeStruct((), jnp.int32)}},
             "embed": {"pos_index": jax.ShapeDtypeStruct((), jnp.int32)}}
-    assert paged.attention_paths(like) == {"block_table": 1, "gather": 1}
+    assert paged.attention_paths(like) == {"block_table": 1, "gather": 1, "latent": 0}
     pools = paged.build_pools(like, 5, 2, ring_num_blocks=3)
     pools = jax.tree.map(
         lambda x: jnp.arange(x.size, dtype=jnp.float32).reshape(
