@@ -25,6 +25,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from flax.linen.dtypes import promote_dtype
 
 from distributed_deep_learning_tpu.models.moe import (ExpertSpec, GatedMLP,
                                                       RoutedExperts)
@@ -32,6 +33,45 @@ from distributed_deep_learning_tpu.runtime.batch_pin import pin_batch
 
 AttentionFn = Callable[..., jnp.ndarray]
 dense_init = nn.initializers.xavier_uniform()
+
+
+class MergedHeadsDense(nn.Module):
+    """``nn.DenseGeneral`` into heads (``features=(H, D)``) or out of them
+    (``features=d_model`` over the input's last two axes): the same
+    parameters under the same names, drawn the same way, but computed on
+    the merged ``H·D``: ONE 2-D product, the bias added to it, and only
+    then the view as heads.  A product or a sum whose result is 4-D, 64
+    wide, XLA:TPU rests sequence-minor (64 of a tile's 128 lanes would be
+    padding), and every reshape to or from ``(B, T, H·D)`` around a kernel
+    that reads that layout is then a copy of the whole array; with no 4-D
+    value computed the reshapes are views.  What an attention function
+    that ``reads_heads_merged`` gets its q, k and v from, and hands to."""
+
+    features: Union[int, tuple]
+    dtype: jnp.dtype = jnp.float32
+    use_bias: bool = True
+
+    @nn.compact
+    def __call__(self, x):
+        into = isinstance(self.features, tuple)
+        if into:
+            shape = (x.shape[-1],) + self.features
+            flat = (x.shape[-1], math.prod(self.features))
+        else:
+            shape = x.shape[-2:] + (self.features,)
+            flat = (math.prod(x.shape[-2:]), self.features)
+            x = x.reshape(x.shape[:-2] + flat[:1])
+        kernel = self.param(
+            "kernel", lambda rng, shape, dtype: dense_init(
+                rng, flat, dtype).reshape(shape), shape, jnp.float32)
+        bias = self.param("bias", nn.initializers.zeros_init(), shape[1:]
+                          if into else shape[2:], jnp.float32) \
+            if self.use_bias else None
+        x, kernel, bias = promote_dtype(x, kernel, bias, dtype=self.dtype)
+        y = x @ kernel.reshape(flat)
+        if bias is not None:
+            y = y + bias.reshape(-1)
+        return y.reshape(y.shape[:-1] + self.features) if into else y
 
 
 def dot_product_attention(q, k, v, *, mask=None, key_valid=None,
@@ -227,9 +267,18 @@ class MultiHeadAttention(nn.Module):
         if self.num_heads % kv_heads:
             raise ValueError(f"num_kv_heads {kv_heads} must divide "
                              f"num_heads {self.num_heads}")
-        proj = lambda name, h: nn.DenseGeneral(  # noqa: E731
-            (h, head_dim), dtype=self.dtype, use_bias=self.use_bias,
-            kernel_init=dense_init, name=name)
+        attn = self.attention_fn or dot_product_attention
+        # a kernel that reads q, k and v as (B, T, H·D) gets them from
+        # projections that never compute a 4-D value (training only: a
+        # cached call attends densely or through the block table)
+        merged = (not self.decode
+                  and getattr(attn, "reads_heads_merged", False))
+        proj = lambda name, h: (  # noqa: E731
+            MergedHeadsDense((h, head_dim), dtype=self.dtype,
+                             use_bias=self.use_bias, name=name) if merged
+            else nn.DenseGeneral(
+                (h, head_dim), dtype=self.dtype, use_bias=self.use_bias,
+                kernel_init=dense_init, name=name))
         q = proj("q", self.num_heads)(x_q)
         k = proj("k", kv_heads)(x_kv)
         v = proj("v", kv_heads)(x_kv)
@@ -244,7 +293,6 @@ class MultiHeadAttention(nn.Module):
         # under a sharded step every value a module hands on stays on the
         # batch axes (runtime.batch_pin): the weights come to the rows
         q, k, v = pin_batch(q), pin_batch(k), pin_batch(v)
-        attn = self.attention_fn or dot_product_attention
         y = None
         if self.decode:
             is_init = not self.has_variable("cache", "cache_index")
@@ -337,6 +385,9 @@ class MultiHeadAttention(nn.Module):
                          kernel_init=dense_init, name="gate")(x_q)
             y = y * nn.sigmoid(a.astype(jnp.float32)).astype(
                 self.dtype)[..., None]
+        if merged:
+            return MergedHeadsDense(d_model, dtype=self.dtype,
+                                    use_bias=self.use_bias, name="out")(y)
         return nn.DenseGeneral(d_model, axis=(-2, -1), dtype=self.dtype,
                                use_bias=self.use_bias,
                                kernel_init=dense_init, name="out")(y)

@@ -148,7 +148,8 @@ class RunTelemetry:
         self.writer.emit("obs_snapshot", snapshot=snap)
         summary = {"goodput": gp, "mfu": rec, "snapshot": snap}
         # what the run's programs said of themselves as they were traced
-        # (batch_pins of a train step, attn_paths of a decode program)
+        # (batch_pins and flash_layout of a train step, attn_paths of a
+        # decode program)
         notes = [{"program": fun, "note": event, "text": text}
                  for event, fun, text in compile_log.notes()]
         if notes:

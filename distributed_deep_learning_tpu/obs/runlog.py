@@ -21,6 +21,7 @@ entries of the current start alone.
 
 from __future__ import annotations
 
+import contextlib
 import time
 from collections import deque
 from typing import Iterable, Optional
@@ -83,6 +84,9 @@ class CompileLog:
 
     def __init__(self, capacity: int = 8192) -> None:
         self.entries: deque = deque(maxlen=capacity)
+        #: what the program being traced has said of itself so far, an
+        #: ``event -> (items, render)`` (:meth:`notes_for`); None outside
+        self._gathered: Optional[dict] = None
 
     def mark(self, label: str) -> None:
         self.entries.append((MARK, label, time.time(), 0.0))
@@ -92,6 +96,31 @@ class CompileLog:
         its paths each layer took), in the log beside its compile events:
         `text` where they carry seconds."""
         self.entries.append((event, fun_name, time.time(), text))
+
+    @contextlib.contextmanager
+    def notes_for(self, program: str, **always):
+        """Around the trace of `program` (a step builder opens it): what
+        code deep inside says of the program one item at a time
+        (:meth:`gather`: a pinned activation, a kernel call) becomes one
+        note an event at the end, ``render(items)``, since a count is known
+        only then.  `always` names the renderers of events noted even where
+        nothing was gathered (``batch_pins``: ``axes=none sites=0``)."""
+        outer = self._gathered
+        self._gathered = {e: ([], render) for e, render in always.items()}
+        try:
+            yield
+        finally:
+            gathered, self._gathered = self._gathered, outer
+            for event, (items, render) in gathered.items():
+                self.note(event, program, render(items))
+
+    def gather(self, event: str, item, render) -> bool:
+        """`item` for the `event` note of the program being traced; False,
+        and nothing kept, outside any :meth:`notes_for`."""
+        if self._gathered is None:
+            return False
+        self._gathered.setdefault(event, ([], render))[0].append(item)
+        return True
 
     def _span(self, event, start, end, fun_name=None, **_):
         short = _SPANS.get(event)

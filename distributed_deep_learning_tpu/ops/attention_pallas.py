@@ -21,16 +21,27 @@ backward, and benched 0.54× dense on a v5e):
 * **Causal block skipping**: a query block at offset ``q_off`` stops its
   key loop at the diagonal (``ceil((q_off+bq)/bk)`` blocks) instead of
   scanning all of K — half the work, and the dominant win at long T.
-* **A real flash backward**: two Pallas kernels (dQ; dK/dV fused) recompute
-  scores blockwise from the forward's saved LSE — O(T·D) HBM traffic in
-  backward too.  The forward emits LSE precisely to enable this (the
-  standard flash-attention-2 decomposition: ``delta = rowsum(dO·O)`` then
+* **A real flash backward**: one Pallas kernel recomputes scores blockwise
+  from the forward's saved LSE — O(T·D) HBM traffic in backward too — and
+  takes dQ, dK and dV from that ONE recomputation (as two kernels, dQ over
+  query blocks and dK/dV over key blocks, each block of scores was made
+  twice).  The forward emits LSE precisely to enable this (the standard
+  flash-attention-2 decomposition: ``delta = rowsum(dO·O)`` then
   ``ds = p·(dO·Vᵀ − delta)``).
+* **Operands where they rest**: q, k and v are read, and o, dq, dk and dv
+  written, as the projections lay them out, ``(B, T, H·D)``: a program takes
+  a block of 128 lanes — two 64-wide heads, walked as separate problems over
+  static lane slices and stored as one full-lane value — so no transpose to
+  ``(B·H, T, D)`` and back surrounds a call (seven copies of an activation
+  a layer, a third of attention's time in a GPT-2 medium step).  Which lanes
+  a program takes is a function of ``(D, H, Hkv)`` alone (:func:`_tiling`);
+  shapes that cannot be tiled in whole heads transpose as before, through
+  the same kernels.
 
-Grid: one program per (batch·head, query-block) forward / (batch·head,
-query-block) for dQ / (batch·head, key-block) for dK/dV; inner loops are
-``fori_loop`` with *dynamic* (diagonal-bounded) trip counts — uniform
-control flow, nothing shape-dependent.
+Grid: one program per (batch row, lane block, query-block) forward /
+(batch row, lane block, key-block) backward; inner loops are ``fori_loop``
+with *dynamic* (diagonal-bounded) trip counts — uniform control flow,
+nothing shape-dependent.
 
 On non-TPU platforms the kernels run in interpreter mode so the identical
 code path is testable on the CPU mesh.
@@ -49,6 +60,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from distributed_deep_learning_tpu.obs import runlog
 
 NEG_INF = -1e30
 
@@ -76,14 +89,85 @@ def _window_lo(q_off, window, block_k):
 
 
 # --------------------------------------------------------------------------
+# layout: which lanes a program takes
+# --------------------------------------------------------------------------
+
+LANES = 128
+#: the VMEM a kernel may use unless it asks for more (of 128 MiB on a v5e)
+VMEM_DEFAULT = 16 << 20
+
+
+def _vmem(held: int):
+    """Compiler parameters of a kernel that holds `held` bytes of operands
+    whole, as long as the sequence is: none (it compiles as it always did)
+    while they take under half of what a kernel gets unasked, T = 2,048 in
+    the backward at 64-wide heads; past that the kernel asks for them on
+    top of it, and the limit to T is the chip's VMEM, not the default."""
+    if 2 * held <= VMEM_DEFAULT:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=held + VMEM_DEFAULT)
+
+
+def _tiling(D: int, H: int, Hkv: int):
+    """``(lanes a block, heads a block)`` of the kernels over q, k and v as
+    the projections write them, ``(B, T, H·D)``, from the shapes alone; None
+    where no whole block of lanes holds whole heads of q AND of their k / v,
+    and the call transposes to ``(B·H, T, D)`` first.
+
+    * ``D`` divides 128 and every query head has its own K/V head: ``128 //
+      D`` heads a block (all of them where ``H·D`` is under 128).  An ``H·D``
+      that is no multiple of 128 ends in a boundary block with fewer heads
+      (gpt2-xl: 25 x 64 = 12 blocks of two and one of one): the lanes past
+      the array are padding on the way in and never stored on the way out.
+    * ``D`` a multiple of 128: a head a block; query block ``j`` reads K/V
+      block ``j // group`` (GQA, as the transposing path maps rows).
+    * ``D`` under 128 with grouped K/V would split a K/V head over query
+      blocks, and a ``D`` such as 96 or 192 a head over blocks: transposed.
+    """
+    if D < LANES and LANES % D == 0 and H == Hkv:
+        return (LANES, LANES // D) if H * D > LANES else (H * D, H)
+    if D % LANES == 0:
+        return D, 1
+    return None
+
+
+def _layout_text(calls) -> str:
+    """The ``flash_layout`` note: ``calls=N lanes_a_block=L heads_a_block=G
+    transposed=M``, the lanes and heads of the calls that read q, k and v
+    where they rest (the widest, should a program hold several shapes), and
+    how many calls did not."""
+    rest = [c for c in calls if not c[2]] or calls
+    return (f"calls={len(calls)} lanes_a_block={max(c[0] for c in rest)} "
+            f"heads_a_block={max(c[1] for c in rest)} "
+            f"transposed={sum(c[2] for c in calls)}")
+
+
+def _note_call(lanes: int, heads: int, transposed: bool) -> None:
+    """One call, for the note of the program being traced (a step builder's
+    ``obs.compile_log.notes_for``); traced outside any, the call leaves its
+    own note under ``flash_attention``."""
+    call = (lanes, heads, transposed)
+    if not runlog.compile_log.gather("flash_layout", call, _layout_text):
+        runlog.compile_log.note("flash_layout", "flash_attention",
+                                _layout_text([call]))
+
+
+# --------------------------------------------------------------------------
 # forward
 # --------------------------------------------------------------------------
 
-# The row-statistic (LSE) tensor is stored (BH, T, 1): Mosaic requires block
-# shapes' last two dims to be (8, 128)-aligned or array-sized, which a
-# (1, block_q) spec over a 2D (BH, T) array violates — but a trailing
-# size-1 dim equals its array dim, so (1, block_q, 1) blocks are legal and
-# cost 4 bytes/row instead of the official kernel's 128-lane broadcast.
+# Operands are (rows, T, W): W = H·D lanes of `rows` = B batch rows where
+# the call reads the projections' own layout, W = D and `rows` = B·H on the
+# transposing path.  One kernel family serves both: a program takes `lanes`
+# of W (a block of whole heads, each `d` wide) and walks its heads as
+# separate problems over static lane slices.
+#
+# The row statistic (LSE) rests (rows, lane blocks, T, G): a column a head
+# of the block, so a program takes its block's, (1, 1, block_q, G), and a
+# head's (block_q, 1) column is a one-lane slice of it.  Mosaic requires a
+# block's last two dims (8, 128)-aligned or array-sized, and G equals its
+# array dim.  (In HBM and VMEM a row of G values still pads to a tile's 128
+# lanes; one column an array, (rows, H, T, 1), would pad twice as much.)
 
 
 def drop_kv(kern, n_fixed):
@@ -94,34 +178,16 @@ def drop_kv(kern, n_fixed):
     return wrapped
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, kv_ref, o_ref, lse_ref, *,
+def _join(heads):
+    """A block's heads side by side: one full-lane value to store."""
+    return heads[0] if len(heads) == 1 else jnp.concatenate(heads, axis=-1)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, kv_ref, o_ref, lse_ref, *, d: int,
                 sm_scale: float, causal: bool, block_k: int, k_len: int,
                 window: int | None = None):
-    q = q_ref[0]                                     # (bq, D), input dtype
-    bq, d = q.shape
-    q_off = pl.program_id(1) * bq
-
-    m0 = jnp.full((bq, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq, 1), jnp.float32)
-    acc0 = jnp.zeros((bq, d), jnp.float32)
-
-    def body(i, carry):
-        m, l, acc = carry
-        k = k_ref[0, pl.ds(i * block_k, block_k), :]
-        v = v_ref[0, pl.ds(i * block_k, block_k), :]
-        s = _dot(q, k, ((1,), (1,))) * sm_scale      # (bq, bk) f32
-        if causal:
-            s = _causal_mask(s, q_off, i * block_k, bq, block_k, window)
-        if kv_ref is not None:
-            valid = kv_ref[0, i]                     # (1, bk) f32
-            s = jnp.where(valid > 0, s, NEG_INF)
-        blk_max = jnp.max(s, axis=-1, keepdims=True)
-        new_m = jnp.maximum(m, blk_max)
-        corr = jnp.exp(m - new_m)
-        p = jnp.exp(s - new_m)
-        new_l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        pv = _dot(p.astype(v.dtype), v, ((1,), (0,)))
-        return new_m, new_l, acc * corr + pv
+    bq, lanes = q_ref.shape[1:]
+    q_off = pl.program_id(2) * bq
 
     n_blocks = k_len // block_k
     lo = 0
@@ -132,20 +198,49 @@ def _fwd_kernel(q_ref, k_ref, v_ref, kv_ref, o_ref, lse_ref, *,
         if window is not None:
             # sliding window: skip key blocks fully below it too
             lo = _window_lo(q_off, window, block_k)
-    m, l, acc = lax.fori_loop(lo, n_blocks, body, (m0, l0, acc0))
-    # all-keys-masked rows (fully-padded sequence) degrade to uniform
-    # attention over the visited key blocks (the dense path averages over
-    # all Tk; same spirit, padded-row values are garbage either way) —
-    # never NaN, and backward treats such rows as zero-gradient
-    l = jnp.maximum(l, 1e-30)
-    o_ref[0] = (acc / l).astype(o_ref.dtype)
-    # clamp m before adding log(l): with m = NEG_INF (fully-masked row)
-    # f32 absorbs log(l) entirely and the backward's exp(s - lse) would
-    # evaluate to 1 per masked key instead of ~0.  Clamped, backward
-    # gradients for fully-padded rows are exactly zero (the dense path
-    # gives dq = dk = 0 via the mask's where-grad and a ~1/Tk·dO dv; we
-    # zero dv too — padded rows contribute no update either way).
-    lse_ref[0] = jnp.maximum(m, -1e20) + jnp.log(l)
+
+    outs, lses = [], []
+    for h in range(lanes // d):                      # the block's heads
+        at = slice(h * d, (h + 1) * d)
+        q = q_ref[0, :, at]                          # (bq, d), input dtype
+
+        def body(i, carry, q=q, at=at):
+            m, l, acc = carry
+            k = k_ref[0, pl.ds(i * block_k, block_k), at]
+            v = v_ref[0, pl.ds(i * block_k, block_k), at]
+            s = _dot(q, k, ((1,), (1,))) * sm_scale  # (bq, bk) f32
+            if causal:
+                s = _causal_mask(s, q_off, i * block_k, bq, block_k, window)
+            if kv_ref is not None:
+                valid = kv_ref[0, i]                 # (1, bk) f32
+                s = jnp.where(valid > 0, s, NEG_INF)
+            blk_max = jnp.max(s, axis=-1, keepdims=True)
+            new_m = jnp.maximum(m, blk_max)
+            corr = jnp.exp(m - new_m)
+            p = jnp.exp(s - new_m)
+            new_l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
+            pv = _dot(p.astype(v.dtype), v, ((1,), (0,)))
+            return new_m, new_l, acc * corr + pv
+
+        m0 = jnp.full((bq, 1), NEG_INF, jnp.float32)
+        l0 = jnp.zeros((bq, 1), jnp.float32)
+        acc0 = jnp.zeros((bq, d), jnp.float32)
+        m, l, acc = lax.fori_loop(lo, n_blocks, body, (m0, l0, acc0))
+        # all-keys-masked rows (fully-padded sequence) degrade to uniform
+        # attention over the visited key blocks (the dense path averages
+        # over all Tk; same spirit, padded-row values are garbage either
+        # way) — never NaN, and backward treats such rows as zero-gradient
+        l = jnp.maximum(l, 1e-30)
+        outs.append((acc / l).astype(o_ref.dtype))
+        # clamp m before adding log(l): with m = NEG_INF (fully-masked row)
+        # f32 absorbs log(l) entirely and the backward's exp(s - lse) would
+        # evaluate to 1 per masked key instead of ~0.  Clamped, backward
+        # gradients for fully-padded rows are exactly zero (the dense path
+        # gives dq = dk = 0 via the mask's where-grad and a ~1/Tk·dO dv; we
+        # zero dv too — padded rows contribute no update either way).
+        lses.append(jnp.maximum(m, -1e20) + jnp.log(l))
+    o_ref[0] = _join(outs)
+    lse_ref[0, 0] = _join(lses)
 
 
 def _fit_block(length: int, requested: int) -> int:
@@ -167,124 +262,135 @@ def _mask_blocks(kvalid, block_k):
     return kvalid.reshape(B, Tk // block_k, 1, block_k)
 
 
-def _flash_fwd(q, k, v, kvalid, sm_scale, causal, block_q, block_k,
-               interpret, window=None):
-    BH, Tq, D = q.shape
-    Tk = k.shape[1]
-    block_q = _fit_block(Tq, block_q)
-    block_k = _fit_block(Tk, block_k)
-    # GQA-native (round 5): q rows are (batch, kv_head, group_member)-
-    # ordered, so query program b reads K/V row b // kv_group — the kernel
-    # streams the TRUE (B·Hkv) K/V, never a (B·H) head-expanded copy (the
-    # group× HBM saving is the whole point of grouped-query attention).
-    # kvalid is per-batch, shared by every head: row b // valid_group.
-    kv_group = BH // k.shape[0]
-    valid_group = BH // kvalid.shape[0] if kvalid is not None else 1
-    kernel = functools.partial(
-        _fwd_kernel if kvalid is not None else drop_kv(_fwd_kernel, 3),
-        sm_scale=sm_scale, causal=causal, block_k=block_k, k_len=Tk,
-        window=window)
-    in_specs = [
-        pl.BlockSpec((1, block_q, D), lambda b, qi: (b, qi, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, Tk, D), lambda b, qi: (b // kv_group, 0, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, Tk, D), lambda b, qi: (b // kv_group, 0, 0),
-                     memory_space=pltpu.VMEM),
-    ]
-    args = [q, k, v]
-    if kvalid is not None:
+class _Grid:
+    """The index maps of one call, grid ``(rows, lane blocks, T blocks)``:
+    which block of each operand a program takes."""
+
+    def __init__(self, q, k, kvalid, d, lanes, block_q, block_k):
+        self.rows, self.Tq, width = q.shape
+        rows = self.rows
+        self.Tk = k.shape[1]
+        self.lanes, self.heads = lanes, lanes // d
+        self.lane_blocks = pl.cdiv(width, lanes)
+        self.block_q = _fit_block(self.Tq, block_q)
+        self.block_k = _fit_block(self.Tk, block_k)
+        # GQA-native (round 5): the kernels stream the TRUE K/V, never a
+        # head-expanded copy (the group× HBM saving is the whole point of
+        # grouped-query attention).  Transposed, q rows are (batch,
+        # kv_head, group_member)-ordered and query row b reads K/V row
+        # b // kv_rows; where they rest, query lane block j reads K/V lane
+        # block j // kv_lanes.  kvalid is per-batch, shared by every head.
+        self.kv_rows = rows // k.shape[0]
+        self.kv_lanes = self.lane_blocks // pl.cdiv(k.shape[2], lanes)
+        self.valid_rows = rows // kvalid.shape[0] if kvalid is not None else 1
+
+    @staticmethod
+    def spec(shape, index_map):
+        return pl.BlockSpec(shape, index_map, memory_space=pltpu.VMEM)
+
+    def grid(self, t_blocks):
+        return self.rows, self.lane_blocks, t_blocks
+
+    def q_block(self):
+        return self.spec((1, self.block_q, self.lanes),
+                         lambda b, j, i: (b, i, j))
+
+    def q_whole(self):
+        return self.spec((1, self.Tq, self.lanes), lambda b, j, i: (b, 0, j))
+
+    def kv_block(self, shared=True):
+        r, g = (self.kv_rows, self.kv_lanes) if shared else (1, 1)
+        return self.spec((1, self.block_k, self.lanes),
+                         lambda b, j, i: (b // r, i, j // g))
+
+    def kv_whole(self):
+        r, g = self.kv_rows, self.kv_lanes
+        return self.spec((1, self.Tk, self.lanes),
+                         lambda b, j, i: (b // r, 0, j // g))
+
+    def stat_block(self):
+        return self.spec((1, 1, self.block_q, self.heads),
+                         lambda b, j, i: (b, j, i, 0))
+
+    def stat_whole(self):
+        return self.spec((1, 1, self.Tq, self.heads),
+                         lambda b, j, i: (b, j, 0, 0))
+
+    def mask_whole(self):
         # every key block of this batch row; the size-1 sublane dim keeps
         # the block Mosaic-legal (a (1, bk) block over a 2D mask is not)
-        in_specs.append(pl.BlockSpec(
-            (1, Tk // block_k, 1, block_k),
-            lambda b, qi: (b // valid_group, 0, 0, 0),
-            memory_space=pltpu.VMEM))
-        args.append(_mask_blocks(kvalid, block_k))
+        r = self.valid_rows
+        return self.spec((1, self.Tk // self.block_k, 1, self.block_k),
+                         lambda b, j, i: (b // r, 0, 0, 0))
+
+    def mask_block(self):
+        r = self.valid_rows
+        return self.spec((1, 1, 1, self.block_k),
+                         lambda b, j, i: (b // r, i, 0, 0))
+
+    def stat_shape(self):
+        return jax.ShapeDtypeStruct(
+            (self.rows, self.lane_blocks, self.Tq, self.heads), jnp.float32)
+
+
+# jitted, so that a model's layers trace and lower each kernel once between
+# them (a 48-layer step traced 96 kernel bodies, a fifth of its set-up)
+_STATIC = tuple(range(4, 12))
+
+
+@functools.partial(jax.jit, static_argnums=_STATIC)
+def _flash_fwd(q, k, v, kvalid, sm_scale, causal, block_q, block_k,
+               interpret, window, d, lanes):
+    g = _Grid(q, k, kvalid, d, lanes, block_q, block_k)
+    kernel = functools.partial(
+        _fwd_kernel if kvalid is not None else drop_kv(_fwd_kernel, 3),
+        d=d, sm_scale=sm_scale, causal=causal, block_k=g.block_k,
+        k_len=g.Tk, window=window)
+    in_specs = [g.q_block(), g.kv_whole(), g.kv_whole()]
+    args = [q, k, v]
+    if kvalid is not None:
+        in_specs.append(g.mask_whole())
+        args.append(_mask_blocks(kvalid, g.block_k))
     out, lse = pl.pallas_call(
         kernel,
-        grid=(BH, Tq // block_q),
+        grid=g.grid(g.Tq // g.block_q),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, qi: (b, qi, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q, 1), lambda b, qi: (b, qi, 0),
-                         memory_space=pltpu.VMEM),
-        ],
+        out_specs=[g.q_block(), g.stat_block()],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
-                   jax.ShapeDtypeStruct((BH, Tq, 1), jnp.float32)],
-        interpret=interpret,
+                   g.stat_shape()],
+        # held as long as T is: K and V whole, two buffers each
+        compiler_params=_vmem(g.Tk * 4 * lanes * k.dtype.itemsize),
+        interpret=interpret, name="flash_fwd",
     )(*args)
     return out, lse
 
 
 # --------------------------------------------------------------------------
-# backward (flash-attention-2 decomposition, two kernels)
+# backward (flash-attention-2 decomposition, one kernel)
 # --------------------------------------------------------------------------
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, kv_ref,
-               dq_ref, *, sm_scale: float, causal: bool, block_k: int,
-               k_len: int, window: int | None = None):
-    q = q_ref[0]                                     # (bq, D)
-    do = do_ref[0]
-    bq, d = q.shape
-    q_off = pl.program_id(1) * bq
-    lse = lse_ref[0]                                 # (bq, 1) f32
-    delta = delta_ref[0]                             # (bq, 1) f32
-
-    def body(i, acc):
-        k = k_ref[0, pl.ds(i * block_k, block_k), :]
-        v = v_ref[0, pl.ds(i * block_k, block_k), :]
-        s = _dot(q, k, ((1,), (1,))) * sm_scale
-        if causal:
-            s = _causal_mask(s, q_off, i * block_k, bq, block_k, window)
-        if kv_ref is not None:
-            valid = kv_ref[0, i]                     # (1, bk)
-            s = jnp.where(valid > 0, s, NEG_INF)
-        p = jnp.exp(s - lse)                         # (bq, bk) f32
-        dp = _dot(do, v, ((1,), (1,)))               # (bq, bk) f32
-        ds = p * (dp - delta) * sm_scale
-        return acc + _dot(ds.astype(k.dtype), k, ((1,), (0,)))
-
-    n_blocks = k_len // block_k
-    lo = 0
-    if causal:
-        n_blocks = jnp.minimum(n_blocks,
-                               (q_off + bq + block_k - 1) // block_k)
-        if window is not None:
-            lo = _window_lo(q_off, window, block_k)
-    acc = lax.fori_loop(lo, n_blocks, body, jnp.zeros((bq, d), jnp.float32))
-    dq_ref[0] = acc.astype(dq_ref.dtype)
-
-
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, kv_ref,
-                dk_ref, dv_ref, *, sm_scale: float, causal: bool,
-                block_q: int, q_len: int, window: int | None = None):
-    k = k_ref[0]                                     # (bk, D)
-    v = v_ref[0]
-    bk, d = k.shape
-    k_off = pl.program_id(1) * bk
+def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, kv_ref,
+                dq_ref, dk_ref, dv_ref, dq_acc, *, d: int, sm_scale: float,
+                causal: bool, block_q: int, q_len: int,
+                window: int | None = None):
+    """dQ, dK and dV of one key block of a lane block's heads: a program
+    walks the query blocks that attend to its keys and recomputes each
+    block of scores ONCE for all three (as two kernels, dQ over query
+    blocks and dK/dV over key blocks, every block of scores, its mask, its
+    exponentials and ``dP`` were computed twice, and the VPU is what these
+    kernels wait for at 64-wide heads).  dK and dV are the program's own;
+    dQ sums over the key blocks, which are the grid's innermost axis: it
+    accumulates in an f32 scratch that the first key block clears and the
+    last one casts into the output block, resident meanwhile."""
+    bk, lanes = k_ref.shape[1:]
+    j = pl.program_id(2)
+    k_off = j * bk
     valid = kv_ref[0, 0] if kv_ref is not None else None     # (1, bk)
 
-    def body(i, carry):
-        dk, dv = carry
-        q = q_ref[0, pl.ds(i * block_q, block_q), :]
-        do = do_ref[0, pl.ds(i * block_q, block_q), :]
-        lse = lse_ref[0, pl.ds(i * block_q, block_q), :]     # (bq, 1)
-        delta = delta_ref[0, pl.ds(i * block_q, block_q), :]
-        s = _dot(q, k, ((1,), (1,))) * sm_scale      # (bq, bk) f32
-        if causal:
-            s = _causal_mask(s, i * block_q, k_off, block_q, bk, window)
-        if valid is not None:
-            s = jnp.where(valid > 0, s, NEG_INF)
-        p = jnp.exp(s - lse)
-        dv = dv + _dot(p.astype(do.dtype), do, ((0,), (0,)))   # (bk, D)
-        dp = _dot(do, v, ((1,), (1,)))               # (bq, bk) f32
-        ds = p * (dp - delta) * sm_scale
-        dk = dk + _dot(ds.astype(q.dtype), q, ((0,), (0,)))    # (bk, D)
-        return dk, dv
+    @pl.when(j == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    zeros = jnp.zeros((bk, d), jnp.float32)
     # causal: query blocks strictly above this key block's row range never
     # attend to it — start the loop at the diagonal
     lo = k_off // block_q if causal else 0
@@ -293,98 +399,98 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, kv_ref,
         # windowed: queries beyond k_pos + window - 1 never attend either
         hi = jnp.minimum(hi,
                          (k_off + bk + window - 2) // block_q + 1)
-    dk, dv = lax.fori_loop(lo, hi, body, (zeros, zeros))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+
+    dks, dvs = [], []
+    for h in range(lanes // d):
+        at = slice(h * d, (h + 1) * d)
+        k = k_ref[0, :, at]                          # (bk, d)
+        v = v_ref[0, :, at]
+
+        def body(i, carry, k=k, v=v, at=at, h=h):
+            dk, dv = carry
+            rows = pl.ds(i * block_q, block_q)
+            q = q_ref[0, rows, at]
+            do = do_ref[0, rows, at]
+            lse = lse_ref[0, 0, rows, h:h + 1]       # (bq, 1) f32
+            # delta = rowsum(dO ⊙ O), here where both blocks are in VMEM
+            # anyway (as a pass of XLA's over (B, T, H·D) it cost two
+            # layout copies beside; a block of it is 1/bk of a block of
+            # scores, so once a key block is as good as once)
+            delta = jnp.sum(do.astype(jnp.float32)
+                            * o_ref[0, rows, at].astype(jnp.float32),
+                            axis=-1, keepdims=True)  # (bq, 1) f32
+            s = _dot(q, k, ((1,), (1,))) * sm_scale  # (bq, bk) f32
+            if causal:
+                s = _causal_mask(s, i * block_q, k_off, block_q, bk, window)
+            if valid is not None:
+                s = jnp.where(valid > 0, s, NEG_INF)
+            p = jnp.exp(s - lse)
+            dv = dv + _dot(p.astype(do.dtype), do, ((0,), (0,)))  # (bk, d)
+            dp = _dot(do, v, ((1,), (1,)))           # (bq, bk) f32
+            ds = (p * (dp - delta) * sm_scale).astype(q.dtype)
+            dk = dk + _dot(ds, q, ((0,), (0,)))      # (bk, d)
+            dq_acc[h, rows, :] += _dot(ds, k, ((1,), (0,)))       # (bq, d)
+            return dk, dv
+
+        zeros = jnp.zeros((bk, d), jnp.float32)
+        dk, dv = lax.fori_loop(lo, hi, body, (zeros, zeros))
+        dks.append(dk.astype(dk_ref.dtype))
+        dvs.append(dv.astype(dv_ref.dtype))
+    dk_ref[0] = _join(dks)
+    dv_ref[0] = _join(dvs)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        dq_ref[0] = _join([dq_acc[h].astype(dq_ref.dtype)
+                           for h in range(lanes // d)])
 
 
-def _flash_bwd(q, k, v, kvalid, out, lse, g, sm_scale, causal, block_q,
-               block_k, interpret, window=None):
-    BH, Tq, D = q.shape
-    Tk = k.shape[1]
-    block_q = _fit_block(Tq, block_q)
-    block_k = _fit_block(Tk, block_k)
-    kv_group = BH // k.shape[0]  # GQA: K/V rows shared by `group` q heads
-    valid_group = BH // kvalid.shape[0] if kvalid is not None else 1
-    # delta = rowsum(dO ⊙ O), precomputed ONCE (plain XLA, fuses with the
-    # surrounding graph) and threaded to both kernels like lse — cheaper
-    # than streaming O into the kernels and recomputing per key block
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1, keepdims=True)
-
-    qspec = pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0),
-                         memory_space=pltpu.VMEM)
-    qfull = pl.BlockSpec((1, Tq, D), lambda b, i: (b, 0, 0),
-                         memory_space=pltpu.VMEM)
-    kspec = pl.BlockSpec((1, block_k, D), lambda b, i: (b, i, 0),
-                         memory_space=pltpu.VMEM)
-    kfull = pl.BlockSpec((1, Tk, D), lambda b, i: (b // kv_group, 0, 0),
-                         memory_space=pltpu.VMEM)
-    kblk_shared = pl.BlockSpec((1, block_k, D),
-                               lambda b, i: (b // kv_group, i, 0),
-                               memory_space=pltpu.VMEM)
-    lseblk = pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0),
-                          memory_space=pltpu.VMEM)
-    lsefull = pl.BlockSpec((1, Tq, 1), lambda b, i: (b, 0, 0),
-                           memory_space=pltpu.VMEM)
-    kvfull = pl.BlockSpec((1, Tk // block_k, 1, block_k),
-                          lambda b, i: (b // valid_group, 0, 0, 0),
-                          memory_space=pltpu.VMEM)
-    kvblk = pl.BlockSpec((1, 1, 1, block_k),
-                         lambda b, i: (b // valid_group, i, 0, 0),
-                         memory_space=pltpu.VMEM)
-    if kvalid is not None:
-        kvalid = _mask_blocks(kvalid, block_k)
-
-    # ---- dQ: grid over query blocks -------------------------------------
-    dq_kernel = functools.partial(
-        _dq_kernel if kvalid is not None else drop_kv(_dq_kernel, 6),
-        sm_scale=sm_scale, causal=causal, block_k=block_k, k_len=Tk,
-        window=window)
-    dq_specs = [qspec, kfull, kfull, qspec, lseblk, lseblk]
-    dq_args = [q, k, v, g, lse, delta]
-    if kvalid is not None:
-        dq_specs.append(kvfull)
-        dq_args.append(kvalid)
-    dq = pl.pallas_call(
-        dq_kernel,
-        grid=(BH, Tq // block_q),
-        in_specs=dq_specs,
-        out_specs=qspec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
-    )(*dq_args)
-
-    # ---- dK/dV (fused): grid over key blocks ----------------------------
+@functools.partial(jax.jit, static_argnums=tuple(range(7, 15)))
+def _flash_bwd(q, k, v, kvalid, out, lse, do, sm_scale, causal, block_q,
+               block_k, interpret, window, d, lanes):
+    g = _Grid(q, k, kvalid, d, lanes, block_q, block_k)
+    rows, Tq, width = q.shape
     # GQA: each query-head program computes ITS contribution to the shared
-    # K/V rows' gradients ((BH, Tk, D) partials); the group-sum reduction
-    # to (B·Hkv, Tk, D) happens outside in f32 — group rows are adjacent
-    # by construction (b = kv_row·group + member), so it is one reshape.
-    dkv_kernel = functools.partial(
-        _dkv_kernel if kvalid is not None else drop_kv(_dkv_kernel, 6),
-        sm_scale=sm_scale, causal=causal, block_q=block_q, q_len=Tq,
+    # K/V heads' gradients (partials the shape of q's heads); the group-sum
+    # reduction to k's shape happens outside in f32 — a group's heads are
+    # adjacent by construction (head = kv_head·group + member), rows when
+    # transposed and lanes where they rest, so it is one reshape.
+    kernel = functools.partial(
+        _bwd_kernel if kvalid is not None else drop_kv(_bwd_kernel, 6),
+        d=d, sm_scale=sm_scale, causal=causal, block_q=g.block_q, q_len=Tq,
         window=window)
-    dkv_specs = [qfull, kblk_shared, kblk_shared, qfull, lsefull, lsefull]
-    dkv_args = [q, k, v, g, lse, delta]
+    in_specs = [g.q_whole(), g.kv_block(), g.kv_block(), g.q_whole(),
+                g.q_whole(), g.stat_whole()]
+    args = [q, k, v, out, do, lse]
     if kvalid is not None:
-        dkv_specs.append(kvblk)
-        dkv_args.append(kvalid)
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
-        grid=(BH, Tk // block_k),
-        in_specs=dkv_specs,
-        out_specs=[kspec, kspec],
-        out_shape=[jax.ShapeDtypeStruct((BH, Tk, D), k.dtype),
-                   jax.ShapeDtypeStruct((BH, Tk, D), v.dtype)],
-        interpret=interpret,
-    )(*dkv_args)
-    if kv_group > 1:
-        def reduce_group(a, dtype):
-            a = a.reshape(k.shape[0], kv_group, Tk, D)
-            return jnp.sum(a.astype(jnp.float32), axis=1).astype(dtype)
+        in_specs.append(g.mask_block())
+        args.append(_mask_blocks(kvalid, g.block_k))
+    partial_shape = (rows, g.Tk, width)
+    # held as long as T is: q, o, dO and dq whole (two buffers each), the
+    # statistic and the f32 dQ scratch, whose rows pad to a tile's lanes
+    held = Tq * (8 * lanes * q.dtype.itemsize + 2 * LANES * 4
+                 + g.heads * max(d, LANES) * 4)
+    dq, dk, dv = pl.pallas_call(
+        kernel,
+        grid=g.grid(g.Tk // g.block_k),
+        in_specs=in_specs,
+        out_specs=[g.q_whole(), g.kv_block(shared=False),
+                   g.kv_block(shared=False)],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(partial_shape, k.dtype),
+                   jax.ShapeDtypeStruct(partial_shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((g.heads, Tq, d), jnp.float32)],
+        compiler_params=_vmem(held), interpret=interpret,
+        name="flash_bwd",
+    )(*args)
+    if g.kv_rows > 1 or g.kv_lanes > 1:
+        def reduce_group(a, like):
+            a = a.reshape(like.shape[0], g.kv_rows, g.Tk,
+                          like.shape[2] // d, g.kv_lanes, d)
+            return jnp.sum(a.astype(jnp.float32), axis=(1, 4)) \
+                .reshape(like.shape).astype(like.dtype)
 
-        dk = reduce_group(dk, k.dtype)
-        dv = reduce_group(dv, v.dtype)
+        dk, dv = reduce_group(dk, k), reduce_group(dv, v)
     return dq, dk, dv
 
 
@@ -392,31 +498,30 @@ def _flash_bwd(q, k, v, kvalid, out, lse, g, sm_scale, causal, block_q,
 # custom_vjp plumbing + public API
 # --------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
-def _flash_bhtd(q, k, v, kvalid, sm_scale, causal, block_q, block_k,
-                interpret, window):
+@functools.partial(jax.custom_vjp, nondiff_argnums=_STATIC)
+def _flash_rows(q, k, v, kvalid, sm_scale, causal, block_q, block_k,
+                interpret, window, d, lanes):
+    """Attention over ``(rows, T, W)`` operands, heads `d` wide side by side
+    in W, `lanes` of them a program."""
     out, _ = _flash_fwd(q, k, v, kvalid, sm_scale, causal, block_q, block_k,
-                        interpret, window)
+                        interpret, window, d, lanes)
     return out
 
 
-def _flash_vjp_fwd(q, k, v, kvalid, sm_scale, causal, block_q, block_k,
-                   interpret, window):
-    out, lse = _flash_fwd(q, k, v, kvalid, sm_scale, causal, block_q,
-                          block_k, interpret, window)
+def _flash_vjp_fwd(q, k, v, kvalid, *static):
+    out, lse = _flash_fwd(q, k, v, kvalid, *static)
     return out, (q, k, v, kvalid, out, lse)
 
 
-def _flash_vjp_bwd(sm_scale, causal, block_q, block_k, interpret, window,
-                   res, g):
+def _flash_vjp_bwd(*args):
+    *static, res, g = args
     q, k, v, kvalid, out, lse = res
-    dq, dk, dv = _flash_bwd(q, k, v, kvalid, out, lse, g, sm_scale, causal,
-                            block_q, block_k, interpret, window)
+    dq, dk, dv = _flash_bwd(q, k, v, kvalid, out, lse, g, *static)
     dkv = None if kvalid is None else jnp.zeros_like(kvalid)
     return dq, dk, dv, dkv
 
 
-_flash_bhtd.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
+_flash_rows.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
 #: default (block_q, block_k) on a TPU backend, and on any other (where the
@@ -455,11 +560,22 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     attend); invalid keys are masked in-kernel with the same NEG_INF
     semantics as the dense path.  ``interpret=None`` auto-selects: compiled
     on TPU, interpreter elsewhere (so CPU tests exercise the identical
-    kernel code).  Forward and backward are both flash kernels; the
-    largest per-program VMEM residency (dK/dV kernel: Q and dO full plus
-    K/V blocks and the (T, 1) lse/delta rows) stays under ~5 MB of the
-    ~16 MB budget through T ≈ 16k at D=64 — beyond that, shard ``seq``
-    (ring attention / Ulysses) first.
+    kernel code).  Forward and backward are both flash kernels.  What a
+    program holds grows with T: the forward K and V a lane block whole,
+    the backward q, o, dO and dq whole plus the row statistic and an f32
+    dQ scratch, whose rows pad to 128 lanes (4 KiB a position at 64-wide
+    heads: 4 MiB at the train cells' 1,024).  Up to T = 2,048 that fits the
+    16 MiB of VMEM a kernel gets unasked; past it the kernels ask for what
+    they hold (:func:`_vmem`), and both compile for a v5e through T =
+    16,384 at D = 64 and 128 and 4,096 at D = 256
+    (``tests/test_chip_compile.py``; compiled, not timed).  Beyond that,
+    shard ``seq`` (ring attention / Ulysses) first.
+
+    The operands are viewed ``(B, T, H·D)`` and tiled in blocks of lanes
+    by :func:`_tiling`'s rule; a shape it cannot tile in whole heads
+    (``D`` under 128 with grouped K/V, a ``D`` such as 96) is transposed
+    to ``(B·H, T, D)`` first.  Either way the compile log of the program
+    being traced gets a ``flash_layout`` note (:func:`_note_call`).
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -483,19 +599,35 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         raise ValueError(f"{H} query heads not a multiple of {Hkv} KV "
                          "heads (GQA groups must be uniform)")
 
-    def to_bhtd(x):
-        return jnp.swapaxes(x, 1, 2).reshape(B * x.shape[2], x.shape[1], D)
-
     kvalid = None
     if key_valid is not None:
-        # per-BATCH mask shaped (B, 1, Tk) — the kernels index it with
-        # b // valid_group, so no head expansion is ever materialised; the
-        # size-1 sublane dim keeps kernel blocks Mosaic-legal; float so
-        # the custom_vjp can hand back an ordinary zero cotangent
+        # per-BATCH mask shaped (B, 1, Tk) — the kernels index it by the
+        # batch row, so no head expansion is ever materialised; the size-1
+        # sublane dim keeps kernel blocks Mosaic-legal; float so the
+        # custom_vjp can hand back an ordinary zero cotangent
         kvalid = key_valid.astype(jnp.float32)[:, None, :]
-    out = _flash_bhtd(to_bhtd(q), to_bhtd(k), to_bhtd(v), kvalid, sm_scale,
-                      causal, block_q, block_k, interpret, window)
-    return jnp.swapaxes(out.reshape(B, H, Tq, D), 1, 2)
+    tiling = _tiling(D, H, Hkv)
+    if tiling is None:
+        # no block of 128 lanes holds whole heads of q and of their k / v:
+        # heads become rows, (B·H, T, D), and a program takes a head
+        def rows(x):
+            return jnp.swapaxes(x, 1, 2).reshape(-1, x.shape[1], D)
+
+        _note_call(D, 1, True)
+        out = _flash_rows(rows(q), rows(k), rows(v), kvalid, sm_scale,
+                          causal, block_q, block_k, interpret, window, D, D)
+        return jnp.swapaxes(out.reshape(B, H, Tq, D), 1, 2)
+    # where the projections wrote them: (B, T, H·D) is a view of a
+    # contiguous minor pair, and so is the way back
+
+    def merged(x):
+        return x.reshape(B, x.shape[1], -1)
+
+    lanes, heads = tiling
+    _note_call(lanes, heads, False)
+    out = _flash_rows(merged(q), merged(k), merged(v), kvalid, sm_scale,
+                      causal, block_q, block_k, interpret, window, D, lanes)
+    return out.reshape(B, Tq, H, D)
 
 
 def make_attention_fn(causal: bool = False, **kw):
@@ -552,6 +684,9 @@ def make_attention_fn(causal: bool = False, **kw):
         return _per_shard(kernel, q, k, v, key_valid).astype(dtype)
 
     attn.supports_gqa = True
+    # the kernels read q, k and v as (B, T, H·D): the layer projects so
+    # that this view, and the one back, move nothing
+    attn.reads_heads_merged = True
     return attn
 
 

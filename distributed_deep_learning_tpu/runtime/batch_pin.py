@@ -23,17 +23,11 @@ so a ``model`` or a sequence axis propagates as it would without the pin.
 
 from __future__ import annotations
 
-import contextlib
-
 import jax
 from jax.sharding import AxisType, PartitionSpec as P
 
 from distributed_deep_learning_tpu.data.loader import BATCH_AXES
 from distributed_deep_learning_tpu.obs import runlog
-
-#: the pins of the program being traced, a tuple of axes a site
-#: (:func:`pins_noted` collects them); None outside any
-_sites: list | None = None
 
 
 def split_axes() -> set:
@@ -58,26 +52,15 @@ def pin_batch(x):
     axes = split_batch_axes()
     if not axes:
         return x
-    if _sites is not None:
-        _sites.append(axes)
+    runlog.compile_log.gather("batch_pins", axes, pins_text)
     return jax.lax.with_sharding_constraint(
         x, P(axes, *[P.UNCONSTRAINED] * (x.ndim - 1)))
 
 
-@contextlib.contextmanager
-def pins_noted(program: str):
-    """Around the trace of `program`: leave in the compile log which batch
-    axes its activations were pinned to and at how many sites
-    (``batch_pins``, ``axes=fsdp sites=23``; ``axes=none sites=0`` where
-    the mesh splits no batch axis)."""
-    global _sites
-    outer, _sites = _sites, []
-    try:
-        yield
-    finally:
-        sites, _sites = _sites, outer
-        axes = sorted({a for site in sites for a in site},
-                      key=BATCH_AXES.index)
-        runlog.compile_log.note(
-            "batch_pins", program,
-            f"axes={','.join(axes) or 'none'} sites={len(sites)}")
+def pins_text(sites) -> str:
+    """The ``batch_pins`` note of a traced program (a step builder names it
+    to ``obs.compile_log.notes_for``, so every step has one): which batch
+    axes its activations were pinned to and at how many sites, ``axes=fsdp
+    sites=23``; ``axes=none sites=0`` where the mesh splits no batch axis."""
+    axes = sorted({a for site in sites for a in site}, key=BATCH_AXES.index)
+    return f"axes={','.join(axes) or 'none'} sites={len(sites)}"

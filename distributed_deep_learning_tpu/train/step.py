@@ -22,7 +22,8 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributed_deep_learning_tpu.data.loader import BATCH_AXES
-from distributed_deep_learning_tpu.runtime.batch_pin import pins_noted
+from distributed_deep_learning_tpu.obs.runlog import compile_log
+from distributed_deep_learning_tpu.runtime.batch_pin import pins_text
 from distributed_deep_learning_tpu.train.objectives import prediction_metrics
 from distributed_deep_learning_tpu.train.state import TrainState
 from distributed_deep_learning_tpu.utils.config import REMAT_POLICIES
@@ -43,13 +44,16 @@ def under_mesh(mesh: Mesh):
     """Decorator for a step body: trace it with `mesh` as the ambient one,
     so code deep in the model can see how its inputs are split — the flash
     kernel must run per shard (``ops.attention_pallas._per_shard``) and
-    the activations stay on the batch axes (``runtime.batch_pin``, whose
-    note in the compile log says what this trace pinned)."""
+    the activations stay on the batch axes (``runtime.batch_pin``).  What
+    either says of the program as it is traced becomes its notes in the
+    compile log: ``batch_pins``, what this trace pinned, and the kernel's
+    ``flash_layout``, how its calls tiled their operands."""
     def decorate(step):
         @functools.wraps(step)
         def traced(*args):
             with jax.sharding.use_abstract_mesh(mesh.abstract_mesh), \
-                    pins_noted(f"jit({step.__name__})"):
+                    compile_log.notes_for(f"jit({step.__name__})",
+                                          batch_pins=pins_text):
                 return step(*args)
         return traced
     return decorate

@@ -333,7 +333,9 @@ def render(events: list[dict], phases: bool = False) -> str:
     if programs:
         # what each program said of itself as it was traced: the batch
         # axes a train step's activations were pinned to and at how many
-        # sites, the paths a decode program's attention layers took
+        # sites (batch_pins), how its flash kernel calls tiled q, k and v
+        # (flash_layout: lanes and heads a block, calls that transposed),
+        # the paths a decode program's attention layers took (attn_paths)
         out.append("== programs, as traced ==")
         out += [f"  {n.get('program')}: {n.get('note')} {n.get('text')}"
                 for n in programs]

@@ -324,12 +324,11 @@ def test_flash_default_blocks_follow_the_backend(monkeypatch, backend,
 
     seen = []
 
-    def spy(q, k, v, kvalid, sm_scale, causal, block_q, block_k, interpret,
-            window):
+    def spy(q, k, v, kvalid, sm_scale, causal, block_q, block_k, *layout):
         seen.append((block_q, block_k))
         return q
 
-    monkeypatch.setattr(ap, "_flash_bhtd", spy)
+    monkeypatch.setattr(ap, "_flash_rows", spy)
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     q, k, v = _qkv(T=32, seed=50)
     flash_attention(q, k, v, causal=True, interpret=True)
@@ -516,3 +515,199 @@ def test_adapter_runs_kernel_per_shard_under_a_step_mesh(padded):
     assert "shard_map" in str(jax.make_jaxpr(step)(q, k, v))
     assert "shard_map" not in str(jax.make_jaxpr(
         lambda *a: loss(fn, *a))(q, k, v))
+
+
+# --- the lane-blocked layout: q, k, v read as the projections write them ---
+
+#: (H, Hkv, D) -> what the call must say of itself: lanes a block, heads a
+#: block, transposed.  One entry a shape class of `_tiling`.
+LAYOUTS = {
+    "two-heads-a-block": ((16, 16, 64), (128, 2, False)),
+    "odd-heads-boundary-block": ((25, 25, 64), (128, 2, False)),
+    "four-heads-a-block": ((4, 4, 32), (128, 4, False)),
+    "under-one-block": ((2, 2, 32), (64, 2, False)),
+    "head-a-block": ((8, 8, 128), (128, 1, False)),
+    "head-a-block-gqa": ((8, 2, 128), (128, 1, False)),
+    "wide-head": ((2, 2, 256), (256, 1, False)),
+    "narrow-gqa-transposed": ((8, 2, 64), (64, 1, True)),
+    "no-lane-multiple-transposed": ((2, 2, 96), (96, 1, True)),
+}
+
+
+def _layout_case(name, T=32, Tk=None, B=2, seed=70):
+    (H, Hkv, D), said = LAYOUTS[name]
+    ks = jax.random.split(jax.random.key(seed), 3)
+    q = jax.random.normal(ks[0], (B, T, H, D))
+    k = jax.random.normal(ks[1], (B, Tk or T, Hkv, D))
+    v = jax.random.normal(ks[2], (B, Tk or T, Hkv, D))
+    return q, k, v, H // Hkv, said
+
+
+def _dense(q, k, v, group, **kw):
+    from distributed_deep_learning_tpu.models.transformer import (
+        dot_product_attention)
+
+    return dot_product_attention(q, _expand(k, group), _expand(v, group),
+                                 **kw)
+
+
+def _flash_notes():
+    from distributed_deep_learning_tpu import obs
+
+    return [text for event, _, text in obs.compile_log.notes()
+            if event == "flash_layout"]
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("name", LAYOUTS)
+def test_layouts_match_dense_and_say_which_path_they_took(name, causal):
+    """Outputs AND the gradients of q, k and v against the dense path, for
+    every shape class the tiling rule knows; the call's ``flash_layout``
+    note says which path it took (read once a class, on the causal case)."""
+    from distributed_deep_learning_tpu import obs
+
+    q, k, v, group, said = _layout_case(name)
+
+    def loss(attend, q, k, v):
+        out = attend(q, k, v)
+        return jnp.sum(out ** 2), out
+
+    flash = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, causal=causal, block_q=16, block_k=8)
+    dense = lambda q, k, v: _dense(q, k, v, group, causal=causal)  # noqa: E731
+    obs.compile_log.mark("test")
+    (_, got), g_flash = jax.value_and_grad(
+        lambda *a: loss(flash, *a), argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    if causal:
+        lanes, heads, transposed = said
+        assert _flash_notes() == [
+            f"calls=1 lanes_a_block={lanes} heads_a_block={heads} "
+            f"transposed={int(transposed)}"]
+    (_, want), g_dense = jax.value_and_grad(
+        lambda *a: loss(dense, *a), argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    for gf, gd in zip(g_flash, g_dense):
+        assert gf.shape == gd.shape
+        np.testing.assert_allclose(np.asarray(gf), np.asarray(gd),
+                                   rtol=1e-4, atol=2e-5)
+
+
+#: what else a call may carry, each on the boundary-block shape (25 heads of
+#: 64) and on a grouped head-a-block one
+FEATURES = {
+    "key_valid-padded-row": dict(valid=[20, 0], T=32),
+    "key_valid-causal": dict(valid=[24, 16], T=32, causal=True),
+    "window": dict(window=5, causal=True, T=32),
+    "cross-lengths": dict(T=8, Tk=32),
+    "cross-lengths-causal": dict(T=8, Tk=32, causal=True),
+    "no-128-divisor": dict(T=24, causal=True, blocks=(16, 16)),
+}
+
+
+@pytest.mark.parametrize("feature", FEATURES)
+@pytest.mark.parametrize("name", ["odd-heads-boundary-block",
+                                  "head-a-block-gqa"])
+def test_layouts_carry_masks_windows_and_lengths(name, feature):
+    """``key_valid`` with a fully padded row (its outputs are the kernel's
+    own convention, so only the other row is compared, and its gradients
+    are zero), ``window``, ``Tq != Tk`` and a ``T`` with no 128-multiple
+    divisor (``_fit_block``), outputs and gradients, lane-blocked."""
+    cfg = FEATURES[feature]
+    T = cfg["T"]
+    q, k, v, group, _ = _layout_case(name, T=T, Tk=cfg.get("Tk"), seed=71)
+    valid = None
+    if "valid" in cfg:
+        valid = jnp.arange(T)[None, :] < jnp.array(cfg["valid"])[:, None]
+    live = np.array([n > 0 for n in cfg.get("valid", [1, 1])])
+    bq, bk = cfg.get("blocks", (8, 8))
+    kw = dict(causal=cfg.get("causal", False), window=cfg.get("window"),
+              key_valid=valid)
+
+    def loss(attend, q, k, v):
+        out = attend(q, k, v)
+        return jnp.sum(out[live] ** 2), out
+
+    flash = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, block_q=bq, block_k=bk, **kw)
+    dense = lambda q, k, v: _dense(q, k, v, group, **kw)  # noqa: E731
+    (_, got), g_flash = jax.value_and_grad(
+        lambda *a: loss(flash, *a), argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    (_, want), g_dense = jax.value_and_grad(
+        lambda *a: loss(dense, *a), argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               rtol=1e-5, atol=1e-5)
+    for gf, gd in zip(g_flash, g_dense):
+        assert gf.shape == gd.shape
+        np.testing.assert_allclose(np.asarray(gf)[live],
+                                   np.asarray(gd)[live],
+                                   rtol=1e-4, atol=2e-5)
+        assert not np.asarray(gf)[~live].any()
+
+
+def test_fully_padded_row_has_zero_gradients_lane_blocked():
+    """The fully-masked-row clamp, two heads a block: with every key of a
+    row masked its q, k and v take exactly zero gradient, no NaN."""
+    q, k, v, _, _ = _layout_case("two-heads-a-block", T=16, seed=72)
+    valid = jnp.zeros((2, 16), bool)
+    g = jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, key_valid=valid, block_q=8, block_k=8) ** 2),
+        argnums=(0, 1, 2))(q, k, v)
+    for arr in g:
+        np.testing.assert_allclose(np.asarray(arr), 0.0, atol=1e-6)
+
+
+def test_merged_projections_are_the_layer_the_dense_path_computes():
+    """Under the flash adapter the layer projects on the merged ``H·D``
+    (``MergedHeadsDense``): the same parameter tree as ``nn.DenseGeneral``
+    gives, drawn the same way from the same key, and the same values and
+    gradients as the dense layer, bias included."""
+    from distributed_deep_learning_tpu.models.transformer import (
+        MultiHeadAttention)
+
+    x = jax.random.normal(jax.random.key(73), (2, 32, 128))
+    dense = MultiHeadAttention(num_heads=4)
+    flash = MultiHeadAttention(
+        num_heads=4, attention_fn=make_attention_fn(block_q=8, block_k=8))
+    params = dense.init(jax.random.key(0), x, x, causal=True)
+    drawn = flash.init(jax.random.key(0), x, x, causal=True)
+    assert jax.tree.structure(params) == jax.tree.structure(drawn)
+    for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(drawn)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    params = jax.tree.map(            # biases that are not zero
+        lambda p: p + 0.1 * jax.random.normal(jax.random.key(1), p.shape),
+        params)
+
+    def loss(layer, params):
+        return jnp.sum(layer.apply(params, x, x, causal=True) ** 2)
+
+    got, g_flash = jax.value_and_grad(lambda p: loss(flash, p))(params)
+    want, g_dense = jax.value_and_grad(lambda p: loss(dense, p))(params)
+    np.testing.assert_allclose(got, want, rtol=1e-4)
+    scale = max(jnp.abs(g).max() for g in jax.tree.leaves(g_dense))
+    for a, b in zip(jax.tree.leaves(g_flash), jax.tree.leaves(g_dense)):
+        # against the tree's largest gradient: k's bias moves no score's
+        # softmax, so its own gradient is rounding noise around zero
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3,
+                                   atol=1e-5 * float(scale))
+
+
+def test_a_traced_step_notes_its_flash_calls_once():
+    """Under the compile log's ``notes_for`` (what the step builders trace
+    under) the program's calls are counted into ONE note, the transposed
+    among them."""
+    from distributed_deep_learning_tpu import obs
+
+    q, k, v, _, _ = _layout_case("two-heads-a-block", T=16)
+    qg, kg, vg, _, _ = _layout_case("narrow-gqa-transposed", T=16)
+    obs.compile_log.mark("test")
+    with obs.compile_log.notes_for("jit(step)"):
+        jax.make_jaxpr(lambda: (flash_attention(q, k, v),
+                                flash_attention(q, k, v, causal=True),
+                                flash_attention(qg, kg, vg)))()
+    with obs.compile_log.notes_for("jit(no_flash)"):
+        pass
+    assert [n for n in obs.compile_log.notes() if n[0] == "flash_layout"] \
+        == [("flash_layout", "jit(step)",
+             "calls=3 lanes_a_block=128 heads_a_block=2 transposed=1")]

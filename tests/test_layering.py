@@ -26,6 +26,7 @@ IMPORTS = {
         "models",    # UP: the dense fallback and the cache-leaf names (D14)
         "serve",     # UP: paged_decode_pallas reads serve.quant.is_quant (D14)
         "runtime",   # attention_pallas._per_shard asks runtime.batch_pin what the mesh splits
+        "obs",       # attention_pallas leaves its flash_layout note in obs.runlog's compile log
     },
     "models": {
         "ops",

@@ -87,14 +87,15 @@ class TestFsdp:
 
 # --- activations pinned to the batch axes (runtime.batch_pin) -------------
 
-def _tiny_lm():
+def _tiny_lm(attention_fn=None):
     from distributed_deep_learning_tpu.models.transformer import CausalLM
 
     return CausalLM(vocab_size=64, num_layers=2, d_model=32, num_heads=4,
-                    mlp_dim=64, max_len=16, with_logits=True, pad_id=None)
+                    mlp_dim=64, max_len=16, with_logits=True, pad_id=None,
+                    attention_fn=attention_fn)
 
 
-def _lm_step(mesh_shape, devices, fsdp: bool):
+def _lm_step(mesh_shape, devices, fsdp: bool, attention_fn=None):
     """One SGD step at rate 1 of the tiny LM through the jitted step: the
     loss, and the parameters after it (start minus the gradient)."""
     from distributed_deep_learning_tpu.train.objectives import (
@@ -103,7 +104,7 @@ def _lm_step(mesh_shape, devices, fsdp: bool):
     mesh = build_mesh(mesh_shape, devices)
     tokens = jnp.asarray(np.random.default_rng(1).integers(1, 64, (8, 17)),
                          jnp.int32)
-    state = create_train_state(_tiny_lm(), jax.random.key(0),
+    state = create_train_state(_tiny_lm(attention_fn), jax.random.key(0),
                                tokens[:1, :-1], optax.sgd(1.0))
     spec = fsdp_state_spec(state, mesh, min_leaf_size=16) if fsdp else P()
     if fsdp:
@@ -213,3 +214,42 @@ def test_obs_report_prints_what_the_step_pinned(tmp_path):
             [sys.executable, os.path.join(repo, "scripts", "obs_report.py"),
              path], capture_output=True, text=True, check=True).stdout
         assert f"jit(train_step): batch_pins {said}" in text, text
+
+
+@pytest.mark.parametrize("mesh_shape", [{"fsdp": 4}, {"data": 1}],
+                         ids=["fsdp4", "one-device"])
+def test_flash_step_says_how_its_kernel_calls_tiled(tmp_path, mesh_shape):
+    """The compile log's ``flash_layout`` note of a step that attends
+    through the flash kernel (two layers, four heads of 8: all four in one
+    32-lane block, nothing transposed; per shard under FSDP, the same
+    shapes a chip): in ``obs.compile_log.notes()`` beside ``batch_pins``,
+    in the ``--obs`` stream (``obs_programs``) and in what
+    ``scripts/obs_report.py`` prints; and the step is the dense one's."""
+    import os
+    import subprocess
+    import sys
+
+    from distributed_deep_learning_tpu import obs
+    from distributed_deep_learning_tpu.ops.attention_pallas import (
+        make_attention_fn)
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    devices = jax.devices()[:int(np.prod(list(mesh_shape.values())))]
+    fsdp = len(devices) > 1
+    loss_dense, _ = _lm_step(mesh_shape, devices, fsdp=fsdp)
+    obs.compile_log.mark("test")
+    path = str(tmp_path / "obs.jsonl")
+    telemetry = obs.RunTelemetry(path)
+    loss, _ = _lm_step(mesh_shape, devices, fsdp=fsdp,
+                       attention_fn=make_attention_fn())
+    telemetry.close()
+    np.testing.assert_allclose(loss, loss_dense, rtol=1e-5)
+    said = "calls=2 lanes_a_block=32 heads_a_block=4 transposed=0"
+    notes = obs.compile_log.notes()
+    assert ("flash_layout", "jit(train_step)", said) in notes, notes
+    assert sorted(n[0] for n in notes if n[1] == "jit(train_step)") == [
+        "batch_pins", "flash_layout"]
+    text = subprocess.run(
+        [sys.executable, os.path.join(repo, "scripts", "obs_report.py"),
+         path], capture_output=True, text=True, check=True).stdout
+    assert f"jit(train_step): flash_layout {said}" in text, text
