@@ -17,8 +17,10 @@ ship: many narrow SwiGLU experts (256 of width 1,024), ten a token, no
 capacity and no dropped token, one shared expert beside them.  At 256
 experts the one-hot dispatch cannot be afforded; it sorts the (token,
 expert) assignments by expert and runs one grouped matrix product over the
-experts it HOLDS (:func:`jax.lax.ragged_dot`), which is also how one chip
-of an expert-parallel deployment computes its share of a layer.
+experts it HOLDS (:func:`jax.lax.ragged_dot`, or in a serving program the
+package's own kernel, :mod:`..ops.grouped_matmul_pallas`), which is also
+how one chip of an expert-parallel deployment computes its share of a
+layer.
 """
 
 from __future__ import annotations
@@ -29,6 +31,8 @@ from typing import Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+
+from distributed_deep_learning_tpu.ops.grouped_matmul_pallas import Visits
 
 dense_init = nn.initializers.xavier_uniform()
 
@@ -271,7 +275,8 @@ def route_top_k(logits, top_k: int, norm_topk: bool = True,
     return w * routed_scale, experts
 
 
-def held_experts(x, w, experts, w_gate, w_up, w_down, offset: int = 0):
+def held_experts(x, w, experts, w_gate, w_up, w_down, offset: int = 0,
+                 grad: bool = True):
     """What the held experts give: ``sum_e w[n, e] * E_e(x[n])`` over the
     assignments ``experts[n, j]`` that fall in ``[offset, offset + E)``,
     and how many assignments each held expert took, ``(E,)``.
@@ -280,7 +285,14 @@ def held_experts(x, w, experts, w_gate, w_up, w_down, offset: int = 0):
     expert (those of absent experts last) and every held expert's rows go
     through ONE grouped product per matrix, SwiGLU in between.  Rows of
     absent experts lie past the last group; a grouped product leaves them
-    unspecified, so they are zeroed on the way out."""
+    unspecified, so they are zeroed on the way out.
+
+    `grad`: the caller may differentiate the result, so the products are
+    XLA's ``ragged_dot``, which has a transpose rule.  A serving program
+    says False, and its products go by their shapes
+    (:class:`..ops.grouped_matmul_pallas.Visits`): on a TPU, from 64 rows
+    up, through the kernel that visits only the row tiles that hold
+    rows."""
     n, k = experts.shape
     E = w_gate.shape[0]
     local = experts.reshape(-1) - offset
@@ -289,9 +301,14 @@ def held_experts(x, w, experts, w_gate, w_up, w_down, offset: int = 0):
     order = jnp.argsort(group, stable=True)
     load = jnp.zeros((E + 1,), jnp.int32).at[group].add(1)[:E]
     rows = x[order // k]
-    h = jax.nn.silu(jax.lax.ragged_dot(rows, w_gate, load)) \
-        * jax.lax.ragged_dot(rows, w_up, load)
-    out = jax.lax.ragged_dot(h.astype(x.dtype), w_down, load)
+    if grad:
+        h = jax.nn.silu(jax.lax.ragged_dot(rows, w_gate, load)) \
+            * jax.lax.ragged_dot(rows, w_up, load)
+        out = jax.lax.ragged_dot(h.astype(x.dtype), w_down, load)
+    else:
+        products = Visits(load, n * k)
+        h = products.swiglu(rows, w_gate, w_up)
+        out = products.product(h.astype(x.dtype), w_down)
     taken = (jnp.arange(n * k) < jnp.sum(load))[:, None]
     out = jnp.where(taken, out, 0).astype(jnp.float32)
     # back to assignment order (a gather, not a scatter-add), then each
@@ -303,7 +320,7 @@ def held_experts(x, w, experts, w_gate, w_up, w_down, offset: int = 0):
 
 
 def _route_and_run(x, router, w_gate, w_up, w_down, spec: ExpertSpec,
-                   bias=None):
+                   bias=None, grad: bool = True):
     """Tokens (N, d) through router and held experts: ``(y, load)``."""
     with jax.named_scope("moe_route"):
         logits = jnp.einsum("nd,de->ne", x.astype(jnp.float32),
@@ -313,7 +330,7 @@ def _route_and_run(x, router, w_gate, w_up, w_down, spec: ExpertSpec,
                                  spec.routed_scale, spec.score, bias)
     with jax.named_scope("moe_experts"):
         return held_experts(x, w, experts, w_gate, w_up, w_down,
-                            spec.expert_offset)
+                            spec.expert_offset, grad)
 
 
 def _tokens_of_all_rows(spec: ExpertSpec):
@@ -324,7 +341,8 @@ def _tokens_of_all_rows(spec: ExpertSpec):
     load comes out as the batch's total, unmapped."""
     @jax.custom_batching.custom_vmap
     def run(x, router, w_gate, w_up, w_down, bias=None):
-        return _route_and_run(x, router, w_gate, w_up, w_down, spec, bias)
+        return _route_and_run(x, router, w_gate, w_up, w_down, spec, bias,
+                              grad=False)     # a serving program's call
 
     @run.def_vmap
     def rule(axis_size, in_batched, x, *weights):

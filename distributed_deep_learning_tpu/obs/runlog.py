@@ -104,15 +104,19 @@ class CompileLog:
         (:meth:`gather`: a pinned activation, a kernel call) becomes one
         note an event at the end, ``render(items)``, since a count is known
         only then.  `always` names the renderers of events noted even where
-        nothing was gathered (``batch_pins``: ``axes=none sites=0``)."""
+        nothing was gathered (``batch_pins``: ``axes=none sites=0``).
+        Yields a dict that holds the notes, ``event -> text``, once the
+        block is left."""
         outer = self._gathered
         self._gathered = {e: ([], render) for e, render in always.items()}
+        said = {}
         try:
-            yield
+            yield said
         finally:
             gathered, self._gathered = self._gathered, outer
             for event, (items, render) in gathered.items():
-                self.note(event, program, render(items))
+                said[event] = render(items)
+                self.note(event, program, said[event])
 
     def gather(self, event: str, item, render) -> bool:
         """`item` for the `event` note of the program being traced; False,

@@ -77,17 +77,28 @@ class CountingJit:
     runs under: opened HERE, not by the caller, so it nests inside
     whatever wraps the program object from outside (the benchmark's
     dispatch annotation), on the same clock.
+
+    What code deep inside says of the program as it is traced
+    (``obs.compile_log.gather``: the grouped expert products' path and
+    tiles) becomes its notes in the compile log, and `said`, the owner's
+    ``program name -> {event: text}`` of each program's newest trace (the
+    owner's, because a caller may wrap the program object).
     """
 
     def __init__(self, fn, name: Optional[str] = None,
-                 span: Optional[str] = None, **jit_kwargs):
+                 span: Optional[str] = None, said: Optional[dict] = None,
+                 **jit_kwargs):
         self.traces = 0
         self.name = name or fn.__name__.strip("_<>")
         self._span = span
 
         def counted(*args):
             self.traces += 1   # runs at trace time only
-            return fn(*args)
+            with runlog.compile_log.notes_for(f"jit({self.name})") as notes:
+                out = fn(*args)
+            if said is not None:
+                said[self.name] = notes
+            return out
 
         counted.__name__ = counted.__qualname__ = self.name
         self._jit = jax.jit(counted, **jit_kwargs)
@@ -855,10 +866,15 @@ class PagedEngine:
         leaf = paged.latent_leaf(self.pools)
         self.latent_row_bytes = 0 if leaf is None else latent_bytes_a_row(
             leaf, self.max_slots, self.blocks_per_slot)
+        #: what the chunk and decode programs said of themselves as they
+        #: were traced, ``program -> {event: text}`` (``grouped_product``)
+        self.program_notes: dict = {}
         self._chunk_prog = CountingJit(self._chunk_impl, "paged_chunk",
-                                       "chunk_dispatch", **dk)
+                                       "chunk_dispatch", self.program_notes,
+                                       **dk)
         self._decode = CountingJit(self._decode_impl, "paged_decode",
-                                   "decode_dispatch", **dk)
+                                   "decode_dispatch", self.program_notes,
+                                   **dk)
         self._copy = CountingJit(self._copy_impl, "paged_copy", **ck)
         if spill_dir is not None and not preempt:
             raise ValueError("spill_dir requires preempt=True (it is the "
@@ -2056,6 +2072,9 @@ class PagedEngine:
             "acceptance_rate": (accepted_total / proposed_total)
             if proposed_total else None,
         }
+        grouped = {name: said["grouped_product"]
+                   for name, said in self.program_notes.items()
+                   if "grouped_product" in said}
         stats = {
             "engine": "paged",
             "requests": n_req,
@@ -2097,6 +2116,9 @@ class PagedEngine:
                     **({"latent_row_bytes": self.latent_row_bytes}
                        if n_latent else {}),
                 },
+                # the grouped expert products of each program as traced:
+                # calls, rows, path and tiles (no key: no expert layer)
+                **({"grouped_product": grouped} if grouped else {}),
             },
             "spec": spec_stats,
             "preempt": {

@@ -335,7 +335,9 @@ def render(events: list[dict], phases: bool = False) -> str:
         # axes a train step's activations were pinned to and at how many
         # sites (batch_pins), how its flash kernel calls tiled q, k and v
         # (flash_layout: lanes and heads a block, calls that transposed),
-        # the paths a decode program's attention layers took (attn_paths)
+        # the paths a decode program's attention layers took (attn_paths),
+        # a serving program's grouped expert products (grouped_product:
+        # calls, rows, experts, pallas or ragged_dot, tiles, fused calls)
         out.append("== programs, as traced ==")
         out += [f"  {n.get('program')}: {n.get('note')} {n.get('text')}"
                 for n in programs]
@@ -366,6 +368,9 @@ def render(events: list[dict], phases: bool = False) -> str:
                           if latent else "")
                        + f"{attn['paths']['gather']} gathered; blocks read "
                        f"{read} of {held} in the tables ({share})")
+        for prog, text in ((st.get("paged") or {})
+                           .get("grouped_product") or {}).items():
+            out.append(f"  grouped expert products, {prog}: {text}")
         if lat.get("measured_requests"):
             out.append(f"  ttft  p50 {1e3 * lat['ttft_p50_s']:8.2f}ms   "
                        f"p99 {1e3 * lat['ttft_p99_s']:8.2f}ms")

@@ -28,6 +28,8 @@ from jax.sharding import SingleDeviceSharding
 
 from distributed_deep_learning_tpu.ops.attention_pallas import (
     flash_attention, make_attention_fn)
+from distributed_deep_learning_tpu.ops.grouped_matmul_pallas import (
+    Visits, _tiling)
 from distributed_deep_learning_tpu.ops.paged_decode_pallas import (
     paged_flash_decode)
 
@@ -191,6 +193,25 @@ def xl_engine(chip):
     }
 
 
+#: the grouped products of ONE expert layer in a compiled serving program
+#: of the two expert cells, by kernel: a chunk program's 4,096 / 5,120 rows
+#: and a decode program's 64 / 160 go through the package's kernels (gate
+#: and up in one), none through XLA's ragged-dot call, three a layer
+#: before PR 36
+GROUPED_KERNELS = {
+    "paged_chunk": {"grouped_swiglu": 1, "grouped_product": 1},
+    "paged_decode": {"grouped_swiglu": 1, "grouped_product": 1},
+}
+
+
+def _grouped_kernels(text: str) -> dict:
+    """Grouped-product kernels in a compiled program, counted by name."""
+    names = re.findall(r"%(ragged-dot-none|grouped_swiglu|grouped_product)"
+                       r'[\w.]* = [^\n]*custom_call_target="tpu_custom_call"',
+                       text)
+    return {n: names.count(n) for n in set(names)}
+
+
 def _decode_kernels(text: str) -> list:
     """The block-table attention kernel's calls in a compiled program."""
     return re.findall(r"%(paged_flash_decode[\w.]*) = [^\n]*"
@@ -293,9 +314,11 @@ def laguna_engine(chip):
 def test_two_kind_paged_program_compiles_for_v5e(laguna_engine, on_tpu,
                                                  program):
     """Both pool kinds rest as they are computed in (``Hkv*D`` = 1,024
-    minor: no whole-leaf copy of either), and each expert layer's three
-    grouped products are the chip's own ragged-dot kernel, not a dense
-    product over every expert.  The decode program attends the full
+    minor: no whole-leaf copy of either), and the expert layer's grouped
+    products are grouped kernels, not a dense product over every expert:
+    in the chunk program (5,120 sorted rows) and in the decode program
+    (160) the package's own two, gate and up fused and down, and no
+    ragged-dot call of XLA's.  The decode program attends the full
     layer's pool in place (the block-table kernel) and gathers the
     sliding layer's ring; the chunk program gathers both."""
     engine, programs = laguna_engine
@@ -309,9 +332,7 @@ def test_two_kind_paged_program_compiles_for_v5e(laguna_engine, on_tpu,
         f"bf16[{ring},16,1024]" in entry
     copies = re.findall(rf"= (bf16\[(?:{full}|{ring}),[^ ]*) copy\(", entry)
     assert not copies, copies
-    kernels = re.findall(r"%(ragged-dot-none[\w.]*) = [^\n]*"
-                         r'custom_call_target="tpu_custom_call"', text)
-    assert len(kernels) == 3, kernels
+    assert _grouped_kernels(text) == GROUPED_KERNELS[program]
     in_place = _decode_kernels(text)
     gathered = re.findall(r"bf16\[16,(?:8192|512,16),(?:8,128|1024)\]", text)
     if program == "paged_decode":
@@ -510,11 +531,70 @@ def test_no_serving_program_holds_a_flash_call(request, on_tpu, engine,
     text = prog._jit.lower(*args).as_text()
     kernels = set(re.findall(r'kernel_name = "([^"]*)"', text))
     assert not {k for k in kernels if k.startswith("flash_")}, kernels
+    want = set()
     if program == "paged_decode":         # the names are in there
-        assert kernels == {"paged_latent_decode" if engine == "glm_engine"
-                           else "paged_flash_decode"}, kernels
-    else:
-        assert not kernels, kernels
+        want = {"paged_latent_decode" if engine == "glm_engine"
+                else "paged_flash_decode"}
+    if program != "paged_copy" and engine != "xl_engine":
+        # since PR 36 an expert layer's grouped products
+        want |= {"grouped_swiglu", "grouped_product"}
+    assert kernels == want, kernels
+
+
+# --- the grouped expert products (ops/grouped_matmul_pallas.py) -----------
+
+# an expert layer's three products at the two expert cells' chunk shapes:
+# sorted rows, d, f, experts held
+GROUPED_CELLS = {
+    "glm-serve-long-context": (4096, 2048, 1536, 64),
+    "laguna-serve-long-mixed": (5120, 3072, 1024, 32),
+}
+
+
+@pytest.mark.parametrize("cell", GROUPED_CELLS)
+def test_grouped_product_compiles_for_v5e(chip, cell):
+    """Gate and up fused, then down, at the tiles the rule picks for the
+    cell (the whole of N a tile: 12.6 MB of weight tiles in flight in the
+    fused call at glm's widths, which asks for its VMEM), with the visit
+    count a traced value in the grid."""
+    M, d, f, E = GROUPED_CELLS[cell]
+    assert _tiling(M, d, f, E, weights=2) == (128, f)
+    assert _tiling(M, f, d, E) == (128, d)
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=chip)
+
+    def layer(rows, w_gate, w_up, w_down, load):
+        products = Visits(load, M, interpret=False)
+        return products.product(products.swiglu(rows, w_gate, w_up), w_down)
+
+    text = _compiled_text(layer, shape(M, d), shape(E, d, f), shape(E, d, f),
+                          shape(E, f, d), shape(E, dtype=jnp.int32))
+    assert _grouped_kernels(text) == GROUPED_KERNELS["paged_chunk"]
+
+
+@pytest.mark.parametrize("program", ["paged_chunk", "paged_decode",
+                                     "paged_copy"])
+def test_no_gpt2_serving_program_names_a_grouped_product(xl_engine, on_tpu,
+                                                         program):
+    """The GPT-2 serve cells' programs hold no expert layer: their lowered
+    text names neither the package's grouped kernels nor XLA's ragged dot,
+    so a change to either cannot move them."""
+    engine, programs = xl_engine
+    prog, args = programs[program]
+    text = prog._jit.lower(*args).as_text()
+    assert "grouped_" not in text and "ragged" not in text
+    assert not [said for said in engine.program_notes.values() if said]
+
+
+@pytest.mark.parametrize("cell", TRAIN_CELLS)
+def test_no_gpt2_train_step_names_a_grouped_product(v5e, cell):
+    argv, chips, _ = TRAIN_CELLS[cell]
+    with pytest.MonkeyPatch.context() as on_tpu:
+        on_tpu.setattr(jax, "default_backend", lambda: "tpu")
+        _, traced = _trace_train_step(argv, v5e[:chips])
+    text = traced.lower().as_text()
+    assert "grouped_" not in text and "ragged" not in text
 
 
 # --- cell 4's step under FSDP: the weights come to the rows ---------------
@@ -749,9 +829,7 @@ def test_latent_paged_program_compiles_for_v5e(glm_engine, on_tpu, program):
     copies = re.findall(rf"= (bf16\[{rows},[^ ]*) copy\(", entry)
     assert not copies, copies
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
-    kernels = re.findall(r"%(ragged-dot-none[\w.]*) = [^\n]*"
-                         r'custom_call_target="tpu_custom_call"', text)
-    assert len(kernels) == 3, kernels
+    assert _grouped_kernels(text) == GROUPED_KERNELS[program]
     gathered = re.findall(r"bf16\[16,(?:24576|1536,16),640\]", text)
     scores = {int(k) for k in re.findall(r"f32\[20,1024,(\d+)\]", text)}
     if program == "paged_decode":
