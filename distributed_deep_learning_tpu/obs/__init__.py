@@ -16,8 +16,9 @@ Layout:
 * :mod:`obs.export`   — JSONL event stream + Prometheus exposition.
 * :mod:`obs.trace`    — spans: the ``span()`` front door (profiler +
   Tracer), the Tracer ring and its Chrome export, ``PhaseClock``.
-* :mod:`obs.runlog`   — ``last_run(kind)``: the record a run publishes
-  as it starts (it outlives a run that raises), and the compile log.
+* :mod:`obs.runlog`   — ``last_run(kind)`` / ``runs(kind)``: the record
+  a run publishes as it starts (it outlives a run that raises; the newest
+  four a kind are kept), and the compile log.
 """
 
 from __future__ import annotations
@@ -30,13 +31,13 @@ from .memory import MemoryTracker
 from .metrics import MetricsRegistry
 from .mfu import chip_peak_flops, measure_step_flops, mfu_record
 from .recorder import FlightRecorder
-from .runlog import compile_log, last_run
+from .runlog import compile_log, last_run, runs
 from .timeline import Timeline
 from .trace import Tracer, span
 
 __all__ = ["RunTelemetry", "MetricsRegistry", "Timeline", "EventWriter",
            "Tracer", "FlightRecorder", "MemoryTracker", "chip_peak_flops",
-           "span", "last_run", "compile_log"]
+           "span", "last_run", "runs", "compile_log"]
 
 
 class RunTelemetry:

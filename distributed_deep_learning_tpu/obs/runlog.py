@@ -5,10 +5,13 @@ the caller.
 A serving run that ends by an exception (a crashing supervisor hook, the
 benchmark's window closing) returns nothing, so everything it counted
 would be lost with it.  :class:`RunRecord` is therefore *published* at
-the top of the run (:func:`publish`), replacing the previous run's of the
-same kind, and filled in place as the run goes: ``last_run("serve")`` is
-the paged engine's last ``run()``, ``last_run("loader")`` the last
-:class:`~..data.loader.DeviceLoader` that was iterated.
+the top of the run (:func:`publish`) and filled in place as the run goes:
+``last_run("serve")`` is the paged engine's last ``run()``,
+``last_run("loader")`` the last :class:`~..data.loader.DeviceLoader` that
+was iterated.  The newest :data:`KEPT` records of a kind stay
+(:func:`runs`, oldest first), so a run that something listened to (a
+profiler's traced window: ``record.phases.listened > 0``) is still there
+after the unlistened run that followed it.
 
 The compile log answers "which program was traced, lowered, compiled or
 fetched from the cache, when, and for how long" by program name, from
@@ -29,8 +32,8 @@ from typing import Iterable, Optional
 from .metrics import MetricsRegistry
 from .trace import PhaseClock
 
-__all__ = ["RunRecord", "publish", "last_run", "CompileLog", "compile_log",
-           "install_compile_log"]
+__all__ = ["RunRecord", "publish", "last_run", "runs", "CompileLog",
+           "compile_log", "install_compile_log"]
 
 
 class RunRecord:
@@ -47,17 +50,32 @@ class RunRecord:
         self.meta = meta
 
 
+#: records kept a kind.  A record holds host numbers only (no device
+#: array): a serving run's full ring is about a megabyte.
+KEPT = 4
 _LAST: dict[str, RunRecord] = {}
+_RUNS: dict[str, deque] = {}
 
 
 def publish(record: RunRecord) -> RunRecord:
-    """Make `record` what :func:`last_run` returns for its kind."""
+    """Make `record` what :func:`last_run` returns for its kind, and the
+    newest of :func:`runs`; one published again (a loader publishes its
+    one record every epoch) is moved there, not kept twice."""
     _LAST[record.kind] = record
+    kept = _RUNS.setdefault(record.kind, deque(maxlen=KEPT))
+    if record in kept:          # by identity: a record defines no equality
+        kept.remove(record)
+    kept.append(record)
     return record
 
 
 def last_run(kind: str) -> Optional[RunRecord]:
     return _LAST.get(kind)
+
+
+def runs(kind: str) -> list:
+    """The newest :data:`KEPT` records published of `kind`, oldest first."""
+    return list(_RUNS.get(kind, ()))
 
 
 # ------------------------------------------------------------ compile log

@@ -362,6 +362,14 @@ class PhaseClock:
     phase, and a bounded ring of per-tick records ``(index, kind, meta,
     wall seconds, seconds per phase in `names` order)``.  A tick left by
     an exception is recorded with kind ``"aborted"``.
+
+    Beside the ring, record for record: `started`, the clock reading each
+    tick began at, so ``started[k + 1] - started[k] - wall(k)`` is the
+    time between two ticks, which no phase owns.  `listened` counts the
+    ticks that ran with something listening: a run whose `listened` is
+    positive is one a profiler session or a Tracer saw.  A phase keeps its
+    last two clock readings as ``t0`` (entered) and ``t1`` (left), for a
+    caller that records instants and must not read the clock again.
     """
 
     def __init__(self, names, spanless=(), ring: int = 4096,
@@ -373,7 +381,9 @@ class PhaseClock:
         self.seconds = [0.0] * len(self.names)
         self.counts = [0] * len(self.names)
         self.ticks: deque = deque(maxlen=ring)
+        self.started: deque = deque(maxlen=ring)
         self.n_ticks = 0
+        self.listened = 0
         self._row = [0.0] * len(self.names)
         self._listening = False
         self._phases = {n: _Phase(self, i, n, n not in spanless)
@@ -399,7 +409,7 @@ class PhaseClock:
 
 
 class _Phase:
-    __slots__ = ("_pc", "_i", "name", "_spanned", "_t0", "_span")
+    __slots__ = ("_pc", "_i", "name", "_spanned", "t0", "t1", "_span")
 
     def __init__(self, pc: PhaseClock, i: int, name: str,
                  spanned: bool) -> None:
@@ -410,12 +420,13 @@ class _Phase:
         if self._spanned and self._pc._listening:
             self._span = span(self.name)
             self._span.__enter__()
-        self._t0 = self._pc.clock()
+        self.t0 = self._pc.clock()
         return self
 
     def __exit__(self, *exc):
         pc, i = self._pc, self._i
-        dt = pc.clock() - self._t0
+        self.t1 = pc.clock()
+        dt = self.t1 - self.t0
         pc.seconds[i] += dt
         pc.counts[i] += 1
         pc._row[i] += dt
@@ -437,6 +448,7 @@ class _Tick:
         pc = self._pc
         pc._listening = _active()
         if pc._listening:
+            pc.listened += 1
             self._span = span(self._span_name, **self._attrs)
             self._span.__enter__()
         self._t0 = pc.clock()
@@ -447,6 +459,7 @@ class _Tick:
         wall = pc.clock() - self._t0
         pc.ticks.append((self.index, "aborted" if et is not None
                          else self.kind, self.meta, wall, tuple(pc._row)))
+        pc.started.append(self._t0)
         pc.n_ticks += 1
         pc._row = [0.0] * len(pc.names)
         if self._span is not None:

@@ -685,6 +685,35 @@ class _SpillRecord:
 #: (:meth:`.paged.BlockManager.blocks_by_kind`) and, for a model with
 #: expert layers, ``counters["experts"]`` (:func:`expert_counters`), counted
 #: on the device and fetched with the tick's tokens.
+#:
+#: ``counters["programs"]``: one dict a program the tick dispatched through
+#: the chunk or the decode program object, in dispatch order, appended once
+#: the dispatch has RETURNED (a dispatch that raises, as the benchmark's
+#: closing window does, leaves none; the tick's meta is set before the
+#: first dispatch, so an aborted tick keeps those it ran):
+#:
+#: * ``program``: ``"paged_chunk"`` or ``"paged_decode"``, the program
+#:   object's own name; its device module is ``jit_<program>``, so the k-th
+#:   record of a name is the k-th module event of that name in a trace.
+#: * ``at``: ``[t_dispatch, t_returned, t_ready]`` on the ring's clock
+#:   (``time.perf_counter``): the ``*_dispatch`` phase entered and left,
+#:   the ``*_wait`` phase left (the host holds the result; None until
+#:   then).  They are the phase clock's own readings, not new ones.
+#: * a chunk also: ``slot``, ``uid``, ``start`` (the position its first
+#:   token feeds) and ``live`` (positions it wrote: neither committed
+#:   before nor past the prompt).
+#: * a model with expert layers also: ``experts``, :func:`expert_counters`
+#:   of THIS program's load, counted on the device; a chunk's rows are all
+#:   ``prefill_chunk`` of them, padding included.  A chunk's load is
+#:   fetched where the host next waits for a LATER program, before that
+#:   barrier (the tick's decode program, or the next chunk), so its
+#:   ``experts`` appears one program later and the chunk a raising
+#:   dispatch cut off may lack it.  A decode program's is the same dict as
+#:   the tick's ``counters["experts"]``, fetched with the tokens.
+#:
+#: The draft, verify and canary dispatches (and a chunk's draft twin)
+#: leave no record: a run that uses them cannot be joined with a trace by
+#: order.  Each tick's START is ``PhaseClock.started``, beside the ring.
 TICK_PHASES = ("admit", "chunk_prepare", "chunk_dispatch", "chunk_commit",
                "chunk_wait", "decode_prepare", "decode_dispatch",
                "decode_wait", "decode_commit", "hook", "tick_end")
@@ -698,18 +727,20 @@ def _on_device(x):
 
 
 def expert_counters(load: np.ndarray) -> dict:
-    """What one decode program did to the experts held here, from its
-    load matrix (a row an expert layer, a column a held expert):
-    assignments in all, held experts touched and the largest expert's
-    load over the mean, both as the mean over the layers (the skew over
-    those that took any assignment; 0 where none did)."""
+    """What one program (a decode tick's or a chunk's) did to the experts
+    held here, from its load matrix (a row an expert layer, a column a
+    held expert): assignments in all, held experts touched and the largest
+    expert's load over the mean, both as the mean over the layers (the
+    skew over those that took any assignment; 0 where none did), and how
+    many expert layers there are."""
     took = load.sum(axis=1)
     busy = took > 0
     skew = (load.max(axis=1)[busy] * load.shape[1] / took[busy]).mean() \
         if busy.any() else 0.0
     return {"assignments": int(took.sum()),
             "touched": float((load > 0).sum(axis=1).mean()),
-            "held": int(load.shape[1]), "skew": float(skew)}
+            "held": int(load.shape[1]), "skew": float(skew),
+            "layers": int(load.shape[0])}
 
 
 class PagedEngine:
@@ -1018,18 +1049,23 @@ class PagedEngine:
         scatter the fresh KV span to its blocks (already-committed /
         padding positions routed to trash), and sample at ``logit_idx``
         (meaningful on the final chunk only — the caller ignores it
-        otherwise; the extra 1-row head projection is noise)."""
+        otherwise; the extra 1-row head projection is noise).  Last of
+        the results, as the decode program's: this chunk's load of each
+        held expert, a row an expert layer, padding rows counted (None for
+        a model without expert layers, whose program is then what it
+        was)."""
         params = self._wp(params)
         with jax.named_scope("kv_gather"):
             cache = self._gather(pools, table, pos)
-        hidden, new = cached_apply(self.lm, params, cache, tokens[None])
+        hidden, new, load = cached_apply_counting(self.lm, params, cache,
+                                                  tokens[None])
         with jax.named_scope("kv_write"):
             span = paged.extract_span(new, pos, self.chunk)
             pools = paged.scatter_span(pools, self._qspan(span), wb, wo)
         with jax.named_scope("sample"):
             h_last = jax.lax.dynamic_slice_in_dim(hidden[0], logit_idx, 1)
             tok, lp, ok = self._sample(params, h_last, key)
-        return pools, tok[0], lp[0], ok[0]
+        return pools, tok[0], lp[0], ok[0], load
 
     def _draft_chunk_impl(self, dparams, dpools, tokens, table, pos,
                           wb, wo):
@@ -1364,7 +1400,11 @@ class PagedEngine:
         is open, ``telemetry.tracer`` or an installed one while there is
         one), and the phase sums, the per-tick ring and the registry are
         published as ``obs.last_run("serve")`` BEFORE the first tick, so
-        they outlive a run that a hook ends by raising.
+        they outlive a run that a hook ends by raising.  Each tick's
+        record lists the programs it dispatched with their instants
+        (:data:`TICK_PHASES`: ``counters["programs"]``), and
+        ``obs.runs("serve")`` keeps the last few runs, those a profiler
+        listened to marked by ``phases.listened``.
         """
         with obs_trace.use_tracer(getattr(telemetry, "tracer", None)):
             return self._run(requests, telemetry, keep_timeline, on_tick,
@@ -1419,6 +1459,8 @@ class PagedEngine:
         e2e_s: dict[int, float] = {}
         timeline = [] if keep_timeline else None
 
+        programs: list = []     # the tick's program records, see the loop
+        unsettled: list = []    # (chunk record, its load still on the device)
         shared_tokens = prompt_tokens = 0
         chunk_calls = spec_rounds = proposed_total = accepted_total = 0
         decode_ticks = occupancy_sum = 0
@@ -1512,6 +1554,17 @@ class PagedEngine:
                          "parent": root_span.get(req.uid)}
             self._make_writable(idx, lo, hi, whose)
 
+        def settle():
+            """Fetch the expert loads of the chunks that have run.  Called
+            where the host is about to wait for a LATER program anyway,
+            before that barrier: the device is busy, the loads are done,
+            so the fetch is on nobody's critical path (fetched after the
+            chunk's own barrier it cost the glm cell 1.9% of its tokens/s,
+            my chip runs, PR 37)."""
+            for rec, load in unsettled:
+                rec["experts"] = expert_counters(np.asarray(load))
+            unsettled.clear()
+
         def run_chunk(idx, ev):
             nonlocal chunk_calls, t_prefill
             with p_chunk_prepare:
@@ -1532,9 +1585,11 @@ class PagedEngine:
                                     else (wb, ring_wb))
                 wo_dev = jnp.asarray(wo)
                 pos = np.int32(plan.feed_start)
+                n_live = (min(plan.feed_start + self.chunk, L)
+                          - max(plan.feed_start, committed[idx]))
             t0 = time.perf_counter()
             with p_chunk_dispatch:
-                self.pools, tok, c_lp, c_ok = self._chunk_prog(
+                self.pools, tok, c_lp, c_ok, load = self._chunk_prog(
                     self.params, self.pools, toks_dev, table_dev, pos,
                     np.int32(max(plan.logit_index, 0)), wb_dev, wo_dev,
                     self._next_key())
@@ -1542,6 +1597,13 @@ class PagedEngine:
                     self.draft_pools = self._draft_chunk(
                         self.draft_params, self.draft_pools, toks_dev,
                         table_dev, pos, wb_dev, wo_dev)
+            # the dispatch has returned: the program ran (or will), so it
+            # gets its record; the instants are the phases' own readings
+            rec = {"program": "paged_chunk",
+                   "at": [p_chunk_dispatch.t0, p_chunk_dispatch.t1, None],
+                   "slot": idx, "uid": req.uid, "start": plan.feed_start,
+                   "live": n_live}
+            programs.append(rec)
             with p_chunk_commit:    # while the device runs the chunk
                 committed[idx] = plan.commit_to
                 mgr.register_committed(idx, stream[idx], committed[idx])
@@ -1550,10 +1612,14 @@ class PagedEngine:
                     ev["chunks"].append(req.uid)
                 sched.note_chunk(idx)
             with p_chunk_wait:
+                settle()                # earlier chunks', behind this one
                 if plan.is_last:
                     first = int(tok)       # host fetch = device barrier
                 else:
                     jax.block_until_ready(self.pools)
+            if load is not None:
+                unsettled.append((rec, load))
+            rec["at"][2] = p_chunk_wait.t1
             now = time.perf_counter()
             t_prefill += now - t0
             if tracer is not None:
@@ -1838,6 +1904,11 @@ class PagedEngine:
                     continue
                 occupancy_sum += sched.occupancy
                 g_occ.set(sched.occupancy)
+                # the tick's counters from here on, so that a tick a
+                # raising dispatch aborts keeps the programs it did run
+                programs = []
+                counters = {"kv_blocks": kv_blocks, "programs": programs}
+                tk.meta = (0, 0, counters)
 
                 # chunked prefill under the per-tick budget, round-robin
                 budget = self.chunks_per_tick
@@ -1856,7 +1927,6 @@ class PagedEngine:
                 # how much prefill work is queued — the stall bound
                 dec = sched.decoding_slots()
                 tk.kind = "decode" if dec else "prefill"
-                counters = {"kv_blocks": kv_blocks}
                 tk.meta = (len(dec), ran, counters)
                 if dec and not (self.draft_layers is not None
                                 and self._spec_enabled):
@@ -1906,12 +1976,18 @@ class PagedEngine:
                         with p_decode_dispatch:
                             self.pools, out, lp_h, ok_h, load = \
                                 self._decode(self.params, self.pools, *dev)
+                        rec = {"program": "paged_decode",
+                               "at": [p_decode_dispatch.t0,
+                                      p_decode_dispatch.t1, None]}
+                        programs.append(rec)
                         with p_decode_wait:
+                            settle()    # the tick's chunks', behind this one
                             out = np.asarray(out)   # host fetch = barrier
                             lp_h, ok_h = np.asarray(lp_h), np.asarray(ok_h)
                             if load is not None:    # came with the tokens
-                                counters["experts"] = expert_counters(
-                                    np.asarray(load))
+                                rec["experts"] = counters["experts"] = \
+                                    expert_counters(np.asarray(load))
+                        rec["at"][2] = p_decode_wait.t1
                     now = time.perf_counter()
                     t_decode += now - t0
                     decode_ticks += 1
@@ -2046,6 +2122,7 @@ class PagedEngine:
                 tick += 1
 
         total = time.perf_counter() - t_start
+        settle()    # the last chunks', where no program followed
         tokens = int(sum(len(v) for v in sched.finished.values()))
         hit = shared_tokens / prompt_tokens if prompt_tokens else 0.0
         g_blocks.set(mgr.in_use)

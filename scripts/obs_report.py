@@ -13,6 +13,7 @@ users saw.
     python scripts/obs_report.py obs_events.jsonl --window   # live windows
     python scripts/obs_report.py obs_events.jsonl --memory   # memory view
     python scripts/obs_report.py --xplane DIR                # profiler trace
+    python scripts/obs_report.py --programs gpt ... --serve --paged
 
 ``--prom`` dumps the final metrics snapshot in Prometheus text
 exposition format (for a textfile collector or diffing against a scrape
@@ -28,6 +29,13 @@ is taken from the stream's ``obs_trace`` event; pass
 ``--window`` prints the rolling-window live signals (``obs_window``
 events): windowed TTFT/ITL percentiles, queue depth, slot occupancy
 and request/token rates over the run.
+
+``--programs ARGV`` runs the workload CLI with ARGV in this process and
+prints what each serving run it made recorded of its own programs
+(``obs.runs("serve")``: the tick ring's ``counters["programs"]`` and each
+tick's start): the host's turnaround between two programs by kind of
+pair, and the longest tick with its phase row, its programs and the time
+before it that no tick owns: where to look first on a run that stalled.
 
 ``--xplane DIR`` reads the profiler trace a ``--profile-dir DIR`` run
 wrote (no event stream needed) and answers the two questions the device
@@ -279,6 +287,66 @@ def render_xplane(trace: dict, depth: int = 3, top: int = 24,
     return "\n".join(out)
 
 
+def render_programs(record) -> str:
+    """What a serving run recorded of its own programs
+    (``obs.last_run("serve")`` / ``obs.runs("serve")``; the tick ring's
+    ``counters["programs"]`` and ``PhaseClock.started``): the host's
+    turnaround from holding one program's result to entering the next
+    dispatch, by kind of pair, and the longest tick, counted from the end
+    of the tick before it, with its phase row, its programs and the time
+    before it that no tick owns."""
+    import statistics
+
+    if record is None:
+        return "no serving run has published a record"
+    pc = record.phases
+    ticks, starts = list(pc.ticks), list(pc.started)
+    progs = [p for t in ticks if len(t[2]) > 2
+             for p in t[2][2].get("programs", ())]
+    lines = [f"programs of the run: {len(progs)} recorded in "
+             f"{len(ticks)} ticks of {pc.n_ticks} (listened to: "
+             f"{pc.listened})"]
+    by_kind: dict = {}
+    for a, b in zip(progs, progs[1:]):
+        if a["at"][2] is not None:
+            by_kind.setdefault(
+                (a["program"], b["program"]), []).append(
+                    (b["at"][0] - a["at"][2]) * 1e3)
+    for (ka, kb), ms in sorted(by_kind.items()):
+        short = [k.replace("paged_", "") for k in (ka, kb)]
+        lines.append(f"  turnaround {short[0]}->{short[1]}: {len(ms)} "
+                     f"pairs, median {statistics.median(ms):.3f} ms, "
+                     f"longest {max(ms):.3f}")
+    touched = [p["experts"]["touched"] / p["experts"]["held"]
+               for p in progs if p["program"] == "paged_chunk"
+               and p.get("experts", {}).get("held")]
+    if touched:
+        lines.append(f"  held experts a chunk touched, a layer: "
+                     f"{100 * statistics.fmean(touched):.1f}% over "
+                     f"{len(touched)} chunks")
+    if ticks and len(starts) == len(ticks):
+        ends = [s + t[3] for s, t in zip(starts, ticks)]
+        spans = [ticks[0][3]] + [b - a for a, b in zip(ends, ends[1:])]
+        k = max(range(len(spans)), key=spans.__getitem__)
+        index, kind, meta, wall, row = ticks[k]
+        median = statistics.median(spans)
+        lines.append(
+            f"  longest tick: {spans[k] * 1e3:.3f} ms (median "
+            f"{median * 1e3:.3f}; {sum(s > 10 * median for s in spans)} "
+            f"of {len(spans)} over ten times it), tick {index} ({kind}), "
+            f"{(spans[k] - wall) * 1e3:.3f} ms before it that no tick owns")
+        lines.append("    phases ms: " + ", ".join(
+            f"{n} {v * 1e3:.3f}" for n, v in zip(pc.names, row) if v))
+        for p in (meta[2].get("programs", ()) if len(meta) > 2 else ()):
+            at = "/".join("-" if t is None else f"{(t - starts[k]) * 1e3:.3f}"
+                          for t in p["at"])
+            rest = {k_: v for k_, v in p.items()
+                    if k_ not in ("program", "at", "experts")}
+            lines.append(f"    {p['program']}: dispatch/returned/ready at "
+                         f"{at} ms of the tick {rest or ''}".rstrip())
+    return "\n".join(lines)
+
+
 def render(events: list[dict], phases: bool = False) -> str:
     run_gp = None
     phase_gps = []
@@ -421,7 +489,21 @@ def main(argv=None) -> int:
     p.add_argument("--memory", action="store_true",
                    help="print the memory view (obs_memory rollup + "
                         "mem_*/kv-cache gauges) instead of the report")
+    p.add_argument("--programs", nargs=argparse.REMAINDER, metavar="ARGV",
+                   help="run the workload CLI with ARGV in THIS process "
+                        "(e.g. --programs gpt -l 2 -s 64 -e 1 -b 8 -m "
+                        "sequential --serve --paged), then print what each "
+                        "serving run recorded of its own programs: "
+                        "turnaround by kind of pair, the longest tick")
     args = p.parse_args(argv)
+    if args.programs:
+        from distributed_deep_learning_tpu import obs
+        from distributed_deep_learning_tpu.__main__ import main as cli
+
+        cli(args.programs)
+        for record in obs.runs("serve") or [None]:
+            print(render_programs(record))
+        return 0
     if args.xplane:
         from distributed_deep_learning_tpu.obs import xplane
 

@@ -784,7 +784,12 @@ def test_the_benchmark_runs_the_cell_and_reads_its_counters(tmp_path):
     assert listed == ["serve_expert_touched_pct", "serve_expert_load_skew",
                       "latent_decode_roofline", "latent_attn_roofline",
                       "serve_latent_row_reads",
-                      "serve_itl_p95_ms.long_context"]
+                      "serve_itl_p95_ms.long_context",
+                      # PR 37: what the program records of its own runs
+                      "grouped_product_roofline",
+                      "serve_chunk_expert_touched_pct",
+                      "serve_turnaround_ms", "serve_launch_notice_ms",
+                      "serve_tick_longest_ms"]
     cell = _tiny_bench(tmp_path)
     assert [m["name"] for m in cell.per_layer] == listed
     out = cellrun.run_cell("tiny-serve", 2 ** 31 + 30, 1.5, False,

@@ -185,6 +185,59 @@ def test_pools_by_kind_and_tick_counters():
     assert sum(c["experts"]["assignments"] for c in both) > 0
 
 
+@pytest.mark.parametrize("held", [(4, 4), (0, 16)])
+def test_a_chunks_experts_equal_a_numpy_recount(held, monkeypatch):
+    """Two expert layers on the CPU: every chunk program's record carries
+    `expert_counters` of ITS OWN load, counted on the device, equal to a
+    recount in numpy of the router's choices over the chunk's rows,
+    padding rows included (with every expert held a chunk of 8 rows makes
+    8 x 3 assignments a layer however short the prompt)."""
+    from distributed_deep_learning_tpu.models import moe
+
+    seen, real = [], moe.route_top_k
+
+    def spy(logits, *args, **kw):
+        w, experts = real(logits, *args, **kw)
+        jax.debug.callback(lambda e: seen.append(np.asarray(e)), experts,
+                           ordered=True)
+        return w, experts
+
+    monkeypatch.setattr(moe, "route_top_k", spy)
+    cfg = tiny(layer_types=(FULL, SLIDING, FULL), held=held)
+    reqs = [(tokens(60 + i, n), 3) for i, n in enumerate((5, 19, 8, 30))]
+    _serve(cfg, reqs, max_slots=2, max_len=64, kv_block_size=4,
+           prefill_chunk=8, num_blocks=40)
+    jax.effects_barrier()
+    progs = [p for t in obs.last_run("serve").phases.ticks
+             if len(t[2]) > 2 for p in t[2][2]["programs"]]
+    chunks = [p for p in progs if p["program"] == "paged_chunk"]
+    # a chunk program routes 8 rows a layer, a decode program its 2 slots
+    by_chunk = [e for e in seen if e.shape == (8, 3)]
+    assert len(chunks) == 1 + 3 + 1 + 4 and len(by_chunk) == 2 * len(chunks)
+    first, count = held
+    for k, p in enumerate(chunks):
+        load = np.zeros((2, count), np.int64)
+        for layer in range(2):
+            local = by_chunk[2 * k + layer].reshape(-1) - first
+            local = local[(local >= 0) & (local < count)]
+            load[layer] = np.bincount(local, minlength=count)
+        took = load.sum(axis=1)
+        busy = took > 0
+        want = {"assignments": int(took.sum()),
+                "touched": float((load > 0).sum(axis=1).mean()),
+                "held": count, "layers": 2,
+                "skew": float((load.max(axis=1)[busy] * count
+                               / took[busy]).mean()) if busy.any() else 0.0}
+        assert p["experts"] == pytest.approx(want), (k, p)
+        if count == 16:         # all held: padding rows are counted too
+            assert p["experts"]["assignments"] == 2 * 8 * 3
+    # the decode program's record is the tick's own counters, unmoved
+    for t in obs.last_run("serve").phases.ticks:
+        for p in (t[2][2]["programs"] if len(t[2]) > 2 else ()):
+            if p["program"] == "paged_decode":
+                assert p["experts"] is t[2][2]["experts"]
+
+
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
 def test_reduced_precision_caches_serve_two_kinds(kv_dtype):
     """A ring rests in bf16 or int8 as a whole-sequence pool does, scales
@@ -515,7 +568,11 @@ def test_the_benchmark_runs_the_cell_and_reads_its_counters(tmp_path):
     assert [m["name"] for m in cell.per_layer] == [
         "moe_decode_roofline", "serve_expert_touched_pct",
         "serve_expert_load_skew",
-        "serve_kv_window_saved_pct"]
+        "serve_kv_window_saved_pct",
+        # PR 37: what the program records of its own runs
+        "grouped_product_roofline", "serve_chunk_expert_touched_pct",
+        "serve_turnaround_ms", "serve_launch_notice_ms",
+        "serve_tick_longest_ms"]
     keep = {}
     out = cellrun.run_cell("tiny-serve", 2 ** 31 + 26, 1.5, False,
                            allow_cpu=True, cell=cell, keep=keep)
@@ -531,6 +588,22 @@ def test_the_benchmark_runs_the_cell_and_reads_its_counters(tmp_path):
                               where=["experts", "assignments"])
     assert 1.0 <= skew <= 4.0
     assert tick_counters.read({}, ["no", "such"]) is None
+    # PR 37: the window's own record, read by the readers of the timed run
+    from benchmark.readers import (tick_longest_ms, traced_run,
+                                   turnaround_ms)
+
+    progs = traced_run.programs(traced_run.timed_record())
+    assert {p["program"] for p in progs} == {"paged_chunk", "paged_decode"}
+    # a chunk's load is fetched behind the NEXT program: the chunk the
+    # window's end cut off may lack it, no other
+    assert sum("experts" not in p for p in progs) <= 1
+    assert 0.0 < turnaround_ms.read({}) < 1e3
+    assert tick_longest_ms.read({}) > 0.0
+    # nothing listened to this window: the traced window's reader reads
+    # nothing of it
+    assert traced_run.timed_record().phases.listened == 0
+    listened = traced_run.traced_record()
+    assert listened is None or listened is not traced_run.timed_record()
     # 2 full layers and 4 sliding ones; a ring of 5 blocks against 7 to 20
     # reserved a request: something is saved, less than the sliding share
     saved = kv_window_saved.read({"config": cell.config})

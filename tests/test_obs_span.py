@@ -25,6 +25,7 @@ from distributed_deep_learning_tpu.serve.engine import (DISPATCH_PHASES,
                                                         TICK_PHASES,
                                                         PagedEngine)
 from distributed_deep_learning_tpu.serve.load import make_trace
+from distributed_deep_learning_tpu.serve.prefill import plan_chunks
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -570,3 +571,443 @@ def test_readers_give_none_on_a_record_of_another_shape(metric,
     read, args = _spec(metric)
     assert read({}, **args) is None
     assert "nothing to read (AttributeError" in capsys.readouterr().out
+
+
+# ------------------------------- PR 37: program records, starts, the run log
+
+def _flat_programs(pc):
+    return [dict(p, tick=k) for k, t in enumerate(pc.ticks) if len(t[2]) > 2
+            for p in t[2][2]["programs"]]
+
+
+def test_the_ring_still_unpacks_into_five_and_keeps_each_ticks_start():
+    """A sixth field would silently drop every metric that reads the ring
+    through `last_run_phases`; a tick's start lies BESIDE the ring."""
+    out = _engine().run(_requests())
+    pc = obs.last_run("serve").phases
+    for t in pc.ticks:
+        index, kind, meta, wall, row = t        # five, as the readers unpack
+        assert len(row) == len(TICK_PHASES)
+    assert len(pc.started) == len(pc.ticks) == pc.n_ticks
+    starts, walls = list(pc.started), [t[3] for t in pc.ticks]
+    between = [b - a - w for a, b, w in zip(starts, starts[1:], walls)]
+    assert all(g >= 0 for g in between)         # time no tick owns
+    assert pc.listened == 0                     # nothing listened
+    read, args = _spec("serve_tick_host_ms")
+    assert read({}, **args) > 0                 # a run of the new code reads
+    read, args = _spec("serve_chunk_program_ms")
+    assert read({}, **args) > 0
+    assert out["stats"]["decode_ticks"] > 0
+
+
+def test_a_ticks_programs_are_recorded_in_dispatch_order():
+    eng = _engine()
+    reqs = _requests()
+    out = eng.run(reqs)
+    pc = obs.last_run("serve").phases
+    progs = _flat_programs(pc)
+    names = [p["program"] for p in progs]
+    assert names.count("paged_chunk") == out["stats"]["prefill_chunks"]
+    assert names.count("paged_decode") == out["stats"]["decode_ticks"]
+    # a tick runs its chunks, then its decode program
+    for t in pc.ticks:
+        if len(t[2]) > 2:
+            mine = [p["program"] for p in t[2][2]["programs"]]
+            assert mine == (["paged_chunk"] * t[2][1]
+                            + ["paged_decode"] * (t[1] == "decode"))
+    # dispatch entered <= returned <= ready, and so on across records
+    instants = [x for p in progs for x in p["at"]]
+    assert None not in instants and instants == sorted(instants)
+    # the instants are the phase clock's own: a tick's records lie inside it
+    for p in progs:
+        t0 = pc.started[p["tick"]]
+        assert t0 <= p["at"][0] and p["at"][2] <= t0 + pc.ticks[p["tick"]][3]
+    # a chunk says whose it is, where it starts and what it wrote
+    chunks = [p for p in progs if p["program"] == "paged_chunk"]
+    by_uid = {}
+    for p in chunks:
+        assert set(p) == {"program", "at", "slot", "uid", "start", "live",
+                          "tick"}               # no expert layer: no experts
+        assert 0 <= p["slot"] < 3
+        by_uid.setdefault(p["uid"], []).append(p)
+    for r in reqs:                              # nothing shared in this mix
+        mine = by_uid[r.uid]
+        # the last slice is shifted back to end at the prompt's end, and
+        # writes only what the slices before it did not
+        assert [p["start"] for p in mine] == [
+            c.feed_start for c in plan_chunks(0, len(r.prompt), 8)]
+        assert sum(p["live"] for p in mine) == len(r.prompt)
+        assert all(0 < p["live"] <= 8 for p in mine)
+    assert all(set(p) == {"program", "at", "tick"} for p in progs
+               if p["program"] == "paged_decode")
+
+
+def test_a_program_that_never_ran_leaves_no_record():
+    """The benchmark ends a window by raising out of the program object it
+    wraps, before the work: the aborted tick keeps the programs it did run
+    and nothing of the one that did not."""
+    eng = _engine()
+    prog, calls = eng._decode, []
+
+    class Spy:
+        traces = property(lambda s: prog.traces)
+        _jit = prog._jit
+
+        def __call__(s, *args):
+            if len(calls) == 4:
+                raise _Closed
+            calls.append(1)
+            return prog(*args)
+
+    eng._decode = Spy()
+    with pytest.raises(_Closed):
+        eng.run(_requests())
+    pc = obs.last_run("serve").phases
+    progs = _flat_programs(pc)
+    assert [p["program"] for p in progs].count("paged_decode") == 4
+    last = pc.ticks[-1]
+    assert last[1] == "aborted" and len(last[2]) > 2
+    assert all(p["program"] == "paged_chunk" and p["at"][2] is not None
+               for p in last[2][2]["programs"])
+
+
+def test_the_run_log_keeps_a_listened_run_and_drops_the_fifth():
+    kind = "test-kind"
+
+    def record(listen):
+        pc = PhaseClock(("a",))
+        with (obs_trace.use_tracer(Tracer()) if listen
+              else contextlib.nullcontext()):
+            with pc.tick(0) as tk:
+                tk.kind = "work"
+        with pc.tick(1):
+            pass
+        return runlog.publish(runlog.RunRecord(kind, pc))
+
+    try:
+        traced, rest = record(True), record(False)
+        assert (traced.phases.listened, rest.phases.listened) == (1, 0)
+        assert obs.runs(kind) == [traced, rest]
+        assert obs.last_run(kind) is rest
+        newest = [r for r in reversed(obs.runs(kind))
+                  if r.phases.listened > 0][0]
+        assert newest is traced                 # found with no clock
+        # published again (a loader's one record, every epoch): once, newest
+        runlog.publish(traced)
+        assert obs.runs(kind) == [rest, traced]
+        assert obs.last_run(kind) is traced
+        runlog.publish(traced)
+        assert obs.runs(kind) == [rest, traced]
+        more = [record(False) for _ in range(3)]
+        assert obs.runs(kind) == [traced] + more and len(more) == 3
+        assert runlog.KEPT == 4                 # the fifth dropped `rest`
+        assert obs.runs("no-such-kind") == []
+    finally:
+        runlog._RUNS.pop(kind, None)
+        runlog._LAST.pop(kind, None)
+
+
+# --------------------------------- PR 37: the readers of the traced window
+
+def _reader(name):
+    if REPO not in sys.path:
+        sys.path.insert(0, REPO)
+    return importlib.import_module("benchmark.readers." + name)
+
+
+MS = 1_000_000
+DEV, HOST = "/device:TPU:0", "/host:CPU"
+
+
+def _module(name, start_ms, dur_ms):
+    return [DEV, "XLA Modules", f"jit_{name}(7)", int(start_ms * MS),
+            int(dur_ms * MS)]
+
+
+def _traced_pair(n_ticks=4, cut=None, copy_between=False, lag_ms=0.5):
+    """A hand-made traced window: `n_ticks` ticks of a chunk (20 ms on
+    the device) then a decode program (5 ms), the host 1 ms between a
+    result and the next dispatch, the device `lag_ms` behind the host on
+    each side (launch 0.3, notice 0.2 of 0.5).  Returns (record, events).
+    The host's clock starts at 1000 s, the device's at 0: no reader may
+    subtract one from the other."""
+    pc = PhaseClock(TICK_PHASES)
+    pc.listened = n_ticks
+    events = [[HOST, "t", "bench:window", 0, int(1000 * MS)]]
+    launch, notice = 0.6 * lag_ms, 0.4 * lag_ms
+
+    def dev_of(host_s):         # ms on the device's clock
+        return (host_s - 1000.0) * 1e3 + 2.0
+
+    host = 1000.0
+    for k in range(n_ticks):
+        t_tick = host
+        progs = []
+        for name, dur in (("paged_chunk", 20.0), ("paged_decode", 5.0)):
+            host += 1e-3                        # the host's bookkeeping
+            t_dispatch = host
+            dev_start = dev_of(t_dispatch) + launch
+            events.append(_module(name, dev_start, dur))
+            for j in range(2 * 2):              # two expert layers
+                events.append([DEV, "XLA Ops",
+                               ("grouped_swiglu" if j % 2 == 0
+                                else "grouped_product")
+                               + f".{j} [tpu_custom_call]",
+                               int((dev_start + 0.1 + j) * MS),
+                               int(0.5 * MS)])
+            host = t_dispatch + (launch + dur + notice) / 1e3
+            rec = {"program": name,
+                   "at": (t_dispatch, t_dispatch + 1e-4, host),
+                   "experts": {"assignments": 48 if name == "paged_chunk"
+                               else 6, "touched": 3.0, "held": 4,
+                               "skew": 1.5, "layers": 2}}
+            if name == "paged_chunk":
+                rec.update(slot=0, uid=k, start=0, live=8)
+                if copy_between and k == 1:
+                    events.append(_module("paged_copy",
+                                          dev_start + dur + 0.2, 0.1))
+            progs.append(rec)
+        pc.ticks.append((k, "decode", (1, 1, {"kv_blocks": {},
+                                              "programs": progs}),
+                         host - t_tick, (0.0,) * len(TICK_PHASES)))
+        pc.started.append(t_tick)
+        pc.n_ticks += 1
+    if cut == "event":          # the window closed on the last program
+        events = events[:-5]
+    elif cut == "record":
+        pc.ticks[-1][2][2]["programs"].pop()
+    return runlog.RunRecord("serve", pc), events
+
+
+@pytest.fixture
+def traced_window(monkeypatch):
+    def install(**kw):
+        record, events = _traced_pair(**kw)
+        monkeypatch.setitem(runlog._RUNS, "serve", [record])
+        monkeypatch.setitem(runlog._LAST, "serve", record)
+        cfg = {"hidden_size": 16, "moe_intermediate_size": 8}
+        return record, {"trace": {"events": events}, "config": cfg,
+                        "peaks": {"hbm_bytes_per_s": 1e9,
+                                  "bf16_flops": 1e12}}
+    return install
+
+
+@pytest.mark.parametrize("cut", [None, "event", "record"])
+def test_the_join_is_by_order_and_bears_a_cut_last_program(traced_window,
+                                                           cut):
+    tr = _reader("traced_run")
+    record, ctx = traced_window(cut=cut)
+    pairs = tr.joined(ctx)
+    # 4 chunks; 4 decode programs, 3 where the last lacks its partner
+    assert [r["program"] for r, _ in pairs].count("paged_chunk") == 4
+    assert len(pairs) == (8 if cut is None else 7)
+    # the k-th record of a name met the k-th event of that name, in order
+    starts = [ev[0] for _, ev in pairs]
+    assert starts == sorted(starts)
+    for r, (s, e) in pairs:
+        assert (e - s) == (20 if r["program"] == "paged_chunk" else 5) * MS
+    assert tr.traced_record() is record and tr.timed_record() is record
+
+
+def test_the_join_refuses_counts_that_differ_by_more_than_one(
+        traced_window, capsys):
+    tr = _reader("traced_run")
+    record, ctx = traced_window()
+    for t in list(record.phases.ticks)[-2:]:
+        t[2][2]["programs"].pop()               # two decode records gone
+    assert tr.joined(ctx) is None
+    assert "2 recorded paged_decode against 4 jit_paged_decode" \
+        in capsys.readouterr().out
+    # an unlistened record is not the traced window's
+    record.phases.listened = 0
+    assert tr.traced_record() is None and tr.joined(ctx) is None
+
+
+def test_the_grouped_cost_at_the_glm_chunk_shape():
+    """4,096 rows, 2,048 x 1,536, all 64 experts touched: the fused call
+    moves 805 MB of weights + 29 MB of rows, 1.02 ms at 819 GB/s against
+    0.26 ms by operations, so the builders' 1.213 ms a call reads 84%."""
+    if REPO not in sys.path:
+        sys.path.insert(0, REPO)
+    cost = importlib.import_module("benchmark.costs.grouped_product").cost
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "glm-4.7-flash-d7.json")) as f:
+        cfg = json.load(f)
+    need = cost(cfg, 64, 4096)
+    fused, down = need["swiglu"], need["down"]
+    assert fused["weight_bytes"] == 2 * 64 * 2048 * 1536 * 2 == 805_306_368
+    assert fused["row_bytes"] == 4096 * (2048 + 1536) * 2 == 29_360_128
+    assert fused["bytes"] / 819e9 == pytest.approx(1.019e-3, rel=1e-3)
+    assert fused["flops"] / 197e12 == pytest.approx(0.2616e-3, rel=1e-3)
+    assert fused["bytes"] / 819e9 / 1.213e-3 == pytest.approx(0.84, abs=5e-3)
+    assert down["weight_bytes"] * 2 == fused["weight_bytes"]
+    assert down["flops"] * 2 == fused["flops"]
+    assert need["bytes"] == fused["bytes"] + down["bytes"]
+    # linear in both: six layers' calls are six times one layer's
+    assert cost(cfg, 6 * 64, 6 * 4096)["bytes"] == 6 * need["bytes"]
+
+
+def test_the_grouped_roofline_reads_the_traced_window_alone(traced_window,
+                                                            capsys):
+    read, args = _spec("grouped_product_roofline")
+    record, ctx = traced_window()
+    # 8 programs x 4 kernel events of 0.5 ms = 16 ms; touched 8 x 3 x 2
+    # experts x 3 matrices of 16 x 8 x 2 B, rows (4 x 48 + 4 x 6) x 2
+    # directions... the cost function's sum, bytes-bound at 1 GB/s
+    cost = importlib.import_module("benchmark.costs.grouped_product").cost
+    need = cost(ctx["config"], 8 * 3.0 * 2, 4 * 48 + 4 * 6)
+    assert need["bytes"] / 1e9 > need["flops"] / 1e12
+    assert read(ctx, **args) == pytest.approx(
+        100.0 * need["bytes"] / 1e9 / 0.016)
+    said = capsys.readouterr().out
+    assert "8 programs joined (4 chunk, 4 decode), 4 kernel events a " \
+           "program" in said and "bound by bytes" in said
+    # the timed run that followed holds other counters: they are not read
+    other, _ = _traced_pair()
+    other.phases.listened = 0
+    for t in other.phases.ticks:
+        for p in t[2][2]["programs"]:
+            p["experts"] = dict(p["experts"], touched=1.0)
+    runlog._RUNS["serve"].append(other)
+    runlog._LAST["serve"] = other
+    assert read(ctx, **args) == pytest.approx(
+        100.0 * need["bytes"] / 1e9 / 0.016)
+    # a program whose calls went another way: not two events a layer
+    ctx["trace"]["events"] = [e for e in ctx["trace"]["events"]
+                              if "grouped_product.3" not in e[2]]
+    assert read(ctx, **args) is None
+    assert "not two a layer" in capsys.readouterr().out
+    assert read(dict(ctx, trace=None), **args) is None
+
+
+def test_chunk_experts_turnaround_and_the_longest_tick(traced_window,
+                                                      capsys):
+    record, ctx = traced_window()
+    read, args = _spec("serve_chunk_expert_touched_pct")
+    assert read(ctx, **args) == pytest.approx(75.0)         # 3 of 4 held
+    read, args = _spec("serve_turnaround_ms")
+    assert read(ctx, **args) == pytest.approx(1.0)          # by construction
+    said = capsys.readouterr().out
+    assert "turnaround chunk->decode: 4 pairs, median 1.000ms" in said
+    assert "turnaround decode->chunk: 3 pairs, median 1.000ms" in said
+    # a ring that has lost its oldest ticks still has consecutive pairs,
+    # but no order from the run's start for a join
+    record.phases.n_ticks += 100
+    assert read(ctx, **args) == pytest.approx(1.0)
+    assert _reader("traced_run").joined(ctx) is None
+    assert "the ring kept 4 of 104 ticks" in capsys.readouterr().out
+    record.phases.n_ticks -= 100
+    # a stall before tick 2 that no tick owns: 2 s between two ticks
+    starts = list(record.phases.started)
+    record.phases.started.clear()
+    record.phases.started.extend(s + (2.0 if k >= 2 else 0.0)
+                                 for k, s in enumerate(starts))
+    for t in list(record.phases.ticks)[2:]:
+        for p in t[2][2]["programs"]:
+            p["at"] = tuple(x + 2.0 for x in p["at"])
+    read, args = _spec("serve_tick_longest_ms")
+    wall = record.phases.ticks[2][3] * 1e3
+    assert read(ctx, **args) == pytest.approx(2000.0 + wall)
+    said = capsys.readouterr().out
+    assert "tick 2 (decode), 1 slots decoding, 1 chunks" in said
+    assert "2000.000ms before it that no tick owns" in said
+    assert "1 of 4 over ten times it" in said
+    assert "paged_chunk 1.000/1.100/" in said
+
+
+@pytest.mark.parametrize("copy_between", [False, True])
+def test_launch_and_notice_is_the_gap_less_the_turnaround(traced_window,
+                                                          copy_between,
+                                                          capsys):
+    read, args = _spec("serve_launch_notice_ms")
+    record, ctx = traced_window(copy_between=copy_between)
+    # every gap is 1 ms of host + 0.2 notice + 0.3 launch
+    assert read(ctx, **args) == pytest.approx(0.5)
+    said = capsys.readouterr().out
+    # 7 consecutive pairs; a block copy ran inside one of them
+    assert f"{6 if copy_between else 7} of 7 pairs with nothing between" \
+        in said
+    assert "mean gap 1.500ms = turnaround 1.000 + remainder 0.500" in said
+    assert "0.00% of pairs read a negative remainder" in said
+
+
+def test_launch_and_notice_refuses_a_join_at_fault(traced_window, capsys):
+    read, args = _spec("serve_launch_notice_ms")
+    record, ctx = traced_window(lag_ms=-0.5)    # the device AHEAD: a fault
+    assert read(ctx, **args) is None
+    assert "over 1% negative" in capsys.readouterr().out
+
+
+NEW_METRICS = ["grouped_product_roofline", "serve_chunk_expert_touched_pct",
+               "serve_turnaround_ms", "serve_launch_notice_ms",
+               "serve_tick_longest_ms"]
+
+
+@pytest.mark.parametrize("metric", NEW_METRICS)
+def test_new_readers_give_none_on_the_parents_program(metric, monkeypatch,
+                                                      traced_window):
+    """The driver runs the new readers over the PARENT's program too: no
+    `obs.runs`, no `started`, no program records; nothing raised."""
+    read, args = _spec(metric)
+    record, ctx = traced_window()
+    pc = PhaseClock(TICK_PHASES)                # a PR-36 ring: no programs
+    pc.ticks.append((0, "decode", (1, 1, {"kv_blocks": {}}), 0.01,
+                     (0.0,) * len(TICK_PHASES)))
+    pc.n_ticks = 1
+    old = runlog.RunRecord("serve", pc)
+    monkeypatch.setitem(runlog._RUNS, "serve", [old])
+    monkeypatch.setitem(runlog._LAST, "serve", old)
+    del pc.started                              # the parent keeps none
+    pc.listened = 1
+    assert read(ctx, **args) is None
+    monkeypatch.delattr(obs, "runs")            # the parent's package
+    assert read(ctx, **args) is None
+    monkeypatch.delattr(obs, "last_run")
+    assert read(ctx, **args) is None
+
+
+@pytest.mark.parametrize("metric", NEW_METRICS)
+def test_new_readers_give_none_on_a_record_of_another_shape(metric,
+                                                            monkeypatch,
+                                                            traced_window,
+                                                            capsys):
+    read, args = _spec(metric)
+    record, ctx = traced_window()
+    monkeypatch.setattr(obs, "runs", lambda kind: [object()])
+    monkeypatch.setattr(obs, "last_run", lambda kind: object())
+    assert read(ctx, **args) is None
+    assert "nothing to read (AttributeError" in capsys.readouterr().out
+
+
+def test_the_report_prints_a_runs_program_records():
+    """`scripts/obs_report.py`'s view of what the readers use."""
+    if os.path.join(REPO, "scripts") not in sys.path:
+        sys.path.insert(0, os.path.join(REPO, "scripts"))
+    import obs_report
+
+    _engine().run(_requests())
+    text = obs_report.render_programs(obs.last_run("serve"))
+    assert "turnaround" in text and "chunk->decode" in text
+    assert "longest tick" in text and "no tick owns" in text
+    assert obs_report.render_programs(None) == \
+        "no serving run has published a record"
+
+
+@pytest.mark.parametrize("metric", ["grouped_product_roofline",
+                                    "serve_chunk_expert_touched_pct",
+                                    "serve_launch_notice_ms"])
+def test_a_recorded_traced_window_reads_what_the_chip_run_printed(
+        metric, monkeypatch):
+    """The laguna cell's traced window as recorded on a v5e
+    (`benchmark/testdata/programs.recorded.json`; the benchmark's own
+    tests, `benchmark/tests/test_traced_run.py`, hold more of it)."""
+    if REPO not in sys.path:
+        sys.path.insert(0, REPO)
+    recorded_window = importlib.import_module(
+        "benchmark.tests.test_traced_run")
+    record, ctx = recorded_window.recorded()
+    monkeypatch.setitem(runlog._RUNS, "serve", [record])
+    monkeypatch.setitem(runlog._LAST, "serve", record)
+    read, args = _spec(metric)
+    assert read(ctx, **args) == pytest.approx(
+        recorded_window.PRINTED[metric], rel=1e-12)
