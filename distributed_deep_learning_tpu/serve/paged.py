@@ -484,6 +484,12 @@ class BlockManager:
         self._chain: dict[int, tuple[int, bytes]] = {}
         self.copies = 0
         self.evictions = 0
+        #: what `register_committed` has done so far: tokens it read out
+        #: of the streams it was given, and blocks it added to the index
+        #: (evicted since or not).  tokens_read / (indexed x block_size)
+        #: is 1.0 where nothing is shared and each block is read once
+        self.tokens_read = 0
+        self.indexed = 0
         self.peak_in_use = 0
         # optional observability hook: ``on_event(kind, **fields)`` fires
         # on evictions and COW detaches (the engine wires it to the
@@ -728,7 +734,12 @@ class BlockManager:
         """Index every full block of ``slot`` whose tokens are final
         (all positions < ``committed``; committed positions are never
         rewritten, so the block's content is frozen).  ``tokens`` is the
-        slot's whole stream (prompt + generated) as known to the host.
+        slot's whole stream (prompt + generated) as known to the host, a
+        list or an array: only the blocks that have just filled are read
+        out of it (`tokens_read`), so a commit that completes no block
+        costs nothing that grows with the stream (converted whole, once a
+        decoding slot a token, it was 5.5-6.9 ms of the glm cell's tick:
+        ledger, PR 37).
         The chain hash is a pure function of the token stream, so a
         COW-copied private block registers under its true prefix hash
         like any other.  Returns how many new blocks were indexed (none,
@@ -737,10 +748,10 @@ class BlockManager:
             return 0
         bs = self.block_size
         done, h = self._chain[slot]
-        toks = np.asarray(tokens)
         added = 0
         while (done + 1) * bs <= committed:
-            blk = tuple(int(t) for t in toks[done * bs:(done + 1) * bs])
+            blk = tuple(int(t) for t in tokens[done * bs:(done + 1) * bs])
+            self.tokens_read += len(blk)
             parent = h
             h = chain_hash(h, blk)
             b = int(self.tables[slot, done])
@@ -749,6 +760,7 @@ class BlockManager:
                 added += 1
             done += 1
         self._chain[slot] = (done, h)
+        self.indexed += added
         return added
 
     def adopt_prefix(self, tokens, n_blocks: int):
@@ -846,6 +858,8 @@ class BlockManager:
             "blocks_in_use": self.in_use,
             "blocks_peak_in_use": self.peak_in_use,
             "indexed_blocks": len(self.index),
+            "indexed_total": self.indexed,
+            "tokens_read": self.tokens_read,
             "cow_copies": self.copies,
             "evictions": self.evictions,
         }

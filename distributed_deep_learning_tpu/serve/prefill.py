@@ -80,15 +80,17 @@ def plan_chunks(shared_len: int, length: int, chunk: int) -> list:
         s += chunk
 
 
-def chunk_tokens(stream: np.ndarray, plan: ChunkPlan, chunk: int,
+def chunk_tokens(stream, plan: ChunkPlan, chunk: int,
                  pad_id: int) -> np.ndarray:
     """The ``(chunk,)`` token slice this plan feeds, right-padded with
-    ``pad_id`` when the prompt is shorter than one chunk."""
-    toks = np.asarray(stream)[plan.feed_start:plan.feed_start + chunk]
-    if len(toks) < chunk:
-        toks = np.concatenate(
-            [toks, np.full(chunk - len(toks), pad_id, toks.dtype)])
-    return toks.astype(np.int64)
+    ``pad_id`` when the prompt is shorter than one chunk.  `stream` is a
+    list or an array; only the slice is converted, to the int32 the chunk
+    program is traced for (an int64 array costs a
+    ``jit_convert_element_type`` program ahead of every chunk)."""
+    feed = stream[plan.feed_start:plan.feed_start + chunk]
+    toks = np.full(chunk, pad_id, np.int32)
+    toks[:len(feed)] = feed
+    return toks
 
 
 def write_targets(feed_start: int, n: int, committed: int, length: int,

@@ -421,7 +421,8 @@ def render(events: list[dict], phases: bool = False) -> str:
                    f"tokens/sec {st.get('tokens_per_sec'):.1f}  "
                    f"occupancy {st.get('mean_slot_occupancy'):.2f}"
                    f"/{st.get('max_slots')}")
-        attn = (st.get("paged") or {}).get("decode_attn")
+        pg = st.get("paged") or {}
+        attn = pg.get("decode_attn")
         if attn:
             # the decode program's attention: layers that read K/V in
             # place through the block table, and what they read of what
@@ -436,8 +437,16 @@ def render(events: list[dict], phases: bool = False) -> str:
                           if latent else "")
                        + f"{attn['paths']['gather']} gathered; blocks read "
                        f"{read} of {held} in the tables ({share})")
-        for prog, text in ((st.get("paged") or {})
-                           .get("grouped_product") or {}).items():
+        if pg.get("indexed_total"):
+            # the prefix index: blocks registered over the run and the
+            # tokens read out of the slots' streams to hash them; 1.0 a
+            # token where each block is read once, as it fills
+            indexed = pg["indexed_total"] * st["kv_block_size"]
+            out.append(f"  prefix index: {pg['indexed_total']} blocks "
+                       f"registered, {pg['tokens_read']} tokens read to "
+                       f"hash them ({pg['tokens_read'] / indexed:.2f} a "
+                       f"token indexed)")
+        for prog, text in (pg.get("grouped_product") or {}).items():
             out.append(f"  grouped expert products, {prog}: {text}")
         if lat.get("measured_requests"):
             out.append(f"  ttft  p50 {1e3 * lat['ttft_p50_s']:8.2f}ms   "

@@ -33,7 +33,9 @@ from distributed_deep_learning_tpu.serve.load import (LoadSpec, make_load,
 from distributed_deep_learning_tpu.serve.migrate import BlockMigrator
 from distributed_deep_learning_tpu.serve.paged import (TRASH, BlockManager,
                                                        chain_hash)
-from distributed_deep_learning_tpu.serve.prefill import (plan_chunks,
+from distributed_deep_learning_tpu.serve.prefill import (ChunkPlan,
+                                                         chunk_tokens,
+                                                         plan_chunks,
                                                          write_targets)
 from distributed_deep_learning_tpu.serve.scheduler import Request
 from distributed_deep_learning_tpu.serve.spec import (greedy_accept,
@@ -171,6 +173,11 @@ def test_obs_report_prints_the_decode_attention_line():
     assert ("decode attention: 2 layers through the block table, 0 "
             f"gathered; blocks read {attn['blocks_read']} of "
             f"{attn['blocks_in_tables']} in the tables") in text
+    pg = stats["paged"]
+    assert pg["indexed_total"] > 0
+    assert (f"prefix index: {pg['indexed_total']} blocks registered, "
+            f"{pg['indexed_total'] * 8} tokens read to hash them (1.00 a "
+            "token indexed)") in text
 
 
 def test_decode_view_keeps_pool_leaves_and_gathers_rings():
@@ -512,6 +519,176 @@ def test_block_manager_refcounts_and_eviction():
     mgr.release(0)
     sp4 = mgr.match_prefix([50, 51, 52, 53, 54])
     assert mgr.can_admit(sp4, 20) is False or mgr.in_use <= 8
+
+
+def _register_whole_stream(mgr, slot, tokens, committed):
+    """`BlockManager.register_committed` as it stood before it read only
+    the blocks that have just filled: the whole stream becomes an array at
+    every call.  The reference the incremental one is held to."""
+    bs = mgr.block_size
+    done, h = mgr._chain[slot]
+    toks = np.asarray(tokens)
+    while (done + 1) * bs <= committed:
+        blk = tuple(int(t) for t in toks[done * bs:(done + 1) * bs])
+        parent, h = h, chain_hash(h, blk)
+        b = int(mgr.tables[slot, done])
+        if b != TRASH and mgr.index.add(parent, h, b, blk):
+            mgr.refs[b] += 1
+        done += 1
+    mgr._chain[slot] = (done, h)
+
+
+def _commits(mode, prompt_len, total, chunk, rng):
+    """The values `committed` takes while one slot is served: the prompt
+    a chunk at a time (the last chunk ends at the prompt's end), then the
+    answer a token a tick, or whole in steps of a chunk, or as the
+    speculative path commits it, 1 to 4 tokens a round."""
+    out = [p.commit_to for p in plan_chunks(0, prompt_len, chunk)]
+    c = prompt_len
+    while c < total:
+        c = min(total, c + {"token": 1, "chunk": chunk,
+                            "spec": int(rng.integers(1, 5))}[mode])
+        out.append(c)
+    return out
+
+
+@pytest.mark.parametrize("mode", ["token", "chunk", "spec"])
+@pytest.mark.parametrize("kind", ["list", "array"])
+@pytest.mark.parametrize("bs", [4, 16])
+def test_register_committed_builds_the_whole_stream_references_index(
+        bs, kind, mode):
+    """Three slots, two of them behind one shared prefix (so a block is
+    hashed that the index already holds), fed the same commits through
+    `register_committed` and through the whole-stream reference: the
+    index entry for entry, the chains and the reference counts are
+    equal, and each indexed block's tokens were read once."""
+    rng = np.random.default_rng(bs * 7 + len(kind) + len(mode))
+    shared = [int(t) for t in rng.integers(1, 1000, 3 * bs + 1)]
+    streams = [shared + [int(t) for t in rng.integers(1, 1000, n)]
+               for n in (5 * bs + 3, 2 * bs + 1)]
+    streams.append([int(t) for t in rng.integers(1, 1000, 4 * bs + 2)])
+    prompt_lens = [3 * bs + 2, 4 * bs, 2 * bs + 3]
+
+    def give(tokens):      # the stream as the caller holds it so far
+        return list(tokens) if kind == "list" else np.asarray(tokens)
+
+    mgrs = [BlockManager(num_blocks=64, block_size=bs, max_slots=3,
+                         blocks_per_slot=12) for _ in range(2)]
+    registers = [BlockManager.register_committed, _register_whole_stream]
+    for mgr, register in zip(mgrs, registers):
+        for slot, (stream, L) in enumerate(zip(streams, prompt_lens)):
+            sp = mgr.match_prefix(give(stream[:L]))
+            mgr.admit(slot, sp, len(stream))
+            for c in _commits(mode, L, len(stream), 2 * bs,
+                              np.random.default_rng(slot)):
+                # the host knows one token past what is committed: the
+                # pending one (engine.py appends before it registers)
+                register(mgr, slot, give(stream[:c + 1]), c)
+    got, want = mgrs
+    assert len(want.index) >= 10           # the reference indexed them
+    assert list(got.index.entries) == list(want.index.entries)
+    for h, e in want.index.entries.items():
+        g = got.index.entries[h]
+        assert (g.block, g.tokens, g.parent) == (e.block, e.tokens, e.parent)
+        assert all(type(t) is int for t in g.tokens)
+    assert got.index.children == want.index.children
+    assert got.index.by_block == want.index.by_block
+    assert got._chain == want._chain
+    np.testing.assert_array_equal(got.refs, want.refs)
+    np.testing.assert_array_equal(got.tables, want.tables)
+    stats = got.stats()
+    assert stats["indexed_total"] == len(want.index)
+    hashed = sum(done for done, _ in got._chain.values())
+    # slot 1 came in behind slot 0's indexed prefix: those blocks were
+    # matched at admission, never hashed again
+    assert stats["tokens_read"] == (hashed - 3) * bs
+
+
+class _CountingStream(list):
+    """A slot's stream that counts what is read out of it."""
+
+    def __init__(self, tokens):
+        super().__init__(tokens)
+        self.reads = []
+
+    def __getitem__(self, key):
+        self.reads.append(key)
+        return super().__getitem__(key)
+
+    def __iter__(self):
+        self.reads.append("iter")
+        return super().__iter__()
+
+
+def test_register_committed_reads_only_the_block_that_filled():
+    bs, L = 16, 20_000
+    mgr = BlockManager(num_blocks=1300, block_size=bs, max_slots=1,
+                       blocks_per_slot=1260)
+    stream = _CountingStream(int(t) for t in
+                             np.random.default_rng(3).integers(1, 5000, L))
+    mgr.admit(0, mgr.match_prefix(list(stream)), L + 64)
+    assert mgr.register_committed(0, stream, L) == L // bs
+    assert mgr.stats()["tokens_read"] == L
+    stream.reads.clear()
+    for c in range(L + 1, L + bs):         # 15 tokens: no block fills
+        stream.append(c)
+        assert mgr.register_committed(0, stream, c) == 0
+    assert stream.reads == []
+    assert mgr.stats()["tokens_read"] == L
+    stream.append(7)
+    assert mgr.register_committed(0, stream, L + bs) == 1
+    assert stream.reads == [slice(L, L + bs)]
+    assert mgr.stats()["tokens_read"] == L + bs
+    assert mgr.stats()["indexed_total"] == L // bs + 1
+
+
+@pytest.mark.parametrize("kind", ["list", "array"])
+@pytest.mark.parametrize("length,plan", [
+    (19, ChunkPlan(8, 16, -1)),            # a whole chunk mid-prompt
+    (19, ChunkPlan(11, 19, 7)),            # the last chunk, slid back
+    (5, ChunkPlan(0, 5, 4)),               # shorter than a chunk: padded
+])
+def test_chunk_tokens_converts_the_slice_alone_to_int32(kind, length, plan):
+    tokens = [int(t) for t in
+              np.random.default_rng(length).integers(1, 50_000, length)]
+    stream = _CountingStream(tokens) if kind == "list" \
+        else np.asarray(tokens)
+    got = chunk_tokens(stream, plan, 8, 61)
+    # the slice as it was taken before: of the whole stream as an array
+    old = np.asarray(tokens)[plan.feed_start:plan.feed_start + 8]
+    old = np.concatenate([old, np.full(8 - len(old), 61, old.dtype)])
+    assert got.dtype == np.int32 and got.shape == (8,)
+    np.testing.assert_array_equal(got, old)
+    if kind == "list":
+        assert stream.reads == [slice(plan.feed_start, plan.feed_start + 8)]
+
+
+def test_no_convert_program_runs_ahead_of_a_chunk():
+    """The chunk's tokens reach the device as the int32 the chunk program
+    is traced for: an int64 host array made `jnp.asarray(toks, jnp.int32)`
+    a device program of its own ahead of every chunk.  The compile log
+    names every program the run compiled; the chunk width is one no other
+    test of this process uses, so a convert program for it could not have
+    been compiled before.  Served tokens are `generate()`'s."""
+    from distributed_deep_learning_tpu import obs
+    from distributed_deep_learning_tpu.runtime.bootstrap import (
+        enable_compile_cache)
+
+    enable_compile_cache()                 # installs the log's listeners
+    eng = _engine(prefill_chunk=13)
+    reqs = _trace(seed=4, n=5, max_new=(2, 10), plens=(3, 30))
+    obs.compile_log.mark("test")
+    out = eng.run(reqs)
+    assert not out["errors"]
+    compiled = [fun for event, fun, *_ in obs.compile_log.since_mark()
+                if event == "compile"]
+    assert "jit(paged_chunk)" in compiled and "jit(paged_decode)" in compiled
+    assert not [f for f in compiled if "convert_element_type" in f], compiled
+    _check_parity(out, reqs, label="int32 chunk")
+    s = out["stats"]
+    assert s["chunk_compiles"] == 1 and s["decode_compiles"] == 1, s
+    assert s["paged"]["indexed_total"] > 0
+    assert s["paged"]["tokens_read"] == s["paged"]["indexed_total"] * 8
 
 
 def test_plan_chunks_tail_shift_single_width():
