@@ -610,13 +610,14 @@ class DisaggEngine:
                         wb[i] = mgr.tables[i, c // bs]
                         wo[i] = c % bs
                     t0 = time.perf_counter()
-                    dw.eng.pools, out, lp_h, ok_h, _ = dw.eng._decode(
+                    up, down = dw.eng._decode_io
+                    dw.eng.pools, got = dw.eng._decode(
                         dw.eng.params, dw.eng.pools,
-                        jnp.asarray(mgr.tables), jnp.asarray(pos),
-                        jnp.asarray(toks), jnp.asarray(wb),
-                        jnp.asarray(wo), self._next_key())
-                    out = np.asarray(out)       # host fetch = barrier
-                    lp_h, ok_h = np.asarray(lp_h), np.asarray(ok_h)
+                        dw.eng.put(up, tables=mgr.tables, pos=pos,
+                                   toks=toks, wb=wb, wo=wo),
+                        self._next_key())
+                    got = dw.eng.fetch(down, got)   # the one fetch = barrier
+                    out, lp_h, ok_h = got["toks"], got["lp"], got["ok"]
                     now = time.perf_counter()
                     t_decode += now - t0
                     h_tick.observe(now - t0)

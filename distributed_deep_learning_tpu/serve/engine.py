@@ -29,6 +29,7 @@ of mixed lengths and staggered arrivals.
 from __future__ import annotations
 
 import dataclasses
+import math
 import os
 import time
 from typing import Callable, Iterable, Optional, Sequence
@@ -82,15 +83,17 @@ class CountingJit:
     (``obs.compile_log.gather``: the grouped expert products' path and
     tiles) becomes its notes in the compile log, and `said`, the owner's
     ``program name -> {event: text}`` of each program's newest trace (the
-    owner's, because a caller may wrap the program object).
+    owner's, because a caller may wrap the program object).  `calls` is the
+    owner's too: every call, traced or not, adds one to its ``"programs"``.
     """
 
     def __init__(self, fn, name: Optional[str] = None,
                  span: Optional[str] = None, said: Optional[dict] = None,
-                 **jit_kwargs):
+                 calls: Optional[dict] = None, **jit_kwargs):
         self.traces = 0
         self.name = name or fn.__name__.strip("_<>")
         self._span = span
+        self._calls = calls
 
         def counted(*args):
             self.traces += 1   # runs at trace time only
@@ -104,6 +107,8 @@ class CountingJit:
         self._jit = jax.jit(counted, **jit_kwargs)
 
     def __call__(self, *args):
+        if self._calls is not None:
+            self._calls["programs"] += 1
         if self._span is None:
             return self._jit(*args)
         with obs_trace.span(self._span, program=self.name):
@@ -704,12 +709,19 @@ class _SpillRecord:
 #:   before nor past the prompt).
 #: * a model with expert layers also: ``experts``, :func:`expert_counters`
 #:   of THIS program's load, counted on the device; a chunk's rows are all
-#:   ``prefill_chunk`` of them, padding included.  A chunk's load is
-#:   fetched where the host next waits for a LATER program, before that
-#:   barrier (the tick's decode program, or the next chunk), so its
-#:   ``experts`` appears one program later and the chunk a raising
-#:   dispatch cut off may lack it.  A decode program's is the same dict as
-#:   the tick's ``counters["experts"]``, fetched with the tokens.
+#:   ``prefill_chunk`` of them, padding included.  A request's last chunk
+#:   and a decode program hand it down with their tokens, in the one fetch
+#:   that is their barrier (a decode program's is the same dict as the
+#:   tick's ``counters["experts"]``).  Any other chunk's result is fetched
+#:   where the host next waits for a LATER program, before that barrier
+#:   (the tick's decode program, or the next chunk), so its ``experts``
+#:   appears one program later and the chunk a raising dispatch cut off
+#:   may lack it.
+#: * ``io``: ``[puts, fetches]``, the host arrays put on the device for
+#:   this program and the fetches made of its result, as the two helpers
+#:   (:meth:`PagedEngine.put`, :meth:`PagedEngine.fetch`) counted them:
+#:   ``[1, 1]``, and ``[1, 0]`` for a chunk nothing is read of (not a
+#:   request's last, no expert layer) or not read yet.
 #:
 #: The draft, verify and canary dispatches (and a chunk's draft twin)
 #: leave no record: a run that uses them cannot be joined with a trace by
@@ -720,10 +732,59 @@ TICK_PHASES = ("admit", "chunk_prepare", "chunk_dispatch", "chunk_commit",
 DISPATCH_PHASES = ("chunk_dispatch", "decode_dispatch")
 
 
-def _on_device(x):
-    """Host arrays, or a tuple of them (a ``(full, ring)`` pair among
-    them), as device arrays."""
-    return jax.tree.map(jnp.asarray, x)
+class Packed:
+    """Several small values as ONE flat int32 array: how a serving
+    program's host-made integers go up in one put, and how what the host
+    reads of its results comes down in one fetch.
+
+    ``like`` names each value with its ``jax.ShapeDtypeStruct``, a
+    ``(full, ring)`` pair of them for a model with rings, or None for what
+    a model does not have (no expert layer: no load); the offsets are
+    fixed here, in the order of the names.  int32 travels as it is, a
+    float32 as its bits (`split` views them back: the same bits), a flag
+    as 0 / 1.  `pack` is numpy alone: a ``jnp`` operation on the host
+    would run a device program between two serving programs.  `split`
+    takes the array apart again by static slices, the host's array or a
+    program's traced one alike, into the structure of ``like``; `join` is
+    `pack` inside a program."""
+
+    def __init__(self, **like):
+        self.like, self.tree = jax.tree.flatten(like)
+        self.ends = np.cumsum([math.prod(x.shape)
+                               for x in self.like]).tolist()
+        self.size = self.ends[-1]
+
+    def pack(self, **values) -> np.ndarray:
+        flat = np.empty(self.size, np.int32)
+        lo = 0
+        for x, hi in zip(jax.tree.leaves(values), self.ends, strict=True):
+            flat[lo:hi] = np.ravel(x)
+            lo = hi
+        return flat
+
+    def join(self, **values):
+        parts = []
+        for x, want in zip(jax.tree.leaves(values), self.like, strict=True):
+            if x.shape != want.shape:
+                raise ValueError(f"a packed value has the shape {x.shape}, "
+                                 f"its layout says {want}")
+            if want.dtype == jnp.float32:
+                x = jax.lax.bitcast_convert_type(x.astype(jnp.float32),
+                                                 jnp.int32)
+            parts.append(x.astype(jnp.int32).reshape(-1))
+        return jnp.concatenate(parts)
+
+    def split(self, flat) -> dict:
+        parts, lo = [], 0
+        for want, hi in zip(self.like, self.ends):
+            x = flat[lo:hi].reshape(want.shape)
+            if want.dtype == jnp.float32:
+                x = x.view(np.float32)
+            elif want.dtype == jnp.bool_:
+                x = x != 0
+            parts.append(x)
+            lo = hi
+        return self.tree.unflatten(parts)
 
 
 def expert_counters(load: np.ndarray) -> dict:
@@ -900,12 +961,48 @@ class PagedEngine:
         #: what the chunk and decode programs said of themselves as they
         #: were traced, ``program -> {event: text}`` (``grouped_product``)
         self.program_notes: dict = {}
-        self._chunk_prog = CountingJit(self._chunk_impl, "paged_chunk",
+        #: what crossed between host and device around the chunk and the
+        #: decode program over the newest run: calls of the two program
+        #: objects, host arrays put (:meth:`put`) and fetches made
+        #: (:meth:`fetch`); one put and at most one fetch a program
+        self.host_io = {"programs": 0, "puts": 0, "fetches": 0}
+        # the two programs' host interface, `(up, down)` each: the layout
+        # of the one int32 array the host puts and of the one it fetches,
+        # from the shapes the engine already knows
+        S, C, bps, ring = (self.max_slots, self.chunk, self.blocks_per_slot,
+                           self.ring_blocks)
+
+        def like(dtype, *shape):
+            return jax.ShapeDtypeStruct(shape, dtype)
+
+        def i32(*shape):
+            return like(jnp.int32, *shape)
+
+        def kinds(full, rings):
+            """A table or its write targets: `full` alone, or the pair
+            the programs' bodies take for a model with rings."""
+            return full if ring is None else (full, rings)
+
+        held = [sp.experts.num_experts for sp in self.lm.layer_specs()
+                if sp.experts]
+        self._load_like = i32(len(held), held[0]) if held else None
+        self._chunk_io = (
+            Packed(toks=i32(C), table=kinds(i32(bps), i32(ring or 0)),
+                   pos=i32(), logit_idx=i32(), wb=kinds(i32(C), i32(C)),
+                   wo=i32(C)),
+            Packed(tok=i32(), lp=like(jnp.float32), ok=like(jnp.bool_),
+                   load=self._load_like))
+        self._decode_io = (
+            Packed(tables=kinds(i32(S, bps), i32(S, ring or 0)), pos=i32(S),
+                   toks=i32(S), wb=kinds(i32(S), i32(S)), wo=i32(S)),
+            Packed(toks=i32(S), lp=like(jnp.float32, S),
+                   ok=like(jnp.bool_, S), load=self._load_like))
+        self._chunk_prog = CountingJit(self._chunk_packed, "paged_chunk",
                                        "chunk_dispatch", self.program_notes,
-                                       **dk)
-        self._decode = CountingJit(self._decode_impl, "paged_decode",
+                                       self.host_io, **dk)
+        self._decode = CountingJit(self._decode_packed, "paged_decode",
                                    "decode_dispatch", self.program_notes,
-                                   **dk)
+                                   self.host_io, **dk)
         self._copy = CountingJit(self._copy_impl, "paged_copy", **ck)
         if spill_dir is not None and not preempt:
             raise ValueError("spill_dir requires preempt=True (it is the "
@@ -1067,6 +1164,18 @@ class PagedEngine:
             tok, lp, ok = self._sample(params, h_last, key)
         return pools, tok[0], lp[0], ok[0], load
 
+    def _chunk_packed(self, params, pools, up, key):
+        """`paged_chunk` as the host calls it: :meth:`_chunk_impl` of the
+        values in the one array the host put (``_chunk_io``'s first
+        layout), its token, logprob, flag and load as the one array the
+        host fetches (the second)."""
+        v = self._chunk_io[0].split(up)
+        pools, tok, lp, ok, load = self._chunk_impl(
+            params, pools, v["toks"], v["table"], v["pos"], v["logit_idx"],
+            v["wb"], v["wo"], key)
+        return pools, self._chunk_io[1].join(tok=tok, lp=lp, ok=ok,
+                                             load=load)
+
     def _draft_chunk_impl(self, dparams, dpools, tokens, table, pos,
                           wb, wo):
         """The draft model's KV for the same chunk — speculation needs
@@ -1113,6 +1222,16 @@ class PagedEngine:
         with jax.named_scope("sample"):
             toks, lp, ok = self._sample(params, h, key)
         return pools, toks, lp, ok, load
+
+    def _decode_packed(self, params, pools, up, key):
+        """`paged_decode` as the host calls it: :meth:`_decode_impl`
+        between ``_decode_io``'s two layouts, as :meth:`_chunk_packed`."""
+        v = self._decode_io[0].split(up)
+        pools, toks, lp, ok, load = self._decode_impl(
+            params, pools, v["tables"], v["pos"], v["toks"], v["wb"],
+            v["wo"], key)
+        return pools, self._decode_io[1].join(toks=toks, lp=lp, ok=ok,
+                                              load=load)
 
     def _draft_impl(self, dparams, dpools, tables, positions, toks,
                     wb, wo):
@@ -1188,6 +1307,24 @@ class PagedEngine:
         return paged.scatter_span(pools, kv, blocks, offsets)
 
     # --- host side --------------------------------------------------------
+    def put(self, layout: Packed, io=None, **values):
+        """A program's host-made values on the device: packed into one
+        array by `layout` and put with ONE call.  Counted in ``host_io``
+        and in `io`, the program's own ``[puts, fetches]``."""
+        self.host_io["puts"] += 1
+        if io is not None:
+            io[0] += 1
+        return jnp.asarray(layout.pack(**values))
+
+    def fetch(self, layout: Packed, down, io=None) -> dict:
+        """What the host reads of a program's result, `down`, taken apart
+        by `layout`: ONE fetch, which is the barrier where the program
+        still runs.  Counted as :meth:`put` counts."""
+        self.host_io["fetches"] += 1
+        if io is not None:
+            io[1] += 1
+        return layout.split(np.asarray(down))
+
     def _cow(self, src: int, dst: int) -> None:
         """Device half of copy-on-write: duplicate the physical block in
         the target pools (and the draft pools, whose tables are shared,
@@ -1352,23 +1489,20 @@ class PagedEngine:
                     wb_old[i] = paged.TRASH
                 else:
                     wb_new[i] = paged.TRASH
-            tables_dev = jnp.asarray(mgr.tables)
-            pos_dev, toks_dev = jnp.asarray(pos), jnp.asarray(toks)
-            wo_dev = jnp.asarray(wo)
-            wb_old, wb_new = jnp.asarray(wb_old), jnp.asarray(wb_new)
+            up, down = self._decode_io
+            up_old, up_new = (
+                self.put(up, tables=mgr.tables, pos=pos, toks=toks, wb=w,
+                         wo=wo) for w in (wb_old, wb_new))
             key = self._next_key()
         with pc.phase("decode_dispatch"):
-            self.pools, out_o, lp_o, ok_o, _ = self._decode(
-                self.params, self.pools, tables_dev, pos_dev, toks_dev,
-                wb_old, wo_dev, key)
-            self.pools, out_n, lp_n, ok_n, _ = self._decode(
-                can.params, self.pools, tables_dev, pos_dev, toks_dev,
-                wb_new, wo_dev, key)
+            self.pools, old = self._decode(self.params, self.pools, up_old,
+                                           key)
+            self.pools, new = self._decode(can.params, self.pools, up_new,
+                                           key)
         with pc.phase("decode_wait"):
-            out_o, lp_o, ok_o = (np.asarray(x)
-                                 for x in (out_o, lp_o, ok_o))
-            out_n, lp_n, ok_n = (np.asarray(x)
-                                 for x in (out_n, lp_n, ok_n))
+            old, new = self.fetch(down, old), self.fetch(down, new)
+            out_o, lp_o, ok_o = old["toks"], old["lp"], old["ok"]
+            out_n, lp_n, ok_n = new["toks"], new["lp"], new["ok"]
         now = time.perf_counter()
         out, lp, ok = out_o.copy(), lp_o.copy(), ok_o.copy()
         for i in can.slots:
@@ -1460,7 +1594,9 @@ class PagedEngine:
         timeline = [] if keep_timeline else None
 
         programs: list = []     # the tick's program records, see the loop
-        unsettled: list = []    # (chunk record, its load still on the device)
+        unsettled: list = []    # (chunk record, its result still on the device)
+        for k in self.host_io:
+            self.host_io[k] = 0
         shared_tokens = prompt_tokens = 0
         chunk_calls = spec_rounds = proposed_total = accepted_total = 0
         decode_ticks = occupancy_sum = 0
@@ -1555,14 +1691,15 @@ class PagedEngine:
             self._make_writable(idx, lo, hi, whose)
 
         def settle():
-            """Fetch the expert loads of the chunks that have run.  Called
-            where the host is about to wait for a LATER program anyway,
-            before that barrier: the device is busy, the loads are done,
-            so the fetch is on nobody's critical path (fetched after the
-            chunk's own barrier it cost the glm cell 1.9% of its tokens/s,
-            my chip runs, PR 37)."""
-            for rec, load in unsettled:
-                rec["experts"] = expert_counters(np.asarray(load))
+            """Fetch the expert loads of the chunks that have run and were
+            not a request's last.  Called where the host is about to wait
+            for a LATER program anyway, before that barrier: the device is
+            busy, the loads are done, so the fetch is on nobody's critical
+            path (fetched after the chunk's own barrier it cost the glm
+            cell 1.9% of its tokens/s, my chip runs, PR 37)."""
+            for rec, down in unsettled:
+                rec["experts"] = expert_counters(
+                    self.fetch(self._chunk_io[1], down, rec["io"])["load"])
             unsettled.clear()
 
         def run_chunk(idx, ev):
@@ -1579,30 +1716,33 @@ class PagedEngine:
                                                 mgr.tables[idx], bs)
                 ring_wb = mgr.ring_targets(
                     idx, plan.feed_start + np.arange(self.chunk), written)
-                table_dev = _on_device(mgr.device_tables(idx))
-                toks_dev = jnp.asarray(toks, jnp.int32)
-                wb_dev = _on_device(wb if ring_wb is None
-                                    else (wb, ring_wb))
-                wo_dev = jnp.asarray(wo)
-                pos = np.int32(plan.feed_start)
+                io = [0, 0]
+                up = self.put(
+                    self._chunk_io[0], io, toks=toks,
+                    table=mgr.device_tables(idx), pos=plan.feed_start,
+                    logit_idx=max(plan.logit_index, 0),
+                    wb=wb if ring_wb is None else (wb, ring_wb), wo=wo)
+                if self.draft_layers is not None:
+                    # the draft's twin keeps its separate arguments (and a
+                    # model with rings has no draft)
+                    draft_args = tuple(map(jnp.asarray, (
+                        toks, mgr.tables[idx], np.int32(plan.feed_start),
+                        wb, wo)))
                 n_live = (min(plan.feed_start + self.chunk, L)
                           - max(plan.feed_start, committed[idx]))
             t0 = time.perf_counter()
             with p_chunk_dispatch:
-                self.pools, tok, c_lp, c_ok, load = self._chunk_prog(
-                    self.params, self.pools, toks_dev, table_dev, pos,
-                    np.int32(max(plan.logit_index, 0)), wb_dev, wo_dev,
-                    self._next_key())
+                self.pools, down = self._chunk_prog(
+                    self.params, self.pools, up, self._next_key())
                 if self.draft_layers is not None:
                     self.draft_pools = self._draft_chunk(
-                        self.draft_params, self.draft_pools, toks_dev,
-                        table_dev, pos, wb_dev, wo_dev)
+                        self.draft_params, self.draft_pools, *draft_args)
             # the dispatch has returned: the program ran (or will), so it
             # gets its record; the instants are the phases' own readings
             rec = {"program": "paged_chunk",
                    "at": [p_chunk_dispatch.t0, p_chunk_dispatch.t1, None],
                    "slot": idx, "uid": req.uid, "start": plan.feed_start,
-                   "live": n_live}
+                   "live": n_live, "io": io}
             programs.append(rec)
             with p_chunk_commit:    # while the device runs the chunk
                 committed[idx] = plan.commit_to
@@ -1614,11 +1754,16 @@ class PagedEngine:
             with p_chunk_wait:
                 settle()                # earlier chunks', behind this one
                 if plan.is_last:
-                    first = int(tok)       # host fetch = device barrier
+                    # the one fetch is the barrier: the first token, its
+                    # logprob, its flag and the chunk's load together
+                    got = self.fetch(self._chunk_io[1], down, io)
+                    first = int(got["tok"])
+                    if got["load"] is not None:
+                        rec["experts"] = expert_counters(got["load"])
                 else:
                     jax.block_until_ready(self.pools)
-            if load is not None:
-                unsettled.append((rec, load))
+                    if self._load_like is not None:
+                        unsettled.append((rec, down))
             rec["at"][2] = p_chunk_wait.t1
             now = time.perf_counter()
             t_prefill += now - t0
@@ -1640,8 +1785,8 @@ class PagedEngine:
                     on_tick(TickReport(
                         tick=tick, kind="prefill", elapsed_s=now - t0,
                         emitted=[(req.uid, first)],
-                        finite={req.uid: bool(c_ok)},
-                        logprob={req.uid: float(c_lp)},
+                        finite={req.uid: bool(got["ok"])},
+                        logprob={req.uid: float(got["lp"])},
                         slots=[idx], engine=self,
                         queue_depth=sched.queue_depth(tick)))
             with p_chunk_commit:
@@ -1965,28 +2110,34 @@ class PagedEngine:
                                 ring_wb[i] = mgr.ring_targets(i, c, True)
                         t0 = time.perf_counter()
                         if self._canary is None:
-                            dev = _on_device((
-                                mgr.device_tables(), pos, toks,
-                                wb if ring_wb is None else (wb, ring_wb),
-                                wo)) + (self._next_key(),)
+                            io = [0, 0]
+                            up = self.put(
+                                self._decode_io[0], io,
+                                tables=mgr.device_tables(), pos=pos,
+                                toks=toks, wo=wo,
+                                wb=wb if ring_wb is None else (wb, ring_wb))
+                            key = self._next_key()
                     if self._canary is not None:
                         out, lp_h, ok_h = self._canary_decode(
                             mgr, pos, toks, wb, wo, dec, pc)
                     else:
                         with p_decode_dispatch:
-                            self.pools, out, lp_h, ok_h, load = \
-                                self._decode(self.params, self.pools, *dev)
+                            self.pools, down = self._decode(
+                                self.params, self.pools, up, key)
                         rec = {"program": "paged_decode",
                                "at": [p_decode_dispatch.t0,
-                                      p_decode_dispatch.t1, None]}
+                                      p_decode_dispatch.t1, None],
+                               "io": io}
                         programs.append(rec)
                         with p_decode_wait:
                             settle()    # the tick's chunks', behind this one
-                            out = np.asarray(out)   # host fetch = barrier
-                            lp_h, ok_h = np.asarray(lp_h), np.asarray(ok_h)
-                            if load is not None:    # came with the tokens
+                            # the one fetch is the barrier
+                            got = self.fetch(self._decode_io[1], down, io)
+                            out, lp_h, ok_h = (got["toks"], got["lp"],
+                                               got["ok"])
+                            if got["load"] is not None:
                                 rec["experts"] = counters["experts"] = \
-                                    expert_counters(np.asarray(load))
+                                    expert_counters(got["load"])
                         rec["at"][2] = p_decode_wait.t1
                     now = time.perf_counter()
                     t_decode += now - t0
@@ -2186,6 +2337,7 @@ class PagedEngine:
                 "shared_tokens": shared_tokens,
                 "prompt_tokens": prompt_tokens,
                 "prefill_tokens_computed": chunk_calls * self.chunk,
+                "host_io": dict(self.host_io),
                 "decode_attn": {
                     "paths": dict(self.decode_attn_paths),
                     "blocks_read": attn_read,

@@ -287,6 +287,17 @@ def render_xplane(trace: dict, depth: int = 3, top: int = 24,
     return "\n".join(out)
 
 
+def _host_io_line(programs: int, puts: int, fetches: int) -> str:
+    """What crossed between host and device around the chunk and the
+    decode program: one put and at most one fetch a program where each
+    takes one packed array and hands one back."""
+    def per(n: int) -> str:
+        return f"{n / programs:.2f}" if programs else "n/a"
+
+    return (f"host io: {programs} programs, {puts} puts ({per(puts)} a "
+            f"program), {fetches} fetches ({per(fetches)} a program)")
+
+
 def render_programs(record) -> str:
     """What a serving run recorded of its own programs
     (``obs.last_run("serve")`` / ``obs.runs("serve")``; the tick ring's
@@ -317,6 +328,11 @@ def render_programs(record) -> str:
         lines.append(f"  turnaround {short[0]}->{short[1]}: {len(ms)} "
                      f"pairs, median {statistics.median(ms):.3f} ms, "
                      f"longest {max(ms):.3f}")
+    ios = [p["io"] for p in progs if "io" in p]
+    if ios:
+        lines.append("  " + _host_io_line(len(ios), sum(i[0] for i in ios),
+                                          sum(i[1] for i in ios))
+                     + ", by the records")
     touched = [p["experts"]["touched"] / p["experts"]["held"]
                for p in progs if p["program"] == "paged_chunk"
                and p.get("experts", {}).get("held")]
@@ -446,6 +462,8 @@ def render(events: list[dict], phases: bool = False) -> str:
                        f"registered, {pg['tokens_read']} tokens read to "
                        f"hash them ({pg['tokens_read'] / indexed:.2f} a "
                        f"token indexed)")
+        if pg.get("host_io"):
+            out.append("  " + _host_io_line(**pg["host_io"]))
         for prog, text in (pg.get("grouped_product") or {}).items():
             out.append(f"  grouped expert products, {prog}: {text}")
         if lat.get("measured_requests"):

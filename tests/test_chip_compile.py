@@ -180,15 +180,11 @@ def xl_engine(chip):
     engine = PagedEngine(model, params, **CELL)
     head = (params, jax.tree.map(on_chip, engine.pools))
     key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
-    slots, chunk, bps = (engine.max_slots, engine.chunk,
-                         engine.blocks_per_slot)
     return engine, {
         "paged_chunk": (engine._chunk_prog, head + (
-            i32(chunk), i32(bps), i32(), i32(), i32(chunk), i32(chunk),
-            key)),
+            i32(engine._chunk_io[0].size), key)),
         "paged_decode": (engine._decode, head + (
-            i32(slots, bps), i32(slots), i32(slots), i32(slots),
-            i32(slots), key)),
+            i32(engine._decode_io[0].size), key)),
         "paged_copy": (engine._copy, (head[1], i32(), i32())),
     }
 
@@ -297,15 +293,11 @@ def laguna_engine(chip):
     engine = PagedEngine(model, params, **LAGUNA_CELL)
     head = (params, jax.tree.map(on_chip, engine.pools))
     key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
-    slots, chunk = engine.max_slots, engine.chunk
-    bps, ring = engine.blocks_per_slot, engine.ring_blocks
     return engine, {
         "paged_chunk": (engine._chunk_prog, head + (
-            i32(chunk), (i32(bps), i32(ring)), i32(), i32(),
-            (i32(chunk), i32(chunk)), i32(chunk), key)),
+            i32(engine._chunk_io[0].size), key)),
         "paged_decode": (engine._decode, head + (
-            (i32(slots, bps), i32(slots, ring)), i32(slots), i32(slots),
-            (i32(slots), i32(slots)), i32(slots), key)),
+            i32(engine._decode_io[0].size), key)),
         "paged_copy": (engine._copy, (head[1], i32(), i32())),
     }
 
@@ -792,15 +784,11 @@ def glm_engine(chip):
     engine = PagedEngine(model, params, **GLM_CELL)
     head = (params, jax.tree.map(on_chip, engine.pools))
     key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
-    slots, chunk, bps = (engine.max_slots, engine.chunk,
-                         engine.blocks_per_slot)
     return engine, {
         "paged_chunk": (engine._chunk_prog, head + (
-            i32(chunk), i32(bps), i32(), i32(), i32(chunk), i32(chunk),
-            key)),
+            i32(engine._chunk_io[0].size), key)),
         "paged_decode": (engine._decode, head + (
-            i32(slots, bps), i32(slots), i32(slots), i32(slots),
-            i32(slots), key)),
+            i32(engine._decode_io[0].size), key)),
         "paged_copy": (engine._copy, (head[1], i32(), i32())),
     }
 
