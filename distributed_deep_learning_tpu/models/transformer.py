@@ -914,11 +914,13 @@ class TransformerSeq2Seq(nn.Module):
 class CausalLM(nn.Module):
     """GPT-style decoder-only LM — the long-context flagship shape.
 
-    ``__call__(tokens)`` returns the final hidden states ``(B, T, d)``;
-    ``loss(params, hidden, targets)`` computes the weight-tied LM loss via
-    :func:`..ops.fused_ce.fused_linear_cross_entropy` (never materialises
-    the ``(B·T, V)`` logit matrix), and ``logits_from(params, hidden)``
-    the explicit projection for eval/tests.  The reference has no autoregressive model
+    ``__call__(tokens)`` returns the final hidden states ``(B, T, d)``
+    (``with_logits=False``), the ``(B, T, V)`` logits (``True``) or, for a
+    training step, both halves of them not yet multiplied
+    (``"deferred"``: :class:`..ops.fused_ce.DeferredLogits`, which the
+    token loss and the prediction metrics take a block of logits at a
+    time); ``logits_from(params, hidden)`` is the explicit projection
+    serving samples through.  The reference has no autoregressive model
     at all (its only sequence model consumes 10-step windows,
     ``LSTM/dataset.py:25``); this is the shape ring attention / Ulysses /
     the SPMD pipeline and the flash kernels are built to scale.
@@ -931,7 +933,9 @@ class CausalLM(nn.Module):
     mlp_dim: int = 3072
     dropout_rate: float = 0.0
     max_len: int = 8192
-    with_logits: bool = False   # True: __call__ returns (B, T, V) logits
+    #: what ``__call__`` returns: False the hidden states, True the
+    #: (B, T, V) logits, "deferred" a DeferredLogits (hidden, table)
+    with_logits: Union[bool, str] = False
     decode: bool = False        # KV-cached autoregressive decode mode
     pos_embedding: str = "learned"   # learned | rope
     attention_window: Optional[int] = None  # causal sliding window
@@ -984,31 +988,21 @@ class CausalLM(nn.Module):
         head = emb.embedding if self.tie_head else self.param(
             "head", nn.initializers.normal(0.02),
             (self.vocab_size, self.d_model))
-        # the CLI/workload convention wants logits (token_cross_entropy +
-        # argmax metrics); the bench path keeps hidden states and the
-        # fused head (loss()) so (B·T, V) never materialises
+        # the CLI's step scores logits (token_cross_entropy + argmax
+        # metrics) and takes them deferred, a block at a time, so (B·T, V)
+        # never rests; serving keeps the hidden states and projects the
+        # positions it samples
+        if self.with_logits == "deferred":
+            from distributed_deep_learning_tpu.ops.fused_ce import (
+                DeferredLogits)
+
+            return DeferredLogits(x, head)
         return Embed.logits(x, head) if self.with_logits else pin_batch(x)
 
     def _table(self, params):
         if not self.tie_head:
             return params["params"]["head"]
         return params["params"]["embed"]["tok"]["embedding"]
-
-    def loss(self, params, hidden, targets):
-        """Mean next-token cross-entropy via the fused head; positions
-        whose target equals ``self.pad_id`` are excluded, and with
-        ``pad_id=None`` every position counts (e.g. imported GPT-2, whose
-        id 0 is a real token).  Pass ``tokens[:, :-1]`` hidden vs
-        ``tokens[:, 1:]``."""
-        from distributed_deep_learning_tpu.ops.fused_ce import (
-            fused_linear_cross_entropy)
-
-        # -1 can never equal a vocab id, so it disables the exclusion
-        ignore_id = self.pad_id if self.pad_id is not None else -1
-        return fused_linear_cross_entropy(
-            hidden.astype(jnp.float32),
-            jnp.asarray(self._table(params), jnp.float32), targets,
-            ignore_id)
 
     def logits_from(self, params, hidden):
         return Embed.logits(hidden, self._table(params))

@@ -18,6 +18,11 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from distributed_deep_learning_tpu.ops.fused_ce import (DeferredLogits,
+                                                        head_cross_entropy,
+                                                        logits_at_rest,
+                                                        note_logits)
+
 
 def cross_entropy_loss(logits: jnp.ndarray, targets: jnp.ndarray,
                        from_probabilities: bool = False) -> jnp.ndarray:
@@ -53,7 +58,17 @@ def token_cross_entropy(logits: jnp.ndarray, targets: jnp.ndarray,
     GPT-2 where id 0 is a real token).
 
     ``label_smoothing`` ε spreads (1−ε) on the target id and ε/V on the
-    rest (the transformer-base recipe, ε = 0.1 in the paper)."""
+    rest (the transformer-base recipe, ε = 0.1 in the paper).
+
+    `logits` not yet taken (:class:`..ops.fused_ce.DeferredLogits`, what
+    the ``gpt`` workload's model hands a step) are scored a block at a
+    time where they are large and a TPU's kernels run, the same loss with
+    no ``(..., T, V)`` array at rest, and taken whole elsewhere
+    (:func:`..ops.fused_ce.logits_at_rest`)."""
+    logits, whole = _taken(logits)
+    if not whole:
+        return head_cross_entropy(logits.hidden, logits.table, targets,
+                                  pad_id, label_smoothing)[0]
     valid = (targets != pad_id if pad_id is not None
              else jnp.ones(targets.shape, bool)).astype(jnp.float32)
     tgt = jnp.maximum(targets, 0)
@@ -67,6 +82,16 @@ def token_cross_entropy(logits: jnp.ndarray, targets: jnp.ndarray,
         per_tok = optax.softmax_cross_entropy_with_integer_labels(logits,
                                                                   tgt)
     return jnp.sum(per_tok * valid) / jnp.maximum(jnp.sum(valid), 1.0)
+
+
+def _taken(pred):
+    """``(pred, whether it holds logits whole)``: arrays as they come, and
+    logits not yet taken multiplied out where the rule says they rest."""
+    if not isinstance(pred, DeferredLogits):
+        note_logits()
+        return pred, True
+    logits = logits_at_rest(pred)
+    return (pred, False) if logits is None else (logits, True)
 
 
 def argmax_correct(pred: jnp.ndarray, targets: jnp.ndarray) -> jnp.ndarray:
@@ -100,7 +125,13 @@ def prediction_metrics(pred: jnp.ndarray, targets: jnp.ndarray,
     """The phase-metric triple every step builder emits: batch loss, argmax
     matches, and prediction-site count (per-sample for (B,C) classifiers —
     the reference's denominator, ``CNN/main.py:90-94`` — per non-pad token
-    for token-level models)."""
-    correct, count = _correct_and_count(pred, targets)
+    for token-level models; logits not yet taken are counted through the
+    same pass over the vocabulary as their loss)."""
+    pred, whole = _taken(pred)
+    if whole:
+        correct, count = _correct_and_count(pred, targets)
+    else:
+        correct = head_cross_entropy(pred.hidden, pred.table, targets)[1]
+        count = jnp.sum(targets != 0).astype(jnp.int32)
     return {"loss": loss, "correct": correct.astype(jnp.int32),
             "count": count}

@@ -23,6 +23,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributed_deep_learning_tpu.data.loader import BATCH_AXES
 from distributed_deep_learning_tpu.obs.runlog import compile_log
+from distributed_deep_learning_tpu.ops.fused_ce import note_text as head_text
 from distributed_deep_learning_tpu.runtime.batch_pin import pins_text
 from distributed_deep_learning_tpu.train.objectives import prediction_metrics
 from distributed_deep_learning_tpu.train.state import TrainState
@@ -46,14 +47,17 @@ def under_mesh(mesh: Mesh):
     kernel must run per shard (``ops.attention_pallas._per_shard``) and
     the activations stay on the batch axes (``runtime.batch_pin``).  What
     either says of the program as it is traced becomes its notes in the
-    compile log: ``batch_pins``, what this trace pinned, and the kernel's
-    ``flash_layout``, how its calls tiled their operands."""
+    compile log: ``batch_pins``, what this trace pinned, the kernel's
+    ``flash_layout``, how its calls tiled their operands, and
+    ``fused_head``, whether the token loss took its logits a block at a
+    time (``logits_at_rest=0``) or was handed them whole."""
     def decorate(step):
         @functools.wraps(step)
         def traced(*args):
             with jax.sharding.use_abstract_mesh(mesh.abstract_mesh), \
                     compile_log.notes_for(f"jit({step.__name__})",
-                                          batch_pins=pins_text):
+                                          batch_pins=pins_text,
+                                          fused_head=head_text):
                 return step(*args)
         return traced
     return decorate

@@ -423,14 +423,14 @@ def _gpt_model(config: Config, dataset):
         return describe.causal_lm(
             describe.read(config.model_file), vocab_size=_vocab(dataset),
             max_len=max(dataset.features.shape[1], 8),
-            dropout_rate=config.dropout, with_logits=True,
+            dropout_rate=config.dropout, with_logits="deferred",
             dtype=config_dtype(config),
             attention_fn=_attention_fn(config))
     d = config.size
     return CausalLM(vocab_size=_vocab(dataset),
                     num_layers=config.num_layers, d_model=d,
                     num_heads=max(2, d // 64), mlp_dim=4 * d,
-                    dropout_rate=config.dropout, with_logits=True,
+                    dropout_rate=config.dropout, with_logits="deferred",
                     max_len=max(dataset.features.shape[1], 8),
                     pos_embedding=config.pos_embedding,
                     attention_window=config.attention_window,

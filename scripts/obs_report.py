@@ -419,6 +419,9 @@ def render(events: list[dict], phases: bool = False) -> str:
         # axes a train step's activations were pinned to and at how many
         # sites (batch_pins), how its flash kernel calls tiled q, k and v
         # (flash_layout: lanes and heads a block, calls that transposed),
+        # whether its token loss took the logits a block at a time
+        # (fused_head: calls, a shard's rows, vocabulary, pallas or logits,
+        # tiles, logits_at_rest),
         # the paths a decode program's attention layers took (attn_paths),
         # a serving program's grouped expert products (grouped_product:
         # calls, rows, experts, pallas or ragged_dot, tiles, fused calls)

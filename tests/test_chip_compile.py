@@ -19,7 +19,9 @@ import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # keep compiler logs out of /tmp
 
+import math
 import re
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -404,8 +406,13 @@ def train_flash_calls(v5e, request):
     note = [text for event, fun, text in obs.compile_log.notes()
             if (event, fun) == ("flash_layout", "jit(train_step)")]
     lowered = traced.lower()
-    # each kernel is lowered once and called a layer
-    assert lowered.as_text().count("tpu_custom_call") == 2
+    # each flash kernel is lowered once and called a layer; in cell 1,
+    # whose logits are taken a block at a time, the head's forward kernel
+    # is asked for twice (the loss, the argmax count: the compiled step
+    # holds it once, ``test_one_chip_step_holds_no_logits``)
+    head = {"head_ce_fwd": 2, "head_ce_bwd": 1} if chips == 1 else {}
+    assert _lowered_kernels(lowered.as_text()) == {
+        "flash_fwd": 1, "flash_bwd": 1, **head}
     calls = []
     for eqn in _eqns(traced.jaxpr.jaxpr, "pallas_call", []):
         assert eqn.params["interpret"] is False
@@ -420,6 +427,15 @@ def train_flash_calls(v5e, request):
     if chips == 1:
         moves += _self_attn_moves(lowered.compile().as_text(), size)
     return rows // chips, heads, calls, note, moves
+
+
+def _lowered_kernels(text: str) -> dict:
+    """Pallas kernels in a lowered module's text, by name: how many
+    ``tpu_custom_call`` sites name each."""
+    import collections
+
+    return dict(collections.Counter(re.findall(
+        r'tpu_custom_call.*?kernel_name = \\?"(\w+)', text)))
 
 
 def _trace_train_step(argv, devices):
@@ -597,15 +613,176 @@ _COLLECTIVE = re.compile(
     re.M)
 
 
+#: cell 1's rows a chip, data parallel over four: no cell of the benchmark,
+#: the one shape at which the head's kernels ran per shard on the chip and
+#: won (``PERF.md`` section 6, PR 45)
+DATA4 = {"gpt2m-train-data4": (
+    "-l 2 -s 1024 -b 64 --dtype bfloat16 -m data --mesh data=4 --lr 0.001 "
+    "--schedule none", 4, 16)}
+
+
 @pytest.fixture(scope="module")
-def fsdp_step_text(v5e):
-    """The two-layer ``gpt2xl-train-fsdp4`` step COMPILED for the four
-    described chips, as text (about half a minute)."""
-    argv, chips, _ = TRAIN_CELLS["gpt2xl-train-fsdp4"]
+def compiled_train_step(v5e):
+    """``cell -> (compiled two-layer step, its notes in the compile log,
+    rows a chip)``, each cell's step COMPILED once a module for the
+    described chips (a quarter to half a minute each)."""
+    from distributed_deep_learning_tpu import obs
+
+    done = {}
+
+    def compiled(cell: str):
+        if cell not in done:
+            argv, chips, _ = {**TRAIN_CELLS, **DATA4}[cell]
+            obs.compile_log.mark("test")
+            with pytest.MonkeyPatch.context() as on_tpu:
+                on_tpu.setattr(jax, "default_backend", lambda: "tpu")
+                rows, traced = _trace_train_step(argv, v5e[:chips])
+            notes = {event: text for event, fun, text
+                     in obs.compile_log.notes() if fun == "jit(train_step)"}
+            done[cell] = traced.lower().compile(), notes, rows // chips
+        return done[cell]
+    return compiled
+
+
+@pytest.fixture(scope="module")
+def fsdp_step_text(compiled_train_step):
+    """The two-layer ``gpt2xl-train-fsdp4`` step compiled for the four
+    described chips, as text."""
+    return compiled_train_step("gpt2xl-train-fsdp4")[0].as_text()
+
+
+# --- the head: the step's logits never rest (ops/fused_ce.py) -------------
+
+_PRODUCED = re.compile(
+    r"^\s*(?:ROOT )?%(?P<name>\S+) = (?P<dtype>\w+)\[(?P<dims>[\d,]+)\]\S* "
+    r"(?P<op>[\w-]+)\((?P<rest>.*)$", re.M)
+VOCAB = 50257
+
+
+def _logit_sized(text: str, positions: int) -> list:
+    """``(dtype, op, name)`` of every instruction of a compiled program
+    whose result is as large as a chip's logits, `positions` x 50,257
+    (views, a fusion of nothing but a view, and a fusion's parameters
+    apart)."""
+    found = []
+    for m in _PRODUCED.finditer(text):
+        dims = [int(d) for d in m["dims"].split(",")]
+        if VOCAB in dims and math.prod(dims) == positions * VOCAB \
+                and m["op"] not in ("parameter", "bitcast",
+                                    "get-tuple-element") \
+                and "calls=%bitcast_fusion" not in m["rest"]:
+            found.append((m["dtype"], m["op"], m["name"]))
+    return found
+
+
+def test_one_chip_step_holds_no_logits(compiled_train_step):
+    """Cell 1 as compiled (16 rows a chip: 3.07 GiB of f32 logits, over
+    ``ops.fused_ce.REST_BYTES``): no f32 (or integer, or predicate) value
+    of ``rows x 1,024 x 50,257`` is a buffer of the step, and the ONE value
+    of that size is the bf16 cotangent the backward kernel writes for the
+    two products that follow; the head's forward kernel, asked for twice
+    (the loss, the argmax count), is in the program once; the step's
+    ``fused_head`` note says so."""
+    compiled, notes, rows = compiled_train_step("gpt2m-train-1chip")
+    text = compiled.as_text()
+    assert _logit_sized(text, rows * T) == [
+        ("bf16", "custom-call", mock.ANY)], _logit_sized(text, rows * T)
+    assert _logit_sized(text, rows * T)[0][2].startswith("head_ce_bwd")
+    for kernel in ("head_ce_fwd", "head_ce_bwd"):
+        assert len(re.findall(rf"%{kernel}[.\d]* = ", text)) == 1, kernel
+    assert notes["fused_head"] == (
+        f"calls=1 rows={rows * T} vocab=50257 path=pallas tiles=512x2048 "
+        "logits_at_rest=0")
+
+
+def test_fsdp_step_takes_its_small_logits_whole(compiled_train_step):
+    """Cell 4 as compiled (2 rows a chip: 0.38 GiB of f32 logits a shard,
+    under ``REST_BYTES``).  ISSUE 45 asked that this step, too, hold no
+    ``f32[..., 50257]`` buffer of ``rows x T`` rows: NOT MET, and left for
+    the next issue.  What ships instead, because on the chip blocks lost
+    2.5% here (``PERF.md`` section 6): the deferred head is multiplied
+    out, as the parent's model did; no head kernel, the f32 logits a
+    buffer, the parent's program (the same instructions by shape and
+    opcode, 0.93 GiB of temporaries: my AOT compiles of both trees, PR
+    45).  This test holds that state, not the issue's criterion."""
+    compiled, notes, rows = compiled_train_step("gpt2xl-train-fsdp4")
+    text = compiled.as_text()
+    assert "head_ce" not in text
+    assert ("f32", mock.ANY, mock.ANY) in _logit_sized(text, rows * T)
+    assert notes["fused_head"] == (
+        f"calls=1 rows={rows * T} vocab=50257 path=logits tiles=none "
+        "logits_at_rest=1")
+    assert compiled.memory_analysis().temp_size_in_bytes <= 995344896
+
+
+def test_data_parallel_step_runs_a_shards_rows_on_the_shard(
+        compiled_train_step):
+    """GPT-2 medium under ``--mesh data=4`` at cell 1's 16 rows a chip
+    (3.07 GiB of f32 logits a shard, over ``REST_BYTES``): each chip runs
+    its own rows through the head's two kernels, once each, inside a
+    per-shard region (XLA cannot partition a Mosaic kernel); the one
+    logit-sized value a chip holds is the bf16 cotangent, and the table's
+    gradient is summed with the others' in the step's all-reduces, no
+    more of them than the logits' step has (2).  On the chip this step
+    read 191,966 tokens/s where the parent's read 180,467 (``PERF.md``
+    section 6, PR 45)."""
+    compiled, notes, rows = compiled_train_step("gpt2m-train-data4")
+    text = compiled.as_text()
+    assert _logit_sized(text, rows * T) == [
+        ("bf16", "custom-call", mock.ANY)], _logit_sized(text, rows * T)
+    for kernel in ("head_ce_fwd", "head_ce_bwd"):
+        assert len(re.findall(rf"%{kernel}[.\d]* = ", text)) == 1, kernel
+    assert len(re.findall(r"= \S+ all-reduce\(", text)) == 2
+    assert notes["fused_head"] == (
+        f"calls=1 rows={rows * T} vocab=50257 path=pallas tiles=512x2048 "
+        "logits_at_rest=0")
+
+
+def test_one_chip_step_temporaries_fell(compiled_train_step):
+    """ISSUE 45 asked for cell 1's step under 12 GiB by memory analysis
+    and ``train_hbm_peak_gib`` 3 GiB lower: NOT MET (at 24 layers both the
+    parent's step and this one sit at the compiler's ceiling, 14.74 GiB:
+    the parent's by making the logits twice and 14 MLP products twice,
+    this one by making 3; ``PERF.md`` section 6), and left for the next
+    issue.  What this test holds is what did fall: at two layers, where
+    the compiler rematerialises nothing, 2.52 GiB of temporaries where the
+    parent's step held 4.05 (my AOT compiles, PR 45)."""
+    compiled, _, _ = compiled_train_step("gpt2m-train-1chip")
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.75 * 2 ** 30
+
+
+@pytest.mark.parametrize("workload,argv", [
+    ("bert", "-l 1 -s 64 -b 8 -m data"), ("resnet", "-b 8 -m data")])
+def test_a_step_on_arrays_names_no_head_kernel(workload, argv):
+    """A workload whose model hands its loss arrays traces the parent's
+    step: no kernel, no per-shard region, and the note says which."""
+    from distributed_deep_learning_tpu import obs
+    from distributed_deep_learning_tpu.runtime.mesh import build_mesh
+    from distributed_deep_learning_tpu.train.state import create_train_state
+    from distributed_deep_learning_tpu.utils.config import parse_args
+    from distributed_deep_learning_tpu.workloads import base, get_spec
+
+    config = parse_args(argv.split(), workload=workload)
+    spec = get_spec(workload)
+    ds = spec.build_dataset(config)
+    mesh = build_mesh({"data": 1}, jax.devices()[:1])
+    state = jax.eval_shape(lambda: create_train_state(
+        spec.build_model(config, ds), jax.random.key(0),
+        spec.example_input(config, ds),
+        base.build_optimizer(spec, config, 17)))
+    sspec = base.derive_state_spec(spec, config, mesh, state)
+    obs.compile_log.mark("test")
     with pytest.MonkeyPatch.context() as on_tpu:
         on_tpu.setattr(jax, "default_backend", lambda: "tpu")
-        _, traced = _trace_train_step(argv, v5e[:chips])
-    return traced.lower().compile().as_text()
+        train_step, _ = base.make_train_eval_steps(
+            config, mesh, spec.build_loss(config), sspec)
+        x, y = (jax.ShapeDtypeStruct((8, *a.shape[1:]), a.dtype)
+                for a in (ds.features, ds.targets))
+        text = train_step.lower(state, x, y).as_text()
+    assert "head_ce" not in text and "shard_map" not in text
+    notes = {event: note for event, fun, note in obs.compile_log.notes()
+             if fun == "jit(train_step)"}
+    assert notes["fused_head"] == "calls=0 path=logits logits_at_rest=1"
 
 
 @pytest.fixture(scope="module")

@@ -42,7 +42,8 @@ IMPORTS = {
         "obs", "utils",
         "data", "models", "train",   # UP: runtime.selftest trains an MLP (D14)
     },
-    "train": {"data", "obs", "runtime", "utils"},
+    "train": {"data", "obs", "ops",    # the token loss on deferred logits
+              "runtime", "utils"},
     "serve": {"models", "obs", "parallel", "reshard", "utils",
               "ops"},    # serve.engine asks the latent kernel what it reads a row
     "reshard": {"data", "models", "parallel", "runtime", "train", "tune",

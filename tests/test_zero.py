@@ -248,7 +248,7 @@ def test_flash_step_says_how_its_kernel_calls_tiled(tmp_path, mesh_shape):
     notes = obs.compile_log.notes()
     assert ("flash_layout", "jit(train_step)", said) in notes, notes
     assert sorted(n[0] for n in notes if n[1] == "jit(train_step)") == [
-        "batch_pins", "flash_layout"]
+        "batch_pins", "flash_layout", "fused_head"]
     text = subprocess.run(
         [sys.executable, os.path.join(repo, "scripts", "obs_report.py"),
          path], capture_output=True, text=True, check=True).stdout
